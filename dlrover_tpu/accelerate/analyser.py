@@ -129,8 +129,6 @@ def compiled_cost(fn, *args) -> Dict[str, float]:
     lowered = jax.jit(fn).lower(*args)
     compiled = lowered.compile()
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):  # older jax returns [dict]
-        cost = cost[0]
     return {
         "flops": float(cost.get("flops", -1.0)),
         "bytes_accessed": float(cost.get("bytes accessed", -1.0)),

@@ -33,6 +33,7 @@ from dlrover_tpu.accelerate.strategy import (
 )
 from dlrover_tpu.common.log import get_logger
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
+from dlrover_tpu.trainer.jax_env import enable_compile_cache
 from dlrover_tpu.trainer.step import (
     make_sharded_init,
     make_train_step,
@@ -307,41 +308,6 @@ def _build_for_strategy(
     return mesh, optimizer, init, step
 
 
-def enable_persistent_compile_cache(
-    cache_dir: Optional[str] = None,
-) -> str:
-    """Point XLA's persistent compilation cache at a directory.
-
-    Keyed by XLA on the optimized HLO + compile flags — i.e. exactly
-    (shapes, shardings, flags) — so strategy-search dry-runs that
-    recur across processes/sessions (and any candidate differing only
-    in knobs that don't change the program) hit disk instead of
-    recompiling. SURVEY §7 calls compile time the TPU-specific hard
-    part of the reference's 13-method combinatorial engine; this is
-    the standing mitigation. Returns the directory used.
-    """
-    import os
-
-    existing = jax.config.jax_compilation_cache_dir
-    if existing:
-        # The user already configured a cache (possibly a warm
-        # NFS/GCS path) — never clobber it, and leave their
-        # min-compile-time threshold alone.
-        return existing
-    if cache_dir is None:
-        cache_dir = os.path.join(
-            os.getenv("DLROVER_TPU_CACHE", "/tmp"),
-            "dlrover_tpu_xla_cache",
-        )
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    # Cache even fast compiles: search candidates are often small.
-    jax.config.update(
-        "jax_persistent_cache_min_compile_time_secs", 0.0
-    )
-    return cache_dir
-
-
 def _roofline_prior(
     model_init: Callable,
     model_loss: Callable,
@@ -355,24 +321,32 @@ def _roofline_prior(
     trace. None when the model cannot be traced abstractly.
     ``chip`` ranks for a NAMED target generation (utils/profiler.py
     peak tables) instead of whatever this host is — essential when
-    planning for a simulated topology from a CPU CI machine."""
+    planning for a simulated topology from a CPU CI machine. With no
+    chip named the attached TPU's peaks are used, and off a TPU there
+    is no prior: a ranking against peaks nobody chose is not one."""
+    from dlrover_tpu.utils.profiler import (
+        PEAK_HBM_GBPS,
+        PEAK_TFLOPS,
+        chip_generation,
+    )
+
+    chip = chip or chip_generation()
+    if chip is None:
+        logger.info(
+            "no roofline prior: no TPU attached and no chip= named "
+            "to plan for; seeding the search from the memory model"
+        )
+        return None
+    peaks = {
+        "peak_tflops": PEAK_TFLOPS[chip],
+        "peak_hbm_gbps": PEAK_HBM_GBPS[chip],
+    }
     try:
         from dlrover_tpu.utils.module_profiler import (
             predict_step_time,
             profile_modules,
             total_cost,
         )
-        from dlrover_tpu.utils.profiler import (
-            PEAK_HBM_GBPS,
-            PEAK_TFLOPS,
-        )
-
-        peaks = {}
-        if chip is not None:
-            peaks = {
-                "peak_tflops": PEAK_TFLOPS[chip],
-                "peak_hbm_gbps": PEAK_HBM_GBPS[chip],
-            }
 
         params_s = jax.eval_shape(model_init, jax.random.PRNGKey(0))
         tok, tgt = sample_batch
@@ -591,6 +565,7 @@ def auto_accelerate(
     excluded from the search.
     """
     devices = list(devices if devices is not None else jax.devices())
+    enable_compile_cache()
     if strategy is not None:
         mesh, optimizer, init, step = _build_for_strategy(
             strategy, model_init, model_loss, logical_axes,
@@ -606,7 +581,6 @@ def auto_accelerate(
             shard_batch_fn=lambda t, g: shard_batch(mesh, t, g),
         )
 
-    enable_persistent_compile_cache()
     analysis = analyse_model(model_init)
     if candidates is None:
         candidates = candidate_strategies(len(devices))

@@ -88,17 +88,7 @@ def save_attn_policy():
     def flash_fwd_saveable(prim, *_, **params):
         if prim.name != "pallas_call":
             return False
-        # jax <= 0.4.33 exposes the kernel name as params["name"];
-        # 0.4.34+ wraps it in params["name_and_src_info"].name. Check
-        # both, or the policy silently degrades to "full" (the fwd
-        # kernel re-traces) on one side of the version line — caught
-        # by the jaxpr-structural test in tests/test_remat_policies.py.
-        name = params.get("name")
-        if name is None:
-            name = getattr(
-                params.get("name_and_src_info"), "name", None
-            )
-        return name == "flash_attention_fwd"
+        return params.get("name") == "flash_attention_fwd"
 
     return jax.checkpoint_policies.save_from_both_policies(
         full_policy(), flash_fwd_saveable

@@ -29,7 +29,10 @@ from typing import Deque, Dict, List, Optional
 from dlrover_tpu import obs
 from dlrover_tpu.agent.master_client import MasterClient
 from dlrover_tpu.common.comm import find_free_port
-from dlrover_tpu.common.config import ensure_framework_on_pythonpath
+from dlrover_tpu.common.config import (
+    ensure_framework_on_pythonpath,
+    tmp_path,
+)
 from dlrover_tpu.common.constants import (
     EventAction,
     NodeAction,
@@ -631,9 +634,8 @@ class ElasticAgent:
 
         from dlrover_tpu.agent.ckpt_saver import AsyncCheckpointSaver
 
-        default_dir = _os.path.join(
-            "/tmp",
-            f"dlrover_tpu_ckpt_{_os.getenv('DLROVER_TPU_JOB_NAME', 'job')}",
+        default_dir = tmp_path(
+            f"dlrover_tpu_ckpt_{_os.getenv('DLROVER_TPU_JOB_NAME', 'job')}"
         )
         saver = AsyncCheckpointSaver.start_async_saving_ckpt(
             checkpoint_dir=default_dir,

@@ -4,11 +4,12 @@ Parity with the reference's agent monitors
 (dlrover/python/elastic_agent/monitor/resource.py:90 ResourceMonitor —
 psutil + pynvml telemetry pushed to the master; monitor/training.py:79
 TorchTrainingMonitor — global-step reports feeding the master's speed
-monitor). TPU adaptation: chip telemetry comes from JAX's
-``local_devices()[i].memory_stats()`` (HBM in use) instead of pynvml,
-and the training side reads the metrics file the trainer process
-writes (same file-drop mechanism as the reference's
-ConfigPath.RUNTIME_METRICS).
+monitor). TPU adaptation: a chip belongs to one process, the trainer,
+so the agent never asks JAX for anything. Chip telemetry (HBM in
+use, from ``local_devices()[i].memory_stats()``) is sampled by the
+trainer and rides the metrics file it writes anyway (same file-drop
+mechanism as the reference's ConfigPath.RUNTIME_METRICS), which the
+agent side reads.
 """
 
 from __future__ import annotations
@@ -17,11 +18,13 @@ import collections
 import json
 import os
 import socket
+import sys
 import threading
 import time
 from typing import Dict, Optional
 
 from dlrover_tpu import obs
+from dlrover_tpu.common.config import tmp_path
 from dlrover_tpu.common.log import get_logger
 from dlrover_tpu.obs import beacon as beacon_mod
 
@@ -47,15 +50,28 @@ def default_metrics_file() -> str:
     default_config_file): two jobs on one host must not cross-talk the
     hang detector and step/speed reports."""
     job = os.getenv("DLROVER_TPU_JOB_NAME", "default")
-    return f"/tmp/dlrover_tpu_train_metrics_{job}.json"
+    return tmp_path(f"dlrover_tpu_train_metrics_{job}.json")
 
 
-def current_resource_stats() -> dict:
-    """One sample of host + TPU utilization."""
+def _read_metrics_file(path: str) -> dict:
+    try:
+        with open(path) as f:
+            data = json.load(f)
+    except (OSError, ValueError):
+        return {}
+    return data if isinstance(data, dict) else {}
+
+
+def current_resource_stats(trainer_metrics: Optional[dict] = None) -> dict:
+    """One sample of host utilization, plus the HBM in use as the
+    trainer last wrote it to its metrics file (``trainer_metrics``,
+    that file's content; 0 without one)."""
     stats = {
         "cpu_percent": 0.0,
         "memory_mb": 0,
-        "hbm_used_gb": 0.0,
+        "hbm_used_gb": float(
+            (trainer_metrics or {}).get("hbm_used_gb", 0.0)
+        ),
         "duty_cycle": 0.0,
     }
     try:
@@ -67,17 +83,20 @@ def current_resource_stats() -> dict:
         )
     except Exception:  # noqa: BLE001 — psutil optional
         pass
-    try:
-        import jax
-
-        hbm = 0
-        for dev in jax.local_devices():
-            ms = dev.memory_stats() or {}
-            hbm += ms.get("bytes_in_use", 0)
-        stats["hbm_used_gb"] = hbm / (1 << 30)
-    except Exception:  # noqa: BLE001 — no device / not initialized
-        pass
     return stats
+
+
+def _local_hbm_used_gb() -> Optional[float]:
+    """HBM in use over this process's devices — for the TRAINING
+    process, which owns them. None where jax is not loaded or the
+    backend reports no memory stats (the CPU)."""
+    jax = sys.modules.get("jax")
+    if jax is None or not jax._src.xla_bridge.backends_are_initialized():
+        return None
+    stats = [d.memory_stats() for d in jax.local_devices()]
+    if not all(stats):
+        return None
+    return sum(ms.get("bytes_in_use", 0) for ms in stats) / (1 << 30)
 
 
 class ResourceMonitor:
@@ -158,12 +177,7 @@ class ResourceMonitor:
         self._stop.set()
 
     def _read_trainer_metrics(self) -> dict:
-        try:
-            with open(self.metrics_file) as f:
-                data = json.load(f)
-        except (OSError, ValueError):
-            return {}
-        return data if isinstance(data, dict) else {}
+        return _read_metrics_file(self.metrics_file)
 
     def _new_step_times(self, data: dict) -> list:
         step = int(data.get("step", -1))
@@ -269,8 +283,8 @@ class ResourceMonitor:
     def build_snapshot(self, stats: Optional[dict] = None) -> dict:
         """The MetricsSnapshotReport payload (sans node_id), exposed
         for tests and for trainers that report their own registry."""
-        resource = dict(stats or current_resource_stats())
         data = self._read_trainer_metrics()
+        resource = dict(stats or current_resource_stats(data))
         tps = self._tokens_per_s(data)
         if tps is not None:
             resource["tokens_per_s"] = tps
@@ -319,7 +333,7 @@ class ResourceMonitor:
         return True
 
     def report_once(self) -> dict:
-        stats = current_resource_stats()
+        stats = current_resource_stats(self._read_trainer_metrics())
         try:
             self.client.report_resource(**stats)
         except Exception:  # noqa: BLE001
@@ -399,6 +413,9 @@ class TrainingMonitor:
         }
         if mfu is not None and mfu > 0:
             data["mfu"] = round(float(mfu), 6)
+        hbm = _local_hbm_used_gb()
+        if hbm is not None:
+            data["hbm_used_gb"] = round(hbm, 4)
         tmp = f"{path}.tmp"
         with open(tmp, "w") as f:
             json.dump(data, f)

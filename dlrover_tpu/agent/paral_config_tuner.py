@@ -17,6 +17,7 @@ import os
 import threading
 from typing import Optional
 
+from dlrover_tpu.common.config import tmp_path
 from dlrover_tpu.common.log import get_logger
 
 logger = get_logger("paral_tuner")
@@ -28,7 +29,7 @@ def default_config_file() -> str:
     """Job-scoped path: a leftover file from another job on the same
     host must not leak its tuning into this one."""
     job = os.getenv("DLROVER_TPU_JOB_NAME", "default")
-    return f"/tmp/dlrover_tpu_paral_config_{job}.json"
+    return tmp_path(f"dlrover_tpu_paral_config_{job}.json")
 
 
 class ParalConfigTuner:

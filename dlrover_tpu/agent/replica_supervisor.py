@@ -86,12 +86,14 @@ class ReplicaSupervisor:
         env = ensure_framework_on_pythonpath(
             dict(self._env if self._env is not None else os.environ)
         )
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # The replica takes the platform JAX finds (the chip, on a TPU
+        # host; a test that wants the CPU says so in ``env``), and its
+        # stderr is this process's: a replica that cannot reach its
+        # device must be seen failing.
         self.proc = subprocess.Popen(
             self._command(),
             env=env,
             stdout=subprocess.DEVNULL,
-            stderr=subprocess.DEVNULL,
         )
         obs.event(
             "serve.replica_spawn",

@@ -7,6 +7,7 @@ come from env vars first, then master-pushed overrides.
 from __future__ import annotations
 
 import os
+import tempfile
 import threading
 from typing import Any, Dict, Optional
 
@@ -45,6 +46,33 @@ def ensure_framework_on_pythonpath(env: Dict[str, str]) -> Dict[str, str]:
     if pkg_root not in parts:
         env["PYTHONPATH"] = os.pathsep.join([pkg_root] + parts)
     return env
+
+
+def cache_dir(name: str) -> str:
+    """``<checkout>/.cache/<name>``, created: where the program keeps
+    what it builds (XLA's compile cache, the native kv-store object).
+    A fixed, git-ignored path inside the checkout: the compile
+    cache's key includes its directory, so one that moves — a temp
+    dir, a pid, a timestamp — never hits."""
+    import dlrover_tpu
+
+    path = os.path.join(
+        os.path.dirname(
+            os.path.dirname(os.path.abspath(dlrover_tpu.__file__))
+        ),
+        ".cache",
+        name,
+    )
+    os.makedirs(path, exist_ok=True)
+    return path
+
+
+def tmp_path(name: str) -> str:
+    """``name`` under the temp directory the environment names
+    (``TMPDIR``): where a job's per-host scratch files default to.
+    Never a literal ``/tmp``, so a run given a temp directory of its
+    own writes nowhere else."""
+    return os.path.join(tempfile.gettempdir(), name)
 
 
 def env_bool(name: str, default: bool = False) -> bool:

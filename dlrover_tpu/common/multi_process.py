@@ -26,11 +26,14 @@ from typing import Any, Dict, Optional
 
 import msgpack
 
+from dlrover_tpu.common.config import tmp_path
 from dlrover_tpu.common.log import get_logger
 
 logger = get_logger("ipc")
 
-SOCKET_DIR = os.getenv("DLROVER_TPU_SOCK_DIR", "/tmp/dlrover_tpu_sock")
+SOCKET_DIR = os.getenv(
+    "DLROVER_TPU_SOCK_DIR", tmp_path("dlrover_tpu_sock")
+)
 
 
 def _socket_path(name: str) -> str:

@@ -43,6 +43,8 @@ import os
 import time
 from typing import Callable, Optional, Tuple
 
+from dlrover_tpu.common.config import tmp_path
+
 BEACON_FILE_ENV = "DLROVER_TPU_BEACON_FILE"
 BEACON_ENABLE_ENV = "DLROVER_TPU_BEACON"
 
@@ -71,7 +73,7 @@ def beacon_file() -> str:
     on one host must not read each other's progress)."""
     job = os.getenv("DLROVER_TPU_JOB_NAME", "default")
     return os.getenv(
-        BEACON_FILE_ENV, f"/tmp/dlrover_tpu_beacon_{job}.json"
+        BEACON_FILE_ENV, tmp_path(f"dlrover_tpu_beacon_{job}.json")
     )
 
 

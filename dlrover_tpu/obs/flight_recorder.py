@@ -34,7 +34,7 @@ master/servicer.py. ``tools/obs_report.py --postmortem <dir>`` renders
 the bundles (obs/postmortem.py).
 
 Knobs: ``DLROVER_TPU_FORENSICS_DIR`` (default
-``/tmp/dlrover_tpu_forensics_<job>``), ``DLROVER_TPU_FLIGHT_RECORDER=0``
+``$TMPDIR/dlrover_tpu_forensics_<job>``), ``DLROVER_TPU_FLIGHT_RECORDER=0``
 disables installation, ``DLROVER_TPU_FORENSICS_KEEP`` bounds retained
 bundles per process (default 8, oldest deleted first).
 """
@@ -53,6 +53,8 @@ import threading
 import time
 import traceback
 from typing import Any, Dict, List, Optional
+
+from dlrover_tpu.common.config import tmp_path
 
 FORENSICS_DIR_ENV = "DLROVER_TPU_FORENSICS_DIR"
 FLIGHT_RECORDER_ENV = "DLROVER_TPU_FLIGHT_RECORDER"
@@ -74,7 +76,7 @@ def forensics_dir() -> str:
     if configured:
         return configured
     job = os.getenv("DLROVER_TPU_JOB_NAME", "default")
-    return f"/tmp/dlrover_tpu_forensics_{job}"
+    return tmp_path(f"dlrover_tpu_forensics_{job}")
 
 
 def stacks_file_path(pid: Optional[int] = None,
@@ -140,16 +142,16 @@ def _process_info() -> dict:
         "platform": platform.platform(),
         "cwd": os.getcwd(),
     }
-    # NEVER import jax here (a crash handler must not initialize a
-    # backend); report its platform only if the process already did.
+    # NEVER import jax or initialize a backend here: the agent's crash
+    # handler would take the chip from the trainer it supervises.
+    # Report the platform only if this process already holds one.
     jax = sys.modules.get("jax")
-    if jax is not None:
-        try:
-            info["jax_platform"] = jax.default_backend()
-        except Exception:  # noqa: BLE001 — backend init failed/raced
-            info["jax_platform"] = "error"
-    else:
+    if jax is None:
         info["jax_platform"] = "not_imported"
+    elif not jax._src.xla_bridge.backends_are_initialized():
+        info["jax_platform"] = "not_initialized"
+    else:
+        info["jax_platform"] = jax.default_backend()
     return info
 
 

@@ -35,12 +35,14 @@ accounting and the capture protocol stay hermetically testable.
 from __future__ import annotations
 
 import collections
+import functools
 import json
 import os
 import threading
 import time
 from typing import Callable, Dict, List, Optional
 
+from dlrover_tpu.common.config import tmp_path
 from dlrover_tpu.common.log import get_logger
 from dlrover_tpu.obs.beacon import ProgressBeacon, default_beacon
 from dlrover_tpu.obs.metrics import counter, gauge
@@ -102,7 +104,7 @@ _PROFILE_CAPTURES = counter(
 
 def _job_scoped(name: str) -> str:
     job = os.getenv("DLROVER_TPU_JOB_NAME", "default")
-    return f"/tmp/dlrover_tpu_{name}_{job}.json"
+    return tmp_path(f"dlrover_tpu_{name}_{job}.json")
 
 
 def profile_request_file() -> str:
@@ -165,25 +167,23 @@ def read_profile_digest(
     return digest
 
 
-def peak_flops_per_s() -> float:
-    """The chip's peak FLOP/s for the MFU denominator.
+def peak_flops_per_s() -> Optional[float]:
+    """The chip's peak FLOP/s for the MFU denominator; None off a
+    TPU, where a utilisation means nothing and the gauge stays unset.
 
-    ``DLROVER_TPU_PEAK_TFLOPS`` overrides (tests, exotic backends);
-    otherwise the generation table in utils/profiler resolves the
-    live device kind. Never raises — an unknown backend falls back to
-    the v5e figure so the gauge stays a ranking, not a crash."""
+    ``DLROVER_TPU_PEAK_TFLOPS`` overrides (tests); otherwise the
+    generation table in utils/profiler resolves the live device kind,
+    and a TPU that is not in it is an error."""
     env = os.getenv(PEAK_TFLOPS_ENV, "")
     if env:
         try:
             return float(env) * 1e12
         except ValueError:
             logger.warning("unparseable %s=%r", PEAK_TFLOPS_ENV, env)
-    try:
-        from dlrover_tpu.utils.profiler import chip_peaks
+    from dlrover_tpu.utils.profiler import _device_peak_tflops
 
-        return chip_peaks()[0] * 1e12
-    except Exception:  # noqa: BLE001 — no jax / no device
-        return 197.0e12
+    peak = _device_peak_tflops()
+    return None if peak is None else peak * 1e12
 
 
 def step_flops(jfn, *args) -> Optional[float]:
@@ -195,8 +195,6 @@ def step_flops(jfn, *args) -> Optional[float]:
     Returns None when the backend can't price the module."""
     try:
         cost = jfn.lower(*args).cost_analysis()
-        if isinstance(cost, list):
-            cost = cost[0] if cost else {}
         flops = float(cost.get("flops", 0.0))
         return flops if flops > 0 else None
     except Exception:  # noqa: BLE001 — backend-dependent analysis
@@ -274,16 +272,17 @@ class MfuMeter:
         peak_flops: Optional[float] = None,
         window: int = 32,
     ):
-        self._peak = peak_flops  # resolved lazily (may import jax)
+        self._peak = peak_flops  # None = ask the device, lazily
         self.flops_per_step: Optional[float] = None
         self._times: collections.deque = collections.deque(maxlen=window)
         self.mfu: Optional[float] = None
 
-    @property
-    def peak(self) -> float:
-        if self._peak is None:
-            self._peak = peak_flops_per_s()
-        return self._peak
+    @functools.cached_property
+    def peak(self) -> Optional[float]:
+        """Peak FLOP/s (imports jax); None off a TPU: no MFU there."""
+        if self._peak is not None:
+            return self._peak
+        return peak_flops_per_s()
 
     def set_flops(self, flops_per_step: Optional[float]) -> None:
         if not flops_per_step or flops_per_step <= 0:
@@ -299,7 +298,7 @@ class MfuMeter:
         if self.flops_per_step is None or not self._times:
             return None
         mean = sum(self._times) / len(self._times)
-        if mean <= 0:
+        if mean <= 0 or self.peak is None:
             return None
         self.mfu = self.flops_per_step / (mean * self.peak)
         _MFU.set(self.mfu)

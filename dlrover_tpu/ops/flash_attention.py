@@ -22,9 +22,15 @@ one Pallas kernel family designed for the MXU:
 * backward is recompute-based (flash-attn v2 style) but FUSED: one
   kernel computes dq, dk and dv in a single sweep, recomputing p once
   per (kv, q) block pair instead of once per output operand. dk/dv
-  accumulate in block scratch; dq accumulates in a full-sequence VMEM
-  scratch (seq * head_dim * 4B — 256KB at 1k context, still only 8MB
-  at 32k) flushed once at the end of each (batch, head) slice.
+  accumulate in block scratch; dq accumulates in a full-sequence f32
+  VMEM scratch flushed once at the end of each (batch, head) slice
+  into a full-sequence output block, which Pallas double-buffers. The
+  head dim pads to 128 lanes, so dq costs seq * 128 * (4 + 2*2) bytes
+  in bf16: 1 MiB at 1k context, 8 MiB at 8k, 32 MiB at 32k. With the
+  per-block operands that passes Mosaic's 16 MiB default scoped-VMEM
+  budget between 4k and 8k (the v5e compiler reports 16.04 MiB at 8k,
+  D=128), so the backward declares what it needs
+  (``_bwd_vmem_limit``) out of the chip's 128 MiB.
   delta = rowsum(dO * O) is precomputed by XLA.
 
 On non-TPU backends kernels run in interpreter mode so the same code
@@ -41,6 +47,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
 
@@ -49,16 +56,82 @@ def _use_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _compiler_params(semantics):
-    # Newer pallas spells it CompilerParams; 0.4.x-era jaxlib (this
-    # container) still calls it TPUCompilerParams.
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams"
+# The mesh axes that split an activation's batch rows and its heads
+# (parallel/sharding.py DEFAULT_RULES: "batch" and "heads").
+_BATCH_AXES = ("data", "fsdp")
+_HEAD_AXIS = "tensor"
+
+
+def per_device(call, *operands, split, heads_dim=None):
+    """``call(*operands)``, run once per device of the mesh the trace
+    is under.
+
+    A Pallas kernel is a Mosaic custom call, and XLA refuses to
+    partition one ("Mosaic kernels cannot be automatically
+    partitioned. Please wrap the call in a shard_map"). The kernels
+    of this package treat every batch row, and the attention ones
+    every head, on its own, so under an ambient mesh (``jax.set_mesh``
+    or the step builders' ``parallel.mesh.under_mesh``) the call goes
+    through ``shard_map``: dim 0 of each operand flagged in ``split``
+    over the batch axes, dim ``heads_dim`` over ``tensor``, the other
+    operands (weights) and dims whole on every device; outputs are
+    split like the operands, and autodiff sums the weights'
+    gradients over the mesh. An axis that does not divide its dim is
+    left out, and XLA gathers that dim instead.
+
+    With no ambient mesh or one device it is a plain call, and so it
+    is inside somebody else's ``shard_map`` (ring attention, the
+    overlapped-reduce steps), where the operands already are one
+    device's blocks."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1 or mesh.manual_axes:
+        return call(*operands)
+    shape = operands[split.index(True)].shape
+    batch, rows = [], shape[0]
+    for axis in _BATCH_AXES:
+        n = mesh.shape.get(axis, 1)
+        if n > 1 and rows % n == 0:
+            batch.append(axis)
+            rows //= n
+    dims = [tuple(batch) or None]
+    if heads_dim is not None:
+        n = mesh.shape.get(_HEAD_AXIS, 1)
+        heads = n > 1 and shape[heads_dim] % n == 0
+        dims += [None] * (heads_dim - 1)
+        dims.append(_HEAD_AXIS if heads else None)
+    if all(d is None for d in dims):
+        return call(*operands)
+    spec = P(*dims)
+    return jax.shard_map(
+        call,
+        in_specs=tuple(spec if s else P() for s in split),
+        out_specs=spec,
+        check_vma=False,
+    )(*operands)
+
+
+# Mosaic's default scoped-VMEM budget for one kernel on a v5e; a
+# kernel that needs more must say so in its compiler parameters.
+_DEFAULT_SCOPED_VMEM = 16 << 20
+
+
+def _bwd_vmem_limit(tq, d, itemsize, block_q, block_k):
+    """``vmem_limit_bytes`` for the fused backward, or None while the
+    default budget covers it. The kernel holds the whole sequence's
+    dq twice over — the f32 accumulator plus the double-buffered
+    output block — on top of its per-block operands, so its footprint
+    grows with Tq: the v5e compiler reports 16.04 MiB at T=8192,
+    D=128 with 1024x1024 blocks, 36 KiB over the default."""
+    dpad = -(-d // 128) * 128  # the minor dim pads to the lane width
+    dq = tq * dpad * (4 + 2 * itemsize)
+    operands = (
+        2 * 2 * (block_q + 2 * block_k) * dpad * itemsize  # q do k v dk dv
+        + 2 * 2 * block_q * 128 * 4  # lse, delta: one column, lane-padded
+        + 2 * block_k * dpad * 4  # dk/dv accumulators
     )
-    try:
-        return cls(dimension_semantics=semantics)
-    except TypeError:  # older/newer API without dimension_semantics
-        return cls()
+    spill = 2 * block_q * block_k * 4  # f32 score tiles Mosaic spills
+    need = dq + operands + spill
+    return need if need > _DEFAULT_SCOPED_VMEM else None
 
 
 def _block_mask(iq, jk, block_q, block_k, causal, seq_len, pad,
@@ -280,8 +353,10 @@ def _fwd(q, k, v, causal, window, scale, block_q, block_k, seq_len,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=_compiler_params(
-            ("parallel", "parallel", "parallel", "arbitrary")
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=(
+                "parallel", "parallel", "parallel", "arbitrary"
+            )
         ),
         interpret=interpret,
         # Stable identity for jax.checkpoint policies: the save_attn
@@ -465,8 +540,13 @@ def _bwd(
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=_compiler_params(
-            ("parallel", "parallel", "arbitrary", "arbitrary")
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=(
+                "parallel", "parallel", "arbitrary", "arbitrary"
+            ),
+            vmem_limit_bytes=_bwd_vmem_limit(
+                tq, d, q.dtype.itemsize, block_q, block_k
+            ),
         ),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
@@ -548,6 +628,17 @@ def _flash_lse_bwd(causal, window, scale, block_q, block_k,
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
+
+
+def _per_device_flash(flash, q, k, v, static):
+    """``_flash``/``_flash_lse`` on [B, H, T, D] operands, batch rows
+    and heads split over the ambient mesh (:func:`per_device`). The
+    whole custom_vjp sits inside the shard_map, so the backward
+    kernel is split with it."""
+    return per_device(
+        lambda q, k, v: flash(q, k, v, *static),
+        q, k, v, split=(True, True, True), heads_dim=1,
+    )
 
 
 def _check_block_chain(blocks, t: int) -> int:
@@ -697,17 +788,15 @@ def flash_attention(
         return x
 
     qk, kk, vk = map(to_kernel_layout, (q, k, v))
+    static = (
+        causal, window, scale, block_q, block_k, block_q_bwd,
+        block_k_bwd, t, interpret,
+    )
     if return_lse:
-        o, lse = _flash_lse(
-            qk, kk, vk, causal, window, scale, block_q, block_k,
-            block_q_bwd, block_k_bwd, t, interpret,
-        )
+        o, lse = _per_device_flash(_flash_lse, qk, kk, vk, static)
         o = o[:, :, :t].transpose(0, 2, 1, 3)
         return o.astype(q.dtype), lse[:, :, :t, 0]
-    o = _flash(
-        qk, kk, vk, causal, window, scale, block_q, block_k,
-        block_q_bwd, block_k_bwd, t, interpret,
-    )
+    o = _per_device_flash(_flash, qk, kk, vk, static)
     o = o[:, :, :t].transpose(0, 2, 1, 3)
     return o.astype(q.dtype)
 
@@ -820,17 +909,15 @@ def flash_attention_rect(
 
     qk = to_kernel(q, pad_q)
     kk_, vk = to_kernel(k, pad_k), to_kernel(v, pad_k)
+    static = (
+        causal, window, scale, bq, bk, bqb, bkb, tk0, interpret,
+        q_offset,
+    )
     if return_lse:
-        o, lse = _flash_lse(
-            qk, kk_, vk, causal, window, scale, bq, bk, bqb, bkb,
-            tk0, interpret, q_offset,
-        )
+        o, lse = _per_device_flash(_flash_lse, qk, kk_, vk, static)
         o = o[:, :, :tq0].transpose(0, 2, 1, 3)
         return o.astype(q.dtype), lse[:, :, :tq0, 0]
-    o = _flash(
-        qk, kk_, vk, causal, window, scale, bq, bk, bqb, bkb,
-        tk0, interpret, q_offset,
-    )
+    o = _per_device_flash(_flash, qk, kk_, vk, static)
     return o[:, :, :tq0].transpose(0, 2, 1, 3).astype(q.dtype)
 
 

@@ -33,11 +33,9 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from dlrover_tpu.ops.flash_attention import (
-    _compiler_params,
-    _use_interpret,
-)
+from dlrover_tpu.ops.flash_attention import _use_interpret, per_device
 
 DEFAULT_BLOCK_ROWS = 256
 # Per-ref VMEM budget for a [block_rows, E] f32 block. The backward
@@ -177,7 +175,9 @@ def _fwd(x2, res2, g, b, *, eps, rms, block_rows, interpret):
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=_compiler_params(("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
         interpret=interpret,
     )(*inputs)
     if add_residual:
@@ -262,7 +262,9 @@ def _bwd(dout2, resid2, g, mu, rstd, *, rms, has_bias, block_rows,
         in_specs=[row_spec, row_spec, gb_spec, stat_spec, stat_spec],
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=_compiler_params(("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
         interpret=interpret,
     )(dout2, resid2, g.reshape(1, e), mu, rstd)
     dx = outs[0][:n]
@@ -354,6 +356,18 @@ def _add_norm_bwd(eps, rms, block_rows, interpret, saved, cots):
 _add_norm.defvjp(_add_norm_fwd, _add_norm_bwd)
 
 
+def _norm_per_device(norm, activations, weights, static):
+    """``_norm``/``_add_norm`` with the batch rows of the activations
+    split over the ambient mesh and the weights whole on every device
+    (flash_attention.per_device); a missing bias stays ``None``."""
+    n = len(activations)
+    return per_device(
+        lambda *ops: norm(*ops, *static),
+        *activations, *weights,
+        split=(True,) * n + (False,) * len(weights),
+    )
+
+
 def fused_layer_norm(
     x: jax.Array,
     g: jax.Array,
@@ -369,7 +383,9 @@ def fused_layer_norm(
         interpret = _use_interpret()
     if block_rows is None:
         block_rows = pick_block_rows(x.shape[-1])
-    return _norm(x, g, b, eps, False, block_rows, interpret)
+    return _norm_per_device(
+        _norm, (x,), (g, b), (eps, False, block_rows, interpret)
+    )
 
 
 def fused_rms_norm(
@@ -384,7 +400,9 @@ def fused_rms_norm(
         interpret = _use_interpret()
     if block_rows is None:
         block_rows = pick_block_rows(x.shape[-1])
-    return _norm(x, g, None, eps, True, block_rows, interpret)
+    return _norm_per_device(
+        _norm, (x,), (g, None), (eps, True, block_rows, interpret)
+    )
 
 
 def fused_add_layer_norm(
@@ -406,8 +424,9 @@ def fused_add_layer_norm(
         interpret = _use_interpret()
     if block_rows is None:
         block_rows = pick_block_rows(x.shape[-1])
-    return _add_norm(
-        x, residual, g, b, eps, False, block_rows, interpret
+    return _norm_per_device(
+        _add_norm, (x, residual), (g, b),
+        (eps, False, block_rows, interpret),
     )
 
 
@@ -424,6 +443,7 @@ def fused_add_rms_norm(
         interpret = _use_interpret()
     if block_rows is None:
         block_rows = pick_block_rows(x.shape[-1])
-    return _add_norm(
-        x, residual, g, None, eps, True, block_rows, interpret
+    return _norm_per_device(
+        _add_norm, (x, residual), (g, None),
+        (eps, True, block_rows, interpret),
     )

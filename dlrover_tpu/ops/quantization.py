@@ -29,13 +29,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dlrover_tpu.ops.flash_attention import _use_interpret
+
 DEFAULT_BLOCK = 1024
 # Rows of blocks processed per kernel grid step (sublane packing).
 _ROWS = 8
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------

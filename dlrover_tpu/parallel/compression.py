@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from dlrover_tpu.parallel.shard_map_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 # Deliberately the jnp (_ref) quantizers, NOT the Pallas kernels:

@@ -16,8 +16,9 @@ fastest across physically-adjacent chips, so put bandwidth-hungry axes
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -185,6 +186,31 @@ def build_mesh(
         len(devices),
     )
     return mesh
+
+
+def under_mesh(fn: Callable, mesh: Mesh) -> Callable:
+    """``fn``, traced with ``mesh`` as the ambient mesh.
+
+    jit learns the mesh from its arguments' shardings, after the
+    trace; code that must know it DURING the trace reads the ambient
+    one. The Pallas kernels do (ops/flash_attention.py
+    ``per_device``): XLA cannot partition a Mosaic call, so under a
+    mesh of several devices they split themselves over batch rows
+    and heads. The step builders wrap the loss in this, so a bare
+    model loss compiles on any mesh; one device needs nothing, and a
+    trace that already has a mesh (a caller's ``jax.set_mesh``, the
+    inside of a ``shard_map``) keeps its own."""
+    if mesh.size == 1:
+        return fn
+
+    @functools.wraps(fn)
+    def traced(*args, **kwargs):
+        if not jax.sharding.get_abstract_mesh().empty:
+            return fn(*args, **kwargs)
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return fn(*args, **kwargs)
+
+    return traced
 
 
 def mesh_slice_blocks(mesh: Mesh, num_slices: int) -> List[List]:

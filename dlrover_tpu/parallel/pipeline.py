@@ -23,7 +23,7 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from dlrover_tpu.parallel.shard_map_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

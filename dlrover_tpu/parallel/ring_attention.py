@@ -22,7 +22,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from dlrover_tpu.parallel.shard_map_compat import shard_map
+from jax import shard_map
+
+from dlrover_tpu.parallel.mesh import under_mesh
 
 
 def _block_attn(q, k, v, scale, mask):
@@ -376,9 +378,14 @@ def make_sharded_attention(
         if use_flash:
             from dlrover_tpu.ops.flash_attention import flash_attention
 
+            # XLA cannot partition the Mosaic call; traced under the
+            # mesh the kernel splits itself over batch and heads.
             return _expand_kv_wrapper(
-                functools.partial(
-                    flash_attention, causal=causal, window=window
+                under_mesh(
+                    functools.partial(
+                        flash_attention, causal=causal, window=window
+                    ),
+                    mesh,
                 )
             )
 

@@ -22,10 +22,10 @@ import ctypes
 import hashlib
 import os
 import subprocess
-import tempfile
 import threading
 from typing import Dict, Optional, Tuple
 
+from dlrover_tpu.common.config import cache_dir
 from dlrover_tpu.common.log import get_logger
 
 logger = get_logger("kv_variable")
@@ -45,12 +45,7 @@ def _build_library() -> str:
     """Compile kv_store.cc to a cached .so keyed by source hash."""
     with open(_SRC, "rb") as f:
         digest = hashlib.sha256(f.read()).hexdigest()[:16]
-    cache_dir = os.path.join(
-        os.getenv("DLROVER_TPU_CACHE", tempfile.gettempdir()),
-        "dlrover_tpu_native",
-    )
-    os.makedirs(cache_dir, exist_ok=True)
-    so_path = os.path.join(cache_dir, f"kv_store_{digest}.so")
+    so_path = os.path.join(cache_dir("native"), f"kv_store_{digest}.so")
     if os.path.exists(so_path):
         return so_path
     tmp = so_path + f".build{os.getpid()}"

@@ -51,7 +51,8 @@ def parse_args(argv=None):
         "--nproc_per_node",
         type=int,
         default=0,
-        help="local chips per node (0 = autodetect jax.local_devices)",
+        help="local chips per node (0 = ask jax.local_devices() in a "
+        "child process that exits before training starts)",
     )
     parser.add_argument("--node_rank", type=int, default=-1)
     parser.add_argument("--node_unit", type=int, default=1)
@@ -160,22 +161,32 @@ def _launch_local_master(
 
 
 def _local_chip_count() -> int:
-    try:
-        import jax
+    """``len(jax.local_devices())``, asked in a short-lived child.
 
-        # Honor an explicit JAX_PLATFORMS=cpu even when a TPU plugin
-        # preregistered itself (the env var alone loses to a
-        # registered backend; same dance as jax_env.setup_distributed)
-        # — otherwise this device query would try to reach a TPU the
-        # caller explicitly opted out of.
-        if os.getenv("JAX_PLATFORMS", "") == "cpu":
-            try:
-                jax.config.update("jax_platforms", "cpu")
-            except Exception:  # noqa: BLE001 — already initialized
-                pass
-        return len(jax.local_devices())
-    except Exception:  # noqa: BLE001
-        return 1
+    This process must never initialise a JAX backend: a chip belongs
+    to one process at a time, and the agent that runs here would hold
+    it against the trainer it spawns. The child has exited, and let
+    go of the chips, before this returns."""
+    from dlrover_tpu.common.config import ensure_framework_on_pythonpath
+
+    result = subprocess.run(
+        [
+            sys.executable,
+            "-c",
+            "import jax; print(len(jax.local_devices()))",
+        ],
+        env=ensure_framework_on_pythonpath(dict(os.environ)),
+        stdout=subprocess.PIPE,
+        stderr=subprocess.PIPE,
+        text=True,
+        timeout=300,
+    )
+    if result.returncode != 0:
+        raise SystemExit(
+            "could not count the local chips (pass --nproc_per_node "
+            f"to skip the query):\n{result.stderr[-2000:]}"
+        )
+    return int(result.stdout.split()[-1])
 
 
 def run(args) -> int:

@@ -43,9 +43,10 @@ from dlrover_tpu.obs.profiling import (
     StepPhaseProfiler,
     step_flops,
 )
+from dlrover_tpu.parallel.mesh import under_mesh
 from dlrover_tpu.parallel.sharding import prune_specs_to_mesh
 from dlrover_tpu.trainer.async_metrics import AsyncScalarReporter
-from dlrover_tpu.trainer.step import batch_spec
+from dlrover_tpu.trainer.step import StateStep, batch_spec
 
 logger = get_logger("elastic_trainer")
 
@@ -391,9 +392,9 @@ class ElasticTrainer:
 
     def _build_step(self):
         accum = self.accum_steps
-        loss_fn = self.loss_fn
         optimizer = self.optimizer
         mesh = self.mesh
+        loss_fn = under_mesh(self.loss_fn, mesh)
         bspec = batch_spec(mesh)
         # Microbatch dim leads: [accum, per_shard_batch, ...]
         mb_spec = P(None, *bspec)
@@ -435,7 +436,7 @@ class ElasticTrainer:
             return params, opt_state, loss_sum / accum
 
         self._mb_spec = mb_spec
-        return jax.jit(train_step, donate_argnums=self._donate_argnums())
+        return StateStep(train_step, self._donate_argnums())
 
     def _build_overlapped_step(self):
         """The overlap_reduce variant of :meth:`_build_step`: same
@@ -455,7 +456,7 @@ class ElasticTrainer:
             bucketed_psum_mean,
             overlap_sync_bytes_per_element,
         )
-        from dlrover_tpu.parallel.shard_map_compat import shard_map
+        from jax import shard_map
 
         accum = self.accum_steps
         loss_fn = self.loss_fn
@@ -618,7 +619,7 @@ class ElasticTrainer:
             )
             return params, opt_state, loss
 
-        return jax.jit(train_step, donate_argnums=self._donate_argnums())
+        return StateStep(train_step, self._donate_argnums())
 
     def _donate_argnums(self) -> Tuple[int, ...]:
         """(params, opt_state) positions when in-place update is on."""

@@ -37,9 +37,36 @@ def restart_count() -> int:
     return int(os.getenv(NodeEnv.RESTART_COUNT, "0"))
 
 
+def enable_compile_cache() -> str:
+    """Turn on XLA's persistent compilation cache; returns its
+    directory. A restarted trainer then loads its step program
+    instead of compiling it again: 0.4 s against 13.7 s for GPT-2
+    124M on a v5e (chip run, PR 21), off an elastic job's time to
+    resume.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set (or the caller already
+    configured a directory) JAX has it and nothing is set here, the
+    owner's minimum compile time included; otherwise the cache lives
+    at the fixed ``<checkout>/.cache/jax`` and keeps fast compiles
+    too, since strategy-search candidates are often small.
+    """
+    import jax
+
+    from dlrover_tpu.common.config import cache_dir
+
+    path = jax.config.jax_compilation_cache_dir
+    if path:
+        os.makedirs(path, exist_ok=True)
+        return path
+    path = cache_dir("jax")
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
+
+
 def setup_distributed() -> None:
     """Initialize jax.distributed if the agent provided a multi-process
-    world. Idempotent."""
+    world, and turn the compile cache on. Idempotent."""
     global _initialized
     if _initialized:
         return
@@ -59,17 +86,7 @@ def setup_distributed() -> None:
         obs.install_flight_recorder(
             "trainer", rank=int(os.getenv(NodeEnv.NODE_RANK, "-1"))
         )
-    # Honor an explicit JAX_PLATFORMS=cpu even when a TPU plugin
-    # preregistered itself (the env var alone loses to a registered
-    # backend): CPU-mesh test runs set this to get the virtual
-    # 8-device world.
-    if os.getenv("JAX_PLATFORMS", "") == "cpu":
-        import jax
-
-        try:
-            jax.config.update("jax_platforms", "cpu")
-        except Exception:  # noqa: BLE001 — backend already initialized
-            pass
+    enable_compile_cache()
     n = num_processes()
     if n <= 1:
         _initialized = True

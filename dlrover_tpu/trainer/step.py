@@ -21,6 +21,7 @@ import numpy as np
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from dlrover_tpu.parallel.mesh import under_mesh
 from dlrover_tpu.parallel.sharding import (
     Rules,
     prune_specs_to_mesh,
@@ -52,8 +53,7 @@ def make_sharded_init(
 
     def _init(key):
         params = init_fn(key)
-        opt_state = optimizer.init(params)
-        return params, opt_state
+        return params, init_opt_state(optimizer, params)
 
     # Optimizer state mirrors param sharding; scalars stay replicated.
     def _out_shardings(key):
@@ -70,22 +70,111 @@ def make_sharded_init(
     return init, param_shardings
 
 
+def init_opt_state(optimizer: optax.GradientTransformation, params):
+    """``optimizer.init(params)`` with every float leaf in at least
+    f32 (the repo's own optimizers, optim/low_bit.py, already keep
+    f32 moments whatever the parameters are).
+
+    optax creates adam's moments in the parameters' dtype and updates
+    them in the gradients'. The trainers sum gradients in an f32
+    accumulator, so with bf16 parameters step 1 took bf16 moments and
+    returned f32 ones: step 2 saw new input dtypes and compiled the
+    whole program again, 11.6 s on a v5e at GPT-2 124M at every start
+    and every resume (chip run, PR 21). State that starts in f32 goes
+    in as it comes out; zeros are the same in either dtype, so the
+    numbers are those the trainers had anyway, and so is the memory
+    they held from step 2 on."""
+
+    def widen(leaf):
+        if jnp.issubdtype(leaf.dtype, jnp.floating):
+            return leaf.astype(jnp.promote_types(leaf.dtype, jnp.float32))
+        return leaf
+
+    return jax.tree.map(widen, optimizer.init(params))
+
+
 def _match_opt_sharding(opt_shape, params_shape, param_shardings, mesh):
     """Give optimizer-state leaves the sharding of the param they
-    mirror (matched by shape), replicating everything else."""
+    mirror (matched by shape: the moments are wider than bf16
+    parameters, see :func:`init_opt_state`), replicating everything
+    else."""
     flat_params = jax.tree.leaves(params_shape)
     flat_shardings = jax.tree.leaves(
         param_shardings, is_leaf=lambda x: isinstance(x, NamedSharding)
     )
     by_shape = {}
     for p, s in zip(flat_params, flat_shardings):
-        by_shape.setdefault((p.shape, p.dtype), s)
+        by_shape.setdefault(p.shape, s)
     replicated = NamedSharding(mesh, P())
 
     def pick(leaf):
-        return by_shape.get((leaf.shape, leaf.dtype), replicated)
+        return by_shape.get(leaf.shape, replicated)
 
     return jax.tree.map(pick, opt_shape)
+
+
+class StateStep:
+    """``jax.jit`` of ``fn(params, opt_state, *rest) -> (params,
+    opt_state, out)`` whose new state keeps the old state's
+    shardings, so the state one step returns is what the next one
+    was compiled for.
+
+    Left alone XLA lays an output out as it likes. Under ``fsdp``
+    GPT-2's ``lnf_b`` and its moments came back split over the axis
+    though the init replicates them; step 2 saw new input shardings
+    and compiled the whole step again, at every start (seen on four
+    v5e chips, PR 21; 19 s a compile in the sandbox). jit wants
+    ``out_shardings`` when it is built, and the builders only get the
+    mesh, so the jit is built at the first call (or ``lower``) from
+    that call's state. Leaves that are not laid out on a mesh (a
+    host-made ``optimizer.init``) are left to XLA as before."""
+
+    def __init__(self, fn: Callable, donate_argnums=()):
+        self._fn = fn
+        self._donate = tuple(donate_argnums)
+        self._jit = None
+
+    def _jitted(self, params, opt_state):
+        if self._jit is None:
+            if any(
+                isinstance(x, jax.core.Tracer)
+                for x in jax.tree.leaves((params, opt_state))
+            ):
+                # Inside somebody else's jit (ElasticTrainer wraps an
+                # external step_fn): nothing is laid out yet, and the
+                # outer jit does the pinning.
+                return self._fn
+
+            def pin(x):
+                sharding = getattr(x, "sharding", None)
+                return (
+                    sharding
+                    if isinstance(sharding, NamedSharding)
+                    else None
+                )
+
+            self._jit = jax.jit(
+                self._fn,
+                donate_argnums=self._donate,
+                out_shardings=(
+                    jax.tree.map(pin, params),
+                    jax.tree.map(pin, opt_state),
+                    None,
+                ),
+            )
+        return self._jit
+
+    def __call__(self, params, opt_state, *rest):
+        return self._jitted(params, opt_state)(params, opt_state, *rest)
+
+    def lower(self, params, opt_state, *rest):
+        return self._jitted(params, opt_state).lower(
+            params, opt_state, *rest
+        )
+
+    def _cache_size(self) -> int:
+        """Programs compiled so far (obs.profiling.CompileTracker)."""
+        return 0 if self._jit is None else self._jit._cache_size()
 
 
 def make_train_step(
@@ -99,7 +188,10 @@ def make_train_step(
 
     Gradients come back with param sharding automatically; XLA emits
     reduce-scatter/all-gather for fsdp axes and psum for data axes.
+    The one thing XLA cannot partition, a Pallas kernel, splits
+    itself over the mesh it is traced under (``under_mesh``).
     """
+    loss_fn = under_mesh(loss_fn, mesh)
 
     def step(params, opt_state, tokens, targets):
         loss, grads = jax.value_and_grad(loss_fn)(params, tokens, targets)
@@ -108,8 +200,7 @@ def make_train_step(
         gnorm = optax.global_norm(grads)
         return params, opt_state, {"loss": loss, "grad_norm": gnorm}
 
-    donate_argnums = (0, 1) if donate else ()
-    return jax.jit(step, donate_argnums=donate_argnums)
+    return StateStep(step, (0, 1) if donate else ())
 
 
 class _CombinedLowered:
@@ -212,6 +303,7 @@ class PipelinedTrainStep:
                 f"pipeline_depth must be >= 1, got {pipeline_depth}"
             )
         self.mesh = mesh
+        loss_fn = under_mesh(loss_fn, mesh)
         self.accum_steps = int(accum_steps)
         self.pipeline_depth = int(pipeline_depth)
         self.donate = donate
@@ -236,7 +328,7 @@ class PipelinedTrainStep:
                 bucket_plan,
                 bucketed_psum_mean,
             )
-            from dlrover_tpu.parallel.shard_map_compat import shard_map
+            from jax import shard_map
 
             if any(
                 s > 1
@@ -354,7 +446,7 @@ class PipelinedTrainStep:
             }
 
         donate_argnums = (0, 1, 2, 3) if donate else (2, 3)
-        self._update_j = jax.jit(update, donate_argnums=donate_argnums)
+        self._update_j = StateStep(update, donate_argnums)
 
         # Device-side microbatch slice with a STATIC index: eager
         # Array.__getitem__ would stage the index as an implicit H2D
@@ -536,10 +628,7 @@ class PipelinedTrainStep:
         loss_sds = jax.ShapeDtypeStruct((), jnp.float32)
 
         def _flops(lowered) -> float:
-            cost = lowered.cost_analysis()
-            if isinstance(cost, list):
-                cost = cost[0] if cost else {}
-            return float(cost.get("flops", 0.0))
+            return float(lowered.cost_analysis().get("flops", 0.0))
 
         micro0_f = _flops(
             self._micro0_j.lower(params, tok_sds, tgt_sds)

@@ -471,7 +471,10 @@ class Trainer:
                 )
         if params is None:
             from dlrover_tpu.parallel.sharding import tree_shardings
-            from dlrover_tpu.trainer.step import _match_opt_sharding
+            from dlrover_tpu.trainer.step import (
+                _match_opt_sharding,
+                init_opt_state,
+            )
 
             # Skeleton matches what train() SAVED: the strategy's
             # optimizer (auto_accelerate never reads args.optimizer)
@@ -483,7 +486,7 @@ class Trainer:
             like = jax.eval_shape(
                 lambda k: (
                     self.model_init(k),
-                    opt.init(self.model_init(k)),
+                    init_opt_state(opt, self.model_init(k)),
                 ),
                 jax.random.PRNGKey(0),
             )

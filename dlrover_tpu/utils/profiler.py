@@ -17,9 +17,12 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 
-# Peak bf16 TFLOP/s and HBM GB/s per chip by generation (public
-# specs). The single source of truth — bench.py and the module
-# profiler read these tables.
+# Peak bf16 TFLOP/s and HBM GB/s per chip by generation. The single
+# source of truth — bench.py, the MFU gauge and the module profiler
+# read these tables. Source: Google Cloud TPU documentation, the
+# system-architecture page of each generation ("TPU v5e": 197 TFLOP/s
+# bf16 and 819 GB/s HBM per chip; likewise "TPU v4", "TPU v5p",
+# "TPU v6e").
 PEAK_TFLOPS = {"v4": 275.0, "v5e": 197.0, "v5p": 459.0, "v6e": 918.0}
 PEAK_HBM_GBPS = {
     "v4": 1228.0,
@@ -27,26 +30,45 @@ PEAK_HBM_GBPS = {
     "v5p": 2765.0,
     "v6e": 1640.0,
 }
+# jax.devices()[0].device_kind -> generation.
+DEVICE_KIND_GENERATION = {
+    "TPU v4": "v4",
+    "TPU v5 lite": "v5e",
+    "TPU v5e": "v5e",
+    "TPU v5": "v5p",
+    "TPU v5p": "v5p",
+    "TPU v6 lite": "v6e",
+    "TPU v6e": "v6e",
+}
 
 
-def chip_peaks(default: str = "v5e") -> Tuple[float, float]:
-    """(peak TFLOP/s, peak HBM GB/s) of the current backend's chip.
-    Unknown kinds (new generations, CPU) fall back to ``default`` so
-    rankings still work rather than raising."""
-    key = default
-    if jax.default_backend() == "tpu":
-        kind = jax.devices()[0].device_kind.lower()
-        lite = "lite" in kind or "e" in kind.split("v")[-1][:2]
-        for ver in ("v6", "v5", "v4"):
-            if ver in kind:
-                key = "v4" if ver == "v4" else ver + (
-                    "e" if lite else "p"
-                )
-                break
-    return (
-        PEAK_TFLOPS.get(key, PEAK_TFLOPS[default]),
-        PEAK_HBM_GBPS.get(key, PEAK_HBM_GBPS[default]),
-    )
+def chip_generation() -> Optional[str]:
+    """The attached TPU's generation; None off a TPU backend. A TPU
+    whose device kind is not in the table is an error, not a default:
+    a utilisation against another chip's peak is a wrong number."""
+    if jax.default_backend() != "tpu":
+        return None
+    kind = jax.devices()[0].device_kind
+    if kind not in DEVICE_KIND_GENERATION:
+        raise ValueError(
+            f"no peaks recorded for device kind {kind!r}; add it to "
+            "utils/profiler.py with its source"
+        )
+    return DEVICE_KIND_GENERATION[kind]
+
+
+def chip_peaks(default: Optional[str] = None) -> Tuple[float, float]:
+    """(peak TFLOP/s, peak HBM GB/s) of the attached TPU. Off a TPU
+    backend, those of ``default``, the generation the caller plans
+    for — nobody inherits one in silence."""
+    gen = chip_generation() or default
+    if gen is None:
+        raise ValueError(
+            f"backend {jax.default_backend()!r} is not a TPU: name "
+            "the generation to plan for, "
+            f"one of {sorted(PEAK_TFLOPS)}"
+        )
+    return PEAK_TFLOPS[gen], PEAK_HBM_GBPS[gen]
 
 
 @dataclasses.dataclass
@@ -64,16 +86,8 @@ class FnProfile:
 
 
 def _device_peak_tflops() -> Optional[float]:
-    if jax.default_backend() != "tpu":
-        return None
-    kind = jax.devices()[0].device_kind.lower()
-    lite = "lite" in kind
-    for ver in ("v6", "v5", "v4"):
-        if ver in kind:
-            if ver == "v4":
-                return PEAK_TFLOPS["v4"]
-            return PEAK_TFLOPS[ver + ("e" if lite else "p")]
-    return None
+    gen = chip_generation()
+    return None if gen is None else PEAK_TFLOPS[gen]
 
 
 def profile_fn(
@@ -88,8 +102,6 @@ def profile_fn(
     compiled = lowered.compile()
 
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):
-        cost = cost[0] if cost else {}
     flops = float(cost.get("flops", 0.0))
     bytes_accessed = float(cost.get("bytes accessed", 0.0))
 

@@ -137,7 +137,10 @@ def start_agent(
         "DLROVER_TPU_TRACE_FILE": os.path.join(
             tmp, f"trace_n{rank}.jsonl"
         ),
-        "JAX_COMPILATION_CACHE_DIR": os.path.join(tmp, "jaxcache"),
+        # The compile cache itself sits where the environment's
+        # JAX_COMPILATION_CACHE_DIR says, else at the fixed path
+        # jax_env.setup_distributed gives every trainer, so each
+        # restarted process hits what the first one wrote.
         "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES": "0",
         "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
     }

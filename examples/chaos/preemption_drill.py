@@ -79,8 +79,9 @@ def main() -> int:
         DLROVER_TPU_METRICS_FILE=metrics,
         # Persistent compilation cache: the restarted process must not
         # pay the cold compile again — same mechanism production TPU
-        # jobs rely on for fast recovery.
-        JAX_COMPILATION_CACHE_DIR=os.path.join(tmp, "jaxcache"),
+        # jobs rely on for fast recovery. Its directory is the
+        # environment's JAX_COMPILATION_CACHE_DIR where set, else the
+        # fixed one jax_env.setup_distributed gives every trainer.
         JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="0",
         JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
     )

@@ -620,3 +620,59 @@ def test_search_raises_when_nothing_fits():
             devices=jax.devices()[:4],
             hbm_bytes=1 << 10,
         )
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("axis,n", [("data", 1), ("data", 4), ("fsdp", 4)])
+def test_sharded_init_and_step_compile_once(axis, n, dtype):
+    """Step 2 reuses step 1's program: what a step returns has the
+    dtypes and the shardings of what the init handed out. With bf16
+    parameters adam's moments used to start bf16 and come back f32
+    from the f32 gradient accumulator (trainer/step.init_opt_state),
+    and under fsdp XLA handed small replicated leaves back split over
+    the axis (trainer/step.StateStep); either way step 2 compiled the
+    whole program again, 11.6 s on a v5e at every start and every
+    resume (chip run, PR 21)."""
+    from dlrover_tpu.trainer.elastic_trainer import ElasticTrainer
+
+    cfg = dataclasses.replace(CFG, dtype=dtype)
+    init = functools.partial(gpt.init_params, cfg=cfg)
+    loss = functools.partial(gpt.loss_fn, cfg=cfg)
+    tokens = jnp.zeros((4, cfg.block_size), jnp.int32)
+    res = auto_accelerate(
+        init, loss, gpt.param_logical_axes(cfg), (tokens, tokens),
+        strategy=Strategy(
+            mesh_shape=((axis, n),), micro_batch_size=2,
+        ),
+        devices=jax.devices()[:n],
+    )
+    trainer = ElasticTrainer(
+        res.mesh, loss, res.optimizer,
+        global_batch_size=2 * n, micro_batch_size=2,
+    )
+    params, opt_state = res.init_fn(jax.random.PRNGKey(0))
+    # Moments are f32 whatever the parameters are.
+    assert {
+        x.dtype for x in jax.tree.leaves(opt_state)
+    } <= {jnp.dtype(jnp.float32), jnp.dtype(jnp.int32)}
+    laid_out = jax.tree.map(
+        lambda x: (x.dtype, x.sharding), (params, opt_state)
+    )
+    batch = np.zeros((2 * n, cfg.block_size), np.int32)
+    for _ in range(3):
+        params, opt_state, _ = trainer.train_step(
+            params, opt_state, batch, batch
+        )
+        assert trainer._compiled._cache_size() == 1
+    assert laid_out == jax.tree.map(
+        lambda x: (x.dtype, x.sharding), (params, opt_state)
+    )
+    # The dry-run step (make_train_step) holds to the same.
+    params, opt_state = res.init_fn(jax.random.PRNGKey(0))
+    tokens = res.shard_batch_fn(
+        np.zeros((2 * n, cfg.block_size), np.int32),
+        np.zeros((2 * n, cfg.block_size), np.int32),
+    )
+    for _ in range(2):
+        params, opt_state, _ = res.step_fn(params, opt_state, *tokens)
+    assert res.step_fn._cache_size() == 1

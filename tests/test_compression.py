@@ -18,7 +18,7 @@ from dlrover_tpu.parallel.compression import (
     sync_bytes_per_element,
 )
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
-from dlrover_tpu.parallel.shard_map_compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 

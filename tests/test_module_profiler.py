@@ -8,6 +8,9 @@ roofline prior than by the memory prior.
 """
 
 import functools
+import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +22,19 @@ from dlrover_tpu.utils.module_profiler import (
     profile_modules,
     total_cost,
 )
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _predict_v5e(*args, **kwargs):
+    """predict_step_time for the generation these CPU tests plan for:
+    off a TPU nobody gets a chip's peaks without naming it."""
+    from dlrover_tpu.utils.profiler import chip_peaks
+
+    tflops, gbps = chip_peaks(default="v5e")
+    return predict_step_time(
+        *args, peak_tflops=tflops, peak_hbm_gbps=gbps, **kwargs
+    )
 
 
 def _toy(p, x):
@@ -106,8 +122,8 @@ class TestRooflinePrior:
     def test_remat_costs_flops(self):
         per_sample = ModuleCost(flops=1e12, bytes=1e9)
         none_s, full_s = self._strategies()
-        t_none = predict_step_time(per_sample, none_s, 1)
-        t_full = predict_step_time(per_sample, full_s, 1)
+        t_none = _predict_v5e(per_sample, none_s, 1)
+        t_full = _predict_v5e(per_sample, full_s, 1)
         assert t_full > t_none  # recompute is not free
 
     def test_dtype_costs_bandwidth(self):
@@ -117,9 +133,9 @@ class TestRooflinePrior:
         per_sample = ModuleCost(flops=1e9, bytes=1e12)
         none_s, _ = self._strategies()
         f32_s = dataclasses.replace(none_s, dtype="float32")
-        assert predict_step_time(
+        assert _predict_v5e(
             per_sample, f32_s, 1
-        ) > predict_step_time(per_sample, none_s, 1)
+        ) > _predict_v5e(per_sample, none_s, 1)
 
     def test_pipe_bubble_costs_time(self):
         """Without a comm model, a pipe mesh must rank BELOW the
@@ -131,9 +147,9 @@ class TestRooflinePrior:
                         micro_batch_size=4)
         pipe = Strategy((("pipe", 8),), remat="none",
                         micro_batch_size=4)
-        assert predict_step_time(
+        assert _predict_v5e(
             per_sample, pipe, 8
-        ) > predict_step_time(per_sample, fsdp, 8)
+        ) > _predict_v5e(per_sample, fsdp, 8)
 
     def test_deep_model_ranks_pipe_above_fsdp(self):
         """The reason pipeline is in the search space at all (ref
@@ -150,17 +166,17 @@ class TestRooflinePrior:
                         micro_batch_size=4)
         deep_params = 40 << 30  # 10B params f32 basis
         small_params = 40 << 20
-        t_fsdp_deep = predict_step_time(
+        t_fsdp_deep = _predict_v5e(
             per_sample, fsdp, 8, param_bytes=deep_params
         )
-        t_pipe_deep = predict_step_time(
+        t_pipe_deep = _predict_v5e(
             per_sample, pipe, 8, param_bytes=deep_params
         )
         assert t_pipe_deep < t_fsdp_deep
-        t_fsdp_small = predict_step_time(
+        t_fsdp_small = _predict_v5e(
             per_sample, fsdp, 8, param_bytes=small_params
         )
-        t_pipe_small = predict_step_time(
+        t_pipe_small = _predict_v5e(
             per_sample, pipe, 8, param_bytes=small_params
         )
         assert t_fsdp_small < t_pipe_small
@@ -193,7 +209,8 @@ class TestRooflinePrior:
         best = candidates[0]  # no-remat: fewer FLOPs, fits easily
 
         roof = _roofline_prior(
-            model_init, model_loss, (tok, tok), candidates, 1
+            model_init, model_loss, (tok, tok), candidates, 1,
+            chip="v5e",
         )
         assert roof is not None
 
@@ -220,28 +237,134 @@ class TestRooflinePrior:
         assert n_roofline < n_memory
 
 
+class TestChipPeaks:
+    """One peaks table, keyed by device kind; nothing is assumed."""
+
+    @staticmethod
+    def _fake_tpu(monkeypatch, kind):
+        import types
+
+        from dlrover_tpu.utils import profiler
+
+        monkeypatch.setattr(profiler.jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(
+            profiler.jax, "devices",
+            lambda: [types.SimpleNamespace(device_kind=kind)],
+        )
+        return profiler
+
+    def test_off_tpu_the_caller_names_the_generation(self):
+        from dlrover_tpu.utils.profiler import (
+            _device_peak_tflops,
+            chip_peaks,
+        )
+
+        assert chip_peaks(default="v5e") == (197.0, 819.0)
+        with pytest.raises(ValueError, match="not a TPU"):
+            chip_peaks()
+        assert _device_peak_tflops() is None
+
+    def test_attached_chip_wins_over_the_default(self, monkeypatch):
+        profiler = self._fake_tpu(monkeypatch, "TPU v5 lite")
+        assert profiler.chip_peaks(default="v4") == (197.0, 819.0)
+        assert profiler._device_peak_tflops() == 197.0
+
+    def test_unknown_tpu_kind_is_an_error(self, monkeypatch):
+        profiler = self._fake_tpu(monkeypatch, "TPU v9 mega")
+        with pytest.raises(ValueError, match="TPU v9 mega"):
+            profiler.chip_peaks(default="v5e")
+        with pytest.raises(ValueError, match="TPU v9 mega"):
+            profiler._device_peak_tflops()
+
+
 class TestCompileCache:
     def test_enable_sets_config_and_creates_dir(self, tmp_path):
-        from dlrover_tpu.accelerate.api import (
-            enable_persistent_compile_cache,
-        )
+        from dlrover_tpu.common.config import cache_dir
+        from dlrover_tpu.trainer.jax_env import enable_compile_cache
 
         old = jax.config.jax_compilation_cache_dir
         try:
+            # Nothing configured: the fixed path inside the checkout.
             jax.config.update("jax_compilation_cache_dir", None)
-            d = enable_persistent_compile_cache(
-                str(tmp_path / "xla")
-            )
-            assert (tmp_path / "xla").is_dir()
+            d = enable_compile_cache()
+            assert d == cache_dir("jax")
+            assert os.path.isdir(d)
+            assert d.startswith(REPO_ROOT + os.sep)
             assert jax.config.jax_compilation_cache_dir == d
-            # A configured cache is never clobbered.
-            d2 = enable_persistent_compile_cache(
-                str(tmp_path / "other")
-            )
-            assert d2 == d
-            assert jax.config.jax_compilation_cache_dir == d
+            # A configured cache (JAX_COMPILATION_CACHE_DIR lands in
+            # the same config value) is never clobbered, only made.
+            other = str(tmp_path / "other")
+            jax.config.update("jax_compilation_cache_dir", other)
+            assert enable_compile_cache() == other
+            assert os.path.isdir(other)
+            assert jax.config.jax_compilation_cache_dir == other
         finally:
             jax.config.update("jax_compilation_cache_dir", old)
+
+    @pytest.mark.parametrize("from_env", [True, False])
+    def test_cache_files_land_where_promised(self, tmp_path, from_env):
+        """A fresh process that goes through setup_distributed writes
+        its compiles under JAX_COMPILATION_CACHE_DIR when that is
+        set, else under <checkout>/.cache/jax."""
+        env = {
+            **os.environ,
+            "JAX_PLATFORMS": "cpu",
+            "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
+            "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES": "0",
+            "PYTHONPATH": REPO_ROOT,
+        }
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
+        want = os.path.join(REPO_ROOT, ".cache", "jax")
+        if from_env:
+            want = env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "c")
+        # A program no other test compiles, so it is a fresh entry.
+        salt = 1000 + os.getpid()
+        code = (
+            "import os, jax, jax.numpy as jnp\n"
+            "from dlrover_tpu.trainer import jax_env\n"
+            "jax_env.setup_distributed()\n"
+            "d = jax.config.jax_compilation_cache_dir\n"
+            "before = set(os.listdir(d))\n"
+            f"jax.jit(lambda x: x * {salt} + 1)(jnp.ones(3))"
+            ".block_until_ready()\n"
+            "print('CACHE', d, len(set(os.listdir(d)) - before))\n"
+        )
+        out = subprocess.run(
+            [sys.executable, "-c", code], env=env, text=True,
+            capture_output=True, timeout=120,
+        )
+        assert out.returncode == 0, out.stderr[-2000:]
+        _, got_dir, n_new = out.stdout.strip().splitlines()[-1].split()
+        assert got_dir == want
+        assert int(n_new) >= 1
+
+    def test_no_cache_path_is_built_elsewhere(self):
+        """One function owns the cache directory: nothing else in the
+        repo sets it, in code or through a child's environment."""
+        offenders = []
+        for root in ("dlrover_tpu", "examples", "tools"):
+            for dirpath, _, files in os.walk(os.path.join(REPO_ROOT, root)):
+                offenders += [
+                    os.path.join(dirpath, f) for f in files
+                    if f.endswith((".py", ".sh"))
+                ]
+        offenders += [
+            os.path.join(REPO_ROOT, f) for f in ("bench.py", "chip_smoke.py")
+        ]
+        setters = (
+            'update("jax_compilation_cache_dir"',
+            "JAX_COMPILATION_CACHE_DIR=",
+            '"JAX_COMPILATION_CACHE_DIR":',
+            "initialize_cache(",
+        )
+        bad = []
+        for path in offenders:
+            with open(path) as f:
+                text = f.read()
+            if path.endswith(os.path.join("trainer", "jax_env.py")):
+                continue
+            bad += [(path, s) for s in setters if s in text]
+        assert not bad
 
 
 class TestTpPlannerPerEdgeBytes:

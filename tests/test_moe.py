@@ -15,7 +15,6 @@ from dlrover_tpu.models.moe import (
     top_k_gating,
 )
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
-from dlrover_tpu.parallel.shard_map_compat import use_mesh
 from dlrover_tpu.parallel.sharding import tree_shardings
 
 
@@ -94,7 +93,7 @@ def test_moe_expert_parallel_on_mesh():
     )
 
     y_ref, aux_ref = moe_mlp(params, x, cfg)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         y, aux = jax.jit(lambda p, x: moe_mlp(p, x, cfg))(
             params_sharded, x_sharded
         )

@@ -25,6 +25,8 @@ from dlrover_tpu.utils.profiler import (
     transformer_component_flops,
 )
 
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 class FakeClient:
     def __init__(self):
@@ -48,6 +50,130 @@ def test_resource_monitor_reports():
     mon = ResourceMonitor(client, interval=999)
     out = mon.report_once()
     assert client.resources and client.resources[0] == out
+
+
+def test_hbm_gauge_comes_from_the_trainers_metrics_file(tmp_path):
+    """The agent never asks JAX for the chips' memory: the trainer,
+    which owns them, writes it to the metrics file it writes anyway."""
+    path = str(tmp_path / "metrics.json")
+    client = FakeClient()
+    mon = ResourceMonitor(client, interval=999, metrics_file=path)
+    assert mon.report_once()["hbm_used_gb"] == 0.0  # no trainer yet
+    TrainingMonitor.write_metrics(3, tokens=10, path=path)
+    data = json.load(open(path))
+    # This process's backend is the CPU, which reports no memory.
+    assert "hbm_used_gb" not in data
+    json.dump({**data, "hbm_used_gb": 1.75}, open(path, "w"))
+    assert mon.report_once()["hbm_used_gb"] == 1.75
+    assert client.resources[-1]["hbm_used_gb"] == 1.75
+    assert mon.build_snapshot()["resource"]["hbm_used_gb"] == 1.75
+
+
+def test_trainer_samples_hbm_where_the_backend_reports_it(
+    tmp_path, monkeypatch
+):
+    import types
+
+    from dlrover_tpu.agent import monitor
+
+    devices = [
+        types.SimpleNamespace(
+            memory_stats=lambda: {"bytes_in_use": 3 << 29}
+        )
+    ] * 4
+    monkeypatch.setattr(jax, "local_devices", lambda: devices)
+    jax.devices()  # the trainer has its backend up by its first step
+    assert monitor._local_hbm_used_gb() == 6.0
+    path = str(tmp_path / "metrics.json")
+    TrainingMonitor.write_metrics(1, path=path)
+    assert json.load(open(path))["hbm_used_gb"] == 6.0
+
+
+_NO_BACKEND = (
+    "import sys\n"
+    "jax = sys.modules.get('jax')\n"
+    "state = ('absent' if jax is None else 'initialized' "
+    "if jax._src.xla_bridge.backends_are_initialized() else 'imported')\n"
+)
+
+
+def test_agent_side_monitors_never_initialise_a_backend():
+    """ResourceMonitor + TrainingMonitor ticking in a process of
+    their own leave JAX without a backend (a process that has one
+    holds the chip against the trainer)."""
+    import subprocess
+    import sys
+
+    code = (
+        "from dlrover_tpu.agent.monitor import *\n"
+        "class C:\n"
+        "    def report_resource(self, **kw): pass\n"
+        "    def report_step(self, *a): pass\n"
+        "ResourceMonitor(C(), interval=999).report_once()\n"
+        "TrainingMonitor(C(), interval=999).report_once()\n"
+        + _NO_BACKEND + "print('JAX', state)\n"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=120, env={**os.environ, "PYTHONPATH": REPO_ROOT},
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.split()[-2:] in (
+        ["JAX", "absent"], ["JAX", "imported"]
+    )
+
+
+def test_launcher_and_agent_never_initialise_a_backend(tmp_path):
+    """elastic_run --standalone with NO --nproc_per_node: the chip
+    count comes from a child that has exited, the agent supervises a
+    trainer that uses JAX, and the launcher/agent process itself ends
+    with no backend of its own."""
+    import subprocess
+    import sys
+
+    script = tmp_path / "train.py"
+    script.write_text(
+        "import jax, jax.numpy as jnp\n"
+        "from dlrover_tpu.agent.monitor import TrainingMonitor\n"
+        "from dlrover_tpu.trainer import jax_env\n"
+        "jax_env.setup_distributed()\n"
+        "x = (jnp.ones((8, 8)) @ jnp.ones((8, 8))).block_until_ready()\n"
+        "TrainingMonitor.write_metrics(1, tokens=64)\n"
+        "print('TRAINER_DEVICES', len(jax.devices()))\n"
+    )
+    code = (
+        "import sys\n"
+        "from dlrover_tpu.trainer import elastic_run\n"
+        "from dlrover_tpu.agent.agent import ElasticAgent\n"
+        "seen = []\n"
+        "spawn = ElasticAgent._spawn\n"
+        "def spy(self, spec):\n"
+        "    seen.append(self.config.local_world_size)\n"
+        "    return spawn(self, spec)\n"
+        "ElasticAgent._spawn = spy\n"
+        f"rc = elastic_run.main(['--standalone', {str(script)!r}])\n"
+        + _NO_BACKEND + "print('AGENT', rc, seen, state)\n"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=240,
+        env={
+            **os.environ, "PYTHONPATH": REPO_ROOT,
+            "DLROVER_TPU_JOB_NAME": f"nojax{os.getpid()}",
+            "DLROVER_TPU_METRICS_FILE": str(tmp_path / "m.json"),
+            "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "jax"),
+        },
+    )
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "TRAINER_DEVICES 8" in out.stdout
+    agent_line = [
+        line for line in out.stdout.splitlines() if line.startswith("AGENT")
+    ][-1]
+    # rc 0, the child counted conftest's 8 virtual devices, and no
+    # backend was ever brought up in this process.
+    assert agent_line in (
+        "AGENT 0 [8] absent", "AGENT 0 [8] imported"
+    ), agent_line
 
 
 def test_training_monitor_relays_new_steps(tmp_path):

@@ -194,7 +194,7 @@ class TestWindowedSeqParallel:
         from dlrover_tpu.parallel.ring_attention import (
             ring_attention_flash,
         )
-        from dlrover_tpu.parallel.shard_map_compat import shard_map
+        from jax import shard_map
 
         mesh = build_mesh(MeshConfig(seq=4, data=2))
         spec = P(("data",), "seq", None, None)
@@ -309,7 +309,7 @@ class TestGqaRing:
         from dlrover_tpu.parallel.ring_attention import (
             ring_attention_flash,
         )
-        from dlrover_tpu.parallel.shard_map_compat import shard_map
+        from jax import shard_map
 
         mesh = build_mesh(MeshConfig(seq=4, data=2))
         spec = P(("data",), "seq", None, None)

@@ -18,7 +18,6 @@ from dlrover_tpu.accelerate.tp_planner import (
     plan_transformer_block,
 )
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
-from dlrover_tpu.parallel.shard_map_compat import use_mesh
 
 
 class TestChainDP:
@@ -150,7 +149,7 @@ class TestPlanModel:
             )
             for name, arr in params.items()
         }
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             got = jax.jit(mlp)(sharded, x)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), atol=1e-5, rtol=1e-5

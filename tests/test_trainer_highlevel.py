@@ -21,6 +21,15 @@ from dlrover_tpu.models import gpt
 from dlrover_tpu.trainer.trainer import Trainer, TrainingArguments
 
 
+@pytest.fixture(autouse=True)
+def _own_job_name(monkeypatch):
+    """The checkpoint lock/shm sockets live under /tmp keyed by job
+    name; with the default name another xdist worker's suite can
+    remove this one's lock server mid-test (seen in 2 of 4 full runs,
+    PR 21)."""
+    monkeypatch.setenv("DLROVER_TPU_JOB_NAME", f"highlevel{os.getpid()}")
+
+
 CFG = gpt.GPTConfig(
     vocab_size=128, block_size=32, n_layer=2, n_head=2, n_embd=32,
     dtype=jnp.float32, remat=False,
