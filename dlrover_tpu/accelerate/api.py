@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from dlrover_tpu import obs
 from dlrover_tpu.accelerate.analyser import (
     ModelAnalysis,
     analyse_model,
@@ -31,6 +32,7 @@ from dlrover_tpu.accelerate.strategy import (
     Strategy,
     candidate_strategies,
 )
+from dlrover_tpu.agent.monitor import TrainingMonitor
 from dlrover_tpu.common.log import get_logger
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.trainer.jax_env import enable_compile_cache
@@ -520,6 +522,27 @@ def plan_strategies(
     return entries
 
 
+def _result(strategy: Strategy, built: Tuple, **search) -> AccelerateResult:
+    """What ``auto_accelerate`` hands back for ``built``
+    (``_build_for_strategy``'s tuple)."""
+    mesh, optimizer, init, step = built
+
+    def init_fn(key):
+        with obs.span("accel.init_state"):
+            return init(key)
+
+    TrainingMonitor.mark_phase("accelerate_done")
+    return AccelerateResult(
+        strategy=strategy,
+        mesh=mesh,
+        optimizer=optimizer,
+        init_fn=init_fn,
+        step_fn=step,
+        shard_batch_fn=lambda t, g: shard_batch(mesh, t, g),
+        **search,
+    )
+
+
 def auto_accelerate(
     model_init: Callable[[jax.Array], Any],
     model_loss: Callable,
@@ -565,23 +588,21 @@ def auto_accelerate(
     excluded from the search.
     """
     devices = list(devices if devices is not None else jax.devices())
+    # The backend is up: what came before is the runtime's start.
+    TrainingMonitor.mark_phase("devices_ready")
     enable_compile_cache()
-    if strategy is not None:
-        mesh, optimizer, init, step = _build_for_strategy(
-            strategy, model_init, model_loss, logical_axes,
-            learning_rate, devices, optimizer_kwargs,
-            seq_attention_kwargs, pipeline_builder,
-        )
-        return AccelerateResult(
-            strategy=strategy,
-            mesh=mesh,
-            optimizer=optimizer,
-            init_fn=init,
-            step_fn=step,
-            shard_batch_fn=lambda t, g: shard_batch(mesh, t, g),
-        )
 
-    analysis = analyse_model(model_init)
+    def build_strategy(s: Strategy):
+        with obs.span("accel.build", strategy=s.name()):
+            return _build_for_strategy(
+                s, model_init, model_loss, logical_axes,
+                learning_rate, devices, optimizer_kwargs,
+                seq_attention_kwargs, pipeline_builder,
+            )
+
+    if strategy is not None:
+        return _result(strategy, build_strategy(strategy))
+
     if candidates is None:
         candidates = candidate_strategies(len(devices))
     # The generic (init, loss) contract gives no stage decomposition,
@@ -615,11 +636,13 @@ def auto_accelerate(
     # first and the budget shrinks). plan_strategies is the single
     # source of that gate + prior wiring (also usable standalone for
     # simulated topologies).
-    entries = plan_strategies(
-        model_init, len(devices), hbm, activation_bytes_per_sample,
-        candidates=candidates, model_loss=model_loss,
-        sample_batch=sample_batch, _analysis=analysis,
-    )
+    with obs.span("accel.plan", candidates=len(candidates)):
+        analysis = analyse_model(model_init)
+        entries = plan_strategies(
+            model_init, len(devices), hbm, activation_bytes_per_sample,
+            candidates=candidates, model_loss=model_loss,
+            sample_batch=sample_batch, _analysis=analysis,
+        )
     logger.info(
         "strategy search: %d candidates, %d fit in memory",
         len(candidates),
@@ -646,11 +669,7 @@ def auto_accelerate(
     def build(s: Strategy):
         key = s.to_json()
         if key not in build_cache:
-            build_cache[key] = _build_for_strategy(
-                s, model_init, model_loss, logical_axes,
-                learning_rate, devices, optimizer_kwargs,
-                seq_attention_kwargs, pipeline_builder,
-            )
+            build_cache[key] = build_strategy(s)
         return build_cache[key]
 
     # BO over the viable set, seeded by the memory cost model (ref
@@ -788,14 +807,7 @@ def auto_accelerate(
     if chosen is None:
         raise RuntimeError(f"all dry-runs failed: {log}")
 
-    mesh, optimizer, init, step = build(chosen)  # cache hit
-    return AccelerateResult(
-        strategy=chosen,
-        mesh=mesh,
-        optimizer=optimizer,
-        init_fn=init,
-        step_fn=step,
-        shard_batch_fn=lambda t, g: shard_batch(mesh, t, g),
-        throughput=search.best_throughput(),
-        search_log=log,
+    return _result(
+        chosen, build(chosen),  # cache hit
+        throughput=search.best_throughput(), search_log=log,
     )

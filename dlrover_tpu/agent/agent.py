@@ -28,6 +28,7 @@ from typing import Deque, Dict, List, Optional
 
 from dlrover_tpu import obs
 from dlrover_tpu.agent.master_client import MasterClient
+from dlrover_tpu.agent.monitor import TrainingMonitor
 from dlrover_tpu.common.comm import find_free_port
 from dlrover_tpu.common.config import (
     ensure_framework_on_pythonpath,
@@ -257,6 +258,13 @@ class ElasticAgent:
         self._proc = subprocess.Popen(
             self.entry_cmd, env=env, stderr=subprocess.PIPE
         )
+        # worker_pid, not pid: that is every record's own process tag.
+        obs.event(
+            "agent.worker_spawned",
+            worker_pid=self._proc.pid,
+            restart_count=self._restart_count,
+        )
+        TrainingMonitor.mark_phase("agent.spawned")
         self._stderr_thread = threading.Thread(
             target=self._pump_stderr,
             args=(self._proc.stderr, self._stderr_tail),
@@ -767,6 +775,11 @@ class ElasticAgent:
                 continue
             code = self._proc.poll() if self._proc else None
             if code is not None:
+                obs.event(
+                    "agent.worker_exit_seen",
+                    worker_pid=self._proc.pid,
+                    returncode=code,
+                )
                 if code == 0:
                     logger.info("training process finished successfully")
                     try:
@@ -777,6 +790,10 @@ class ElasticAgent:
                             exc_info=True,
                         )
                     return 0
+                # A failure opens a relaunch: the agent's marks of the
+                # last one go, and this one's start here. A clean exit
+                # ends the job and leaves them for whoever reads next.
+                TrainingMonitor.mark_phase("agent.exit_seen")
                 if not self._handle_failure(code):
                     return code
                 continue

@@ -22,6 +22,8 @@ import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import List, Optional
 
+from dlrover_tpu import obs
+from dlrover_tpu.agent.monitor import TrainingMonitor
 from dlrover_tpu.common.ckpt_shm import SharedMemoryHandler
 from dlrover_tpu.common.log import get_logger
 from dlrover_tpu.common.multi_process import (
@@ -209,11 +211,21 @@ class AsyncCheckpointSaver:
         """Copy every local shard's shm to storage and commit when the
         job-wide shard set is complete. ``step`` is advisory — the shm
         contents (one consistent step across shards) win."""
-        with self._persist_lock:
-            snapshots = self._snapshot_shards()
+        with self._persist_lock, obs.span(
+            "ckpt.persist", step=step
+        ) as span:
+            # While the snapshot lasts the segment's lock is held and
+            # the trainer's save_to_memory is dropped ("shm busy").
+            with obs.span("ckpt.persist_snapshot"):
+                snapshots = self._snapshot_shards()
             if snapshots is None:
                 return False
             step = snapshots[0][0]
+            span.set(
+                step=step,
+                bytes=sum(len(s[3]) for s in snapshots),
+                shards=len(snapshots),
+            )
             # The staged metadata names the trainer's checkpoint dir —
             # authoritative even when the only save events so far were
             # memory-only (flash fast path flushed before a restart).
@@ -226,25 +238,29 @@ class AsyncCheckpointSaver:
                 self._persisted_step = self._read_tracker()
             if step <= self._persisted_step:
                 return True
+            TrainingMonitor.mark_phase("agent.persist_begin")
             wdir = writing_dir(self.checkpoint_dir, step)
             ddir = done_dir(self.checkpoint_dir, step)
-            with ThreadPoolExecutor(
-                    max_workers=min(8, self.local_shard_num)) as pool:
-                futs = [
-                    pool.submit(self._persist_shard, wdir, step,
-                                entries, extra, payload)
-                    for _, entries, extra, payload in snapshots
-                ]
-                ranks = [f.result() for f in futs]
-            for rank in ranks:
-                self.storage.write_bytes(b"", f"{ddir}/{rank}.done")
-            if self.is_commit_owner:
-                committed = self.commit_checkpoint(step)
-            else:
-                committed = self._wait_commit(step)
+            with obs.span("ckpt.persist_write"):
+                with ThreadPoolExecutor(
+                        max_workers=min(8, self.local_shard_num)) as pool:
+                    futs = [
+                        pool.submit(self._persist_shard, wdir, step,
+                                    entries, extra, payload)
+                        for _, entries, extra, payload in snapshots
+                    ]
+                    ranks = [f.result() for f in futs]
+                for rank in ranks:
+                    self.storage.write_bytes(b"", f"{ddir}/{rank}.done")
+            with obs.span("ckpt.persist_commit"):
+                if self.is_commit_owner:
+                    committed = self.commit_checkpoint(step)
+                else:
+                    committed = self._wait_commit(step)
             if committed:
                 self._persisted_step = step
                 self._status.set("latest_persisted_step", step)
+            TrainingMonitor.mark_phase("agent.persist_done")
             return committed
 
     def _persist_shard(self, wdir: str, step: int, entries, extra,
@@ -326,17 +342,19 @@ class AsyncCheckpointSaver:
         """Flush whatever step the shm currently holds — called on
         SIGTERM, on trainer failure, and before an elastic restart
         (the reference's _save_ckpt_to_storage, training.py:572)."""
-        with self._locks[0]:
-            snap = self._shms[0].load()
-        if snap is None:
-            logger.info("no shm checkpoint state to flush")
-            return False
-        if snap[0] <= self._persisted_step:
-            logger.info("shm step %s already persisted", snap[0])
-            return True
-        logger.info("flushing shm checkpoint step %s to storage",
-                    snap[0])
-        return self.save_step_checkpoint(snap[0])
+        with obs.span("ckpt.flush_on_restart") as span:
+            with self._locks[0]:
+                snap = self._shms[0].load()
+            span.set(found=snap is not None)
+            if snap is None:
+                logger.info("no shm checkpoint state to flush")
+                return False
+            if snap[0] <= self._persisted_step:
+                logger.info("shm step %s already persisted", snap[0])
+                return True
+            logger.info("flushing shm checkpoint step %s to storage",
+                        snap[0])
+            return self.save_step_checkpoint(snap[0])
 
     def latest_persisted_step(self) -> int:
         return self._persisted_step

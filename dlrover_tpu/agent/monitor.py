@@ -15,6 +15,7 @@ agent side reads.
 from __future__ import annotations
 
 import collections
+import fcntl
 import json
 import os
 import socket
@@ -36,6 +37,8 @@ RECENT_STEP_TIMES = 32
 
 METRICS_FILE_ENV = "DLROVER_TPU_METRICS_FILE"
 PHASES_FILE_ENV = "DLROVER_TPU_PHASES_FILE"
+# Phase marks of this prefix are the agent's (TrainingMonitor.mark_phase).
+AGENT_MARK_PREFIX = "agent."
 
 # Local staleness threshold before the agent treats the co-hosted
 # trainer's beacon as wedged and fires its forensics hook. Sits above
@@ -423,35 +426,52 @@ class TrainingMonitor:
 
     @staticmethod
     def mark_phase(name: str, path: Optional[str] = None) -> None:
-        """Timestamp a startup/recovery phase boundary from the
-        TRAINING process (proc_start, dist_ready, built, restore_done,
-        first_step_done, ...). Written only when
-        DLROVER_TPU_PHASES_FILE is set (or ``path`` given) — chaos
-        drills use the marks to break a recovery time into
-        explainable, budget-checkable segments. Each trainer (re)start
-        overwrites the file from its own proc_start, so the file
-        always describes the LATEST attempt."""
-        # Mirror every mark into the obs tracer (its own env gate,
-        # DLROVER_TPU_TRACE_FILE): the recovery-timeline reconstructor
-        # (obs/timeline.py) folds these "trainer.<mark>" events into
-        # the canonical failure-detect/rendezvous/restore/first-step
-        # breakdown. No-op when tracing is off.
-        obs.event(f"trainer.{name}")
+        """Timestamp a startup/recovery phase boundary (proc_start,
+        dist_ready, devices_ready, accelerate_done, built,
+        restore_read_done, restore_done, first_step_done, ...).
+        Written only when DLROVER_TPU_PHASES_FILE is set (or ``path``
+        given) — chaos drills and the benchmark use the marks to break
+        a recovery time into explainable, budget-checkable segments.
+
+        Two processes write the file. The trainer's marks describe its
+        LATEST attempt: its ``proc_start`` empties them. Marks whose
+        name starts with ``agent.`` are the agent's (exit seen,
+        spawned, persist begun and done): they outlive ``proc_start``,
+        so the file holds a whole relaunch from the exit to the first
+        step, and ``agent.exit_seen`` empties them when the next
+        failure comes. The read-modify-rename runs under a lock on
+        ``<path>.lock`` so neither writer loses the other's marks."""
+        agents = name.startswith(AGENT_MARK_PREFIX)
+        if not agents:
+            # Mirror the trainer's marks into the obs tracer (its own
+            # env gate, DLROVER_TPU_TRACE_FILE): the recovery-timeline
+            # reconstructor (obs/timeline.py) folds these
+            # "trainer.<mark>" events into the canonical failure-
+            # detect/rendezvous/restore/first-step breakdown. The
+            # agent's marks each stand beside an event or span of
+            # their own.
+            obs.event(f"trainer.{name}")
         path = path or os.getenv(PHASES_FILE_ENV)
         if not path:
             return
-        marks = {}
-        if name != "proc_start":
+        now = time.time()
+        with open(f"{path}.lock", "w") as lock:
+            fcntl.flock(lock, fcntl.LOCK_EX)
             try:
                 with open(path) as f:
                     marks = json.load(f)
             except (OSError, ValueError):
                 marks = {}
-        marks[name] = time.time()
-        tmp = f"{path}.tmp{os.getpid()}"
-        with open(tmp, "w") as f:
-            json.dump(marks, f)
-        os.replace(tmp, path)
+            if name in ("proc_start", AGENT_MARK_PREFIX + "exit_seen"):
+                marks = {
+                    k: v for k, v in marks.items()
+                    if k.startswith(AGENT_MARK_PREFIX) != agents
+                }
+            marks[name] = now
+            tmp = f"{path}.tmp{os.getpid()}"
+            with open(tmp, "w") as f:
+                json.dump(marks, f)
+            os.replace(tmp, path)
 
     def report_once(self) -> Optional[int]:
         try:

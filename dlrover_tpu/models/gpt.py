@@ -368,10 +368,11 @@ def loss_fn_fused(
 
     x = backbone(params, tokens, cfg, attn_fn)
     n = x.shape[0] * x.shape[1]
-    return fused_cross_entropy(
-        x.reshape(n, -1), params["wte"], targets.reshape(n), num_chunks,
-        save_logits,
-    )
+    with jax.named_scope("head"):
+        return fused_cross_entropy(
+            x.reshape(n, -1), params["wte"], targets.reshape(n),
+            num_chunks, save_logits,
+        )
 
 
 def num_params(params: Params) -> int:

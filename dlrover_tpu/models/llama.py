@@ -357,35 +357,42 @@ def _block(x, lp, cfg: LlamaConfig, attn_fn, cos, sin):
             fused_add_rms_norm,
             fused_rms_norm,
         )
-
-        h = fused_rms_norm(x, lp["rms1"], eps=cfg.rms_eps)
-    else:
-        h = _rms_norm(x, lp["rms1"], cfg.rms_eps)
-    q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]
-    if cfg.qkv_bias:
-        q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-    q = q.reshape(B, T, H, D)
-    k = k.reshape(B, T, Hkv, D)
-    v = v.reshape(B, T, Hkv, D)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
-    if Hkv != H and not getattr(attn_fn, "supports_gqa", False):
-        # grouped-query: broadcast each kv head over its query group.
-        # GQA-aware attention (the seq-parallel constructors) takes
-        # the COMPACT k/v instead — the ring/a2a then move 1/q_per_kv
-        # the bytes and broadcast per block on-device.
-        k = jnp.repeat(k, cfg.q_per_kv, axis=2)
-        v = jnp.repeat(v, cfg.q_per_kv, axis=2)
-    att = attn_fn(q, k, v).reshape(B, T, E)
-    if fused:
-        # Attention residual add fused into the second norm's kernel.
-        h, x = fused_add_rms_norm(
-            att @ lp["wo"], x, lp["rms2"], eps=cfg.rms_eps
-        )
-    else:
-        x = x + att @ lp["wo"]
-        h = _rms_norm(x, lp["rms2"], cfg.rms_eps)
-    return mlp_tail(x, h, lp, cfg)
+    # The scopes models/gpt.py has: the module profiler and the
+    # device trace's operation metadata attribute cost by them.
+    with jax.named_scope("attn"):
+        if fused:
+            h = fused_rms_norm(x, lp["rms1"], eps=cfg.rms_eps)
+        else:
+            h = _rms_norm(x, lp["rms1"], cfg.rms_eps)
+        q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]
+        if cfg.qkv_bias:
+            q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+        q = q.reshape(B, T, H, D)
+        k = k.reshape(B, T, Hkv, D)
+        v = v.reshape(B, T, Hkv, D)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
+        if Hkv != H and not getattr(attn_fn, "supports_gqa", False):
+            # grouped-query: broadcast each kv head over its query
+            # group. GQA-aware attention (the seq-parallel
+            # constructors) takes the COMPACT k/v instead — the
+            # ring/a2a then move 1/q_per_kv the bytes and broadcast
+            # per block on-device.
+            k = jnp.repeat(k, cfg.q_per_kv, axis=2)
+            v = jnp.repeat(v, cfg.q_per_kv, axis=2)
+        att = attn_fn(q, k, v).reshape(B, T, E)
+        att_out = att @ lp["wo"]
+    with jax.named_scope("mlp"):
+        if fused:
+            # Attention residual add fused into the second norm's
+            # kernel.
+            h, x = fused_add_rms_norm(
+                att_out, x, lp["rms2"], eps=cfg.rms_eps
+            )
+        else:
+            x = x + att_out
+            h = _rms_norm(x, lp["rms2"], cfg.rms_eps)
+        return mlp_tail(x, h, lp, cfg)
 
 
 def mlp_tail(x, h, lp, cfg: LlamaConfig):
@@ -404,10 +411,11 @@ def mlp_tail(x, h, lp, cfg: LlamaConfig):
 def head_logits(params: Params, x: jax.Array) -> jax.Array:
     """lm_head projection in f32 — the single definition shared by
     forward() and the loss paths."""
-    return jnp.einsum(
-        "...te,ve->...tv", x, params["lm_head"],
-        preferred_element_type=jnp.float32,
-    )
+    with jax.named_scope("head"):
+        return jnp.einsum(
+            "...te,ve->...tv", x, params["lm_head"],
+            preferred_element_type=jnp.float32,
+        )
 
 
 def default_attention_for(cfg: LlamaConfig) -> Callable:
@@ -430,7 +438,8 @@ def backbone_with_aux(
         attn_fn = default_attention_for(cfg)
     B, T = tokens.shape
     cos, sin = rope_table(cfg, T)
-    x = params["wte"][tokens].astype(cfg.dtype)
+    with jax.named_scope("embed"):
+        x = params["wte"][tokens].astype(cfg.dtype)
 
     from dlrover_tpu.accelerate.remat import wire_block
 
@@ -498,13 +507,15 @@ def loss_fn_fused(
 
     x, aux = backbone_with_aux(params, tokens, cfg, attn_fn)
     n = x.shape[0] * x.shape[1]
-    return fused_cross_entropy(
-        x.reshape(n, -1),
-        params["lm_head"],
-        targets.reshape(n),
-        num_chunks,
-        save_logits,
-    ) + aux
+    with jax.named_scope("head"):
+        loss = fused_cross_entropy(
+            x.reshape(n, -1),
+            params["lm_head"],
+            targets.reshape(n),
+            num_chunks,
+            save_logits,
+        )
+    return loss + aux
 
 
 def flops_per_token(cfg: LlamaConfig) -> float:

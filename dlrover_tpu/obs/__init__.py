@@ -12,9 +12,11 @@ doing through this package, so "what is the job doing right now" and
   by tests/test_obs.py::test_no_prometheus_or_otel_imports).
 * :mod:`dlrover_tpu.obs.tracer` — lightweight events/spans with
   monotonic timestamps and process/role/rank tags, exported as JSON
-  lines when ``DLROVER_TPU_TRACE_FILE`` is set. Disabled (the
-  default) every hook is a None-check costing well under a
-  microsecond, so instrumented hot paths stay hot.
+  lines when ``DLROVER_TPU_TRACE_FILE`` is set, and as
+  ``dlrover.<span>`` annotations into any ``jax.profiler`` capture
+  that is running. Disabled (the default) every hook is a None-check
+  costing well under a microsecond, so instrumented hot paths stay
+  hot.
 * :mod:`dlrover_tpu.obs.trace_store` — the master-side distributed-
   trace assembler: bounded per-trace span timelines (serving request
   hops with TTFT phase spans, remediation decision chains, rendezvous

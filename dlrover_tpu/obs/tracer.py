@@ -16,6 +16,17 @@ a single None-check and :func:`span` returns a shared no-op context
 manager — well under a microsecond either way, cheap enough for
 per-step hot paths.
 
+**The profiler's clock.** In a process that has already imported
+``jax`` (``sys.modules``; this module never imports it, so master and
+agent pay nothing and stay off the chip) a span also enters
+``jax.profiler.TraceAnnotation(PROFILER_PREFIX + name, **tags)``,
+whether or not the tracer above is on. Outside a capture that is one
+flag test (``TraceAnnotation.is_enabled()``) and the shared no-op
+still comes back; inside one (``jax.profiler.trace``, the ``profile``
+action) the program's spans stand on the ``/host:CPU`` lines of the
+``.xplane.pb`` beside the device's ``XLA Ops``, on one clock. Events
+have no duration and go to the tracer only.
+
 Role/rank tags come from the environment: ``DLROVER_TPU_ROLE`` (set by
 the elastic launcher) and ``JAX_PROCESS_INDEX`` /
 ``DLROVER_TPU_NODE_RANK``.
@@ -41,12 +52,16 @@ import contextlib
 import json
 import os
 import random
+import sys
 import threading
 import time
 from typing import Dict, List, Optional
 
 TRACE_FILE_ENV = "DLROVER_TPU_TRACE_FILE"
 TRACE_ENV = "DLROVER_TPU_TRACE"
+# What a span is called in a jax.profiler capture: "dlrover.ckpt.save".
+# Fixed: readers of a profile select the program's spans by it.
+PROFILER_PREFIX = "dlrover."
 
 _RING_SIZE = 4096
 
@@ -252,6 +267,10 @@ class Span:
         self._t0_mono = 0.0
         self._ctx: Optional[TraceContext] = None
 
+    def set(self, **tags) -> None:
+        """Tags known only inside the span (``ok``, ``bytes``)."""
+        self.tags.update(tags)
+
     def __enter__(self) -> "Span":
         self._t0_wall = time.time()
         self._t0_mono = time.monotonic()
@@ -306,6 +325,9 @@ class Span:
 class _NoopSpan:
     __slots__ = ()
 
+    def set(self, **tags) -> None:
+        return None
+
     def __enter__(self):
         return self
 
@@ -314,6 +336,31 @@ class _NoopSpan:
 
 
 _NOOP_SPAN = _NoopSpan()
+
+
+class _ProfiledSpan:
+    """A span that is also an annotation in the profiler's trace.
+    ``span`` is the tracer's (or the no-op when the tracer is off)."""
+
+    __slots__ = ("_span", "_annotation")
+
+    def __init__(self, span, annotation):
+        self._span = span
+        self._annotation = annotation
+
+    def set(self, **tags) -> None:
+        self._span.set(**tags)
+        self._annotation.set_metadata(**tags)
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        self._span.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._span.__exit__(*exc)
+        self._annotation.__exit__(*exc)
+        return None
 
 
 class EventTracer:
@@ -493,8 +540,17 @@ def event(name: str, **tags) -> Optional[dict]:
 
 
 def span(name: str, **tags):
-    """Span context manager; a shared no-op when tracing is disabled."""
+    """Span context manager; a shared no-op when tracing is disabled
+    and no ``jax.profiler`` capture is running (module docstring)."""
     tr = _tracer if _init_done else _lazy_init()
-    if tr is None:
-        return _NOOP_SPAN
-    return tr.span(name, **tags)
+    own = _NOOP_SPAN if tr is None else tr.span(name, **tags)
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return own
+    try:
+        annotate = jax.profiler.TraceAnnotation
+    except AttributeError:  # jax is still being imported
+        return own
+    if not annotate.is_enabled():  # no capture is running
+        return own
+    return _ProfiledSpan(own, annotate(PROFILER_PREFIX + name, **tags))

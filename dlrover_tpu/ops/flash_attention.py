@@ -549,6 +549,9 @@ def _bwd(
             ),
         ),
         interpret=interpret,
+        # What the device trace calls the kernel, whatever transform
+        # encloses the call (remat, shard_map, a scope).
+        name="flash_attention_bwd",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

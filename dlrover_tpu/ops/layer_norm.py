@@ -179,6 +179,7 @@ def _fwd(x2, res2, g, b, *, eps, rms, block_rows, interpret):
             dimension_semantics=("arbitrary",)
         ),
         interpret=interpret,
+        name="rms_norm_fwd" if rms else "layer_norm_fwd",
     )(*inputs)
     if add_residual:
         out, resid, mu, rstd = outs
@@ -266,6 +267,7 @@ def _bwd(dout2, resid2, g, mu, rstd, *, rms, has_bias, block_rows,
             dimension_semantics=("arbitrary",)
         ),
         interpret=interpret,
+        name="rms_norm_bwd" if rms else "layer_norm_bwd",
     )(dout2, resid2, g.reshape(1, e), mu, rstd)
     dx = outs[0][:n]
     dg = jnp.sum(outs[1], axis=0)

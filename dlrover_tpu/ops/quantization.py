@@ -62,8 +62,9 @@ def _row_spec(width):
     )
 
 
-def _quant_call(kernel, x2, out_width, out_dtype):
-    """Run a quantize kernel over block rows -> (q, scales)."""
+def _quant_call(kernel, x2, out_width, out_dtype, name):
+    """Run a quantize kernel over block rows -> (q, scales). ``name``
+    is what the device trace calls it."""
     grid = x2.shape[0] // _ROWS
     return pl.pallas_call(
         kernel,
@@ -75,10 +76,11 @@ def _quant_call(kernel, x2, out_width, out_dtype):
             jax.ShapeDtypeStruct((x2.shape[0], 1), jnp.float32),
         ],
         interpret=_use_interpret(),
+        name=name,
     )(x2)
 
 
-def _dequant_call(kernel, q, scales, block_size, dtype):
+def _dequant_call(kernel, q, scales, block_size, dtype, name):
     """Run a dequantize kernel -> values [rows_padded, block]."""
     rows = q.shape[0]
     row_pad = (-rows) % _ROWS
@@ -93,6 +95,7 @@ def _dequant_call(kernel, q, scales, block_size, dtype):
         out_specs=_row_spec(block_size),
         out_shape=jax.ShapeDtypeStruct((q.shape[0], block_size), dtype),
         interpret=_use_interpret(),
+        name=name,
     )(q, scales)
 
 
@@ -133,7 +136,9 @@ def quantize_blockwise(
     the shared block — callers with hard accuracy needs should size
     params to block multiples)."""
     x2, rows, shape = _to_block_rows(x, block_size)
-    q, scales = _quant_call(_quantize_kernel, x2, block_size, jnp.int8)
+    q, scales = _quant_call(
+        _quantize_kernel, x2, block_size, jnp.int8, "quantize_int8"
+    )
     return q[:rows], scales[:rows], shape
 
 
@@ -144,7 +149,9 @@ def dequantize_blockwise(
     dtype=jnp.float32,
 ) -> jax.Array:
     rows, block_size = q.shape
-    out = _dequant_call(_dequantize_kernel, q, scales, block_size, dtype)
+    out = _dequant_call(
+        _dequantize_kernel, q, scales, block_size, dtype, "dequantize_int8"
+    )
     return _unflatten(out, rows, shape)
 
 
@@ -197,7 +204,7 @@ def quantize_blockwise_4bit(
     x2, rows, shape = _to_block_rows(x, block_size)
     q, scales = _quant_call(
         functools.partial(_quantize4_kernel, signed=signed),
-        x2, block_size // 2, jnp.uint8,
+        x2, block_size // 2, jnp.uint8, "quantize_4bit",
     )
     return q[:rows], scales[:rows], shape
 
@@ -212,7 +219,7 @@ def dequantize_blockwise_4bit(
     rows, half = q.shape
     out = _dequant_call(
         functools.partial(_dequantize4_kernel, signed=signed),
-        q, scales, half * 2, dtype,
+        q, scales, half * 2, dtype, "dequantize_4bit",
     )
     return _unflatten(out, rows, shape)
 

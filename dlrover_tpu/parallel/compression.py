@@ -291,8 +291,9 @@ def make_compressed_train_step(
         # caller reading metrics["grad_norm"] must not crash only when
         # the search picks an overlap/compressed strategy.
         gnorm = optax.global_norm(grads)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, {"loss": loss, "grad_norm": gnorm}
 
     donate_argnums = (0, 1) if donate else ()

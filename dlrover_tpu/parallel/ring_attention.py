@@ -490,7 +490,7 @@ def ring_prefix_lm_attention(
     causal ring step) in FLOPs — still correct, and still O(T^2 /
     shards) memory, but the FLOP saving advertised above should be
     confirmed with a per-op profile on a real chip before relying on
-    it (tools/profile_step.py). A masking-based schedule (zeroing
+    it (a ``jax.profiler`` trace). A masking-based schedule (zeroing
     contributions instead of branching) would make the cost explicit
     and uniform if profiling shows both branches execute.
 

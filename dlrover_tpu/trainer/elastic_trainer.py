@@ -429,10 +429,11 @@ class ElasticTrainer:
             (grads, loss_sum), _ = jax.lax.scan(
                 micro, (zeros, 0.0), (tokens, targets)
             )
-            updates, opt_state = optimizer.update(
-                grads, opt_state, params
-            )
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope("optimizer"):
+                updates, opt_state = optimizer.update(
+                    grads, opt_state, params
+                )
+                params = optax.apply_updates(params, updates)
             return params, opt_state, loss_sum / accum
 
         self._mb_spec = mb_spec
@@ -515,10 +516,11 @@ class ElasticTrainer:
             # returned scalar the global-batch mean, matching the
             # serial step's replicated loss.
             loss = jax.lax.pmean(loss_sum / accum, axis)
-            updates, opt_state = optimizer.update(
-                grads, opt_state, params
-            )
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope("optimizer"):
+                updates, opt_state = optimizer.update(
+                    grads, opt_state, params
+                )
+                params = optax.apply_updates(params, updates)
             return params, opt_state, loss
 
         rep = P()
@@ -818,11 +820,15 @@ class ElasticTrainer:
                 )
             )
         t0 = time.perf_counter()
-        params, opt_state, loss = self._compiled(
-            params, opt_state, tokens, targets
-        )
-        now = time.perf_counter()
-        compiled_now = self._compile_tracker.observe_call(now - t0)
+        with obs.span(
+            "trainer.dispatch", step=self.step_num + 1
+        ) as span:
+            params, opt_state, loss = self._compiled(
+                params, opt_state, tokens, targets
+            )
+            now = time.perf_counter()
+            compiled_now = self._compile_tracker.observe_call(now - t0)
+            span.set(compiled=compiled_now)
         if self.profiler is not None:
             self.profiler.note_dispatch(now - t0, compiled=compiled_now)
         if self._last_step_t is None:

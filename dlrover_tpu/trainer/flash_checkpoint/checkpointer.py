@@ -19,6 +19,7 @@ import os
 from enum import Enum
 from typing import Optional
 
+from dlrover_tpu import obs
 from dlrover_tpu.common.log import get_logger
 from dlrover_tpu.trainer.flash_checkpoint.engine import CheckpointEngine
 
@@ -84,9 +85,16 @@ class Checkpointer:
         """Stage ``state`` (sharded jax pytree) into host shm; for
         DISK also trigger async persistence. Returns once staging is
         done — storage IO never blocks the train loop."""
-        if storage_type == StorageType.MEMORY:
-            return self.engine.save_to_memory(step, state, extra)
-        return self.engine.save_to_storage(step, state, extra)
+        to_memory = storage_type == StorageType.MEMORY
+        with obs.span(
+            "ckpt.save", step=step,
+            storage="memory" if to_memory else "disk",
+        ) as span:
+            save = (self.engine.save_to_memory if to_memory
+                    else self.engine.save_to_storage)
+            ok = save(step, state, extra)
+            span.set(ok=ok)
+            return ok
 
     def load_checkpoint(self, like, shardings=None,
                         step: Optional[int] = None):

@@ -27,6 +27,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 
 from dlrover_tpu import obs
+from dlrover_tpu.agent.monitor import TrainingMonitor
 from dlrover_tpu.common import ckpt_shm
 from dlrover_tpu.common.ckpt_shm import (
     SharedMemoryHandler,
@@ -160,9 +161,9 @@ class CheckpointEngine:
         """device→host copy of this process's primary shards."""
         import jax
 
-        named = flatten_named(state)
         plans = []
-        hosts: List[np.ndarray] = []
+        shards = []
+        named = flatten_named(state)
         for name, leaf in named:
             if not isinstance(leaf, jax.Array):
                 leaf = jax.numpy.asarray(leaf)
@@ -182,15 +183,22 @@ class CheckpointEngine:
                 if index in seen_index:
                     continue
                 seen_index.add(index)
-                host = np.asarray(shard.data)
-                dtype_name = str(leaf.dtype)
+                plans.append((name, str(leaf.dtype), gshape, index,
+                              shard.data.nbytes))
+                shards.append(shard.data)
+        entries, total = plan_entries(plans)
+        hosts: List[np.ndarray] = []
+        with obs.span(
+            "ckpt.d2h",
+            bytes=sum(p[4] for p in plans),
+            leaves=len(named),
+        ):
+            for (_, dtype_name, *_), data in zip(plans, shards):
+                host = np.asarray(data)
                 raw = ckpt_shm._np_view(dtype_name)
                 if raw is not None:
                     host = host.view(raw)
-                plans.append((name, dtype_name, gshape, index,
-                              host.nbytes))
                 hosts.append(host)
-        entries, total = plan_entries(plans)
         return list(zip(entries, hosts)), total
 
     def save_to_memory(self, step: int, state,
@@ -214,12 +222,17 @@ class CheckpointEngine:
                 "step %s: shm busy (agent persisting); skip staging",
                 step)
             _CKPT_OPS.inc(op="save_memory", result="skipped")
+            obs.event("ckpt.save_skipped", step=step, reason="shm_busy")
             return False
         t0 = time.monotonic()
         try:
             with obs.span("ckpt.save_memory", step=step):
                 arrays, _ = self._stage(state)
-                self._shm.save(step, arrays, extra)
+                with obs.span(
+                    "ckpt.shm_copy",
+                    bytes=sum(e.nbytes for e, _ in arrays),
+                ):
+                    self._shm.save(step, arrays, extra)
             self._cached_step = step
         except Exception:
             # Staging failures must be countable from /metrics, not
@@ -241,13 +254,14 @@ class CheckpointEngine:
         if self._events is not None:
             # The agent-hosted saver learns the checkpoint dir from the
             # event: the agent starts before any trainer chose a dir.
-            self._events.put(
-                {
-                    "type": "save",
-                    "step": step,
-                    "dir": self.checkpoint_dir,
-                }
-            )
+            with obs.span("ckpt.notify_agent", step=step):
+                self._events.put(
+                    {
+                        "type": "save",
+                        "step": step,
+                        "dir": self.checkpoint_dir,
+                    }
+                )
         _CKPT_OPS.inc(op="persist_request", result="ok")
         obs.event("ckpt.persist_requested", step=step)
         return True
@@ -420,51 +434,66 @@ class CheckpointEngine:
         """
         import jax
 
-        res = self.read_shard_metas(step)
-        if res is None:
-            return None
-        found_step, index, extra = res
         named = flatten_named(like)
         like_def = jax.tree_util.tree_structure(like)
-        shard_def = jax.tree_util.tree_structure(shardings)
-        if like_def != shard_def:
-            raise ValueError(
-                f"shardings tree structure {shard_def} does not "
-                f"match `like` tree structure {like_def}")
         sharding_leaves = jax.tree_util.tree_leaves(shardings)
-        # Fail on missing leaves BEFORE streaming gigabytes of the
-        # present ones.
-        missing = [n for n, _ in named if n not in index]
-        if missing:
-            raise KeyError(
-                f"checkpoint step {found_step} missing leaves: "
-                f"{missing[:5]}{'...' if len(missing) > 5 else ''}")
-        leaves = []
-        for (name, leaf), sharding in zip(named, sharding_leaves):
-            sources = index[name]
-            gshape = sources[0][2].global_shape
-            dtype_name = sources[0][2].dtype
-            jdtype = getattr(leaf, "dtype", None)
-            # Replicated device shards share an index: assemble each
-            # UNIQUE slice once, not once per device.
-            slice_cache: Dict[Tuple, np.ndarray] = {}
+        # Every byte is read before the first array is placed, so the
+        # two halves of a restore can be told apart (the host then
+        # holds this process's shards at once, as a save does).
+        with obs.span("ckpt.restore_read", source="disk") as span:
+            res = self.read_shard_metas(step)
+            if res is None:
+                return None
+            found_step, index, extra = res
+            shard_def = jax.tree_util.tree_structure(shardings)
+            if like_def != shard_def:
+                raise ValueError(
+                    f"shardings tree structure {shard_def} does not "
+                    f"match `like` tree structure {like_def}")
+            # Fail on missing leaves BEFORE streaming gigabytes of the
+            # present ones.
+            missing = [n for n, _ in named if n not in index]
+            if missing:
+                raise KeyError(
+                    f"checkpoint step {found_step} missing leaves: "
+                    f"{missing[:5]}{'...' if len(missing) > 5 else ''}")
+            nbytes = 0
+            readers = []
+            for (name, _), sharding in zip(named, sharding_leaves):
+                sources = index[name]
+                gshape = sources[0][2].global_shape
+                dtype_name = sources[0][2].dtype
+                # Replicated device shards share an index: assemble
+                # each UNIQUE slice once, not once per device.
+                slice_cache: Dict[Tuple, np.ndarray] = {}
 
-            def read_cached(idx, s=sources, g=gshape, d=dtype_name,
-                            cache=slice_cache):
-                key = tuple(
-                    (sl.start, sl.stop, sl.step) for sl in idx)
-                if key not in cache:
-                    cache[key] = self._read_slice(s, g, d, idx)
-                return cache[key]
+                def read_cached(idx, s=sources, g=gshape, d=dtype_name,
+                                cache=slice_cache):
+                    key = tuple(
+                        (sl.start, sl.stop, sl.step) for sl in idx)
+                    if key not in cache:
+                        cache[key] = self._read_slice(s, g, d, idx)
+                    return cache[key]
 
-            arr = jax.make_array_from_callback(
-                gshape, sharding, read_cached,
-            )
-            if jdtype is not None and arr.dtype != jdtype:
-                arr = arr.astype(jdtype)
-            leaves.append(arr)
-        treedef = jax.tree_util.tree_structure(like)
-        state = jax.tree_util.tree_unflatten(treedef, leaves)
+                for idx in sharding.addressable_devices_indices_map(
+                        gshape).values():
+                    read_cached(idx)
+                nbytes += sum(a.nbytes for a in slice_cache.values())
+                readers.append((gshape, read_cached))
+            span.set(bytes=nbytes, step=found_step)
+        TrainingMonitor.mark_phase("restore_read_done")
+        with obs.span("ckpt.restore_put"):
+            leaves = []
+            for (_, leaf), sharding, (gshape, read_cached) in zip(
+                    named, sharding_leaves, readers):
+                arr = jax.make_array_from_callback(
+                    gshape, sharding, read_cached,
+                )
+                jdtype = getattr(leaf, "dtype", None)
+                if jdtype is not None and arr.dtype != jdtype:
+                    arr = arr.astype(jdtype)
+                leaves.append(arr)
+            state = jax.tree_util.tree_unflatten(like_def, leaves)
         return found_step, state, extra
 
     def load(self, like, shardings=None,
@@ -497,38 +526,39 @@ class CheckpointEngine:
         # would re-download the file — assemble-then-reshard instead.
         if shardings is not None and self.storage.supports_range():
             return self.load_streaming(like, shardings, step)
-        res = self.load_flat(step)
-        if res is None:
-            return None
-        found_step, flat, extra = res
+        with obs.span("ckpt.restore_read", source="disk") as span:
+            res = self.load_flat(step)
+            if res is None:
+                return None
+            found_step, flat, extra = res
+            span.set(
+                bytes=sum(a.nbytes for a in flat.values()),
+                step=found_step,
+            )
+        TrainingMonitor.mark_phase("restore_read_done")
         named = flatten_named(like)
-        leaves = []
-        missing = []
-        for name, leaf in named:
-            if name not in flat:
-                missing.append(name)
-                leaves.append(None)
-                continue
-            arr = flat[name]
-            leaves.append(arr)
+        missing = [name for name, _ in named if name not in flat]
         if missing:
             raise KeyError(
                 f"checkpoint step {found_step} missing leaves: "
                 f"{missing[:5]}{'...' if len(missing) > 5 else ''}")
         treedef = jax.tree_util.tree_structure(like)
-        state = jax.tree_util.tree_unflatten(treedef, leaves)
-        if shardings is not None:
-            # Match load_streaming: cast to `like`'s dtype so the two
-            # backends produce identical state trees.
-            def put(x, l, s):
-                want = getattr(l, "dtype", None)
-                if want is not None and x.dtype != want:
-                    x = x.astype(want)
-                return jax.device_put(x, s)
+        state = jax.tree_util.tree_unflatten(
+            treedef, [flat[name] for name, _ in named]
+        )
+        with obs.span("ckpt.restore_put"):
+            if shardings is not None:
+                # Match load_streaming: cast to `like`'s dtype so the
+                # two backends produce identical state trees.
+                def put(x, l, s):
+                    want = getattr(l, "dtype", None)
+                    if want is not None and x.dtype != want:
+                        x = x.astype(want)
+                    return jax.device_put(x, s)
 
-            state = jax.tree.map(put, state, like, shardings)
-        else:
-            state = jax.tree.map(jax.numpy.asarray, state)
+                state = jax.tree.map(put, state, like, shardings)
+            else:
+                state = jax.tree.map(jax.numpy.asarray, state)
         return found_step, state, extra
 
     def close(self) -> None:

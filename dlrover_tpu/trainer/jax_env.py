@@ -13,6 +13,7 @@ from __future__ import annotations
 import os
 from typing import Optional
 
+from dlrover_tpu import obs
 from dlrover_tpu.common.constants import NodeEnv
 from dlrover_tpu.common.log import get_logger
 
@@ -70,43 +71,40 @@ def setup_distributed() -> None:
     global _initialized
     if _initialized:
         return
-    # Black box before the backend: an agent-supervised training
-    # process gets its flight recorder (crash bundles + the SIGUSR1
-    # while-hung stack-dump contract the agent's hang forensics rely
-    # on) before jax.distributed can wedge or die. Standalone runs
-    # opt in with DLROVER_TPU_FLIGHT_RECORDER=1 or a direct
-    # obs.install_flight_recorder("trainer") call — in-process test
-    # harnesses must not have their excepthooks rewired implicitly.
-    if (
-        os.getenv("DLROVER_TPU_AGENT_PRESENT", "") == "1"
-        or os.getenv("DLROVER_TPU_FLIGHT_RECORDER", "") == "1"
-    ):
-        from dlrover_tpu import obs
-
-        obs.install_flight_recorder(
-            "trainer", rank=int(os.getenv(NodeEnv.NODE_RANK, "-1"))
-        )
-    enable_compile_cache()
     n = num_processes()
-    if n <= 1:
-        _initialized = True
-        return
-    import jax
+    with obs.span("boot.distributed_init", num_processes=n):
+        # Black box before the backend: an agent-supervised training
+        # process gets its flight recorder (crash bundles + the SIGUSR1
+        # while-hung stack-dump contract the agent's hang forensics
+        # rely on) before jax.distributed can wedge or die. Standalone
+        # runs opt in with DLROVER_TPU_FLIGHT_RECORDER=1 or a direct
+        # obs.install_flight_recorder("trainer") call — in-process test
+        # harnesses must not have their excepthooks rewired implicitly.
+        if (
+            os.getenv("DLROVER_TPU_AGENT_PRESENT", "") == "1"
+            or os.getenv("DLROVER_TPU_FLIGHT_RECORDER", "") == "1"
+        ):
+            obs.install_flight_recorder(
+                "trainer", rank=int(os.getenv(NodeEnv.NODE_RANK, "-1"))
+            )
+        enable_compile_cache()
+        if n > 1:
+            import jax
 
-    addr = coordinator_address()
-    pid = process_id()
-    logger.info(
-        "jax.distributed.initialize(coordinator=%s, num_processes=%d, "
-        "process_id=%d)",
-        addr,
-        n,
-        pid,
-    )
-    jax.distributed.initialize(
-        coordinator_address=addr,
-        num_processes=n,
-        process_id=pid,
-    )
+            addr = coordinator_address()
+            pid = process_id()
+            logger.info(
+                "jax.distributed.initialize(coordinator=%s, "
+                "num_processes=%d, process_id=%d)",
+                addr,
+                n,
+                pid,
+            )
+            jax.distributed.initialize(
+                coordinator_address=addr,
+                num_processes=n,
+                process_id=pid,
+            )
     _initialized = True
 
 

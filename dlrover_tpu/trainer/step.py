@@ -195,8 +195,9 @@ def make_train_step(
 
     def step(params, opt_state, tokens, targets):
         loss, grads = jax.value_and_grad(loss_fn)(params, tokens, targets)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         gnorm = optax.global_norm(grads)
         return params, opt_state, {"loss": loss, "grad_norm": gnorm}
 
@@ -436,10 +437,11 @@ class PipelinedTrainStep:
 
         def update(params, opt_state, grad_acc, loss_sum):
             gnorm = optax.global_norm(grad_acc)
-            updates, opt_state = optimizer.update(
-                grad_acc, opt_state, params
-            )
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope("optimizer"):
+                updates, opt_state = optimizer.update(
+                    grad_acc, opt_state, params
+                )
+                params = optax.apply_updates(params, updates)
             return params, opt_state, {
                 "loss": loss_sum / accum,
                 "grad_norm": gnorm,

@@ -52,8 +52,7 @@ def is_xent_token(p: str) -> bool:
 
 def build_spec(spec: str):
     """Parse a sweep spec -> (cfg, attn_fn, batch, save_logits,
-    xent_chunks). Shared with tools/profile_step.py so the profiled
-    config is byte-identical to the benchmarked one. Omitted fields
+    xent_chunks). Omitted fields
     default to flash attention with the kernel's own autotuned block
     sizes and batch 16; xent_chunks resolves here (xcN token, else
     SWEEP_XENT_CHUNKS, else 8) so every caller sees one value."""

@@ -2,10 +2,10 @@
 # Probe-gated chain of the round's hardware jobs, ordered per the
 # round-4 verdict: the moment the TPU answers, land the bench
 # record FIRST (PERF_r05.json), the kernel smoke SECOND
-# (KERNELS_r05.json), then the multi-run stability record, a step
-# profile, the autotune+tuned re-bench (pins the headline config),
-# AGD convergence on chip, long-context bench, decode bench, the
-# uncapped tune retry, and a profile of the tuned winner. Each
+# (KERNELS_r05.json), then the multi-run stability record, the
+# autotune+tuned re-bench (pins the headline config), AGD convergence
+# on chip, long-context bench, decode bench, and the uncapped tune
+# retry. Each
 # stage's gate is an artifact written ONLY on success, so an outage
 # mid-stage retries on the next probe instead of permanently
 # skipping.
@@ -157,22 +157,6 @@ for i in $(seq 1 400); do
       echo "[$(date +%T)] bench stability (3 runs)"
       run_stage 3600 python -u tools/bench_stability.py >> /tmp/bench_stability.log 2>&1
       echo "[$(date +%T)] stability rc=$?"
-    elif [ ! -f /tmp/profile_step.txt ] && [ "$(cat /tmp/profile_step.fails 2>/dev/null || echo 0)" -lt 2 ]; then
-      # Moved ahead of the long stages: the per-op attribution gates
-      # the round's attention-optimization work, and an outage
-      # after stability must not leave the builder blind for hours.
-      # Capped at 2 failures so a deterministically broken profiler
-      # can't starve AGD/longctx/decode/tune of the whole window.
-      echo "[$(date +%T)] profiling the tuned step"
-      if run_stage 900 python -u tools/profile_step.py 'full,flash,18,1024,1024,-,nofn' > /tmp/profile_step.partial 2>&1; then
-        mv /tmp/profile_step.partial /tmp/profile_step.txt
-        echo "[$(date +%T)] profile ok ($(wc -l < /tmp/profile_step.txt) lines)"
-      else
-        rc=$?
-        fails=$(( $(cat /tmp/profile_step.fails 2>/dev/null || echo 0) + 1 ))
-        echo "$fails" > /tmp/profile_step.fails
-        echo "[$(date +%T)] profile failed rc=$rc (failure $fails/2)"
-      fi
     elif [ ! -f /tmp/capture_tune.done ] && [ "$(cat /tmp/capture_tune.fails 2>/dev/null || echo 0)" -lt 2 ]; then
       # Ahead of AGD/longctx/decode: the tune winner auto-pins into
       # bench_tuned.json, which the driver's end-of-round capture
@@ -200,7 +184,7 @@ for i in $(seq 1 400); do
         && [ "$(cat /tmp/agd_conv.fails 2>/dev/null || echo 0)" -lt 2 ]; then
       # A labeled reduced-scale CPU fallback (written if the chip
       # stayed dead) is superseded by a real-chip run. Capped at 2
-      # failures like profile/tune so a deterministically broken
+      # failures like tune so a deterministically broken
       # study can't starve longctx/decode of the window.
       echo "[$(date +%T)] running agd convergence (200 steps x 3 runs)"
       if run_stage 2700 python -u tools/agd_convergence.py --steps 200 >> /tmp/agd_conv.log 2>&1; then
@@ -226,22 +210,6 @@ for i in $(seq 1 400); do
       rc=$?
       [ $rc -eq 0 ] && touch /tmp/capture_tune.done
       echo "[$(date +%T)] tune retry rc=$rc"
-    elif [ -f bench_tuned.json ] && [ ! -f /tmp/profile_tuned.txt ]; then
-      # Attribution of the TUNED step (where does the winner's time
-      # go) — spec comes straight from the pinned winner.
-      spec=$(python -c "import json;print(json.load(open('bench_tuned.json'))['spec'])" 2>/dev/null)
-      if [ -n "$spec" ]; then
-        echo "[$(date +%T)] profiling the tuned winner: $spec"
-        run_stage 900 python -u tools/profile_step.py "$spec" > /tmp/profile_tuned.partial 2>&1
-        rc=$?
-        # single attempt either way; keep the output (including the
-        # failure diagnostics) rather than touching an empty file
-        mv /tmp/profile_tuned.partial /tmp/profile_tuned.txt
-        echo "[$(date +%T)] tuned profile rc=$rc"
-      else
-        echo "[$(date +%T)] bench_tuned.json has no readable spec; skipping tuned profile"
-        touch /tmp/profile_tuned.txt
-      fi
     else
       echo "[$(date +%T)] all jobs done"; exit 0
     fi
