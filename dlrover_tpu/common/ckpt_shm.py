@@ -34,7 +34,8 @@ that must stay light (and must not grab a TPU chip).
 from __future__ import annotations
 
 import threading
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+import time
+from typing import Dict, Iterable, List, Optional, Sequence, Tuple, Union
 
 import msgpack
 import numpy as np
@@ -168,32 +169,53 @@ class SharedMemoryHandler:
                                        size=int(size * 1.1) + 4096)
         return self._shm
 
-    def save(self, step: int,
-             arrays: List[Tuple[TensorEntry, np.ndarray]],
-             extra: Optional[dict] = None) -> None:
-        """Write staged shards into shm. ``arrays`` pairs each planned
-        entry with its host ndarray (raw view for bf16 etc.)."""
-        entries = [e for e, _ in arrays]
+    def save(self, step: int, entries: List[TensorEntry],
+             arrivals: Iterable[Union[np.ndarray, Iterable[np.ndarray]]],
+             extra: Optional[dict] = None) -> float:
+        """Write planned shards into shm. ``arrivals`` gives, for each
+        of ``entries`` in order, the entry's host ndarray or the
+        ndarrays that make it up, in order (raw view or true dtype, any
+        shape: only their bytes count). Each is written as it is taken,
+        so ``arrivals`` may be an iterator that is still waiting for
+        the later ones. Returns the seconds spent copying."""
         meta = pack_meta(step, entries, extra)
         payload = (entries[-1].offset + entries[-1].nbytes) if entries else 0
         base = _META_LEN_BYTES + len(meta)
+        copy_s = 0.0
         with self._lock:
             shm = self._ensure(base + payload)
             buf = shm.buf
             # Torn-write guard: invalidate the segment (meta_len=0)
             # before touching bytes, and publish the meta length only
             # after the full payload landed. A trainer killed mid-save
-            # leaves meta_len=0 and readers see "no state" instead of a
-            # silently mixed-step checkpoint.
+            # (or an arrival that raises) leaves meta_len=0 and readers
+            # see "no state" instead of a silently mixed-step
+            # checkpoint.
             buf[:_META_LEN_BYTES] = (0).to_bytes(_META_LEN_BYTES,
                                                  "little")
             buf[_META_LEN_BYTES:base] = meta
-            for entry, arr in arrays:
-                start = base + entry.offset
-                flat = np.ascontiguousarray(arr).view(np.uint8).reshape(-1)
-                buf[start:start + entry.nbytes] = flat.data
+            for entry, pieces in zip(entries, arrivals, strict=True):
+                if isinstance(pieces, np.ndarray):
+                    pieces = (pieces,)
+                at = base + entry.offset
+                left = entry.nbytes
+                for arr in pieces:
+                    flat = np.ascontiguousarray(arr).reshape(-1).view(
+                        np.uint8)
+                    left -= flat.size
+                    if left < 0:
+                        break
+                    t0 = time.perf_counter()
+                    buf[at:at + flat.size] = flat.data
+                    copy_s += time.perf_counter() - t0
+                    at += flat.size
+                if left:
+                    raise ValueError(
+                        f"{entry.name}: the arrays given do not hold "
+                        f"the {entry.nbytes} bytes planned")
             buf[:_META_LEN_BYTES] = len(meta).to_bytes(_META_LEN_BYTES,
                                                        "little")
+        return copy_s
 
     # -- reader side -----------------------------------------------------
 

@@ -8,9 +8,15 @@ save_to_memory:169 with the shm-lock + all-rank-ready barrier
 * state is one *global* sharded pytree, not per-rank torch state_dicts;
   each process stages only the addressable shards it owns (replica 0 of
   each shard, so replicated leaves are written exactly once per shard);
-* device→host is a ``jax.device_get`` of those shards (the analogue of
-  the reference's GPU→CPU ``tensor.copy_`` into shm, measured 2.3s for
-  3GB in docs/design/async-checkpoint.md);
+* device→host keeps many transfers of a few MiB in flight at once
+  (``_ReadAhead``): a large shard is laid out linearly on the device
+  and cut into pieces, a bounded number of bytes ahead, and each piece
+  is written into shm as it arrives. The analogue of the reference's
+  GPU→CPU ``tensor.copy_`` into shm (2.3 s for 3 GB in
+  docs/design/async-checkpoint.md); on a v5e, device idle, GPT-2
+  124M's 1.244 GB reach the segment in 0.17 s (7.3 GB/s) and 7 GB of
+  Mistral-width state in 1.04 s, where one ``np.asarray`` a shard and
+  then the copy took 0.77 s and 4.57 s (PERF.md, PR 25);
 * persistence is delegated to the host agent via a SharedQueue event —
   the trainer never blocks on storage.
 
@@ -21,8 +27,10 @@ reshard-on-restart (atorch/utils/fsdp_save_util.py) by construction.
 
 from __future__ import annotations
 
+import collections
+import functools
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
 
@@ -56,6 +64,19 @@ _CKPT_RESTORE_SECONDS = obs.histogram(
     "dlrover_ckpt_restore_seconds",
     "End-to-end restore time of CheckpointEngine.load",
 )
+
+# How a save reads the state off the device (``_ReadAhead``). A shard
+# above _PIECE_BYTES goes to the host as linear pieces of about that
+# size: well under the 32 MiB from which the C allocator maps fresh
+# pages for every buffer, so a piece written into the segment leaves
+# its memory, already mapped, to a later one. Shards are started until
+# _AHEAD_BYTES of them are on their way, so the read holds that much of
+# device memory beyond the state, and while it cuts a shard up to twice
+# that shard more. Measured on a v5e with GPT-2 124M's and Mistral's
+# states, pieces of 2 to 32 MiB and 128 MiB to 2 GiB ahead (PERF.md,
+# PR 25); not options.
+_PIECE_BYTES = 8 << 20
+_AHEAD_BYTES = 512 << 20
 
 CKPT_EVENT_QUEUE = "ckpt_events"
 CKPT_STATUS_DICT = "ckpt_status"
@@ -114,6 +135,67 @@ def unpack_shard_file(data: bytes) -> Tuple[int, List[TensorEntry],
     return step, entries, extra, data[8 + meta_len:]
 
 
+@functools.cache
+def _linear_pieces():
+    """The jitted cut of one shard into linear pieces of ``size``
+    elements (the last one shorter), on the shard's device. jax is not
+    imported with this module: the agent's saver imports it too."""
+    import jax
+
+    def cut(x, size):
+        flat = x.reshape(-1)
+        return tuple(
+            flat[at:at + size] for at in range(0, flat.shape[0], size)
+        )
+
+    return jax.jit(cut, static_argnums=1)
+
+
+class _ReadAhead:
+    """The host arrays of ``shards`` (single-device ``jax.Array``s), in
+    order: each item is an iterator over the ndarrays whose bytes, in
+    order, are that shard's.
+
+    The runtime gives every transfer a new host buffer and fills it
+    with one thread. A whole shard at a time that reads 1.4-1.8 GB/s on
+    a v5e, and 2.4 GB/s with all of them in flight: the buffers are
+    fresh mappings whose pages fault in as they are filled. Many
+    transfers of a few MiB in flight, each dropped once its bytes are
+    in the segment, read 3.6-7.3 GB/s (PERF.md, PR 25). So a shard
+    above ``_PIECE_BYTES`` is first laid out linearly on the device and
+    cut into pieces, and shards are started until ``_AHEAD_BYTES`` are
+    on their way; taking a shard's arrays starts the ones behind it.
+    ``in_flight`` counts the transfers started before the first wait.
+    """
+
+    def __init__(self, shards):
+        self._todo = iter(shards)
+        self._started = collections.deque()  # (nbytes, its pieces)
+        self._ahead = 0  # bytes started and not yet taken
+        self._start_more()
+        self.in_flight = sum(len(p) for _, p in self._started)
+
+    def _start_more(self) -> None:
+        while self._ahead < _AHEAD_BYTES:
+            shard = next(self._todo, None)
+            if shard is None:
+                return
+            pieces = (shard,) if shard.nbytes <= _PIECE_BYTES else (
+                _linear_pieces()(
+                    shard, _PIECE_BYTES // shard.dtype.itemsize))
+            for piece in pieces:
+                piece.copy_to_host_async()
+            self._started.append((shard.nbytes, pieces))
+            self._ahead += shard.nbytes
+
+    def __iter__(self) -> Iterator[Iterator[np.ndarray]]:
+        while self._started:
+            nbytes, pieces = self._started.popleft()
+            yield (np.asarray(piece) for piece in pieces)
+            self._ahead -= nbytes
+            self._start_more()
+
+
 class CheckpointEngine:
     """Stages sharded jax state into shm; loads committed checkpoints.
 
@@ -156,9 +238,9 @@ class CheckpointEngine:
 
     # -- save ------------------------------------------------------------
 
-    def _stage(self, state) -> Tuple[List[Tuple[TensorEntry, np.ndarray]],
-                                     int]:
-        """device→host copy of this process's primary shards."""
+    def _plan(self, state) -> Tuple[List[TensorEntry], list, int]:
+        """This process's primary shards, laid out in the segment:
+        (entries, each entry's single-device array, leaves)."""
         import jax
 
         plans = []
@@ -186,20 +268,25 @@ class CheckpointEngine:
                 plans.append((name, str(leaf.dtype), gshape, index,
                               shard.data.nbytes))
                 shards.append(shard.data)
-        entries, total = plan_entries(plans)
-        hosts: List[np.ndarray] = []
-        with obs.span(
-            "ckpt.d2h",
-            bytes=sum(p[4] for p in plans),
-            leaves=len(named),
-        ):
-            for (_, dtype_name, *_), data in zip(plans, shards):
-                host = np.asarray(data)
-                raw = ckpt_shm._np_view(dtype_name)
-                if raw is not None:
-                    host = host.view(raw)
-                hosts.append(host)
-        return list(zip(entries, hosts)), total
+        entries, _ = plan_entries(plans)
+        return entries, shards, len(named)
+
+    def _stage(self, step: int, state, extra: dict) -> None:
+        """device→shm: start the transfers of the planned shards
+        (``_ReadAhead``), then write each array into the segment as it
+        arrives, in plan order. Returns once the last byte is in the
+        segment and the segment is published."""
+        entries, shards, leaves = self._plan(state)
+        nbytes = sum(e.nbytes for e in entries)
+        with obs.span("ckpt.d2h", bytes=nbytes, leaves=leaves) as d2h:
+            t0 = time.monotonic()
+            arrivals = _ReadAhead(shards)
+            d2h.set(in_flight=arrivals.in_flight)
+            with obs.span("ckpt.shm_copy", bytes=nbytes) as copy:
+                copy.set(write_s=round(
+                    self._shm.save(step, entries, arrivals, extra), 6))
+            d2h.set(gbps=round(
+                nbytes / 1e9 / max(time.monotonic() - t0, 1e-9), 3))
 
     def save_to_memory(self, step: int, state,
                        extra: Optional[dict] = None) -> bool:
@@ -227,12 +314,7 @@ class CheckpointEngine:
         t0 = time.monotonic()
         try:
             with obs.span("ckpt.save_memory", step=step):
-                arrays, _ = self._stage(state)
-                with obs.span(
-                    "ckpt.shm_copy",
-                    bytes=sum(e.nbytes for e, _ in arrays),
-                ):
-                    self._shm.save(step, arrays, extra)
+                self._stage(step, state, extra)
             self._cached_step = step
         except Exception:
             # Staging failures must be countable from /metrics, not

@@ -17,6 +17,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dlrover_tpu.agent.ckpt_saver import AsyncCheckpointSaver
 from dlrover_tpu.common import ckpt_shm
+from dlrover_tpu.trainer.flash_checkpoint import engine as engine_mod
 from dlrover_tpu.trainer.flash_checkpoint.engine import CheckpointEngine
 
 
@@ -70,8 +71,7 @@ class TestShmFormat:
         assert entries[1].offset % 128 == 0
         handler = ckpt_shm.SharedMemoryHandler(0)
         try:
-            handler.save(7, list(zip(entries, [a for _, a in arrs])),
-                         {"k": "v"})
+            handler.save(7, entries, [a for _, a in arrs], {"k": "v"})
             step, got_entries, extra, payload = handler.load()
             assert step == 7 and extra["k"] == "v"
             flat = ckpt_shm.assemble_global(got_entries, payload)
@@ -90,7 +90,7 @@ class TestShmFormat:
         entries, _ = ckpt_shm.plan_entries(plans)
         handler = ckpt_shm.SharedMemoryHandler(0)
         try:
-            handler.save(1, [(entries[0], raw)])
+            handler.save(1, entries, [raw])
             _, got, _, payload = handler.load()
             flat = ckpt_shm.assemble_global(got, payload)
             assert flat["x"].dtype == ml_dtypes.bfloat16
@@ -98,6 +98,114 @@ class TestShmFormat:
         finally:
             handler.unlink()
             handler.close()
+
+
+    @pytest.mark.parametrize("pieces", [
+        pytest.param(lambda a: a, id="one-array"),
+        pytest.param(lambda a: [a[:1], a[1:]], id="two-pieces"),
+        pytest.param(lambda a: iter(np.split(a.reshape(-1), 4)),
+                     id="an-iterator-of-four"),
+    ])
+    def test_an_entry_is_its_pieces_in_order(self, pieces):
+        a = np.arange(24, dtype=np.float32).reshape(4, 6)
+        b = np.arange(5, dtype=np.int32)
+        plans = [("a", "float32", a.shape, [(0, 4), (0, 6)], a.nbytes),
+                 ("b", "int32", b.shape, [(0, 5)], b.nbytes)]
+        entries, _ = ckpt_shm.plan_entries(plans)
+        handler = ckpt_shm.SharedMemoryHandler(0)
+        try:
+            copy_s = handler.save(2, entries, iter([pieces(a), b]))
+            assert copy_s >= 0.0
+            _, got, _, payload = handler.load()
+            flat = ckpt_shm.assemble_global(got, payload)
+            np.testing.assert_array_equal(flat["a"], a)
+            np.testing.assert_array_equal(flat["b"], b)
+        finally:
+            handler.unlink()
+            handler.close()
+
+    @pytest.mark.parametrize("arrivals", [
+        pytest.param(lambda a, b: [a[:3], b], id="too-few-bytes"),
+        pytest.param(lambda a, b: [[a, a[:1]], b], id="too-many-bytes"),
+        pytest.param(lambda a, b: [a], id="an-entry-never-arrives"),
+        pytest.param(lambda a, b: [a, b, b], id="one-arrival-too-many"),
+    ])
+    def test_a_save_that_does_not_fit_its_plan_leaves_no_state(
+            self, arrivals):
+        a = np.arange(24, dtype=np.float32).reshape(4, 6)
+        b = np.arange(5, dtype=np.int32)
+        plans = [("a", "float32", a.shape, [(0, 4), (0, 6)], a.nbytes),
+                 ("b", "int32", b.shape, [(0, 5)], b.nbytes)]
+        entries, _ = ckpt_shm.plan_entries(plans)
+        handler = ckpt_shm.SharedMemoryHandler(0)
+        try:
+            handler.save(1, entries, [a, b])
+            assert handler.load()[0] == 1
+            with pytest.raises(ValueError):
+                handler.save(2, entries, arrivals(a, b))
+            assert handler.load() is None
+        finally:
+            handler.unlink()
+            handler.close()
+
+
+class _FakeShard:
+    """Stands for a single-device array: says when its transfer was
+    started and when its value was taken."""
+
+    def __init__(self, log, i, nbytes):
+        self.log, self.i, self.nbytes = log, i, nbytes
+
+    def copy_to_host_async(self):
+        self.log.append(("start", self.i))
+
+    def __array__(self, dtype=None, copy=None):
+        self.log.append(("take", self.i))
+        return np.full(self.nbytes, self.i, np.uint8)
+
+
+class TestReadAhead:
+    @pytest.mark.parametrize("n, nbytes, ahead", [
+        pytest.param(6, 64, None, id="all-within-the-window"),
+        pytest.param(7, 100, 250, id="a-window-of-three"),
+        pytest.param(4, 100, 1, id="a-window-of-one"),
+    ])
+    def test_transfers_are_started_before_any_is_waited_on(
+            self, monkeypatch, n, nbytes, ahead):
+        if ahead is not None:
+            monkeypatch.setattr(engine_mod, "_AHEAD_BYTES", ahead)
+        window = n if ahead is None else -(-ahead // nbytes)
+        log = []
+        read = engine_mod._ReadAhead(
+            [_FakeShard(log, i, nbytes) for i in range(n)])
+        # Before the first wait: the whole window is on its way.
+        assert log == [("start", i) for i in range(window)]
+        assert read.in_flight == window
+        for i, pieces in enumerate(read):
+            (host,) = pieces
+            assert host.tobytes() == bytes([i]) * nbytes
+            started = [k for what, k in log if what == "start"]
+            taken = [k for what, k in log if what == "take"]
+            # In plan order, each started before it is taken, and
+            # never more than the window ahead of the one being taken.
+            assert taken == list(range(i + 1))
+            assert started == list(range(len(started)))
+            assert i < len(started) <= i + window
+        assert [k for what, k in log if what == "start"] == list(range(n))
+
+    def test_a_shard_above_the_piece_size_arrives_in_linear_pieces(
+            self, monkeypatch):
+        monkeypatch.setattr(engine_mod, "_PIECE_BYTES", 4096)
+        big = jnp.arange(48 * 100, dtype=jnp.bfloat16).reshape(48, 100)
+        small = jnp.arange(7, dtype=jnp.int32)
+        read = engine_mod._ReadAhead([big, small])
+        assert read.in_flight == 3 + 1
+        got_big, got_small = ([np.asarray(p) for p in pieces]
+                              for pieces in read)
+        assert [p.shape for p in got_big] == [(2048,), (2048,), (704,)]
+        assert (b"".join(p.tobytes() for p in got_big)
+                == np.asarray(big).tobytes())
+        assert got_small[0].tobytes() == np.asarray(small).tobytes()
 
 
 class TestEngineSaverEndToEnd:
@@ -167,6 +275,106 @@ class TestEngineSaverEndToEnd:
             assert engine.latest_step() == 2
             _, flat, _ = engine.load_flat()
             np.testing.assert_array_equal(flat["x"], np.full(8, 2.0))
+        finally:
+            engine.close()
+
+
+class TestStagedBytes:
+    """What a save leaves in the segment, against one ``np.asarray``
+    of each planned shard taken here."""
+
+    def _mixed_state(self):
+        mesh = _mesh((4,), ("data",))
+        rows = NamedSharding(mesh, P("data", None))
+        everywhere = NamedSharding(mesh, P())
+        key = jax.random.PRNGKey(0)
+        return {
+            "w": jax.device_put(
+                jax.random.normal(key, (64, 128), jnp.float32), rows),
+            "h": jax.device_put(
+                jax.random.normal(key, (48, 100)).astype(jnp.bfloat16),
+                everywhere),
+            "inner": {
+                "v": jax.device_put(
+                    jnp.arange(4 * 1500, dtype=jnp.bfloat16
+                               ).reshape(4, 1500), rows),
+                "b": jax.device_put(
+                    jnp.arange(100, dtype=jnp.float32), everywhere),
+                "count": jnp.int32(7),
+            },
+        }
+
+    @pytest.mark.parametrize("piece_bytes, ahead_bytes", [
+        pytest.param(None, None, id="every-shard-as-it-lies"),
+        pytest.param(4096, None, id="large-shards-in-pieces"),
+        pytest.param(4096, 1, id="in-pieces-one-shard-ahead"),
+        pytest.param(1000, 10000, id="odd-pieces-a-small-window"),
+    ])
+    def test_the_segment_holds_each_shards_bytes(
+            self, monkeypatch, tmp_path, piece_bytes, ahead_bytes):
+        if piece_bytes is not None:
+            monkeypatch.setattr(engine_mod, "_PIECE_BYTES", piece_bytes)
+        if ahead_bytes is not None:
+            monkeypatch.setattr(engine_mod, "_AHEAD_BYTES", ahead_bytes)
+        state = self._mixed_state()
+        engine = CheckpointEngine(str(tmp_path / "ckpt"), use_agent=False)
+        try:
+            entries, shards, leaves = engine._plan(state)
+            assert leaves == 5
+            # Sharded leaves: a shard a device; replicated: one.
+            assert [e.name for e in entries] == (
+                ["h"] + ["inner/b", "inner/count"] + ["inner/v"] * 4
+                + ["w"] * 4)
+            assert engine.save_to_memory(9, state, {"k": 1})
+            step, got, extra, payload = engine._shm.load()
+            assert step == 9 and extra["k"] == 1
+            assert ([e.to_dict() for e in got]
+                    == [e.to_dict() for e in entries])
+            for e, shard in zip(entries, shards):
+                want = np.asarray(shard).tobytes()
+                assert len(want) == e.nbytes
+                assert payload[e.offset:e.offset + e.nbytes] == want, (
+                    e.name, e.index)
+            flat = ckpt_shm.assemble_global(got, payload)
+            for name, leaf in engine_mod.flatten_named(state):
+                assert flat[name].dtype == leaf.dtype
+                np.testing.assert_array_equal(flat[name], np.asarray(leaf))
+        finally:
+            engine._shm.unlink()
+            engine.close()
+
+    @pytest.mark.parametrize("fails_at", [0, 2, 4])
+    def test_a_transfer_that_fails_leaves_no_state(
+            self, saver, monkeypatch, tmp_path, fails_at):
+        state = self._mixed_state()
+        real_iter = engine_mod._ReadAhead.__iter__
+
+        def breaks(self):
+            for i, pieces in enumerate(real_iter(self)):
+                if i == fails_at:
+                    raise RuntimeError("transfer failed")
+                yield pieces
+
+        errors = engine_mod._CKPT_OPS.value(
+            op="save_memory", result="error")
+        engine = CheckpointEngine(str(tmp_path / "ckpt"), use_agent=True)
+        try:
+            assert engine.save_to_memory(1, state)
+            assert engine._shm.load()[0] == 1
+            monkeypatch.setattr(engine_mod._ReadAhead, "__iter__", breaks)
+            with pytest.raises(RuntimeError, match="transfer failed"):
+                engine.save_to_memory(2, state)
+            # No state, rather than step 1's bytes under step 2's
+            # name; the lock is free; the failure is counted once.
+            assert engine._shm.load() is None
+            assert engine._lock.acquire(blocking=False)
+            engine._lock.release()
+            assert engine_mod._CKPT_OPS.value(
+                op="save_memory", result="error") == errors + 1
+            monkeypatch.setattr(
+                engine_mod._ReadAhead, "__iter__", real_iter)
+            assert engine.save_to_memory(3, state)
+            assert engine._shm.load()[0] == 3
         finally:
             engine.close()
 

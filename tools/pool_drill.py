@@ -106,7 +106,7 @@ def _flash_checkpoint(state: JobState, node_id: int) -> int:
     )
     handler = SharedMemoryHandler(node_id, job=state.job_id)
     try:
-        handler.save(step, [(entries[0], done)])
+        handler.save(step, entries, [done])
         loaded = handler.load()
         if loaded is None:
             raise DrillError(
