@@ -1,0 +1,12 @@
+"""Kernel ``moe_tgmm``: what one weight-gradient product of the expert
+MLP (ops/grouped_matmul.py, ``moe_tgmm``) has to do on one device."""
+
+from benchmark.kernel_work import moe_gmm
+
+
+def work(shape: dict, batch_rows: int) -> dict:
+    """``rows x embd`` transposed times ``rows x expert_width``, group
+    by group: the operations of the product it is the gradient of, and
+    the same bytes the other way round (both activations read, every
+    expert's ``embd x expert_width`` gradient written once, bf16)."""
+    return moe_gmm.work(shape, batch_rows)

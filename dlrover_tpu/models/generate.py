@@ -166,11 +166,14 @@ def gpt_prefill(params, cache: KVCache, tokens, cfg):
 
 
 def _llama_qkv(h, lp, cfg, B, T):
-    """q/k/v projections incl. the optional GLM-style bias, reshaped
-    to [B, T, heads, D]."""
+    """q/k/v projections incl. the optional GLM-style bias and
+    OLMoE-style query/key norm, reshaped to [B, T, heads, D]."""
     q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]
     if getattr(cfg, "qkv_bias", False):
         q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+    if getattr(cfg, "qk_norm", False):
+        q = llama_mod._rms_norm(q, lp["q_norm"], cfg.rms_eps)
+        k = llama_mod._rms_norm(k, lp["k_norm"], cfg.rms_eps)
     D = cfg.head_dim
     return (
         q.reshape(B, T, cfg.n_head, D),

@@ -35,11 +35,23 @@ def _np(t) -> np.ndarray:
 
 
 def llama_config_from_hf(hf_config) -> LlamaConfig:
-    """Map an HF Llama (or Mistral/Mixtral) config to ours — Mixtral
-    configs carry num_local_experts/num_experts_per_tok, which switch
-    the native family into MoE mode; a Mistral ``sliding_window``
+    """Map an HF Llama (or Mistral/Mixtral/OLMoE) config to ours —
+    Mixtral configs carry num_local_experts/num_experts_per_tok and
+    OLMoE ones num_experts/num_experts_per_tok/norm_topk_prob, which
+    switch the native family into MoE mode (OLMoE also into
+    normalised queries and keys); a Mistral ``sliding_window``
     carries through to the banded flash kernel."""
+    olmoe = getattr(hf_config, "model_type", "") == "olmoe"
+    n_experts = getattr(
+        hf_config, "num_experts" if olmoe else "num_local_experts", 0
+    )
     return LlamaConfig(
+        qk_norm=olmoe,
+        # Mixtral always renormalises its top-k weights.
+        moe_renorm_top_k=getattr(hf_config, "norm_topk_prob", True),
+        moe_aux_loss_weight=getattr(
+            hf_config, "router_aux_loss_coef", 1e-2
+        ),
         sliding_window=getattr(hf_config, "sliding_window", None),
         vocab_size=hf_config.vocab_size,
         block_size=hf_config.max_position_embeddings,
@@ -53,16 +65,17 @@ def llama_config_from_hf(hf_config) -> LlamaConfig:
         intermediate=hf_config.intermediate_size,
         rope_theta=getattr(hf_config, "rope_theta", 10000.0),
         rms_eps=hf_config.rms_norm_eps,
-        n_experts=getattr(hf_config, "num_local_experts", 0),
+        n_experts=n_experts,
         moe_top_k=getattr(hf_config, "num_experts_per_tok", 2),
-        # No-drop capacity (capacity == all tokens): HF Mixtral has no
-        # capacity concept, so a converted model must never drop or it
+        # No-drop capacity (capacity == all tokens) for the one-hot
+        # path an ``expert`` mesh axis selects: HF has no capacity
+        # concept, so a converted model must never drop or it
         # diverges from the source. Lower it explicitly to fine-tune
         # with GShard-style dropping.
         moe_capacity_factor=(
-            float(getattr(hf_config, "num_local_experts", 0))
+            float(n_experts)
             / max(getattr(hf_config, "num_experts_per_tok", 2), 1)
-            if getattr(hf_config, "num_local_experts", 0)
+            if n_experts
             else 1.25
         ),
     )
@@ -130,9 +143,24 @@ def llama_params_from_hf(
             transpose=False,
         ).astype(np.float32),
     }
+    if cfg.qk_norm:
+        blocks.update(
+            q_norm=stack(
+                "layers.{i}.self_attn.q_norm.weight", transpose=False
+            ).astype(np.float32),
+            k_norm=stack(
+                "layers.{i}.self_attn.k_norm.weight", transpose=False
+            ).astype(np.float32),
+        )
     if cfg.n_experts > 0:
         # Mixtral block_sparse_moe: gate -> router, experts j:
-        # w1 = SwiGLU gate, w3 = up, w2 = down.
+        # w1 = SwiGLU gate, w3 = up, w2 = down. OLMoE: mlp.gate and
+        # mlp.experts.j.{gate,up,down}_proj.
+        if any(k.endswith("layers.0.mlp.gate.weight") for k in sd):
+            moe, names = "mlp", ("gate_proj", "up_proj", "down_proj")
+        else:
+            moe, names = "block_sparse_moe", ("w1", "w3", "w2")
+
         def stack_experts(fmt):
             mats = []
             for i in range(L):
@@ -148,17 +176,15 @@ def llama_params_from_hf(
 
         blocks["moe"] = {
             "router": stack(
-                "layers.{i}.block_sparse_moe.gate.weight"
+                "layers.{i}." + moe + ".gate.weight"
             ).astype(np.float32),
-            "wg": stack_experts(
-                "layers.{i}.block_sparse_moe.experts.{j}.w1.weight"
-            ),
-            "wi": stack_experts(
-                "layers.{i}.block_sparse_moe.experts.{j}.w3.weight"
-            ),
-            "wo": stack_experts(
-                "layers.{i}.block_sparse_moe.experts.{j}.w2.weight"
-            ),
+            **{
+                leaf: stack_experts(
+                    "layers.{i}." + moe + ".experts.{j}." + name
+                    + ".weight"
+                )
+                for leaf, name in zip(("wg", "wi", "wo"), names)
+            },
         }
     else:
         blocks.update(

@@ -57,13 +57,27 @@ class LlamaConfig:
     # Declared attention masking (read by the auto_accelerate
     # seq-parallel binding, like GPTConfig.causal).
     causal: bool = True
-    # > 0 switches every block's MLP to a mixture-of-experts routed
-    # over the ``expert`` mesh axis (models/moe.py — Mixtral-shaped
-    # family; experts use the GShard FFN formulation). ``intermediate``
-    # then sets the per-expert hidden width.
+    # > 0 switches every block's MLP to a mixture of SwiGLU experts
+    # (models/moe.py: routed by sort and dropless, or one-hot under
+    # an ``expert`` mesh axis). ``intermediate`` then sets the
+    # per-expert hidden width.
     n_experts: int = 0
     moe_top_k: int = 2
+    # The one-hot path's alone (a mesh with an ``expert`` axis).
     moe_capacity_factor: float = 1.25
+    # True: the top-k weights are renormalised to sum to 1 (Mixtral);
+    # False: the full softmax's values as they are (OLMoE's
+    # ``norm_topk_prob`` false).
+    moe_renorm_top_k: bool = True
+    # Weights of the load-balancing loss and the router z-loss, each
+    # averaged over layers (Mixtral's and OLMoE's
+    # ``router_aux_loss_coef``; the OLMoE paper's z-loss).
+    moe_aux_loss_weight: float = 1e-2
+    moe_z_loss_weight: float = 1e-3
+    # RMSNorm with a learned gain over the whole projected query and
+    # key vectors, before the split into heads and the rotation
+    # (OLMoE's ``q_norm`` / ``k_norm``).
+    qk_norm: bool = False
     # Mistral-style sliding-window attention: query i sees keys
     # (i-sliding_window, i]. None = full causal attention. The flash
     # kernel skips kv blocks entirely below the band (O(T*window)
@@ -181,6 +195,27 @@ class LlamaConfig:
         )
 
     @staticmethod
+    def olmoe_1b_7b() -> "LlamaConfig":
+        """OLMoE-1B-7B (Muennighoff et al. 2024): 64 experts of width
+        1024, 8 a token with the softmax's weights unrenormalised,
+        normalised queries and keys, full attention."""
+        return LlamaConfig(
+            vocab_size=50304,
+            block_size=4096,
+            n_layer=16,
+            n_head=16,
+            n_kv_head=16,
+            n_embd=2048,
+            intermediate=1024,
+            rope_theta=10000.0,
+            rms_eps=1e-5,
+            n_experts=64,
+            moe_top_k=8,
+            moe_renorm_top_k=False,
+            qk_norm=True,
+        )
+
+    @staticmethod
     def moe_tiny() -> "LlamaConfig":
         return dataclasses.replace(
             LlamaConfig.tiny(), n_experts=4, moe_top_k=2
@@ -195,9 +230,11 @@ class LlamaConfig:
             expert_hidden=self.intermediate,
             top_k=self.moe_top_k,
             capacity_factor=self.moe_capacity_factor,
+            aux_loss_weight=self.moe_aux_loss_weight,
+            z_loss_weight=self.moe_z_loss_weight,
             dtype=self.dtype,
-            gated=True,  # SwiGLU experts + renormalized top-k:
-            renorm_top_k=True,  # the Mixtral block shape
+            gated=True,  # SwiGLU experts, as Mixtral and OLMoE
+            renorm_top_k=self.moe_renorm_top_k,
         )
 
 
@@ -237,6 +274,11 @@ def init_params(key: jax.Array, cfg: LlamaConfig) -> Params:
             bq=jnp.zeros((L, E), cfg.dtype),
             bk=jnp.zeros((L, Hkv * D), cfg.dtype),
             bv=jnp.zeros((L, Hkv * D), cfg.dtype),
+        )
+    if cfg.qk_norm:
+        blocks.update(
+            q_norm=jnp.ones((L, E), jnp.float32),
+            k_norm=jnp.ones((L, Hkv * D), jnp.float32),
         )
     if cfg.n_experts > 0:
         from dlrover_tpu.models.moe import init_moe_params
@@ -278,6 +320,11 @@ def param_logical_axes(cfg: LlamaConfig) -> Params:
             bq=("layers", "heads"),
             bk=("layers", "heads"),
             bv=("layers", "heads"),
+        )
+    if cfg.qk_norm:
+        blocks.update(
+            q_norm=("layers", "heads"),
+            k_norm=("layers", "heads"),
         )
     if cfg.n_experts > 0:
         from dlrover_tpu.models.moe import moe_logical_axes
@@ -346,7 +393,7 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
 
 def _block(x, lp, cfg: LlamaConfig, attn_fn, cos, sin):
     """One block. Returns (x, aux_loss) — aux is 0 for dense MLPs,
-    the router load-balancing loss for MoE blocks."""
+    this layer's share of the router losses for MoE blocks."""
     B, T, E = x.shape
     H, Hkv, D = cfg.n_head, cfg.n_kv_head, cfg.head_dim
     from dlrover_tpu.models.gpt import use_fused_norm
@@ -367,6 +414,9 @@ def _block(x, lp, cfg: LlamaConfig, attn_fn, cos, sin):
         q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]
         if cfg.qkv_bias:
             q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+        if cfg.qk_norm:
+            q = _rms_norm(q, lp["q_norm"], cfg.rms_eps)
+            k = _rms_norm(k, lp["k_norm"], cfg.rms_eps)
         q = q.reshape(B, T, H, D)
         k = k.reshape(B, T, Hkv, D)
         v = v.reshape(B, T, Hkv, D)
@@ -398,12 +448,14 @@ def _block(x, lp, cfg: LlamaConfig, attn_fn, cos, sin):
 def mlp_tail(x, h, lp, cfg: LlamaConfig):
     """Dense-SwiGLU or expert-routed MLP tail of a block. Shared by
     the training block and the decode paths (models/generate.py).
-    Returns (x + mlp(h), aux_loss)."""
+    Returns (x + mlp(h), aux_loss): the layer's weighted router losses
+    over ``n_layer``, so that the callers' sum over layers (the scan
+    here, the pipeline's stages) is the mean over layers."""
     if cfg.n_experts > 0:
         from dlrover_tpu.models.moe import moe_mlp
 
         y, aux = moe_mlp(lp["moe"], h, cfg._moe_cfg())
-        return x + y.astype(x.dtype), aux
+        return x + y.astype(x.dtype), aux / cfg.n_layer
     gated = jax.nn.silu(h @ lp["w_gate"]) * (h @ lp["w_up"])
     return x + gated @ lp["w_down"], jnp.zeros((), jnp.float32)
 

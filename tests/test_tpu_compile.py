@@ -24,7 +24,8 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from dlrover_tpu.models import gpt
+from dlrover_tpu.models import gpt, llama
+from dlrover_tpu.ops import grouped_matmul
 from dlrover_tpu.ops.flash_attention import (
     flash_attention,
     flash_attention_rect,
@@ -84,7 +85,8 @@ def compiled_kernels(monkeypatch):
     ``_use_interpret()``, which sees this process's CPU backend; here
     the answer is the chip's. ``dlrover_tpu.ops.flash_attention`` the
     attribute is the re-exported function, so go through sys.modules."""
-    for name in ("flash_attention", "layer_norm", "quantization"):
+    for name in ("flash_attention", "layer_norm", "quantization",
+                 "grouped_matmul"):
         monkeypatch.setattr(
             sys.modules[f"dlrover_tpu.ops.{name}"],
             "_use_interpret",
@@ -230,6 +232,36 @@ def test_kernels_split_themselves_over_a_mesh(topo, compiled_kernels, kernel):
         assert "all-gather" not in text and "all-reduce" not in text
 
 
+@pytest.mark.parametrize("form", [
+    "gate_up", "down", "input_grad", "weight_grad",
+])
+def test_grouped_matmul_compiles_at_olmoe_widths(one_chip, form):
+    """The expert layer's products at the benchmark cell's shape:
+    131,072 (token, choice) rows in 64 groups, 2048 x 1024. One
+    expert's whole matrix and a row tile sit in VMEM, over the default
+    budget, so each kernel declares its ``vmem_limit_bytes``."""
+    rows, e, w, experts = 131072, 2048, 1024, 64
+    sizes = jax.ShapeDtypeStruct((experts,), jnp.int32, sharding=one_chip)
+    wide, narrow = _bf16(one_chip, rows, e), _bf16(one_chip, rows, w)
+    if form == "gate_up":
+        fn = functools.partial(grouped_matmul.moe_gmm, interpret=False)
+        args = (wide, _bf16(one_chip, experts, e, w), sizes)
+    elif form == "down":
+        fn = functools.partial(grouped_matmul.moe_gmm, interpret=False)
+        args = (narrow, _bf16(one_chip, experts, w, e), sizes)
+    elif form == "input_grad":
+        fn = functools.partial(
+            grouped_matmul.moe_gmm, transpose_rhs=True, interpret=False
+        )
+        args = (narrow, _bf16(one_chip, experts, e, w), sizes)
+    else:
+        fn = functools.partial(grouped_matmul.moe_tgmm, interpret=False)
+        args = (wide, narrow, sizes)
+    text = _compile(fn, *args).as_text()
+    assert "tpu_custom_call" in text
+    assert ("moe_tgmm" if form == "weight_grad" else "moe_gmm") in text
+
+
 def _gpt2_step(devices, axis, global_batch):
     """make_train_step for GPT-2 124M as chip_smoke.py trains it
     (full remat, fused cross-entropy, adamw, flash attention) lowered
@@ -237,18 +269,22 @@ def _gpt2_step(devices, axis, global_batch):
     cfg = dataclasses.replace(
         gpt.GPTConfig.gpt2(), use_flash_attention=True
     )
+    return _train_step(gpt, cfg, devices, axis, global_batch)
+
+
+def _train_step(model, cfg, devices, axis, global_batch):
     mesh = build_mesh(MeshConfig(**{axis: len(devices)}), devices=devices)
     optimizer = optax.adamw(6e-4)
     step = make_train_step(
-        mesh, functools.partial(gpt.loss_fn_fused, cfg=cfg), optimizer
+        mesh, functools.partial(model.loss_fn_fused, cfg=cfg), optimizer
     )
     param_shapes = jax.eval_shape(
-        functools.partial(gpt.init_params, cfg=cfg), jax.random.PRNGKey(0)
+        functools.partial(model.init_params, cfg=cfg), jax.random.PRNGKey(0)
     )
     param_shardings = jax.tree.map(
         lambda s: NamedSharding(mesh, s),
         prune_specs_to_mesh(
-            mesh, tree_specs(gpt.param_logical_axes(cfg), None)
+            mesh, tree_specs(model.param_logical_axes(cfg), None)
         ),
         is_leaf=lambda x: isinstance(x, P),
     )
@@ -302,3 +338,32 @@ def test_gpt2_train_step_compiles_on_four_chips(
     _assert_fits_with_flash(compiled)
     # It is one program across the mesh, not four copies of one.
     assert "all-reduce" in compiled.as_text()
+
+
+def _olmoe_step(devices, axis, global_batch):
+    cfg = dataclasses.replace(
+        llama.LlamaConfig.olmoe_1b_7b(), n_layer=1,
+        use_flash_attention=True,
+    )
+    return _train_step(llama, cfg, devices, axis, global_batch)
+
+
+def test_olmoe_train_step_compiles_on_one_chip(topo, compiled_kernels):
+    """The program of the benchmark's ``olmoe-1b-7b.steady``: one
+    OLMoE layer at published widths, 4 x 4096 tokens: sorted routing,
+    the grouped-product kernels forward and backward under full remat
+    inside the layer scan, flash attention at head size 128."""
+    compiled = _olmoe_step(topo.devices[:1], "data", 4)
+    _assert_fits_with_flash(compiled)
+    text = compiled.as_text()
+    assert "moe_gmm" in text and "moe_tgmm" in text
+
+
+def test_olmoe_train_step_compiles_on_four_chips(topo, compiled_kernels):
+    """Tokens over ``fsdp=4``: each chip sorts its own tokens inside
+    the kernels' shard_map, the expert weights are gathered whole and
+    their gradients reduced over the mesh."""
+    compiled = _olmoe_step(list(topo.devices), "fsdp", 8)
+    _assert_fits_with_flash(compiled)
+    text = compiled.as_text()
+    assert "moe_gmm" in text and "all-gather" in text
