@@ -62,7 +62,32 @@ _BATCH_AXES = ("data", "fsdp")
 _HEAD_AXIS = "tensor"
 
 
-def per_device(call, *operands, split, heads_dim=None):
+def _ambient_mesh():
+    """The mesh the trace is under, or None where :func:`per_device`
+    makes a plain call: no mesh, one device, or the inside of a
+    ``shard_map`` (the operands already are one device's blocks)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1 or mesh.manual_axes:
+        return None
+    return mesh
+
+
+def batch_axes(rows: int):
+    """(the ambient mesh's batch axes that :func:`per_device` splits
+    ``rows`` rows over, the rows one device then holds): every batch
+    axis larger than one that divides what the axes before it left."""
+    mesh = _ambient_mesh()
+    batch = []
+    for axis in _BATCH_AXES if mesh is not None else ():
+        n = mesh.shape.get(axis, 1)
+        if n > 1 and rows % n == 0:
+            batch.append(axis)
+            rows //= n
+    return tuple(batch), rows
+
+
+def per_device(call, *operands, split, heads_dim=None, summed=(),
+               manual_all=True):
     """``call(*operands)``, run once per device of the mesh the trace
     is under.
 
@@ -79,21 +104,23 @@ def per_device(call, *operands, split, heads_dim=None):
     gradients over the mesh. An axis that does not divide its dim is
     left out, and XLA gathers that dim instead.
 
+    ``summed`` flags outputs (``call`` then returns a tuple) that are
+    one device's share of a sum over the batch rows, a weight's
+    gradient formed by hand: they are summed over the batch axes in
+    the dtype they have and come back whole. ``manual_all=False``
+    leaves the mesh axes no spec names (``tensor``, ``seq``) to XLA
+    inside the call, which nothing but a Mosaic kernel forbids.
+
     With no ambient mesh or one device it is a plain call, and so it
     is inside somebody else's ``shard_map`` (ring attention, the
     overlapped-reduce steps), where the operands already are one
     device's blocks."""
-    mesh = jax.sharding.get_abstract_mesh()
-    if mesh.empty or mesh.size == 1 or mesh.manual_axes:
+    mesh = _ambient_mesh()
+    if mesh is None:
         return call(*operands)
     shape = operands[split.index(True)].shape
-    batch, rows = [], shape[0]
-    for axis in _BATCH_AXES:
-        n = mesh.shape.get(axis, 1)
-        if n > 1 and rows % n == 0:
-            batch.append(axis)
-            rows //= n
-    dims = [tuple(batch) or None]
+    batch, _ = batch_axes(shape[0])
+    dims = [batch or None]
     if heads_dim is not None:
         n = mesh.shape.get(_HEAD_AXIS, 1)
         heads = n > 1 and shape[heads_dim] % n == 0
@@ -102,10 +129,23 @@ def per_device(call, *operands, split, heads_dim=None):
     if all(d is None for d in dims):
         return call(*operands)
     spec = P(*dims)
+    out_specs, body = spec, call
+    if summed:
+        out_specs = tuple(P() if s else spec for s in summed)
+
+        def body(*blocks):
+            return tuple(
+                jax.lax.psum(o, batch) if s else o
+                for o, s in zip(call(*blocks), summed)
+            )
+
+    # No names: every mesh axis is manual, shard_map's default.
+    named = set() if manual_all else set(batch) | ({_HEAD_AXIS} & set(dims))
     return jax.shard_map(
-        call,
+        body,
         in_specs=tuple(spec if s else P() for s in split),
-        out_specs=spec,
+        out_specs=out_specs,
+        axis_names=frozenset(named),
         check_vma=False,
     )(*operands)
 

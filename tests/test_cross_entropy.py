@@ -1,14 +1,20 @@
 """Fused chunked cross-entropy vs naive log-softmax path."""
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from dlrover_tpu.models import gpt
+from dlrover_tpu import obs
+from dlrover_tpu.models import gpt, llama
 from dlrover_tpu.ops.cross_entropy import fused_cross_entropy
+from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh, under_mesh
+from dlrover_tpu.parallel.sharding import prune_specs_to_mesh, tree_specs
+from dlrover_tpu.trainer.step import batch_spec, shard_batch
 
 
 def _naive(x, wte, targets):
@@ -123,3 +129,183 @@ def test_gpt_fused_loss_grads_under_remat():
     g2 = jax.grad(lambda p: gpt.loss_fn(p, tokens, targets, cfg2))(params)
     for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
         np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-3)
+
+
+# -- the head under a mesh: each device's own rows (ISSUE 27) ---------------
+
+_COLLECTIVE = re.compile(
+    r"^\s*(?:ROOT )?%?[\w.\-]+ = (.*?) "
+    r"(all-reduce|all-gather|reduce-scatter)(-start)?\("
+)
+
+
+def _mesh(**axes):
+    n = int(np.prod(list(axes.values())))
+    return build_mesh(MeshConfig(**axes), devices=jax.devices()[:n])
+
+
+def _tiny_model(name):
+    if name == "llama":
+        cfg = llama.LlamaConfig(
+            vocab_size=384, block_size=32, n_layer=1, n_head=4,
+            n_kv_head=2, n_embd=64, intermediate=128,
+            dtype=jnp.float32, remat=False,
+        )
+        return llama, cfg
+    cfg = gpt.GPTConfig(
+        vocab_size=384, block_size=32, n_layer=1, n_head=4, n_embd=64,
+        dtype=jnp.float32, remat=False, use_flash_attention=False,
+    )
+    return gpt, cfg
+
+
+@pytest.mark.parametrize(
+    "model_name,axes",
+    [
+        ("llama", {"fsdp": 4}),
+        ("gpt", {"data": 4}),
+        ("gpt", {"data": 2, "fsdp": 2}),
+    ],
+    ids=["llama-fsdp4", "gpt-data4", "gpt-data2xfsdp2"],
+)
+def test_no_collective_on_the_logits(model_name, axes):
+    """The compiled value_and_grad of loss_fn_fused, parameters laid
+    out by the model's own logical axes: no all-reduce, all-gather or
+    reduce-scatter gives a [rows, vocab] array or comes from the
+    logits product. Left to XLA, the table's embed dim split over
+    fsdp makes every chip form partial logits of all rows of a chunk
+    and all-reduce them (f32[4096,32000] twice a step in
+    mistral-7b-host4.fsdp4)."""
+    model, cfg = _tiny_model(model_name)
+    mesh = _mesh(**axes)
+    params = model.init_params(jax.random.PRNGKey(0), cfg)
+    specs = prune_specs_to_mesh(
+        mesh, tree_specs(model.param_logical_axes(cfg), None)
+    )
+    params = jax.tree.map(
+        lambda s, p: jax.device_put(p, NamedSharding(mesh, s)),
+        specs, params, is_leaf=lambda x: isinstance(x, P),
+    )
+    tokens = jnp.zeros((8, cfg.block_size), jnp.int32)
+    tok, tgt = shard_batch(mesh, tokens, tokens)
+    loss = functools.partial(model.loss_fn_fused, cfg=cfg, num_chunks=4)
+    fn = jax.jit(jax.value_and_grad(under_mesh(loss, mesh)))
+    text = fn.lower(params, tok, tgt).compile().as_text()
+    seen = 0
+    for line in text.splitlines():
+        m = _COLLECTIVE.match(line)
+        if not m:
+            continue
+        seen += 1
+        assert "ce,ve->cv" not in line, line
+        for dims in re.findall(r"\[([\d,]+)\]", m.group(1)):
+            dims = [int(d) for d in dims.split(",")]
+            assert not (
+                len(dims) >= 2 and dims[-1] == cfg.vocab_size
+            ), line
+    assert seen  # the step has collectives: the pattern reads them
+
+
+def _head_inputs(n, e, v, dtype):
+    kx, kw, kt = jax.random.split(jax.random.PRNGKey(0), 3)
+    x = jax.random.normal(kx, (n, e), jnp.float32).astype(dtype)
+    wte = (0.1 * jax.random.normal(kw, (v, e), jnp.float32)).astype(dtype)
+    return x, wte, jax.random.randint(kt, (n,), 0, v)
+
+
+def _rel(got, want):
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+@pytest.mark.parametrize(
+    "axes,table_spec,dtype,rows,scale",
+    [
+        ({"fsdp": 4}, P(None, "fsdp"), jnp.float32, 256, 1.0),
+        ({"data": 2, "fsdp": 2}, P(None, "fsdp"), jnp.float32, 256, 1.0),
+        ({"data": 2, "tensor": 2}, P("tensor", None), jnp.float32, 256, 1.0),
+        ({"fsdp": 4}, P(None, "fsdp"), jnp.bfloat16, 256, 1.0),
+        ({"data": 2, "tensor": 2}, P("tensor", None), jnp.bfloat16, 256, 1.0),
+        # an upstream cotangent other than 1
+        ({"fsdp": 4}, P(None, "fsdp"), jnp.float32, 256, 0.25),
+        # 4 devices leave 36 rows each, which 8 chunks do not divide,
+        # and 4 does not divide 250 rows: the plain call both times
+        ({"fsdp": 4}, P(None, "fsdp"), jnp.float32, 144, 1.0),
+        ({"fsdp": 4}, P(None, "fsdp"), jnp.float32, 250, 1.0),
+    ],
+    ids=["fsdp4-f32", "data2xfsdp2-f32", "data2xtensor2-f32", "fsdp4-bf16",
+         "data2xtensor2-bf16", "fsdp4-cotangent", "fsdp4-chunks-left-over",
+         "fsdp4-rows-left-over"],
+)
+def test_per_device_head_matches_one_device(
+    axes, table_spec, dtype, rows, scale
+):
+    """Loss, dx and dwte of the head under a mesh against the same
+    call on one device. In float32 only the order of the table
+    gradient's sum over devices may differ (1e-6 relative); with
+    bfloat16 inputs that sum is still float32 and cast once, so the
+    table's gradient is equal after the cast in all but a handful of
+    its 24,576 elements (a float32 sum that lands on a rounding
+    boundary), each off by one bfloat16 step."""
+    e, v = 64, 384
+    chunks = 2 if rows == 250 else 8
+    x, wte, targets = _head_inputs(rows, e, v, dtype)
+
+    def loss(x, wte, targets):
+        return fused_cross_entropy(x, wte, targets, chunks) * scale
+
+    grad = functools.partial(jax.value_and_grad, argnums=(0, 1))
+    want, (want_dx, want_dw) = jax.jit(grad(loss))(x, wte, targets)
+
+    mesh = _mesh(**axes)
+    rows_spec = P(batch_spec(mesh)[0] if rows % 4 == 0 else None)
+    put = lambda a, s: jax.device_put(a, NamedSharding(mesh, s))  # noqa: E731
+    got, (dx, dw) = jax.jit(grad(under_mesh(loss, mesh)))(
+        put(x, P(*rows_spec, None)), put(wte, table_spec),
+        put(targets, rows_spec),
+    )
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
+    if dtype == jnp.float32:
+        assert _rel(dx, want_dx) < 1e-6
+        assert _rel(dw, want_dw) < 1e-6
+    else:
+        assert dx.dtype == dw.dtype == jnp.bfloat16
+        # vocab split over tensor sums dx over two devices' halves
+        assert _rel(dx, want_dx) < (2e-3 if "tensor" in axes else 1e-6)
+        differ = np.asarray(dw != want_dw)
+        assert differ.sum() <= 32, differ.sum()
+        assert _rel(dw, want_dw) < 2e-4
+
+
+def test_head_per_device_event():
+    """A trace under a four-device mesh emits ``head.per_device``
+    once with the axes and the rows a device holds; one device, and
+    rows the chunks do not divide on a device, emit none."""
+    x, wte, targets = _head_inputs(256, 64, 384, jnp.float32)
+    loss = lambda x, w, t: fused_cross_entropy(x, w, t, 8)  # noqa: E731
+    tracer = obs.configure_tracer()
+    try:
+        def events():
+            return [
+                e for e in tracer.events()
+                if e["name"] == "head.per_device"
+            ]
+
+        jax.jit(jax.value_and_grad(loss)).lower(x, wte, targets)
+        one = _mesh(data=1)
+        jax.jit(jax.value_and_grad(under_mesh(loss, one))).lower(
+            x, wte, targets
+        )
+        assert events() == []
+        mesh = _mesh(data=2, fsdp=2)
+        jax.jit(jax.value_and_grad(under_mesh(loss, mesh))).lower(
+            x, wte, targets
+        )
+        (ev,) = events()
+        assert ev["axes"] == ["data", "fsdp"]
+        assert ev["rows_per_device"] == 64
+        assert ev["chunks"] == 8
+        jax.jit(under_mesh(loss, mesh)).lower(x[:144], wte, targets[:144])
+        assert len(events()) == 1
+    finally:
+        obs.disable_tracer()

@@ -232,6 +232,35 @@ def test_kernels_split_themselves_over_a_mesh(topo, compiled_kernels, kernel):
         assert "all-gather" not in text and "all-reduce" not in text
 
 
+def test_head_keeps_the_logits_on_their_chip(topo):
+    """The loss head of ``mistral-7b-host4.fsdp4`` at its real size
+    (4 x 8192 rows, E 4096, V 32000, bf16, the table's embed dim on
+    fsdp=4), compiled by the chip's partitioner: no collective on a
+    ``[rows, 32000]`` array. Left to XLA it all-reduced
+    ``f32[4096,32000]`` eight times a pass, twice a step (73.5 ms
+    each on the chip, PERF.md PR 27)."""
+    from dlrover_tpu.ops.cross_entropy import fused_cross_entropy
+    from dlrover_tpu.parallel.mesh import under_mesh
+
+    mesh = build_mesh(MeshConfig(fsdp=4), devices=list(topo.devices))
+    x = _bf16(NamedSharding(mesh, P("fsdp", None)), 32768, 4096)
+    table = _bf16(NamedSharding(mesh, P(None, "fsdp")), 32000, 4096)
+    targets = jax.ShapeDtypeStruct(
+        (32768,), jnp.int32, sharding=NamedSharding(mesh, P("fsdp"))
+    )
+    grad = jax.value_and_grad(
+        under_mesh(fused_cross_entropy, mesh), argnums=(0, 1)
+    )
+    text = _compile(grad, x, table, targets).as_text()
+    collectives = [
+        line for line in text.splitlines()
+        if " all-reduce(" in line or " all-gather(" in line
+        or " reduce-scatter(" in line or " all-to-all(" in line
+    ]
+    assert collectives  # the table is gathered, its gradient summed
+    assert not [c for c in collectives if ",32000]" in c], collectives
+
+
 @pytest.mark.parametrize("form", [
     "gate_up", "down", "input_grad", "weight_grad",
 ])
