@@ -264,38 +264,7 @@ def measure() -> int:
         if overlap_on
         else {}
     )
-    # BENCH_PIPELINE_DEPTH>0: microbatch-pipelined accumulation
-    # (trainer/step.py PipelinedTrainStep) — the step takes
-    # [accum, batch/accum, ...] and stages/consumes microbatches with
-    # donated double-buffered device slots, so H2D (and, with
-    # overlap, the bucketed reduce) hides behind backward compute.
-    # BENCH_ACCUM_STEPS sets the accumulation factor (default 2 when
-    # pipelining so there is something to overlap); the global batch
-    # and tokens/step stay identical to the monolithic run.
-    pipe_depth = int(os.getenv("BENCH_PIPELINE_DEPTH", "0"))
-    accum_steps = int(
-        os.getenv("BENCH_ACCUM_STEPS", "2" if pipe_depth > 0 else "1")
-    )
-    pipelined = pipe_depth > 0
-    if pipelined:
-        from dlrover_tpu.trainer.step import make_pipelined_train_step
-
-        if batch % accum_steps:
-            raise ValueError(
-                f"BENCH_ACCUM_STEPS={accum_steps} must divide the "
-                f"global batch ({batch})"
-            )
-        step = make_pipelined_train_step(
-            mesh, loss, optimizer,
-            accum_steps=accum_steps,
-            pipeline_depth=pipe_depth,
-            overlap=overlap_on,
-            # Device batches here always come from step.stage_batch
-            # ([accum, ...] form); host batches stage per microbatch.
-            staged_device_inputs=True,
-            **overlap,
-        )
-    elif overlap_on:
+    if overlap_on:
         from dlrover_tpu.parallel.compression import (
             make_overlapped_train_step,
         )
@@ -315,8 +284,6 @@ def measure() -> int:
         "BENCH_UNROLL", "BENCH_XENT_CHUNKS", "BENCH_BATCH_PER_CHIP",
         "BENCH_SAVE_LOGITS", "BENCH_OVERLAP_REDUCE",
         "BENCH_REDUCE_BUCKET_MB", "BENCH_REDUCE_BITS",
-        "BENCH_DEVICE_PREFETCH", "BENCH_PIPELINE_DEPTH",
-        "BENCH_ACCUM_STEPS",
     )
     effective_pins = {
         k: os.environ[k] for k in _PIN_KNOBS if k in os.environ
@@ -327,12 +294,7 @@ def measure() -> int:
     # device_put overlapping compute) — measures the full
     # read-to-update path instead of re-feeding one static device
     # batch. Default 0 keeps the historical static-batch metric.
-    # BENCH_DEVICE_PREFETCH (default 1) keeps the H2D stage in the
-    # prefetch worker (device-resident queue); 0 pushes the transfer
-    # onto the measured loop — the A/B that makes the device-prefetch
-    # win visible in data_wait_s.
     prefetch_input = os.getenv("BENCH_PREFETCH", "0") == "1"
-    device_prefetch = os.getenv("BENCH_DEVICE_PREFETCH", "1") != "0"
     pf = None
     if prefetch_input:
         import numpy as np
@@ -349,21 +311,9 @@ def measure() -> int:
                 )
                 yield t, np.roll(t, -1, axis=1)
 
-        if pipelined and not device_prefetch:
-            # The pipelined step stages its own microbatches from the
-            # delivered host batch (overlapping each slot's H2D with
-            # the previous microbatch's backward) — no pipeline-side
-            # H2D at all.
-            _h2d = None
-        elif pipelined:
-            _h2d = lambda b: step.stage_batch(b[0], b[1])  # noqa: E731
-        else:
-            _h2d = lambda b: shard_batch(mesh, b[0], b[1])  # noqa: E731
-
         pf = Prefetcher(
             batch_stream(),
-            h2d_fn=_h2d,
-            device_prefetch=device_prefetch,
+            h2d_fn=lambda b: shard_batch(mesh, b[0], b[1]),
             name="bench",
         )
     else:
@@ -372,10 +322,7 @@ def measure() -> int:
             key, (batch, cfg.block_size), 0, cfg.vocab_size
         )
         targets = jnp.roll(tokens, -1, axis=1)
-        if pipelined:
-            tokens, targets = step.stage_batch(tokens, targets)
-        else:
-            tokens, targets = shard_batch(mesh, tokens, targets)
+        tokens, targets = shard_batch(mesh, tokens, targets)
 
     # Fetch-then-dispatch: every fetched batch is trained on, and the
     # loop never pays a trailing fetch for a batch it will discard.
@@ -448,17 +395,6 @@ def measure() -> int:
                     {"pins_source": pins_source} if pins_source else {}
                 ),
                 **({"overlap": overlap} if overlap else {}),
-                **(
-                    {
-                        "pipeline": {
-                            "depth": pipe_depth,
-                            "accum_steps": accum_steps,
-                            "device_prefetch": int(device_prefetch),
-                        }
-                    }
-                    if pipelined
-                    else {}
-                ),
                 "tune_key": tune_key,
                 **(
                     {"data_wait_s": round(data_wait_s, 4)}
@@ -481,15 +417,6 @@ def measure() -> int:
                 {
                     "pins": effective_pins,
                     "overlap": overlap or None,
-                    "pipeline": (
-                        {
-                            "depth": pipe_depth,
-                            "accum_steps": accum_steps,
-                            "device_prefetch": int(device_prefetch),
-                        }
-                        if pipelined
-                        else None
-                    ),
                 },
                 per_chip,
                 extra={

@@ -271,27 +271,7 @@ def _build_for_strategy(
             "non-pure-data mesh; overlapped reduction needs "
             "replicated params"
         )
-    if getattr(strategy, "pipeline_depth", 0) > 0:
-        # Microbatch-pipelined accumulate-then-update (trainer/step.py
-        # PipelinedTrainStep): the dry-run measures the real split
-        # micro/update program pair (accum collapses to 1 at this
-        # layer — ElasticTrainer supplies the real accumulation depth
-        # at train time), composed with the overlapped bucketed
-        # reduce when the strategy selects both.
-        from dlrover_tpu.trainer.step import make_pipelined_train_step
-
-        step = make_pipelined_train_step(
-            mesh, loss, optimizer,
-            accum_steps=1,
-            pipeline_depth=strategy.pipeline_depth,
-            overlap=strategy.overlap_reduce,
-            bucket_mb=strategy.reduce_bucket_mb,
-            # Dry-runs feed the flat make_train_step batch convention
-            # (shard_batch output) — never the [accum, ...] staged
-            # form, even at batch size 1.
-            staged_device_inputs=False,
-        )
-    elif strategy.overlap_reduce:
+    if strategy.overlap_reduce:
         # Bucketed reduces issued as gradients finalize (the schedule
         # ElasticTrainer's overlap_reduce uses inside its accumulation
         # scan; here accum collapses to 1 but bucketing still replaces

@@ -61,16 +61,6 @@ def encode_strategy(s: Strategy) -> np.ndarray:
         if s.overlap_reduce
         else 0.0
     )
-    # Input-pipelining knobs: log2(1 + depth) keeps 0 (off) a natural
-    # origin while depths 1/2/4 stay smoothly ordered; device_prefetch
-    # is a plain flag. Old Strategy records (pre-knob) decode with the
-    # dataclass defaults, so warm-started caches stay replayable.
-    feats.append(
-        math.log2(1.0 + max(getattr(s, "pipeline_depth", 0), 0))
-    )
-    feats.append(
-        1.0 if getattr(s, "device_prefetch", True) else 0.0
-    )
     return np.asarray(feats, np.float64)
 
 

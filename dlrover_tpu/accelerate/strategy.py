@@ -39,17 +39,6 @@ class Strategy:
     # bayes search can sweep.
     overlap_reduce: bool = False
     reduce_bucket_mb: float = 4.0
-    # Device-resident input pipelining (data/prefetch.py +
-    # trainer/step.py PipelinedTrainStep): ``device_prefetch`` moves
-    # the H2D staging of batch N+1 into the prefetch worker so the
-    # step never pays the transfer on the critical path;
-    # ``pipeline_depth`` > 0 additionally runs gradient accumulation
-    # as a host-driven microbatch pipeline (stage k+1 while k
-    # computes, donated input slots). Both are cheap knobs every mesh
-    # supports (pipelining composes with GSPMD and overlap_reduce),
-    # so the bayes search can tune them alongside the mesh.
-    pipeline_depth: int = 0
-    device_prefetch: bool = True
 
     @property
     def pure_data_parallel(self) -> bool:
@@ -76,14 +65,10 @@ class Strategy:
             if self.overlap_reduce
             else ""
         )
-        pd = (
-            f"-pd:{self.pipeline_depth}" if self.pipeline_depth else ""
-        )
-        dp = "" if self.device_prefetch else "-devpf:0"
         return (
             f"{mesh or 'single'}-{self.dtype}"
             f"-remat:{self._remat_name()}-{self.optimizer}"
-            f"-mb{self.micro_batch_size}{sp}{ov}{pd}{dp}"
+            f"-mb{self.micro_batch_size}{sp}{ov}"
         )
 
     def to_json(self) -> str:
@@ -124,8 +109,6 @@ def candidate_strategies(
     seq_impls: Tuple[str, ...] = ("auto",),
     overlap_reduces: Tuple[bool, ...] = (False,),
     reduce_bucket_mbs: Tuple[float, ...] = (4.0,),
-    pipeline_depths: Tuple[int, ...] = (0,),
-    device_prefetchs: Tuple[bool, ...] = (True,),
 ) -> List[Strategy]:
     """Enumerate the raw candidate grid (the reference's
     CombinationAlgorithm, auto/engine/sg_algo/combination_sg.py:16).
@@ -155,16 +138,8 @@ def candidate_strategies(
         # degenerates to off so the grid stays duplicate-free.
         pure_dp = all(s == 1 for a, s in shape if a != "data")
         ovs = overlap_reduces if pure_dp else (False,)
-        # Pipelined accumulation needs the built-in step (no 1F1B
-        # pipe axis — that step owns its own microbatch schedule);
-        # with overlap it additionally needs the pure-data regime,
-        # which the ovs gate above already enforces per candidate.
-        pds = (
-            pipeline_depths if d.get("pipe", 1) == 1 else (0,)
-        )
-        for mb, dt, opt, rm, sp, ov, pd, dp in itertools.product(
-            micro_batch_sizes, dtypes, optimizers, remats, sps, ovs,
-            pds, device_prefetchs,
+        for mb, dt, opt, rm, sp, ov in itertools.product(
+            micro_batch_sizes, dtypes, optimizers, remats, sps, ovs
         ):
             # Bucket size only distinguishes overlapped candidates.
             bks = reduce_bucket_mbs if ov else (4.0,)
@@ -179,8 +154,6 @@ def candidate_strategies(
                         seq_impl=sp,
                         overlap_reduce=ov,
                         reduce_bucket_mb=bk,
-                        pipeline_depth=pd,
-                        device_prefetch=dp,
                     )
                 )
     return out

@@ -1,11 +1,8 @@
 from dlrover_tpu.data.coworker import CoworkerDataLoader
 from dlrover_tpu.data.prefetch import (
     Prefetcher,
-    SyncPipeline,
-    device_prefetch_enabled,
     make_input_pipeline,
     prefetch_depth,
-    prefetch_enabled,
 )
 from dlrover_tpu.data.shm_ring import ShmBatchRing
 
@@ -13,9 +10,6 @@ __all__ = [
     "CoworkerDataLoader",
     "Prefetcher",
     "ShmBatchRing",
-    "SyncPipeline",
-    "device_prefetch_enabled",
     "make_input_pipeline",
     "prefetch_depth",
-    "prefetch_enabled",
 ]

@@ -29,26 +29,16 @@ the queued-but-untrained ones are replayed. ``close()`` additionally
 frees the device buffers of staged-but-undelivered batches so dropped
 HBM slots return immediately instead of waiting for GC.
 
-Knobs (see docs/PERFORMANCE.md):
-
-* ``DLROVER_TPU_PREFETCH=0`` — disable switch consulted by the
-  high-level ``Trainer`` (:func:`prefetch_enabled`); the loop then
-  stages synchronously, exactly the pre-prefetch behavior.
-* ``DLROVER_TPU_PREFETCH_DEPTH`` — queue depth (staged batches held
-  ahead), default 2.
-* ``DLROVER_TPU_DEVICE_PREFETCH=0`` — keep ``h2d_fn`` OUT of the
-  worker: batches are delivered host-staged and the consumer pays the
-  H2D transfer inline (honestly recorded as the ``h2d`` split). The
-  A/B switch that makes the device-resident win measurable.
+One knob (see docs/PERFORMANCE.md): ``DLROVER_TPU_PREFETCH_DEPTH`` —
+queue depth (staged batches held ahead), default 2.
 
 Observability: every consumer wait lands in the
-``dlrover_train_data_wait_seconds`` histogram (total, host + inline
-H2D); with tracing on, the worker emits ``trainer.prefetch_stage``
-(host) and ``trainer.prefetch_h2d`` (device placement) spans per
-staged batch and the consumer emits ``trainer.prefetch_wait`` events
-carrying the split, so ``tools/obs_report.py`` can show data-wait vs
-host-staging vs H2D-staging vs step time — identically for the async
-:class:`Prefetcher` and the :class:`SyncPipeline` fallback.
+``dlrover_train_data_wait_seconds`` histogram; with tracing on, the
+worker emits ``trainer.prefetch_stage`` (host) and
+``trainer.prefetch_h2d`` (device placement) spans per staged batch
+and the consumer emits ``trainer.prefetch_wait`` events carrying the
+split, so ``tools/obs_report.py`` can show data-wait vs host-staging
+vs H2D-staging vs step time.
 """
 
 from __future__ import annotations
@@ -64,16 +54,13 @@ from dlrover_tpu.common.log import get_logger
 
 logger = get_logger("prefetch")
 
-PREFETCH_ENV = "DLROVER_TPU_PREFETCH"
 PREFETCH_DEPTH_ENV = "DLROVER_TPU_PREFETCH_DEPTH"
-DEVICE_PREFETCH_ENV = "DLROVER_TPU_DEVICE_PREFETCH"
 DEFAULT_DEPTH = 2
 
 _DATA_WAIT = obs.histogram(
     "dlrover_train_data_wait_seconds",
     "Time the train loop waited on the input pipeline per batch "
-    "(near zero when prefetch keeps up; includes inline H2D staging "
-    "when device prefetch is off)",
+    "(near zero when prefetch keeps up)",
 )
 _BATCHES = obs.counter(
     "dlrover_prefetch_batches_total",
@@ -83,24 +70,9 @@ _BATCHES = obs.counter(
 _STAGE_SECONDS = obs.counter(
     "dlrover_prefetch_stage_seconds_total",
     "Input staging cost by phase: host (source pull + collate) vs "
-    "h2d (device placement), wherever it ran (worker or consumer)",
+    "h2d (device placement), both in the worker",
     ("phase",),  # host | h2d
 )
-
-
-def prefetch_enabled() -> bool:
-    """The DLROVER_TPU_PREFETCH=0 disable switch (default: on)."""
-    return os.getenv(PREFETCH_ENV, "1") != "0"
-
-
-def device_prefetch_enabled(default: bool = True) -> bool:
-    """DLROVER_TPU_DEVICE_PREFETCH: run ``h2d_fn`` in the worker so
-    batches arrive device-resident (default). ``0`` keeps H2D on the
-    consumer, the pre-device-prefetch behavior."""
-    val = os.getenv(DEVICE_PREFETCH_ENV, "")
-    if not val:
-        return default
-    return val != "0"
 
 
 def prefetch_depth(default: int = DEFAULT_DEPTH) -> int:
@@ -139,8 +111,7 @@ def free_device_buffers(batch) -> None:
 
 def _epoch_stream(source, sampler, auto_epoch: bool, name: str):
     """Items from ``source``; on exhaustion with ``auto_epoch``, bump
-    the sampler epoch and re-iterate. The single shared rollover
-    implementation for both pipeline flavors.
+    the sampler epoch and re-iterate.
 
     A resumed sampler's FIRST pass may legitimately yield nothing
     (checkpoint taken near the epoch boundary with a drop_last tail),
@@ -183,14 +154,13 @@ class _Entry:
     """One staged batch in flight: payload + sampler snapshot + the
     per-stage costs the consumer uses to split its wait."""
 
-    __slots__ = ("batch", "state", "host_s", "h2d_s", "device_done")
+    __slots__ = ("batch", "state", "host_s", "h2d_s")
 
-    def __init__(self, batch, state, host_s, h2d_s, device_done):
+    def __init__(self, batch, state, host_s, h2d_s):
         self.batch = batch
         self.state = state
         self.host_s = host_s
         self.h2d_s = h2d_s
-        self.device_done = device_done
 
 
 class Prefetcher:
@@ -206,12 +176,10 @@ class Prefetcher:
     h2d_fn: optional ``staged_batch -> device_batch`` — the
         host->device placement step (``jax.device_put`` under the
         step's ``NamedSharding``, e.g.
-        ``ElasticTrainer.shard_microbatches``). Runs in the worker
-        when ``device_prefetch`` (default), so the queue hands the
-        trainer committed device arrays; with ``device_prefetch``
-        off it runs in the consumer and its cost is recorded as the
-        h2d slice of the wait. A worker-side ``h2d_fn`` failure is
-        relayed to the consumer as a loud step error, never a hang.
+        ``ElasticTrainer.shard_microbatches``). Runs in the worker,
+        so the queue hands the trainer committed device arrays. An
+        ``h2d_fn`` failure is relayed to the consumer as a loud step
+        error, never a hang.
     depth: staged batches held ahead of the consumer (bounded queue;
         the worker blocks when full). None = DLROVER_TPU_PREFETCH_DEPTH
         or 2 (double buffering).
@@ -221,8 +189,6 @@ class Prefetcher:
     auto_epoch: when the source exhausts, bump ``sampler.set_epoch
         (epoch + 1)`` and re-iterate instead of ending the stream —
         the shape of the high-level Trainer's epoch loop.
-    device_prefetch: where ``h2d_fn`` runs (see above). None reads
-        ``DLROVER_TPU_DEVICE_PREFETCH`` (default on).
     """
 
     def __init__(
@@ -234,16 +200,12 @@ class Prefetcher:
         auto_epoch: bool = False,
         name: str = "train",
         h2d_fn: Optional[Callable[[Any], Any]] = None,
-        device_prefetch: Optional[bool] = None,
     ):
         if auto_epoch and sampler is None:
             raise ValueError("auto_epoch requires a sampler")
         self._source = source
         self._stage_fn = stage_fn
         self._h2d_fn = h2d_fn
-        if device_prefetch is None:
-            device_prefetch = device_prefetch_enabled()
-        self.device_prefetch = bool(device_prefetch) and h2d_fn is not None
         self.depth = depth if depth is not None else prefetch_depth()
         if self.depth < 1:
             raise ValueError(f"depth must be >= 1, got {self.depth}")
@@ -267,14 +229,13 @@ class Prefetcher:
         self.host_wait_s_total = 0.0
         self.h2d_wait_s_total = 0.0
         self._last_split: Tuple[float, float] = (0.0, 0.0)
-        # Staging cost totals (worker- or consumer-side).
+        # Staging cost totals.
         self.host_stage_s_total = 0.0
         self.h2d_stage_s_total = 0.0
         obs.event(
             "trainer.prefetch_start",
             pipeline=name,
             depth=self.depth,
-            device_prefetch=int(self.device_prefetch),
         )
         self._thread = threading.Thread(
             target=self._run, name=f"prefetch-{name}", daemon=True
@@ -323,8 +284,7 @@ class Prefetcher:
                     )
                 host_s = time.perf_counter() - t_pull
                 h2d_s = 0.0
-                device_done = False
-                if self.device_prefetch:
+                if self._h2d_fn is not None:
                     # The worker finishes with committed device
                     # arrays: a failing device_put lands in the
                     # _Error relay below — a loud step error at the
@@ -335,19 +295,17 @@ class Prefetcher:
                     ):
                         staged = self._h2d_fn(staged)
                     h2d_s = time.perf_counter() - t_h2d
-                    device_done = True
+                    _STAGE_SECONDS.inc(h2d_s, phase="h2d")
                 self.host_stage_s_total += host_s
                 self.h2d_stage_s_total += h2d_s
                 _STAGE_SECONDS.inc(host_s, phase="host")
-                if device_done:
-                    _STAGE_SECONDS.inc(h2d_s, phase="h2d")
                 # Count BEFORE the put: a concurrent close() may
                 # drain (and count dropped) the entry immediately,
                 # and staged == delivered + dropped must hold at
                 # prefetch_stop.
                 self.staged += 1
                 _BATCHES.inc(outcome="staged")
-                entry = _Entry(staged, state, host_s, h2d_s, device_done)
+                entry = _Entry(staged, state, host_s, h2d_s)
                 if not self._put(entry):
                     # Stopped while blocked on a full queue: the
                     # batch never reached the consumer — free any
@@ -390,39 +348,12 @@ class Prefetcher:
         if isinstance(entry, _Error):
             self._exhausted = True
             raise entry.exc
-        batch = entry.batch
-        if entry.device_done or self._h2d_fn is None:
-            # Queue wait splits by what the worker was doing for this
-            # batch: a blocked consumer was waiting on host staging
-            # and H2D in that proportion (both ~0 on a queue hit).
-            stage_total = entry.host_s + entry.h2d_s
-            frac = (
-                entry.h2d_s / stage_total if stage_total > 0 else 0.0
-            )
-            host_wait, h2d_wait = wait * (1.0 - frac), wait * frac
-        else:
-            # Device prefetch off: the consumer pays H2D inline —
-            # measured directly, counted in the wait (it IS input
-            # latency on the critical path). A failing inline
-            # device_put still keeps the staged == delivered + dropped
-            # invariant (the batch was popped but never delivered) and
-            # frees any partially-created device buffers.
-            t_h2d = time.perf_counter()
-            try:
-                with obs.span(
-                    "trainer.prefetch_h2d", pipeline=self.name
-                ):
-                    batch = self._h2d_fn(batch)
-            except BaseException:
-                free_device_buffers(batch)
-                self.dropped += 1
-                _BATCHES.inc(outcome="dropped")
-                raise
-            h2d_wait = time.perf_counter() - t_h2d
-            host_wait = wait
-            wait += h2d_wait
-            self.h2d_stage_s_total += h2d_wait
-            _STAGE_SECONDS.inc(h2d_wait, phase="h2d")
+        # Queue wait splits by what the worker was doing for this
+        # batch: a blocked consumer was waiting on host staging and
+        # H2D in that proportion (both ~0 on a queue hit).
+        stage_total = entry.host_s + entry.h2d_s
+        frac = entry.h2d_s / stage_total if stage_total > 0 else 0.0
+        host_wait, h2d_wait = wait * (1.0 - frac), wait * frac
         # Record the wait only for REAL batches — the terminal
         # sentinel fetch must not add a phantom sample to the
         # data-wait histogram / trainer.prefetch_wait stream.
@@ -442,7 +373,7 @@ class Prefetcher:
             self._delivered_state = entry.state
         self.delivered += 1
         _BATCHES.inc(outcome="delivered")
-        return batch
+        return entry.batch
 
     def wait_breakdown(self) -> Tuple[float, float]:
         """(host_wait_s, h2d_wait_s) of the LAST delivered batch's
@@ -455,9 +386,8 @@ class Prefetcher:
         """Sampler state as of the last batch the CONSUMER received.
 
         Batches staged ahead in the queue (or mid-stage in the
-        worker) are NOT counted — host- or device-resident alike —
-        so checkpointing this dict makes an elastic restart replay
-        them instead of skipping data.
+        worker) are NOT counted, so checkpointing this dict makes an
+        elastic restart replay them instead of skipping data.
         """
         state = self._delivered_state
         return dict(state) if state is not None else None
@@ -518,166 +448,6 @@ class Prefetcher:
         self.close()
 
 
-class SyncPipeline:
-    """The DLROVER_TPU_PREFETCH=0 fallback: stages in the CONSUMER
-    thread (data-wait == full staging cost, honestly recorded in the
-    same ``dlrover_train_data_wait_seconds`` histogram) with the
-    Prefetcher's interface — epoch rollover, zero-batch-epoch guard,
-    ``sampler_state_dict()`` (trivially exact: nothing is ever in
-    flight), ``wait_breakdown()`` and an idempotent ``close()``.
-
-    Reports the SAME split host/h2d staging metrics and trace events
-    as the async path (``dlrover_prefetch_stage_seconds_total``,
-    ``trainer.prefetch_stage`` / ``trainer.prefetch_h2d`` /
-    ``trainer.prefetch_wait``), so ``obs_report`` input-pipeline
-    summaries stay comparable across modes."""
-
-    def __init__(
-        self,
-        source: Iterable,
-        stage_fn: Optional[Callable[[Any], Any]] = None,
-        sampler=None,
-        auto_epoch: bool = False,
-        name: str = "train",
-        h2d_fn: Optional[Callable[[Any], Any]] = None,
-        device_prefetch: Optional[bool] = None,  # noqa: ARG002 — knob
-        # accepted for interface parity; there is no worker to move
-        # the H2D into, the consumer always pays it.
-    ):
-        if auto_epoch and sampler is None:
-            raise ValueError("auto_epoch requires a sampler")
-        self._stage_fn = stage_fn
-        self._h2d_fn = h2d_fn
-        self._sampler = sampler
-        self.name = name
-        self._it = _epoch_stream(source, sampler, auto_epoch, name)
-        self.delivered = 0
-        self.wait_s_total = 0.0
-        self.host_wait_s_total = 0.0
-        self.h2d_wait_s_total = 0.0
-        self.host_stage_s_total = 0.0
-        self.h2d_stage_s_total = 0.0
-        self._last_split: Tuple[float, float] = (0.0, 0.0)
-        self._closed = False
-        obs.event(
-            "trainer.prefetch_start",
-            pipeline=name,
-            depth=0,
-            device_prefetch=0,
-        )
-
-    def __iter__(self) -> "SyncPipeline":
-        return self
-
-    def __next__(self):
-        t0 = time.perf_counter()
-        raw = next(self._it)  # StopIteration ends the stream
-        with obs.span("trainer.prefetch_stage", pipeline=self.name):
-            staged = (
-                self._stage_fn(raw)
-                if self._stage_fn is not None
-                else raw
-            )
-        host_s = time.perf_counter() - t0
-        h2d_s = 0.0
-        if self._h2d_fn is not None:
-            t_h2d = time.perf_counter()
-            with obs.span("trainer.prefetch_h2d", pipeline=self.name):
-                staged = self._h2d_fn(staged)
-            h2d_s = time.perf_counter() - t_h2d
-        wait = host_s + h2d_s
-        self.wait_s_total += wait
-        self.host_wait_s_total += host_s
-        self.h2d_wait_s_total += h2d_s
-        self.host_stage_s_total += host_s
-        self.h2d_stage_s_total += h2d_s
-        self._last_split = (host_s, h2d_s)
-        _DATA_WAIT.observe(wait)
-        _STAGE_SECONDS.inc(host_s, phase="host")
-        if self._h2d_fn is not None:
-            _STAGE_SECONDS.inc(h2d_s, phase="h2d")
-        obs.event(
-            "trainer.prefetch_wait",
-            pipeline=self.name,
-            dur_s=round(wait, 6),
-            host_s=round(host_s, 6),
-            h2d_s=round(h2d_s, 6),
-        )
-        self.delivered += 1
-        _BATCHES.inc(outcome="delivered")
-        return staged
-
-    def wait_breakdown(self) -> Tuple[float, float]:
-        """(host_s, h2d_s) of the last batch — exact in sync mode:
-        the consumer paid both inline."""
-        return self._last_split
-
-    def sampler_state_dict(self) -> Optional[dict]:
-        if self._sampler is None:
-            return None
-        return dict(self._sampler.state_dict())
-
-    def close(self) -> None:
-        # Idempotent like Prefetcher.close(): a defensive second
-        # close (context manager + finally, elastic restart) must not
-        # emit a duplicate prefetch_stop event with doubled counts.
-        if self._closed:
-            return
-        self._closed = True
-        obs.event(
-            "trainer.prefetch_stop",
-            pipeline=self.name,
-            staged=self.delivered,
-            delivered=self.delivered,
-            dropped=0,
-            wait_s_total=round(self.wait_s_total, 6),
-            host_stage_s_total=round(self.host_stage_s_total, 6),
-            h2d_stage_s_total=round(self.h2d_stage_s_total, 6),
-        )
-
-    def __enter__(self) -> "SyncPipeline":
-        return self
-
-    def __exit__(self, *exc) -> None:
-        self.close()
-
-
-def make_input_pipeline(
-    source: Iterable,
-    stage_fn: Optional[Callable[[Any], Any]] = None,
-    depth: Optional[int] = None,
-    sampler=None,
-    auto_epoch: bool = False,
-    name: str = "train",
-    h2d_fn: Optional[Callable[[Any], Any]] = None,
-    device_prefetch: Optional[bool] = None,
-):
-    """The one switch every train loop uses: a background
-    :class:`Prefetcher` normally, or the synchronous
-    :class:`SyncPipeline` under ``DLROVER_TPU_PREFETCH=0`` — same
-    interface either way (iterate, ``sampler_state_dict()``,
-    ``wait_breakdown()``, ``close()``). ``h2d_fn`` is the
-    host->device staging step (device placement under the training
-    step's sharding); ``device_prefetch`` keeps it in the worker
-    (default, device-resident queue) or on the consumer
-    (``DLROVER_TPU_DEVICE_PREFETCH=0``)."""
-    if prefetch_enabled():
-        return Prefetcher(
-            source,
-            stage_fn=stage_fn,
-            depth=depth,
-            sampler=sampler,
-            auto_epoch=auto_epoch,
-            name=name,
-            h2d_fn=h2d_fn,
-            device_prefetch=device_prefetch,
-        )
-    return SyncPipeline(
-        source,
-        stage_fn=stage_fn,
-        sampler=sampler,
-        auto_epoch=auto_epoch,
-        name=name,
-        h2d_fn=h2d_fn,
-        device_prefetch=device_prefetch,
-    )
+# The name every train loop (and the benchmark) builds its feeder by:
+# ``make_input_pipeline(source, h2d_fn=..., name=...)``.
+make_input_pipeline = Prefetcher

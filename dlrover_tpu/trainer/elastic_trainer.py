@@ -102,29 +102,6 @@ class TrainerReport:
     accum_steps: int
 
 
-class _PipelinedAdapter:
-    """Adapts a :class:`~dlrover_tpu.trainer.step.PipelinedTrainStep`
-    (metrics-dict contract) to the trainer's internal
-    ``(params, opt_state, loss)`` step shape, delegating the
-    profiling seams (``_cache_size`` for the CompileTracker,
-    ``lower`` for MFU pricing) to the driver."""
-
-    def __init__(self, driver):
-        self.driver = driver
-
-    def __call__(self, params, opt_state, tokens, targets):
-        params, opt_state, metrics = self.driver(
-            params, opt_state, tokens, targets
-        )
-        return params, opt_state, metrics["loss"]
-
-    def _cache_size(self):
-        return self.driver._cache_size()
-
-    def lower(self, *args):
-        return self.driver.lower(*args)
-
-
 class ElasticTrainer:
     """Builds a compiled global-step function with gradient
     accumulation and keeps the global batch size fixed.
@@ -155,7 +132,6 @@ class ElasticTrainer:
         overlap_reduce: Optional[bool] = None,
         reduce_bucket_mb: Optional[float] = None,
         reduce_bits: Optional[int] = None,
-        pipeline_depth: Optional[int] = None,
     ):
         """``step_fn``: a prebuilt full-batch training step —
         ``step_fn(params, opt_state, tokens[B, ...], targets) ->
@@ -195,22 +171,7 @@ class ElasticTrainer:
         (``DLROVER_TPU_REDUCE_BITS``; unset = exact sync). The
         donation / zero-host-sync contracts are identical to the
         serial step, and numerics parity is tested
-        (tests/test_elastic_trainer.py).
-
-        ``pipeline_depth``: with ``accum_steps > 1``, run the
-        accumulation as a host-driven microbatch pipeline
-        (trainer/step.py PipelinedTrainStep) instead of one jitted
-        scan: microbatch k+1's H2D staging is dispatched while k
-        computes (``pipeline_depth`` staged device slots ahead —
-        double buffering at 1), and every consumed slot's buffers are
-        donated so steady-state HBM beyond one in-flight batch is
-        zero. Works on host batches (staged per microbatch right
-        here, the low-HBM path) or pre-staged ``[accum, B, ...]``
-        device arrays (sliced device-side). Composes with
-        ``overlap_reduce``. ``None`` reads
-        ``DLROVER_TPU_PIPELINE_DEPTH`` (default 0 = the monolithic
-        scan step). Bitwise numerics parity with the serial step is
-        tested."""
+        (tests/test_elastic_trainer.py)."""
         self.mesh = mesh
         self.loss_fn = loss_fn
         self.optimizer = optimizer
@@ -247,18 +208,6 @@ class ElasticTrainer:
         self.overlap_reduce = bool(overlap_reduce)
         self.reduce_bucket_mb = float(reduce_bucket_mb)
         self.reduce_bits = reduce_bits
-        _pd_explicit = pipeline_depth is not None
-        if pipeline_depth is None:
-            _pd_env = os.getenv("DLROVER_TPU_PIPELINE_DEPTH", "")
-            try:
-                pipeline_depth = int(_pd_env) if _pd_env else 0
-            except ValueError:
-                logger.warning(
-                    "unparseable DLROVER_TPU_PIPELINE_DEPTH=%r; "
-                    "pipelining off", _pd_env,
-                )
-                pipeline_depth = 0
-        self.pipeline_depth = max(int(pipeline_depth), 0)
         self.num_shards = data_shards(mesh)
         self.step_num = 0
         # Loss scalars reach report_fn via the async drain: the hot
@@ -307,21 +256,6 @@ class ElasticTrainer:
                         "step_fn (e.g. a 1F1B pipeline) owns its own "
                         "collective schedule"
                     )
-            if self.pipeline_depth > 0:
-                if not _pd_explicit:
-                    logger.warning(
-                        "ignoring DLROVER_TPU_PIPELINE_DEPTH=%d: an "
-                        "external step_fn owns its own microbatch "
-                        "schedule", self.pipeline_depth,
-                    )
-                    self.pipeline_depth = 0
-                else:
-                    raise ValueError(
-                        "pipeline_depth applies to the built-in "
-                        "accumulate-then-update step; an external "
-                        "step_fn (e.g. a 1F1B pipeline) owns its own "
-                        "microbatch schedule"
-                    )
             # The external step (e.g. a 1F1B pipeline) consumes the
             # WHOLE global batch in one call and owns its own
             # microbatching: accumulation collapses to 1, and the
@@ -369,9 +303,7 @@ class ElasticTrainer:
                         "(overlap_reduce=False), which lets XLA "
                         "schedule those axes' collectives"
                     )
-            if self.pipeline_depth > 0:
-                self._compiled = self._build_pipelined_step()
-            elif self.overlap_reduce:
+            if self.overlap_reduce:
                 self._compiled = self._build_overlapped_step()
             else:
                 self._compiled = self._build_step()
@@ -537,49 +469,6 @@ class ElasticTrainer:
         )
         return jax.jit(fn, donate_argnums=self._donate_argnums())
 
-    def _build_pipelined_step(self):
-        """The ``pipeline_depth`` variant: the accumulation runs as a
-        host-driven pipeline of per-microbatch jitted programs
-        (trainer/step.py :class:`PipelinedTrainStep`) — microbatch
-        k+1's H2D staging dispatches while k computes, input slots
-        are donated as consumed, and with ``overlap_reduce`` each
-        microbatch's bucketed reduce rides inside its own program.
-        Same accumulate-then-update math as :meth:`_build_step`
-        (bitwise, tested); staging goes through
-        :meth:`stage_microbatch` so the multi-process per-shard batch
-        contract is identical to :meth:`shard_microbatches`."""
-        from dlrover_tpu.parallel.compression import (
-            overlap_sync_bytes_per_element,
-        )
-        from dlrover_tpu.trainer.step import PipelinedTrainStep
-
-        bspec = batch_spec(self.mesh)
-        self._mb_spec = P(None, *bspec)
-        on_plan = None
-        if self.overlap_reduce:
-            self._overlap_bytes_per_el = overlap_sync_bytes_per_element(
-                self.reduce_bits, self.accum_steps
-            )
-            on_plan = self._note_overlap_plan
-        driver = PipelinedTrainStep(
-            self.mesh,
-            self.loss_fn,
-            self.optimizer,
-            accum_steps=self.accum_steps,
-            pipeline_depth=self.pipeline_depth,
-            donate=self.donate_state,
-            acc_dtype=self.accum_dtype,
-            overlap=self.overlap_reduce,
-            bucket_mb=self.reduce_bucket_mb,
-            bits=self.reduce_bits,
-            stage_fn=self.stage_microbatch,
-            on_plan=on_plan,
-            # train_step validates/ships [accum, micro*shards, ...]
-            # device batches exclusively — never the flat form.
-            staged_device_inputs=True,
-        )
-        return _PipelinedAdapter(driver)
-
     def _note_overlap_plan(self, plan) -> None:
         """Trace-time observability hook for the overlapped schedule:
         bucket count + per-element sync bytes as gauges and a trace
@@ -674,55 +563,6 @@ class ElasticTrainer:
         )
 
     @property
-    def _microbatch_sharding(self) -> NamedSharding:
-        """The (mesh-invariant) sharding one staged microbatch gets —
-        computed once, reused by every hop of the pipelined staging
-        path (accum_steps constructions per step would be pure
-        overhead)."""
-        cached = getattr(self, "_mb_sharding", None)
-        if cached is None:
-            spec = prune_specs_to_mesh(self.mesh, batch_spec(self.mesh))
-            cached = self._mb_sharding = NamedSharding(self.mesh, spec)
-        return cached
-
-    def stage_microbatch(self, tokens, targets, k: int):
-        """Host arrays -> microbatch ``k``'s ``[micro * shards, ...]``
-        device arrays on the mesh — the per-hop staging step of the
-        pipelined schedule (:class:`PipelinedTrainStep` calls this as
-        its ``stage_fn``). Slicing matches
-        :meth:`shard_microbatches`'s ``(accum, -1)`` reshape exactly:
-        microbatch k is rows ``[k*mb, (k+1)*mb)`` of the (per-process)
-        host batch, so the two staging paths feed identical data."""
-        if self.profiler is not None and self.profiler.beacon is not None:
-            # Stall beacon: microbatch granularity localizes a wedge
-            # *within* a step (host h parked at microbatch k while
-            # peers reached k+1). Host-side mmap write, no sync.
-            self.profiler.beacon.stamp(microbatch=k)
-        sharding = self._microbatch_sharding
-        n_proc = jax.process_count()
-        if n_proc <= 1:
-            mb = self.micro_batch_size * self.num_shards
-            sl = slice(k * mb, (k + 1) * mb)
-            return (
-                jax.device_put(tokens[sl], sharding),
-                jax.device_put(targets[sl], sharding),
-            )
-        local_mb = self.local_samples_per_step // self.accum_steps
-        global_mb = self.micro_batch_size * self.num_shards
-        sl = slice(k * local_mb, (k + 1) * local_mb)
-        gshape = lambda a: (global_mb,) + tuple(a.shape[1:])  # noqa: E731
-        local_tok = np.ascontiguousarray(tokens[sl])
-        local_tgt = np.ascontiguousarray(targets[sl])
-        return (
-            jax.make_array_from_process_local_data(
-                sharding, local_tok, gshape(local_tok)
-            ),
-            jax.make_array_from_process_local_data(
-                sharding, local_tgt, gshape(local_tgt)
-            ),
-        )
-
-    @property
     def samples_per_step(self) -> int:
         return self.accum_steps * self.micro_batch_size * self.num_shards
 
@@ -771,22 +611,9 @@ class ElasticTrainer:
         touch the inputs again.
         """
         if isinstance(tokens, np.ndarray):
-            if self.pipeline_depth > 0:
-                # The pipelined step stages per MICROBATCH itself
-                # (stage_microbatch), overlapping each slot's H2D
-                # with the previous microbatch's compute — a full
-                # up-front shard_microbatches would defeat it. Trim to
-                # this process's draw like shard_microbatches does.
-                n = (
-                    self.samples_per_step
-                    if jax.process_count() <= 1
-                    else self.local_samples_per_step
-                )
-                tokens, targets = tokens[:n], targets[:n]
-            else:
-                # Host batch of ANY rank gets staged; device arrays
-                # are assumed already sharded and are never re-staged.
-                tokens, targets = self.shard_microbatches(tokens, targets)
+            # Host batch of ANY rank gets staged; device arrays
+            # are assumed already sharded and are never re-staged.
+            tokens, targets = self.shard_microbatches(tokens, targets)
         else:
             # Loud contract check for the passthrough path: a caller
             # still feeding flat [N, ...] jnp host batches (the

@@ -152,13 +152,11 @@ class Trainer:
             strategy=args.strategy,
             optimizer_kwargs=self._optimizer_kwargs(),
         )
-        # A strategy that selected overlapped gradient reduction /
-        # microbatch pipelining (the search can tune both) forces the
-        # trainer onto that schedule; otherwise the env defaults
-        # (DLROVER_TPU_OVERLAP_REDUCE / DLROVER_TPU_PIPELINE_DEPTH)
-        # decide.
+        # A strategy that selected overlapped gradient reduction (the
+        # search can tune it) forces the trainer onto that schedule;
+        # otherwise the env default (DLROVER_TPU_OVERLAP_REDUCE)
+        # decides.
         _overlap = getattr(res.strategy, "overlap_reduce", False)
-        _pipe_depth = getattr(res.strategy, "pipeline_depth", 0)
         trainer = ElasticTrainer(
             res.mesh,
             self.model_loss,
@@ -169,7 +167,6 @@ class Trainer:
             reduce_bucket_mb=(
                 res.strategy.reduce_bucket_mb if _overlap else None
             ),
-            pipeline_depth=_pipe_depth if _pipe_depth else None,
         )
         params, opt_state = res.init_fn(
             jax.random.PRNGKey(args.seed)
@@ -224,34 +221,16 @@ class Trainer:
             return np.asarray(tokens), np.asarray(targets)
 
         def _h2d(batch):
-            # Device stage: H2D under the step's NamedSharding. With
-            # device_prefetch (default) this also runs in the worker,
-            # so the queue hands the loop committed device arrays and
-            # step N+1's transfer overlaps step N's compute.
+            # Device stage: H2D under the step's NamedSharding. This
+            # also runs in the worker, so the queue hands the loop
+            # committed device arrays and step N+1's transfer overlaps
+            # step N's compute.
             return trainer.shard_microbatches(*batch)
 
-        # The strategy supplies the device_prefetch default (the
-        # search tunes it); an explicitly-set
-        # DLROVER_TPU_DEVICE_PREFETCH env wins, so a deployment can
-        # flip the schedule without re-searching. A pipelined trainer
-        # fed host batches stages per microbatch itself — don't ALSO
-        # full-batch-stage in the pipeline.
-        from dlrover_tpu.data.prefetch import device_prefetch_enabled
-
-        device_prefetch = device_prefetch_enabled(
-            default=getattr(res.strategy, "device_prefetch", True)
-        )
-        h2d_fn = _h2d
-        if trainer.pipeline_depth > 0 and not device_prefetch:
-            h2d_fn = None
-
-        # Background Prefetcher normally; the synchronous fallback
-        # under DLROVER_TPU_PREFETCH=0 — same interface either way.
         batches = make_input_pipeline(
             loader,
             stage_fn=_collate,
-            h2d_fn=h2d_fn,
-            device_prefetch=device_prefetch,
+            h2d_fn=_h2d,
             sampler=sampler,
             auto_epoch=True,
             name="trainer",

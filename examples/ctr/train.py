@@ -237,7 +237,7 @@ def main(argv=None) -> int:
     def h2d(batch):
         # Device placement split from the host collate so the staging
         # metrics attribute host vs H2D cost separately (see
-        # docs/PERFORMANCE.md "Device-resident input pipeline").
+        # docs/PERFORMANCE.md "Input pipeline and accumulation").
         keys_flat, labels = batch
         return keys_flat, jnp.asarray(labels)
 

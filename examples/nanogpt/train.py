@@ -186,8 +186,7 @@ def main(argv=None) -> int:
     def stage(batch):
         # Device placement under the step's sharding — registered as
         # h2d_fn so the worker delivers committed device arrays and
-        # the host/H2D staging split lands in the metrics
-        # (DLROVER_TPU_DEVICE_PREFETCH=0 moves it to the consumer).
+        # the host/H2D staging split lands in the metrics.
         return trainer.shard_microbatches(*batch)
 
     batches = make_input_pipeline(

@@ -482,6 +482,36 @@ class TestTuneCacheWarmStart:
         )
 
 
+    def test_old_tune_cache_row_is_a_miss_not_an_error(self, tmp_path):
+        """A row written before the input-pipelining fields left
+        ``Strategy`` (its config JSON still holds them) matches no
+        candidate: the lookup skips it and pays the dry run again."""
+        import json as _json
+
+        from dlrover_tpu.accelerate import tune_cache as tc
+        from dlrover_tpu.accelerate.api import _tune_cache_key
+        from dlrover_tpu.accelerate.analyser import analyse_model
+        from dlrover_tpu.obs.metrics import get_registry
+
+        init, loss, axes = _model()
+        key = _tune_cache_key(
+            analyse_model(init), _sample_batch(), 4
+        )
+        cache = tc.TuneCache(str(tmp_path / "tune.jsonl"))
+        for s in self.CANDS:
+            old = dataclasses.asdict(s)
+            old.update(pipeline_depth=0, device_prefetch=True)
+            cache.record(key, _json.dumps(old), 1e9)
+        misses = get_registry().get("dlrover_tune_cache_misses_total")
+        m0 = misses.value()
+        r = self._run(cache)
+        assert len(self._dry_runs(r)) == 2  # both paid again
+        assert not [e for e in r.search_log if e.get("cached")]
+        assert misses.value() == m0 + 1
+        # the fresh rows are findable: the next run replays them
+        assert len(self._dry_runs(self._run(cache))) == 0
+
+
 class TestOverlapStrategy:
     def test_grid_overlap_only_on_pure_data_factorizations(self):
         cands = candidate_strategies(
@@ -543,73 +573,33 @@ class TestOverlapStrategy:
             )
 
 
-class TestPipelineStrategy:
-    def test_grid_pipeline_knobs_and_json_roundtrip(self):
-        cands = candidate_strategies(
-            8,
-            micro_batch_sizes=(4,),
-            remats=(False,),
-            pipeline_depths=(0, 2),
-            device_prefetchs=(True, False),
-        )
-        with_pd = [c for c in cands if c.pipeline_depth]
-        assert with_pd, "no pipelined candidates generated"
-        # pipelining needs the built-in step: never on a pipe axis
-        assert all(
-            c.mesh_dict.get("pipe", 1) == 1 for c in with_pd
-        )
-        assert {c.device_prefetch for c in cands} == {True, False}
-        assert len({c.name() for c in cands}) == len(cands)
-        s = with_pd[0]
-        assert "-pd:2" in s.name()
-        assert Strategy.from_json(s.to_json()) == s
-        # pre-knob Strategy JSON (older tune-cache records) decodes
-        # with the defaults — warm starts stay replayable
-        import dataclasses as _dc
-        import json as _json
+# What the parent commit's grid held, by the three things a candidate
+# is chosen for: (n_devices, candidates, sha256 of the sorted
+# (mesh_shape, remat, micro_batch_size) reprs, first 16 hex digits).
+_PARENT_GRID = {4: (180, "e237cb5151a06a51"), 8: (420, "f6ecd2f09c2d98e5")}
+_RETIRED = ("pipeline_depth", "device_prefetch", "-pd:", "-devpf:")
 
-        d = _dc.asdict(s)
-        d.pop("pipeline_depth")
-        d.pop("device_prefetch")
-        old = Strategy.from_json(_json.dumps(d))
-        assert old.pipeline_depth == 0 and old.device_prefetch
 
-    def test_encoding_covers_pipeline_knobs(self):
-        from dlrover_tpu.accelerate.bayes_search import encode_strategy
+@pytest.mark.parametrize("n_devices", [4, 8])
+def test_candidate_grid_unchanged_by_retired_axes(n_devices):
+    """Retiring the two input-pipelining axes removed no candidate
+    and added none, and no Strategy still spells a retired field."""
+    import hashlib
 
-        base = Strategy(mesh_shape=(("data", 8),))
-        pd = Strategy(mesh_shape=(("data", 8),), pipeline_depth=2)
-        pd4 = Strategy(mesh_shape=(("data", 8),), pipeline_depth=4)
-        nodp = Strategy(
-            mesh_shape=(("data", 8),), device_prefetch=False
-        )
-        encs = [
-            tuple(encode_strategy(s)) for s in (base, pd, pd4, nodp)
-        ]
-        assert len(set(encs)) == 4
-
-    def test_explicit_pipelined_strategy_trains(self):
-        init, loss, axes = _model()
-        s = Strategy(
-            mesh_shape=(("data", 4),),
-            dtype="float32",
-            micro_batch_size=4,
-            pipeline_depth=1,
-        )
-        res = auto_accelerate(
-            init, loss, axes, _sample_batch(), strategy=s,
-            devices=jax.devices()[:4],
-        )
-        params, opt_state = res.init_fn(jax.random.PRNGKey(0))
-        tokens, targets = res.shard_batch_fn(*_sample_batch(4))
-        losses = []
-        for _ in range(5):
-            params, opt_state, metrics = res.step_fn(
-                params, opt_state, tokens, targets
-            )
-            losses.append(float(metrics["loss"]))
-        assert losses[-1] < losses[0]
-        assert "grad_norm" in metrics  # the shared metrics contract
+    cands = candidate_strategies(n_devices)
+    keys = sorted(
+        repr((c.mesh_shape, c.remat, c.micro_batch_size)) for c in cands
+    )
+    count, digest = _PARENT_GRID[n_devices]
+    assert len(cands) == count and len(set(keys)) == count
+    assert (
+        hashlib.sha256("\n".join(keys).encode()).hexdigest()[:16]
+        == digest
+    )
+    for c in cands:
+        assert not any(r in c.name() for r in _RETIRED), c.name()
+        assert not any(r in c.to_json() for r in _RETIRED), c.to_json()
+    assert len(dataclasses.fields(Strategy)) == 8
 
 
 def test_search_raises_when_nothing_fits():

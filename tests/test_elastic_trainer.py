@@ -1,11 +1,13 @@
 """ElasticTrainer fixed-global-batch semantics + checkpointable sampler."""
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
-from jax.sharding import Mesh
+from jax.sharding import PartitionSpec as P
 
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.trainer.elastic_trainer import (
@@ -16,8 +18,93 @@ from dlrover_tpu.trainer.elastic_trainer import (
 )
 
 
+# The meshes the cells run or will run (fsdp=4 is
+# mistral-7b-host4.fsdp4's; data=4 and data=2 x fsdp=2 are those of
+# the planned gpt2-124m.data4 and of a re-layout, ROADMAP R1-R2),
+# each with what is left of it when half the chips go.
+_MESHES = {
+    "data4": {"data": 4},
+    "fsdp4": {"fsdp": 4},
+    "data2xfsdp2": {"data": 2, "fsdp": 2},
+}
+_HALVED = {
+    "data4": {"data": 2},
+    "fsdp4": {"fsdp": 2},
+    "data2xfsdp2": {"fsdp": 2},
+}
+_MESH_NAMES = list(_MESHES)
+
+
+def _mesh_of(axes):
+    n = math.prod(axes.values())
+    return build_mesh(MeshConfig(**axes), devices=jax.devices()[:n])
+
+
 def _mesh(data=4):
-    return build_mesh(MeshConfig(data=data), devices=jax.devices()[:data])
+    return _mesh_of({"data": data})
+
+
+# A two-layer MLP whose logical axes shard every leaf but ``b1`` over
+# ``fsdp`` under the default rules ("embed" -> fsdp), as the models'
+# weights are on the chip.
+_MLP_AXES = {
+    "w1": ("embed", "mlp"),
+    "b1": ("mlp",),
+    "w2": ("mlp", "embed"),
+    "b2": ("embed",),
+}
+
+
+def _mlp_init(key):
+    k1, k2 = jax.random.split(key)
+    return {
+        "w1": 0.3 * jax.random.normal(k1, (8, 16)),
+        "b1": jnp.zeros((16,)),
+        "w2": 0.3 * jax.random.normal(k2, (16, 8)),
+        "b2": jnp.zeros((8,)),
+    }
+
+
+def _mlp_loss(params, x, y):
+    h = jnp.tanh(x @ params["w1"] + params["b1"])
+    return jnp.mean((h @ params["w2"] + params["b2"] - y) ** 2)
+
+
+def _mlp_data(n=32, seed=0):
+    rng = np.random.default_rng(seed)
+    x = rng.normal(size=(n, 8)).astype(np.float32)
+    y = np.tanh(x @ rng.normal(size=(8, 8))).astype(np.float32)
+    return x, y
+
+
+def _sharded_state(mesh, opt):
+    """(params, opt_state) made on the mesh by ``make_sharded_init``;
+    on a mesh with an ``fsdp`` axis the weights and their moments are
+    really split over it."""
+    from dlrover_tpu.trainer.step import make_sharded_init
+
+    init, _ = make_sharded_init(mesh, _mlp_init, _MLP_AXES, opt)
+    params, opt_state = init(jax.random.PRNGKey(0))
+    if mesh.shape.get("fsdp", 1) > 1:
+        assert params["w1"].sharding.spec == P("fsdp", None)
+        assert len(params["w1"].addressable_shards) == mesh.size
+        assert params["w1"].addressable_shards[0].data.shape == (
+            8 // mesh.shape["fsdp"], 16,
+        )
+    return params, opt_state
+
+
+def _sharded_trainer(mesh_name, accum, **kw):
+    """Trainer + state on one of ``_MESHES`` at a global batch of 32
+    rows split into ``accum`` microbatches."""
+    mesh = _mesh_of(_MESHES[mesh_name])
+    opt = optax.sgd(0.1, momentum=0.9)
+    tr = ElasticTrainer(
+        mesh, _mlp_loss, opt, global_batch_size=32,
+        micro_batch_size=32 // (4 * accum), **kw,
+    )
+    assert tr.accum_steps == accum and tr.num_shards == 4
+    return tr, opt, _sharded_state(mesh, opt)
 
 
 def _linear_loss(params, x, y):
@@ -42,55 +129,97 @@ def test_accum_steps_keeps_global_batch():
     assert gradient_accumulation_steps(100, 4, 8) == 4
 
 
-def test_accumulated_step_equals_big_batch_step():
+@pytest.mark.parametrize("accum", [1, 4])
+@pytest.mark.parametrize("mesh_name", _MESH_NAMES)
+def test_accumulated_step_equals_big_batch_step(mesh_name, accum):
     """accum microbatches must produce the same update as one big
-    batch (the whole point of fixed-global-batch elasticity)."""
-    x, y = _toy_data(32)
-    params = {"w": jnp.zeros((8, 1)), "b": jnp.zeros((1,))}
-    opt = optax.sgd(0.1)
-
-    mesh = _mesh(2)
-    # donate_state=False: ``params`` is aliased below to compute the
+    batch (the whole point of fixed-global-batch elasticity), with
+    the state split over the mesh as it is on the chip."""
+    x, y = _mlp_data(32)
+    # donate_state=False: the state is read again below for the
     # big-batch reference (the documented donation escape hatch).
-    tr = ElasticTrainer(
-        mesh, _linear_loss, opt, global_batch_size=32,
-        micro_batch_size=4, donate_state=False,
+    tr, opt, (params, opt_state) = _sharded_trainer(
+        mesh_name, accum, donate_state=False
     )
-    assert tr.accum_steps == 4
-    p1, _, loss1 = tr.train_step(params, opt.init(params), x, y)
+    p1, _, loss1 = tr.train_step(params, opt_state, x, y)
 
-    # one big-batch step on the same data
-    loss_big, grads = jax.value_and_grad(_linear_loss)(
-        params, jnp.asarray(x), jnp.asarray(y)
-    )
-    updates, _ = opt.update(grads, opt.init(params), params)
-    p2 = optax.apply_updates(params, updates)
+    # one big-batch step on the same data, on one device
+    host = jax.device_get(params)
+    loss_big, grads = jax.value_and_grad(_mlp_loss)(host, x, y)
+    updates, _ = opt.update(grads, opt.init(host), host)
+    p2 = optax.apply_updates(host, updates)
 
     np.testing.assert_allclose(loss1, loss_big, rtol=1e-5)
-    for a, b in zip(jax.tree.leaves(p1), jax.tree.leaves(p2)):
-        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+    for name in p2:
+        assert p1[name].sharding == params[name].sharding
+        np.testing.assert_allclose(
+            p1[name], p2[name], rtol=1e-5, atol=1e-6
+        )
 
 
-def test_world_shrink_same_global_batch():
-    """4-shard and 2-shard trainers apply the same global batch and
-    produce the same parameters."""
-    x, y = _toy_data(32)
-    params = {"w": jnp.zeros((8, 1)), "b": jnp.zeros((1,))}
-    opt = optax.sgd(0.1)
+@pytest.mark.parametrize("mesh_name", _MESH_NAMES)
+def test_host_and_prestaged_batches_take_the_same_step(mesh_name):
+    """A numpy batch handed to ``train_step`` and the arrays
+    ``shard_microbatches`` makes of it (what the Prefetcher's worker
+    hands over) give bitwise equal losses and parameters."""
+    x, y = _mlp_data(32)
+    tr, _, (params, opt_state) = _sharded_trainer(
+        mesh_name, 4, donate_state=False
+    )
+    p_host, _, l_host = tr.train_step(params, opt_state, x, y)
+    tok, tgt = tr.shard_microbatches(x, y)
+    assert tok.shape == (4, 8, 8) and isinstance(tok, jax.Array)
+    p_dev, _, l_dev = tr.train_step(params, opt_state, tok, tgt)
+    np.testing.assert_array_equal(
+        jax.device_get(l_host), jax.device_get(l_dev)
+    )
+    for name in p_host:
+        np.testing.assert_array_equal(
+            jax.device_get(p_host[name]), jax.device_get(p_dev[name])
+        )
+    # and the microbatches are the rows in order, not a permutation
+    np.testing.assert_array_equal(
+        jax.device_get(tok).reshape(32, 8), x
+    )
+
+
+@pytest.mark.parametrize("mesh_name", _MESH_NAMES)
+def test_one_step_program(mesh_name):
+    """However the batch arrives, the trainer compiles ONE program
+    (the CPU twin of the benchmark's ``step_programs.train``): the
+    state a step returns is laid out as the state it took, and a host
+    batch is staged under the sharding a pre-staged one has."""
+    x, y = _mlp_data(32)
+    tr, _, (params, opt_state) = _sharded_trainer(mesh_name, 4)
+    want = jax.tree.map(lambda a: a.sharding, (params, opt_state))
+    for _ in range(3):
+        params, opt_state, _ = tr.train_step(params, opt_state, x, y)
+    for _ in range(3):
+        params, opt_state, _ = tr.train_step(
+            params, opt_state, *tr.shard_microbatches(x, y)
+        )
+    assert tr._compiled._cache_size() == 1
+    assert jax.tree.map(lambda a: a.sharding, (params, opt_state)) == want
+
+
+@pytest.mark.parametrize("mesh_name", _MESH_NAMES)
+def test_world_shrink_same_global_batch(mesh_name):
+    """The mesh and what is left of it when half the chips go apply
+    the same global batch and produce the same parameters."""
+    x, y = _mlp_data(32)
+    opt = optax.sgd(0.1, momentum=0.9)
 
     results = []
-    for shards in (4, 2):
+    for axes in (_MESHES[mesh_name], _HALVED[mesh_name]):
+        mesh = _mesh_of(axes)
         tr = ElasticTrainer(
-            _mesh(shards),
-            _linear_loss,
-            opt,
-            global_batch_size=32,
+            mesh, _mlp_loss, opt, global_batch_size=32,
             micro_batch_size=4,
-            donate_state=False,  # params fed to BOTH trainers
         )
         assert tr.samples_per_step == 32
-        p, _, _ = tr.train_step(params, opt.init(params), x, y)
-        results.append(p)
+        assert tr.accum_steps == 8 // mesh.size
+        p, _, _ = tr.train_step(*_sharded_state(mesh, opt), x, y)
+        results.append(jax.device_get(p))
     for a, b in zip(jax.tree.leaves(results[0]), jax.tree.leaves(results[1])):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
 
@@ -193,32 +322,27 @@ def test_donation_numerics_parity():
     np.testing.assert_array_equal(w_d, w_c)
 
 
-def test_donation_deletes_inputs_and_escape_hatch():
-    x, y = _toy_data(32)
-    opt = optax.sgd(0.1)
+@pytest.mark.parametrize("mesh_name", _MESH_NAMES)
+def test_donation_deletes_inputs_and_escape_hatch(mesh_name):
+    x, y = _mlp_data(32)
 
-    def fresh():
-        p = {"w": jnp.ones((8, 1)), "b": jnp.zeros((1,))}
-        return p, opt.init(p)
-
-    tr = ElasticTrainer(
-        _mesh(2), _linear_loss, opt, global_batch_size=32,
-        micro_batch_size=4,
-    )
+    tr, _, (params, opt_state) = _sharded_trainer(mesh_name, 4)
     assert tr.donate_state  # in-place update is the default
-    params, opt_state = fresh()
-    old_w = params["w"]
+    old = jax.tree.leaves((params, opt_state))
     tr.train_step(params, opt_state, x, y)
-    assert old_w.is_deleted()  # XLA really updated in place
+    # XLA really updated in place: every shard of every leaf, the
+    # moments with the weights
+    assert all(leaf.is_deleted() for leaf in old)
 
-    tr2 = ElasticTrainer(
-        _mesh(2), _linear_loss, opt, global_batch_size=32,
-        micro_batch_size=4, donate_state=False,
+    tr2, _, (params, opt_state) = _sharded_trainer(
+        mesh_name, 4, donate_state=False
     )
-    params, opt_state = fresh()
     tr2.train_step(params, opt_state, x, y)
-    assert not params["w"].is_deleted()  # escape hatch: alias freely
-    _ = params["w"] + 1
+    # escape hatch: alias freely
+    assert not any(
+        leaf.is_deleted() for leaf in jax.tree.leaves((params, opt_state))
+    )
+    _ = params["w1"] + 1
 
 
 # -- host-batch dispatch ---------------------------------------------------
@@ -323,7 +447,8 @@ def test_trainer_reports_every_step_one_late_then_flush():
 # -- zero-sync hot loop ----------------------------------------------------
 
 
-def test_hot_loop_no_host_sync_under_transfer_guard():
+@pytest.mark.parametrize("mesh_name", _MESH_NAMES)
+def test_hot_loop_no_host_sync_under_transfer_guard(mesh_name):
     """Steady-state tripwire: with pre-staged inputs, train_step plus
     async reporting performs NO device<->host transfer. Enforced two
     ways — jax.transfer_guard("disallow") (live on real accelerators;
@@ -332,15 +457,11 @@ def test_hot_loop_no_host_sync_under_transfer_guard():
     error on every backend."""
     from jax._src import array as jax_array
 
-    x, y = _toy_data(32)
-    params = {"w": jnp.zeros((8, 1)), "b": jnp.zeros((1,))}
-    opt = optax.adam(0.05)
+    x, y = _mlp_data(32)
     reports = []
-    tr = ElasticTrainer(
-        _mesh(2), _linear_loss, opt, global_batch_size=32,
-        micro_batch_size=4, report_fn=reports.append,
+    tr, _, (params, opt_state) = _sharded_trainer(
+        mesh_name, 4, report_fn=reports.append
     )
-    opt_state = opt.init(params)
     batches = [tr.shard_microbatches(x, y) for _ in range(4)]
     # step 1 pays the compile; the guard covers steady state only
     params, opt_state, _ = tr.train_step(params, opt_state, *batches[0])
@@ -597,389 +718,6 @@ def test_overlapped_step_sources_free_of_host_syncs():
          "_build_overlapped_step"),
     ):
         audit(textwrap.dedent(inspect.getsource(fn)), where)
-
-
-# -- pipelined microbatch accumulation -------------------------------------
-
-
-def test_pipelined_step_matches_serial_step_bitwise():
-    """The acceptance gate: the host-driven microbatch pipeline must
-    be BITWISE identical to the monolithic scan step on the 8-device
-    CPU mesh — pipelining changes buffer lifetimes and dispatch
-    order, never math."""
-    x, y = _toy_data(64)
-    params = {"w": jnp.zeros((8, 1)), "b": jnp.zeros((1,))}
-    opt = optax.adam(0.05)
-    mesh = _mesh(8)
-    kw = dict(
-        global_batch_size=64, micro_batch_size=4, donate_state=False
-    )
-    tr_serial = ElasticTrainer(mesh, _linear_loss, opt, **kw)
-    tr_pipe = ElasticTrainer(
-        mesh, _linear_loss, opt, pipeline_depth=1, **kw
-    )
-    assert tr_serial.accum_steps == tr_pipe.accum_steps == 2
-    p_s, _, l_s = tr_serial.train_step(params, opt.init(params), x, y)
-    p_p, _, l_p = tr_pipe.train_step(params, opt.init(params), x, y)
-    np.testing.assert_array_equal(
-        jax.device_get(l_s), jax.device_get(l_p)
-    )
-    for a, b in zip(jax.tree.leaves(p_s), jax.tree.leaves(p_p)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_pipelined_step_device_staged_batch_matches_host_path():
-    """Pre-staged [accum, B, ...] device arrays (a device-resident
-    prefetch queue) slice device-side and produce the same update as
-    host staging."""
-    x, y = _toy_data(64)
-    params = {"w": jnp.zeros((8, 1)), "b": jnp.zeros((1,))}
-    opt = optax.adam(0.05)
-    tr = ElasticTrainer(
-        _mesh(8), _linear_loss, opt, global_batch_size=64,
-        micro_batch_size=4, donate_state=False, pipeline_depth=2,
-    )
-    p_h, _, l_h = tr.train_step(params, opt.init(params), x, y)
-    tok, tgt = tr.shard_microbatches(x, y)
-    p_d, _, l_d = tr.train_step(params, opt.init(params), tok, tgt)
-    np.testing.assert_array_equal(
-        jax.device_get(l_h), jax.device_get(l_d)
-    )
-    for a, b in zip(jax.tree.leaves(p_h), jax.tree.leaves(p_d)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_pipelined_composes_with_overlap_reduce():
-    """pipeline_depth + overlap_reduce: per-microbatch bucketed
-    reduce inside each pipelined micro program. Parity vs the serial
-    step to float tolerance (the reduce schedule reorders sums,
-    exactly like the monolithic overlapped step)."""
-    x, y = _toy_data(64)
-    params = {"w": jnp.zeros((8, 1)), "b": jnp.zeros((1,))}
-    opt = optax.adam(0.05)
-    mesh = _mesh(8)
-    kw = dict(
-        global_batch_size=64, micro_batch_size=4, donate_state=False
-    )
-    tr_serial = ElasticTrainer(mesh, _linear_loss, opt, **kw)
-    tr_po = ElasticTrainer(
-        mesh, _linear_loss, opt, overlap_reduce=True,
-        reduce_bucket_mb=0.0001, pipeline_depth=1, **kw
-    )
-    p_s, _, l_s = tr_serial.train_step(params, opt.init(params), x, y)
-    p_o, _, l_o = tr_po.train_step(params, opt.init(params), x, y)
-    np.testing.assert_allclose(
-        np.asarray(jax.device_get(l_s)),
-        np.asarray(jax.device_get(l_o)),
-        rtol=1e-5,
-    )
-    for a, b in zip(jax.tree.leaves(p_s), jax.tree.leaves(p_o)):
-        np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6
-        )
-    # the overlap observability rides along
-    from dlrover_tpu.obs.metrics import get_registry
-
-    assert (
-        get_registry().get("dlrover_train_reduce_buckets").value() >= 1
-    )
-
-
-def test_pipelined_training_converges_with_donation():
-    x, y = _toy_data(64)
-    params = {"w": jnp.zeros((8, 1)), "b": jnp.zeros((1,))}
-    opt = optax.adam(0.05)
-    tr = ElasticTrainer(
-        _mesh(4), _linear_loss, opt, global_batch_size=64,
-        micro_batch_size=4, pipeline_depth=1,
-    )
-    assert tr.donate_state and tr.accum_steps == 4
-    opt_state = opt.init(params)
-    losses = []
-    for _ in range(30):
-        params, opt_state, loss = tr.train_step(
-            params, opt_state, x, y
-        )
-        losses.append(float(loss))
-    assert losses[-1] < losses[0] * 0.1
-
-
-def test_pipelined_env_knob_and_step_fn_gates(monkeypatch):
-    monkeypatch.setenv("DLROVER_TPU_PIPELINE_DEPTH", "2")
-    tr = ElasticTrainer(
-        _mesh(2), _linear_loss, optax.sgd(0.1),
-        global_batch_size=16, micro_batch_size=4,
-    )
-    assert tr.pipeline_depth == 2
-    # explicit ctor beats env
-    tr2 = ElasticTrainer(
-        _mesh(2), _linear_loss, optax.sgd(0.1),
-        global_batch_size=16, micro_batch_size=4, pipeline_depth=0,
-    )
-    assert tr2.pipeline_depth == 0
-
-    def step_fn(p, s, tok, tgt):
-        return p, s, {"loss": jnp.float32(0)}
-
-    # env default downgrades on an external step_fn...
-    tr3 = ElasticTrainer(
-        _mesh(4), None, optax.sgd(0.1), global_batch_size=16,
-        micro_batch_size=4, step_fn=step_fn,
-    )
-    assert tr3.pipeline_depth == 0
-    # ...but an explicit request raises
-    monkeypatch.delenv("DLROVER_TPU_PIPELINE_DEPTH", raising=False)
-    with pytest.raises(ValueError, match="microbatch schedule"):
-        ElasticTrainer(
-            _mesh(4), None, optax.sgd(0.1), global_batch_size=16,
-            micro_batch_size=4, step_fn=step_fn, pipeline_depth=1,
-        )
-
-
-def test_pipelined_hot_loop_no_host_sync_under_transfer_guard():
-    """The zero-sync contract holds for the pipelined step: steady
-    state (pre-staged device batches) performs no implicit
-    device<->host transfer and no float() fetch."""
-    from jax._src import array as jax_array
-
-    x, y = _toy_data(32)
-    params = {"w": jnp.zeros((8, 1)), "b": jnp.zeros((1,))}
-    opt = optax.adam(0.05)
-    reports = []
-    tr = ElasticTrainer(
-        _mesh(2), _linear_loss, opt, global_batch_size=32,
-        micro_batch_size=4, report_fn=reports.append,
-        pipeline_depth=1,
-    )
-    opt_state = opt.init(params)
-    batches = [tr.shard_microbatches(x, y) for _ in range(4)]
-    params, opt_state, _ = tr.train_step(params, opt_state, *batches[0])
-
-    def _boom(self):
-        raise AssertionError(
-            "implicit device->host sync (float(arr)) in the "
-            "pipelined hot loop"
-        )
-
-    orig = jax_array.ArrayImpl.__float__
-    jax_array.ArrayImpl.__float__ = _boom
-    try:
-        with jax.transfer_guard("disallow"):
-            for tok, tgt in batches[1:]:
-                params, opt_state, loss = tr.train_step(
-                    params, opt_state, tok, tgt
-                )
-                assert isinstance(loss, jax.Array)
-    finally:
-        jax_array.ArrayImpl.__float__ = orig
-    tr.flush_metrics()
-    assert [r.step for r in reports] == [1, 2, 3, 4]
-
-
-def test_pipelined_host_staging_is_explicit_transfers_only():
-    """Host-batch pipelining stages each microbatch via EXPLICIT
-    device_put — legal under transfer_guard('disallow'), which only
-    forbids implicit transfers. The tripwire float() stays armed."""
-    from jax._src import array as jax_array
-
-    x, y = _toy_data(32)
-    params = {"w": jnp.zeros((8, 1)), "b": jnp.zeros((1,))}
-    opt = optax.adam(0.05)
-    tr = ElasticTrainer(
-        _mesh(2), _linear_loss, opt, global_batch_size=32,
-        micro_batch_size=4, pipeline_depth=1,
-    )
-    opt_state = opt.init(params)
-    params, opt_state, _ = tr.train_step(params, opt_state, x, y)
-
-    orig = jax_array.ArrayImpl.__float__
-
-    def _boom(self):
-        raise AssertionError("implicit fetch in pipelined staging")
-
-    jax_array.ArrayImpl.__float__ = _boom
-    try:
-        with jax.transfer_guard("disallow"):
-            params, opt_state, loss = tr.train_step(
-                params, opt_state, x, y
-            )
-            assert isinstance(loss, jax.Array)
-    finally:
-        jax_array.ArrayImpl.__float__ = orig
-
-
-def test_pipelined_step_sources_free_of_host_syncs():
-    """AST audit (the CI satellite, extended to the new builders):
-    the code that builds and drives the pipelined step must contain
-    no host-sync calls."""
-    import ast
-    import inspect
-    import textwrap
-
-    from dlrover_tpu.trainer import step as step_mod
-    from dlrover_tpu.trainer.elastic_trainer import (
-        ElasticTrainer,
-        _PipelinedAdapter,
-    )
-
-    FORBIDDEN_CALLS = {"float", "bool"}
-    FORBIDDEN_ATTRS = {
-        "item", "asarray", "device_get", "block_until_ready",
-        "tolist",
-    }
-
-    def audit(fn_source, where):
-        tree = ast.parse(fn_source)
-        for node in ast.walk(tree):
-            if not isinstance(node, ast.Call):
-                continue
-            f = node.func
-            if isinstance(f, ast.Name):
-                assert f.id not in FORBIDDEN_CALLS, (
-                    f"{where}:{node.lineno}: host sync {f.id}() in "
-                    "the pipelined step path"
-                )
-            if isinstance(f, ast.Attribute):
-                assert f.attr not in FORBIDDEN_ATTRS, (
-                    f"{where}:{node.lineno}: host sync .{f.attr}() "
-                    "in the pipelined step path"
-                )
-
-    P = step_mod.PipelinedTrainStep
-    for fn, where in (
-        # The build path (__init__ holds the jitted micro/update
-        # bodies) and everything the per-step drive touches. lower()
-        # is excluded: it prices cost-analysis DICTS host-side and
-        # never runs in the hot loop.
-        (P.__init__, "PipelinedTrainStep.__init__"),
-        (P.__call__, "PipelinedTrainStep.__call__"),
-        (P._plan_input, "PipelinedTrainStep._plan_input"),
-        (P._default_stage, "PipelinedTrainStep._default_stage"),
-        (P.stage_batch, "PipelinedTrainStep.stage_batch"),
-        (step_mod.make_pipelined_train_step,
-         "make_pipelined_train_step"),
-        (ElasticTrainer._build_pipelined_step,
-         "_build_pipelined_step"),
-        (ElasticTrainer.stage_microbatch, "stage_microbatch"),
-        (_PipelinedAdapter, "_PipelinedAdapter"),
-    ):
-        audit(textwrap.dedent(inspect.getsource(fn)), where)
-
-
-def test_make_pipelined_train_step_metrics_contract():
-    """The standalone builder keeps make_train_step's metrics
-    contract ({"loss","grad_norm"}) and matches it numerically on a
-    flat accum=1 batch — without donating the caller's batch."""
-    from dlrover_tpu.trainer.step import (
-        make_pipelined_train_step,
-        make_train_step,
-        shard_batch,
-    )
-
-    x, y = _toy_data(32)
-    params = {"w": jnp.zeros((8, 1)), "b": jnp.zeros((1,))}
-    opt = optax.sgd(0.1)
-    mesh = _mesh(4)
-    tok, tgt = shard_batch(mesh, jnp.asarray(x), jnp.asarray(y))
-
-    base = make_train_step(mesh, _linear_loss, opt, donate=False)
-    piped = make_pipelined_train_step(
-        mesh, _linear_loss, opt, accum_steps=1, pipeline_depth=1,
-        donate=False,
-    )
-    p_b, _, m_b = base(params, opt.init(params), tok, tgt)
-    p_p, _, m_p = piped(params, opt.init(params), tok, tgt)
-    assert set(m_p) == {"loss", "grad_norm"}
-    assert not tok.is_deleted()  # caller's flat batch NOT donated
-    np.testing.assert_allclose(
-        float(m_b["loss"]), float(m_p["loss"]), rtol=1e-6
-    )
-    np.testing.assert_allclose(
-        float(m_b["grad_norm"]), float(m_p["grad_norm"]), rtol=1e-6
-    )
-    for a, b in zip(jax.tree.leaves(p_b), jax.tree.leaves(p_p)):
-        np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=1e-6, atol=1e-7
-        )
-
-
-def test_pipelined_flat_batch_of_one_not_misread_as_staged():
-    """Review regression: a FLAT device batch whose leading dim is 1
-    (global microbatch 1, the make_train_step convention api.py's
-    dry-runs use) must not be misread as the [1, micro, ...] staged
-    form — staged_device_inputs=False pins the flat reading and the
-    caller's buffers stay undonated."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from dlrover_tpu.trainer.step import make_pipelined_train_step
-
-    def loss1(params, x, y):  # works at batch size 1
-        pred = x @ params["w"] + params["b"]
-        return jnp.mean((pred - y) ** 2)
-
-    mesh = _mesh(2)
-    params = {"w": jnp.ones((8, 1)), "b": jnp.zeros((1,))}
-    opt = optax.sgd(0.1)
-    step = make_pipelined_train_step(
-        mesh, loss1, opt, accum_steps=1, pipeline_depth=1,
-        donate=False, staged_device_inputs=False,
-    )
-    rep = NamedSharding(mesh, P())
-    x = jax.device_put(jnp.ones((1, 8)), rep)  # flat [1, features]
-    y = jax.device_put(jnp.ones((1, 1)), rep)
-    p, _, m = step(params, opt.init(params), x, y)
-    assert np.isfinite(float(m["loss"]))
-    assert not x.is_deleted()  # flat passthrough never donates
-    # second call reuses the same buffers — the dry-run loop shape
-    step(p, opt.init(p), x, y)
-
-    # lower() must read the batch the same way the step does: flat
-    # pin -> priced with the full [1, 8] shape, no rank stripping
-    lowered = step.lower(params, opt.init(params), x, y)
-    assert lowered.cost_analysis()["flops"] > 0
-
-    # and the staged pin rejects a wrong-shaped batch loudly
-    staged = make_pipelined_train_step(
-        mesh, loss1, opt, accum_steps=2, pipeline_depth=1,
-        donate=False, staged_device_inputs=True,
-    )
-    with pytest.raises(ValueError, match="accum=2"):
-        staged(params, opt.init(params), x, y)
-
-
-def test_pipelined_donates_staged_microbatch_slots():
-    """Donation-clean: the input slots the pipeline stages are
-    consumed (deleted) as their microbatch executes — steady-state
-    HBM beyond the in-flight slots is zero."""
-    from dlrover_tpu.trainer.step import make_pipelined_train_step
-
-    x, y = _toy_data(32)
-    params = {"w": jnp.zeros((8, 1)), "b": jnp.zeros((1,))}
-    opt = optax.sgd(0.1)
-    mesh = _mesh(4)
-    staged = []
-
-    def spy_stage(tokens, targets, k):
-        import jax as _jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        sharding = NamedSharding(mesh, P("data"))
-        mb = tokens.shape[0] // 2
-        pair = (
-            _jax.device_put(tokens[k * mb:(k + 1) * mb], sharding),
-            _jax.device_put(targets[k * mb:(k + 1) * mb], sharding),
-        )
-        staged.append(pair)
-        return pair
-
-    step = make_pipelined_train_step(
-        mesh, _linear_loss, opt, accum_steps=2, pipeline_depth=1,
-        stage_fn=spy_stage,
-    )
-    step(params, opt.init(params), x, y)
-    assert len(staged) == 2
-    for tok_k, tgt_k in staged:
-        assert tok_k.is_deleted() and tgt_k.is_deleted()
 
 
 def test_dataloader_batches():

@@ -259,44 +259,29 @@ class TestLedgerPinDiff:
         )
         assert "BENCH_REMAT" not in report  # unchanged pins silent
 
-    def test_compare_prints_device_prefetch_and_pipeline_pins(
-        self, tmp_path
-    ):
-        """The device-resident input-pipeline knobs ride the same
-        pin-diff surface: a tuned run pinning BENCH_DEVICE_PREFETCH /
-        BENCH_PIPELINE_DEPTH against a baseline without them must
-        name both in the compare output."""
+    def test_compare_names_pins_only_one_side_set(self, tmp_path):
+        """Against a record that pinned nothing, every pin of the
+        other side is named with ``<unset>`` on the empty side, in
+        both directions (a head that dropped its baseline's pins)."""
         bench_ledger = importlib.import_module("bench_ledger")
-        path = str(tmp_path / "ledger.jsonl")
-        base = {
-            "metric": "m", "value": 100.0, "unit": "u",
-            "config_hash": "aaa", "pins": {},
-        }
-        head = {
-            "metric": "m", "value": 110.0, "unit": "u",
-            "config_hash": "bbb",
-            "pins": {
-                "BENCH_DEVICE_PREFETCH": "1",
-                "BENCH_PIPELINE_DEPTH": "2",
-                "BENCH_ACCUM_STEPS": "2",
-            },
-        }
-        bench_ledger.append_record(base, path=path)
-        bench_ledger.append_record(head, path=path)
-        rc, report = bench_ledger.compare("last", path=path)
-        assert rc == 0
-        assert (
-            "pin BENCH_DEVICE_PREFETCH: head=1 baseline=<unset>"
-            in report
-        )
-        assert (
-            "pin BENCH_PIPELINE_DEPTH: head=2 baseline=<unset>"
-            in report
-        )
-        assert (
-            "pin BENCH_ACCUM_STEPS: head=2 baseline=<unset>"
-            in report
-        )
+        pinned = {"BENCH_XENT_CHUNKS": "8", "BENCH_BLOCKS": "512,512"}
+        for head_pins, base_pins, fmt in (
+            (pinned, {}, "pin {k}: head={v} baseline=<unset>"),
+            ({}, pinned, "pin {k}: head=<unset> baseline={v}"),
+        ):
+            path = str(tmp_path / f"ledger{len(base_pins)}.jsonl")
+            for h, pins in (("aaa", base_pins), ("bbb", head_pins)):
+                bench_ledger.append_record(
+                    {
+                        "metric": "m", "value": 100.0, "unit": "u",
+                        "config_hash": h, "pins": pins,
+                    },
+                    path=path,
+                )
+            rc, report = bench_ledger.compare("last", path=path)
+            assert rc == 0
+            for k, v in pinned.items():
+                assert fmt.format(k=k, v=v) in report
 
     def test_compare_same_config_no_pin_section(
         self, tmp_path
@@ -361,15 +346,11 @@ class TestBenchPinsEmission:
         assert not trials[0]["failed"]
 
 
-class TestBenchPipelinedSmoke:
-    def test_smoke_child_pipelined_device_prefetch_record(
-        self, tmp_path
-    ):
-        """The device-resident configuration end-to-end through
-        bench.py's child: prefetch + worker-side H2D + pipelined
-        accumulation. The record must carry the pipeline config, the
-        new pins, and a data_wait_s figure (the attributable input
-        wait)."""
+class TestBenchPrefetchSmoke:
+    def test_smoke_child_prefetch_record(self, tmp_path):
+        """bench.py's child fed by the Prefetcher (BENCH_PREFETCH=1:
+        fresh host batches, placed by the worker): the record carries
+        a data_wait_s figure and nothing of the retired options."""
         import subprocess
 
         repo = os.path.dirname(TOOLS)
@@ -380,9 +361,6 @@ class TestBenchPipelinedSmoke:
             "BENCH_STEPS": "2",
             "BENCH_NO_LEDGER": "1",
             "BENCH_PREFETCH": "1",
-            "BENCH_DEVICE_PREFETCH": "1",
-            "BENCH_PIPELINE_DEPTH": "1",
-            "BENCH_ACCUM_STEPS": "2",
             "DLROVER_TPU_TUNE_CACHE": "0",
         }
         p = subprocess.run(
@@ -398,12 +376,8 @@ class TestBenchPipelinedSmoke:
             if line.startswith("{")
         )
         assert rec["value"] > 0
-        assert rec["pins"]["BENCH_PIPELINE_DEPTH"] == "1"
-        assert rec["pins"]["BENCH_DEVICE_PREFETCH"] == "1"
-        assert rec["pipeline"] == {
-            "depth": 1, "accum_steps": 2, "device_prefetch": 1,
-        }
-        assert "data_wait_s" in rec
+        assert rec["data_wait_s"] >= 0
+        assert "pipeline" not in rec and rec["pins"] == {}
 
 
 class TestAGDTraceSelection:

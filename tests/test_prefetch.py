@@ -10,12 +10,9 @@ import pytest
 
 from dlrover_tpu.data.prefetch import (
     Prefetcher,
-    SyncPipeline,
-    device_prefetch_enabled,
     free_device_buffers,
     make_input_pipeline,
     prefetch_depth,
-    prefetch_enabled,
 )
 from dlrover_tpu.trainer.elastic_trainer import (
     ElasticDataLoader,
@@ -187,8 +184,6 @@ def test_auto_epoch_restarts_source_and_bumps_epoch():
 def test_auto_epoch_requires_sampler():
     with pytest.raises(ValueError, match="auto_epoch"):
         Prefetcher(CountingSource(3), auto_epoch=True)
-    with pytest.raises(ValueError, match="auto_epoch"):
-        SyncPipeline(CountingSource(3), auto_epoch=True)
 
 
 def test_zero_batch_epoch_fails_loudly_not_hangs():
@@ -200,9 +195,6 @@ def test_zero_batch_epoch_fails_loudly_not_hangs():
     with pytest.raises(RuntimeError, match="no batches"):
         next(pf)
     pf.close()
-    sync = SyncPipeline(loader, sampler=sampler, auto_epoch=True)
-    with pytest.raises(RuntimeError, match="no batches"):
-        next(sync)
 
 
 def test_resume_at_epoch_boundary_rolls_not_raises():
@@ -218,52 +210,54 @@ def test_resume_at_epoch_boundary_rolls_not_raises():
     assert pf.sampler_state_dict()["epoch"] == 1
     pf.close()
 
-    sampler2 = ElasticDistributedSampler(
-        20, num_shards=1, shard_rank=0, shuffle=False, seed=3
-    )
-    sampler2.load_state_dict({"epoch": 0, "consumed": 20, "seed": 3})
-    loader2 = ElasticDataLoader(
-        np.arange(20, dtype=np.int64), batch_size=5, sampler=sampler2
-    )
-    sync = SyncPipeline(loader2, sampler=sampler2, auto_epoch=True)
-    assert next(sync).shape == (5,)
-    assert sampler2.epoch == 1
 
+def test_make_input_pipeline_keeps_the_names_data_wait_reads():
+    """``make_input_pipeline(source, h2d_fn=..., name=...)`` is what
+    the benchmark's loop calls, and its ``data_wait_ms.train`` reads
+    these span, event and metric names letter for letter."""
+    from dlrover_tpu.obs import tracer as tracer_mod
+    from dlrover_tpu.obs.metrics import get_registry
 
-def test_make_input_pipeline_switches_on_env(monkeypatch):
-    monkeypatch.delenv("DLROVER_TPU_PREFETCH", raising=False)
-    pipe = make_input_pipeline(
-        CountingSource(3), stage_fn=lambda x: x + 1
+    reg = get_registry()
+    stage = reg.get("dlrover_prefetch_stage_seconds_total")
+    h2d_before = stage.value(phase="h2d")
+    waits_before = reg.get("dlrover_train_data_wait_seconds").count()
+    tracer = tracer_mod.configure_tracer()
+    try:
+        pipe = make_input_pipeline(
+            CountingSource(3), h2d_fn=lambda b: b * 10, name="train"
+        )
+        assert isinstance(pipe, Prefetcher)
+        assert list(pipe) == [0, 10, 20]
+        pipe.close()
+        events = tracer.events()
+    finally:
+        tracer_mod.disable_tracer()
+    names = [e["name"] for e in events]
+    for name in (
+        "trainer.prefetch_stage",
+        "trainer.prefetch_h2d",
+        "trainer.prefetch_wait",
+    ):
+        assert names.count(name) == 3, name
+    assert all(
+        e["pipeline"] == "train"
+        for e in events
+        if e["name"].startswith("trainer.prefetch_")
     )
-    assert isinstance(pipe, Prefetcher)
-    assert list(pipe) == [1, 2, 3]
-    pipe.close()
-
-    monkeypatch.setenv("DLROVER_TPU_PREFETCH", "0")
-    loader, sampler = _loader(n=20, batch=5)
-    sync = make_input_pipeline(
-        loader, stage_fn=lambda b: b * 2, sampler=sampler,
-        auto_epoch=True,
+    waits = [e for e in events if e["name"] == "trainer.prefetch_wait"]
+    assert all({"dur_s", "host_s", "h2d_s"} <= set(e) for e in waits)
+    assert stage.value(phase="h2d") > h2d_before
+    assert (
+        reg.get("dlrover_train_data_wait_seconds").count()
+        == waits_before + 3
     )
-    assert isinstance(sync, SyncPipeline)
-    np.testing.assert_array_equal(next(sync), np.arange(5) * 2)
-    # nothing in flight in sync mode: state tracks delivery exactly
-    assert sync.sampler_state_dict()["consumed"] == 5
-    batches = [next(sync) for _ in range(5)]  # rolls into epoch 1
-    assert sampler.epoch == 1 and len(batches) == 5
-    assert sync.wait_s_total >= 0.0 and sync.delivered == 6
-    sync.close()  # no-op, idempotent
-    sync.close()
 
 
 # -- knobs -----------------------------------------------------------------
 
 
 def test_env_knobs(monkeypatch):
-    monkeypatch.delenv("DLROVER_TPU_PREFETCH", raising=False)
-    assert prefetch_enabled()
-    monkeypatch.setenv("DLROVER_TPU_PREFETCH", "0")
-    assert not prefetch_enabled()
     monkeypatch.setenv("DLROVER_TPU_PREFETCH_DEPTH", "5")
     assert prefetch_depth() == 5
     monkeypatch.setenv("DLROVER_TPU_PREFETCH_DEPTH", "junk")
@@ -337,7 +331,6 @@ def test_worker_h2d_delivers_committed_sharded_device_arrays():
     with Prefetcher(
         source(),
         h2d_fn=lambda b: jax.device_put(b, sharding),
-        device_prefetch=True,
         depth=2,
     ) as pf:
         got = list(pf)
@@ -353,34 +346,40 @@ def test_worker_h2d_delivers_committed_sharded_device_arrays():
     assert pf.h2d_stage_s_total > 0.0
 
 
-def test_consumer_h2d_when_device_prefetch_off():
-    """DLROVER_TPU_DEVICE_PREFETCH=0 semantics: the worker stays
-    host-side, the consumer pays the H2D inline and the wait split
-    reports it as the h2d slice."""
+def test_h2d_runs_in_the_worker_only():
+    """``h2d_fn`` never executes on the consumer's thread: every
+    batch comes off the queue already placed, whether the consumer
+    finds it waiting or blocks for it."""
     import jax
 
     mesh = _mesh8()
     sharding = _batch_sharding(mesh)
     h2d_threads = []
+    gate = threading.Event()
 
     def h2d(b):
-        h2d_threads.append(threading.current_thread().name)
+        h2d_threads.append(threading.current_thread())
         return jax.device_put(b, sharding)
 
     def source():
-        for _ in range(3):
-            yield np.zeros((8, 2), dtype=np.float32)
+        for i in range(4):
+            if i == 2:
+                gate.wait(5.0)  # the consumer blocks for this one
+            yield np.full((8, 2), i, dtype=np.float32)
 
-    pf = Prefetcher(
-        source(), h2d_fn=h2d, device_prefetch=False, depth=2
-    )
-    arr = next(pf)
-    assert isinstance(arr, jax.Array) and arr.sharding == sharding
-    # the h2d ran on THIS thread, not the prefetch worker
-    assert h2d_threads[0] == threading.current_thread().name
-    host_w, h2d_w = pf.wait_breakdown()
-    assert h2d_w > 0.0
-    assert pf.h2d_wait_s_total == pytest.approx(h2d_w)
+    pf = Prefetcher(source(), h2d_fn=h2d, depth=2)
+    first = next(pf)
+    assert isinstance(first, jax.Array) and first.sharding == sharding
+    next(pf)
+    threading.Timer(0.05, gate.set).start()
+    got = [next(pf), next(pf)]  # queue empty: waits on the worker
+    assert [int(a[0, 0]) for a in got] == [2, 3]
+    assert len(h2d_threads) == 4
+    assert set(h2d_threads) == {pf._thread}
+    assert threading.current_thread() not in h2d_threads
+    # and what the worker spent there is what the counters hold
+    assert pf.h2d_stage_s_total > 0.0
+    assert pf.h2d_wait_s_total <= pf.wait_s_total
     pf.close()
 
 
@@ -465,8 +464,7 @@ def test_worker_h2d_failure_is_loud_not_a_hang():
         raise RuntimeError("device_put exploded")
 
     pf = Prefetcher(
-        CountingSource(5), h2d_fn=bad_h2d, device_prefetch=True,
-        depth=2,
+        CountingSource(5), h2d_fn=bad_h2d, depth=2,
     )
     with pytest.raises(RuntimeError, match="device_put exploded"):
         next(pf)
@@ -491,7 +489,7 @@ def test_zero_batch_epoch_guard_under_device_staging():
 
 
 def test_wait_split_attribution_proportional():
-    """With device prefetch, a consumer wait is split by the worker's
+    """A consumer wait is split by the worker's
     host vs h2d staging proportion for that batch."""
     gate = threading.Event()
 
@@ -504,9 +502,7 @@ def test_wait_split_attribution_proportional():
         time.sleep(0.03)
         return b
 
-    pf = Prefetcher(
-        slow_source(), h2d_fn=h2d, device_prefetch=True, depth=1
-    )
+    pf = Prefetcher(slow_source(), h2d_fn=h2d, depth=1)
     time.sleep(0.05)
     gate.set()
     next(pf)
@@ -516,126 +512,14 @@ def test_wait_split_attribution_proportional():
     pf.close()
 
 
-def test_sync_pipeline_reports_same_split_metrics_as_async():
-    """Satellite: SyncPipeline emits the SAME split host/h2d staging
-    events and counters as the async path so obs_report summaries
-    stay comparable across modes."""
-    from dlrover_tpu.obs import tracer as tracer_mod
-    from dlrover_tpu.obs.metrics import get_registry
-
-    counter = get_registry().get("dlrover_prefetch_stage_seconds_total")
-    host_before = counter.value(phase="host")
-    h2d_before = counter.value(phase="h2d")
-    tracer = tracer_mod.configure_tracer()
-    try:
-        sync = SyncPipeline(
-            CountingSource(2),
-            stage_fn=lambda x: x,
-            h2d_fn=lambda x: x * 10,
-        )
-        assert list(sync) == [0, 10]
-        sync.close()
-        names = [e["name"] for e in tracer.events()]
-        assert "trainer.prefetch_start" in names
-        assert names.count("trainer.prefetch_stage") == 2
-        assert names.count("trainer.prefetch_h2d") == 2
-        waits = [
-            e for e in tracer.events()
-            if e["name"] == "trainer.prefetch_wait"
-        ]
-        assert len(waits) == 2
-        assert all("host_s" in e and "h2d_s" in e for e in waits)
-        stop = [
-            e for e in tracer.events()
-            if e["name"] == "trainer.prefetch_stop"
-        ][-1]
-        assert stop["delivered"] == 2
-        assert "h2d_stage_s_total" in stop
-    finally:
-        tracer_mod.disable_tracer()
-    assert counter.value(phase="host") > host_before
-    assert counter.value(phase="h2d") >= h2d_before
-    host_w, h2d_w = sync.wait_breakdown()
-    assert host_w >= 0.0 and h2d_w >= 0.0
-
-
-def test_consumer_h2d_failure_keeps_batch_accounting_invariant():
-    """Review regression: an inline (device_prefetch=0) h2d_fn
-    failure must count the popped batch as dropped so
-    staged == delivered + dropped still holds at prefetch_stop."""
-    calls = []
-
-    def flaky_h2d(b):
-        calls.append(b)
-        if len(calls) == 2:
-            raise RuntimeError("transient device OOM")
-        return b
-
-    pf = Prefetcher(
-        CountingSource(3), h2d_fn=flaky_h2d, device_prefetch=False,
-        depth=2,
-    )
-    assert next(pf) == 0
-    with pytest.raises(RuntimeError, match="transient device OOM"):
-        next(pf)
-    pf.close()
-    assert pf.staged == pf.delivered + pf.dropped
-    assert pf.delivered == 1 and pf.dropped >= 1
-
-
-def test_sync_pipeline_close_idempotent_single_stop_event():
-    """Review regression: a defensive double close (context manager +
-    finally) must emit exactly ONE prefetch_stop event, like the
-    async pipeline's guarded close."""
-    from dlrover_tpu.obs import tracer as tracer_mod
-
-    tracer = tracer_mod.configure_tracer()
-    try:
-        with SyncPipeline(CountingSource(2)) as sync:
-            list(sync)
-            sync.close()
-        sync.close()
-        stops = [
-            e for e in tracer.events()
-            if e["name"] == "trainer.prefetch_stop"
-        ]
-        assert len(stops) == 1
-        assert stops[0]["delivered"] == 2
-    finally:
-        tracer_mod.disable_tracer()
-
-
-def test_device_prefetch_env_knob(monkeypatch):
-    monkeypatch.delenv("DLROVER_TPU_DEVICE_PREFETCH", raising=False)
-    assert device_prefetch_enabled()
-    assert not device_prefetch_enabled(default=False)
-    monkeypatch.setenv("DLROVER_TPU_DEVICE_PREFETCH", "0")
-    assert not device_prefetch_enabled()
-    assert not device_prefetch_enabled(default=True)
-    monkeypatch.setenv("DLROVER_TPU_DEVICE_PREFETCH", "1")
-    assert device_prefetch_enabled(default=False)
-
-    monkeypatch.setenv("DLROVER_TPU_DEVICE_PREFETCH", "0")
-    calls = []
-    pf = make_input_pipeline(
-        CountingSource(2), h2d_fn=lambda b: calls.append(b) or b
-    )
-    assert isinstance(pf, Prefetcher)
-    assert not pf.device_prefetch
-    assert list(pf) == [0, 1]
-    assert len(calls) == 2  # consumer-side h2d still applied
-    pf.close()
-
-
 # -- the point of it all: overlap ------------------------------------------
 
 
 def test_device_prefetch_hides_h2d_behind_compute():
     """The acceptance fallback for CPU-only containers: with H2D cost
-    H per batch and compute cost C >= H per step, worker-side device
-    staging (device_prefetch on) must hide H2D almost entirely, while
-    the consumer-side flavor pays ~N*H on the critical path — and the
-    split attribution shows exactly that difference."""
+    H per batch and compute cost C >= H per step, the worker's device
+    staging hides H2D almost entirely — the consumer's wait is far
+    below the N*H it would pay placing each batch itself."""
     h2d_s = 0.02
     compute_s = 0.03
     n_steps = 8
@@ -644,35 +528,22 @@ def test_device_prefetch_hides_h2d_behind_compute():
         time.sleep(h2d_s)
         return x
 
-    def run(device_prefetch):
-        pf = Prefetcher(
-            CountingSource(n_steps + 2),
-            h2d_fn=slow_h2d,
-            device_prefetch=device_prefetch,
-            depth=2,
-        )
-        next(pf)  # warmup: pays the initial pipeline fill
-        pf.wait_s_total = 0.0
-        pf.h2d_wait_s_total = 0.0
-        for _ in range(n_steps):
-            time.sleep(compute_s)  # "the XLA step"
-            next(pf)
-        wait, h2d_wait = pf.wait_s_total, pf.h2d_wait_s_total
-        pf.close()
-        return wait, h2d_wait
-
-    hidden_wait, _ = run(device_prefetch=True)
-    inline_wait, inline_h2d = run(device_prefetch=False)
+    pf = Prefetcher(
+        CountingSource(n_steps + 2), h2d_fn=slow_h2d, depth=2
+    )
+    next(pf)  # warmup: pays the initial pipeline fill
+    pf.wait_s_total = 0.0
+    for _ in range(n_steps):
+        time.sleep(compute_s)  # "the XLA step"
+        next(pf)
+    hidden_wait = pf.wait_s_total
+    pf.close()
     sequential = n_steps * h2d_s
-    # worker-side H2D: nearly all of it hides behind compute
+    assert pf.h2d_stage_s_total >= 0.9 * (n_steps + 1) * h2d_s
     assert hidden_wait < 0.5 * sequential, (
         f"device prefetch hid only "
         f"{sequential - hidden_wait:.3f}s of {sequential:.3f}s H2D"
     )
-    # consumer-side H2D: the cost is ON the critical path and the
-    # split attributes it to the h2d slice specifically
-    assert inline_h2d >= 0.9 * sequential
-    assert inline_wait >= inline_h2d
 
 
 def test_prefetch_overlaps_staging_with_compute():

@@ -50,62 +50,6 @@ class TokenDataset:
         return self.data[i, :-1], self.data[i, 1:]
 
 
-def test_trainer_pipelined_strategy_end_to_end(tmp_path, monkeypatch):
-    """Strategy.pipeline_depth + device_prefetch drive the whole
-    Trainer.train stack: device-resident prefetch queue, pipelined
-    accumulation (accum 2 here), split data_wait/h2d phase
-    accounting — and the run still trains and checkpoints."""
-    monkeypatch.setenv(
-        "DLROVER_TPU_METRICS_FILE", str(tmp_path / "metrics.json")
-    )
-    args = TrainingArguments(
-        max_steps=4,
-        global_batch_size=32,  # 4 shards x micro 4 -> accum 2
-        micro_batch_size=4,
-        checkpoint_dir=str(tmp_path / "ckpt"),
-        save_steps=4,
-        log_steps=2,
-        strategy=Strategy(
-            mesh_shape=(("data", 4),), dtype="float32",
-            micro_batch_size=4, pipeline_depth=1,
-            device_prefetch=True,
-        ),
-    )
-    t = Trainer(
-        functools.partial(gpt.init_params, cfg=CFG),
-        functools.partial(gpt.loss_fn, cfg=CFG),
-        gpt.param_logical_axes(CFG),
-        TokenDataset(),
-        args,
-    )
-    out = t.train()
-    assert out["final_step"] == 4
-    assert out["final_loss"] is not None
-
-    # the low-HBM flavor: host-delivered batches, per-microbatch
-    # staging inside the pipelined step
-    monkeypatch.setenv("DLROVER_TPU_DEVICE_PREFETCH", "0")
-    args2 = TrainingArguments(**{
-        **args.__dict__,
-        "checkpoint_dir": str(tmp_path / "ckpt2"),
-        "strategy": Strategy(
-            mesh_shape=(("data", 4),), dtype="float32",
-            micro_batch_size=4, pipeline_depth=1,
-            device_prefetch=False,
-        ),
-    })
-    t2 = Trainer(
-        functools.partial(gpt.init_params, cfg=CFG),
-        functools.partial(gpt.loss_fn, cfg=CFG),
-        gpt.param_logical_axes(CFG),
-        TokenDataset(),
-        args2,
-    )
-    out2 = t2.train()
-    assert out2["final_step"] == 4
-    assert out2["final_loss"] is not None
-
-
 def test_trainer_end_to_end(tmp_path, monkeypatch):
     monkeypatch.setenv(
         "DLROVER_TPU_METRICS_FILE", str(tmp_path / "metrics.json")
