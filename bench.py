@@ -24,7 +24,7 @@ Env knobs:
   BENCH_MAX_WAIT_S     total retry budget, default 1200 (20 min)
   BENCH_PROBE_TIMEOUT  per-probe timeout, default 120 s
   BENCH_RUN_TIMEOUT    measurement-child timeout, default 900 s
-  BENCH_REMAT / BENCH_SAVE_LOGITS / BENCH_BATCH_PER_CHIP / BENCH_STEPS
+  BENCH_REMAT / BENCH_BATCH_PER_CHIP / BENCH_STEPS
                        forwarded to the measurement child
 """
 
@@ -226,7 +226,6 @@ def measure() -> int:
             cfg, n_layer=2, n_head=2, n_embd=128, block_size=128,
             vocab_size=1024,
         )
-    save_logits = os.getenv("BENCH_SAVE_LOGITS", "0") == "1"
     xent_chunks = int(os.getenv("BENCH_XENT_CHUNKS", "8"))
 
     batch_per_chip = int(os.getenv("BENCH_BATCH_PER_CHIP", "18"))
@@ -236,8 +235,7 @@ def measure() -> int:
 
     optimizer = optax.adamw(3e-4, weight_decay=0.1)
     loss = functools.partial(
-        gpt.loss_fn_fused, cfg=cfg, save_logits=save_logits,
-        num_chunks=xent_chunks,
+        gpt.loss_fn_fused, cfg=cfg, num_chunks=xent_chunks,
     )
     init, _ = make_sharded_init(
         mesh,
@@ -282,7 +280,7 @@ def measure() -> int:
     _PIN_KNOBS = (
         "BENCH_REMAT", "BENCH_BLOCKS", "BENCH_FUSED_NORM",
         "BENCH_UNROLL", "BENCH_XENT_CHUNKS", "BENCH_BATCH_PER_CHIP",
-        "BENCH_SAVE_LOGITS", "BENCH_OVERLAP_REDUCE",
+        "BENCH_OVERLAP_REDUCE",
         "BENCH_REDUCE_BUCKET_MB", "BENCH_REDUCE_BITS",
     )
     effective_pins = {

@@ -357,13 +357,10 @@ def loss_fn_fused(
     cfg: GPTConfig,
     attn_fn: Optional[Callable] = None,
     num_chunks: int = 8,
-    save_logits: bool = False,
 ) -> jax.Array:
     """Same loss via the fused chunked cross-entropy
     (ops/cross_entropy.py): never materializes [B*T, V] log-softmax,
-    backward matmuls get bf16 cotangents. Use for big batch*seq.
-    ``save_logits`` trades [N,V] bf16 HBM for skipping the backward
-    logits recompute — right for GPT-2-size vocab heads with headroom."""
+    backward matmuls get bf16 cotangents. Use for big batch*seq."""
     from dlrover_tpu.ops.cross_entropy import fused_cross_entropy
 
     x = backbone(params, tokens, cfg, attn_fn)
@@ -371,7 +368,7 @@ def loss_fn_fused(
     with jax.named_scope("head"):
         return fused_cross_entropy(
             x.reshape(n, -1), params["wte"], targets.reshape(n),
-            num_chunks, save_logits,
+            num_chunks,
         )
 
 

@@ -553,7 +553,6 @@ def loss_fn_fused(
     cfg: LlamaConfig,
     attn_fn: Optional[Callable] = None,
     num_chunks: int = 8,
-    save_logits: bool = False,
 ) -> jax.Array:
     from dlrover_tpu.ops.cross_entropy import fused_cross_entropy
 
@@ -565,7 +564,6 @@ def loss_fn_fused(
             params["lm_head"],
             targets.reshape(n),
             num_chunks,
-            save_logits,
         )
     return loss + aux
 

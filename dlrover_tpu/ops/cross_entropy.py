@@ -8,21 +8,25 @@ float32 tensors (logits and log-softmax) — 6.6 GB at batch 16, seq
 1024, vocab 50k — and routes the backward matmuls through float32
 cotangents (quarter-rate on the MXU). This implementation:
 
-* never holds more than one [chunk, V] logits block (forward and
-  backward recompute per chunk inside ``lax.map``);
-* stores only the per-token logsumexp (f32 [N]) between fwd and bwd;
-* emits bf16 cotangents into the unembedding matmuls so the backward
-  runs at full MXU rate;
+* never holds more than one [chunk, V] logits block;
+* forms the gradients in the forward pass while a chunk's logits are
+  there, so the logits are computed once: three [rows, V] x E
+  products a step (logits, ``dx``, the table's gradient), which is
+  what the mathematics needs. The backward rule only scales. A call
+  nothing differentiates is the lean chunked loss, one product;
+* stores ``dx`` (the activations' dtype) and the table's float32
+  gradient between fwd and bwd, not ``x``, the table or any logits;
+* emits bf16 cotangents into the two gradient matmuls so they run at
+  full MXU rate;
 * under a mesh runs once per device on that device's own rows
   (``_on_own_rows``), so the logits never cross a link: the table is
-  gathered once a pass, its float32 gradient summed over the batch
+  gathered once a step, its float32 gradient summed over the batch
   axes once a step.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -31,31 +35,63 @@ from dlrover_tpu import obs
 from dlrover_tpu.ops.flash_attention import batch_axes, per_device
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def fused_cross_entropy(
-    x, wte, targets, num_chunks: int = 8, save_logits: bool = False
-):
+# XLA fuses the cotangent's elementwise chain (exp, one-hot, scale,
+# cast) into both gradient products and computes it again for every
+# tile of their outputs across the width: cheaper than one pass over
+# HBM for a narrow table, dearer for a wide one. From this width the
+# cotangent is formed once a chunk and both products read it. Same
+# values either way. v5e, value_and_grad of the head alone, ms a
+# call, fused / once: width 768 (18,432 rows x 50,304) 32.4 / 38.8;
+# 16,384 rows x 50,304 at width 1280 42.9 / 48.2, 1792 68.7 / 68.4,
+# 2048 78.4 / 74.4; width 4096 (8,192 rows x 32,000) 49.5 / 42.1
+# (chip runs, PR 29).
+_COTANGENT_ONCE_FROM = 2048
+
+
+def _chunks(x, targets, num_chunks):
+    n = x.shape[0]
+    return (
+        x.reshape(num_chunks, n // num_chunks, -1),
+        targets.reshape(num_chunks, -1),
+    )
+
+
+def _loss_terms(x_c, t_c, wte):
+    """A chunk's float32 logits, their logsumexp and the gold logit."""
+    logits = jnp.einsum(
+        "ce,ve->cv", x_c, wte, preferred_element_type=jnp.float32
+    )
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    gold = jnp.take_along_axis(logits, t_c[:, None], axis=-1)[:, 0]
+    return logits, lse, gold
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def fused_cross_entropy(x, wte, targets, num_chunks: int = 8):
     """Mean token cross-entropy of ``x @ wte^T`` against targets.
 
     x: [N, E] (activations, bf16 ok); wte: [V, E] tied embedding;
     targets: [N] int. N must be divisible by num_chunks (pad or pick a
     divisor; model code uses B*T which is a power of two).
 
-    ``save_logits=True`` stashes the forward logits in x.dtype (bf16:
-    2 bytes/entry, 1.6 GB at batch 16 x 1024 x 50k vocab) so the
-    backward skips the [N,V] recompute matmul — ~V*E MACs/token of
-    work MFU accounting never credits. Numerics caveat: with bf16
-    activations the saved logits are rounded to bf16 before the
-    backward ``exp``, so per-element softmax probabilities (and hence
-    dlogits) carry a few-percent relative error versus the f32
-    recompute path — zero-mean rounding noise on top of the bf16
-    cotangent cast both paths share. Use it when HBM has room and
-    bf16-grade gradients are acceptable (the GPT-2 bench regime);
-    leave it off at Llama-7B scale where the recompute is the right
-    trade, or when gradient bit-accuracy matters.
+    This body is the call nothing differentiates (evaluation, a
+    reference check): the loss alone, one product a chunk. Under
+    differentiation JAX takes ``_fwd`` in its place.
     """
-    loss, _ = _fwd(x, wte, targets, num_chunks, save_logits)
-    return loss
+
+    def rows_fn(x, targets, wte):
+        def chunk(args):
+            _, lse, gold = _loss_terms(*args, wte)
+            return lse - gold
+
+        return jax.lax.map(chunk, _chunks(x, targets, num_chunks)).reshape(-1)
+
+    nll = _on_own_rows(
+        rows_fn, (x, targets), (wte,), num_chunks, announce=True
+    )
+    # Every device holds the same number of rows: the mean over all
+    # rows of all devices.
+    return jnp.mean(nll)
 
 
 def _on_own_rows(rows_fn, by_row, whole, num_chunks, summed=(),
@@ -66,7 +102,7 @@ def _on_own_rows(rows_fn, by_row, whole, num_chunks, summed=(),
     every device a multiple of ``num_chunks``) the chunked loop runs
     once per device (ops.flash_attention ``per_device``): the
     ``by_row`` operands stay where the batch put them and ``whole``
-    (the table) enters whole, gathered once a pass. Left to XLA the
+    (the table) enters whole, gathered once a step. Left to XLA the
     loop is partitioned along the table's ``embed`` dimension, which
     ``fsdp`` splits: every chip forms partial logits for ALL rows of
     a chunk and the ``[chunk, V]`` float32 logits are all-reduced,
@@ -91,79 +127,48 @@ def _on_own_rows(rows_fn, by_row, whole, num_chunks, summed=(),
     )
 
 
-def _fwd(x, wte, targets, num_chunks, save_logits):
+def _fwd(x, wte, targets, num_chunks):
+    """The loss and, while each chunk's logits are there, its share of
+    ``dx`` and of the table's gradient for an upstream cotangent of 1:
+    the backward needs no logits, so it does not form them again."""
+    inv_rows = 1.0 / x.shape[0]  # the mean is over every device's rows
+
     def rows_fn(x, targets, wte):
-        n = x.shape[0]
-        xc = x.reshape(num_chunks, n // num_chunks, -1)
-        tc = targets.reshape(num_chunks, -1)
-
-        def chunk(args):
+        def chunk(dwte, args):
             x_c, t_c = args
-            logits = jnp.einsum(
-                "ce,ve->cv", x_c, wte, preferred_element_type=jnp.float32
-            )
-            lse = jax.nn.logsumexp(logits, axis=-1)
-            gold = jnp.take_along_axis(logits, t_c[:, None], axis=-1)[:, 0]
-            kept = logits if save_logits else logits[:, :0]
-            return lse, gold, kept.astype(x.dtype)
-
-        lse, gold, saved = jax.lax.map(chunk, (xc, tc))
-        saved = saved.reshape(n, saved.shape[-1])
-        return lse.reshape(n), gold.reshape(n), saved
-
-    lse, gold, saved = _on_own_rows(
-        rows_fn, (x, targets), (wte,), num_chunks, announce=True
-    )
-    # Every device holds the same number of rows: the mean over all
-    # rows of all devices.
-    loss = jnp.mean(lse - gold)
-    return loss, (x, wte, targets, lse, saved)
-
-
-def _bwd(num_chunks, save_logits, res, g):
-    x, wte, targets, lse, saved = res
-    scale = g / x.shape[0]  # the mean is over every device's rows
-
-    def rows_fn(x, targets, lse, saved, wte, scale):
-        n = x.shape[0]
-        c = n // num_chunks
-        xc = x.reshape(num_chunks, c, -1)
-        tc = targets.reshape(num_chunks, c)
-        lc = lse.reshape(num_chunks, c)
-        sc = saved.reshape(num_chunks, c, saved.shape[-1])
-
-        def chunk_grads(carry, args):
-            x_c, t_c, lse_c, saved_c = args
-            if save_logits:
-                logits = saved_c.astype(jnp.float32)
-            else:
-                logits = jnp.einsum(
-                    "ce,ve->cv", x_c, wte,
-                    preferred_element_type=jnp.float32,
-                )
-            p = jnp.exp(logits - lse_c[:, None])
+            logits, lse, gold = _loss_terms(x_c, t_c, wte)
+            p = jnp.exp(logits - lse[:, None])
             dlogits = p - jax.nn.one_hot(t_c, wte.shape[0], dtype=p.dtype)
-            dlogits = (dlogits * scale).astype(x.dtype)  # bf16 cotangent
-            dx_c = jnp.einsum("cv,ve->ce", dlogits, wte)
-            dwte = carry + jnp.einsum(
+            dlogits = (dlogits * inv_rows).astype(x.dtype)  # bf16 cotangent
+            if wte.shape[1] >= _COTANGENT_ONCE_FROM:
+                dlogits = jax.lax.optimization_barrier(dlogits)
+            dx_c = jnp.einsum("cv,ve->ce", dlogits, wte).astype(x.dtype)
+            dwte = dwte + jnp.einsum(
                 "cv,ce->ve", dlogits, x_c,
                 preferred_element_type=jnp.float32,
             )
-            return dwte, dx_c
+            return dwte, (lse - gold, dx_c)
 
-        dwte0 = jnp.zeros(wte.shape, jnp.float32)
-        dwte, dxc = jax.lax.scan(chunk_grads, dwte0, (xc, tc, lc, sc))
-        return dxc.reshape(x.shape), dwte
+        dwte, (nll, dx) = jax.lax.scan(
+            chunk, jnp.zeros(wte.shape, jnp.float32),
+            _chunks(x, targets, num_chunks),
+        )
+        return nll.reshape(-1), dx.reshape(x.shape), dwte
 
     # The table's gradient: float32 over the chunks, summed over the
-    # devices in float32 (``summed``), cast once.
-    dx, dwte = _on_own_rows(
-        rows_fn, (x, targets, lse, saved), (wte, scale), num_chunks,
-        summed=(False, True),
+    # devices in float32 (``summed``), cast once, in ``_bwd``.
+    nll, dx, dwte = _on_own_rows(
+        rows_fn, (x, targets), (wte,), num_chunks,
+        summed=(False, False, True), announce=True,
     )
-    return dx, dwte.astype(wte.dtype), None
+    obs.event("head.grads_in_forward", rows=x.shape[0], chunks=num_chunks)
+    # The empty array carries the table's dtype to ``_bwd``.
+    return jnp.mean(nll), (dx, dwte, jnp.zeros((0,), wte.dtype))
 
 
-fused_cross_entropy.defvjp(
-    lambda x, wte, t, nc, sl: _fwd(x, wte, t, nc, sl), _bwd
-)
+def _bwd(num_chunks, res, g):
+    dx, dwte, like_wte = res
+    return (g * dx).astype(dx.dtype), (g * dwte).astype(like_wte.dtype), None
+
+
+fused_cross_entropy.defvjp(_fwd, _bwd)

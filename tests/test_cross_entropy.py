@@ -39,63 +39,113 @@ def test_fused_xent_matches_naive(num_chunks):
     np.testing.assert_allclose(got, want, rtol=1e-5)
 
 
-@pytest.mark.parametrize("num_chunks", [1, 4])
-def test_fused_xent_save_logits_matches_naive(num_chunks):
-    key = jax.random.PRNGKey(0)
-    n, e, v = 64, 16, 96
-    x = jax.random.normal(key, (n, e), jnp.float32)
+@pytest.mark.parametrize("g", [1.0, 0.25, 3.0])
+def test_fused_xent_grads_match_naive(g):
+    """The gradients are formed in the forward rule for an upstream
+    cotangent of 1 and scaled by the real one in the backward."""
+    n, e, v = 32, 8, 64
+    x = jax.random.normal(jax.random.PRNGKey(0), (n, e), jnp.float32)
     wte = jax.random.normal(jax.random.PRNGKey(1), (v, e), jnp.float32)
     targets = jax.random.randint(jax.random.PRNGKey(2), (n,), 0, v)
-    got = fused_cross_entropy(x, wte, targets, num_chunks, True)
-    want = _naive(x, wte, targets)
-    np.testing.assert_allclose(got, want, rtol=1e-5)
     g1 = jax.grad(
-        lambda x, w: fused_cross_entropy(x, w, targets, num_chunks, True),
+        lambda x, w: g * fused_cross_entropy(x, w, targets, 4),
         argnums=(0, 1),
     )(x, wte)
-    g2 = jax.grad(_naive, argnums=(0, 1))(x, wte, targets)
+    g2 = jax.grad(
+        lambda x, w: g * _naive(x, w, targets), argnums=(0, 1)
+    )(x, wte)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(a, b, atol=2e-6, rtol=1e-4)
 
 
-def test_fused_xent_save_logits_bf16_grads_close():
-    """bf16 activations + save_logits: grads agree with the f32
-    recompute path to bf16-rounding tolerance (documented caveat)."""
+def test_fused_xent_bf16_grads_close_to_f32_naive():
+    """bf16 activations and table: the gradients (bf16 cotangent into
+    both products, float32 table-gradient accumulator cast once) agree
+    with the float32 naive head's to bf16-rounding tolerance."""
     n, e, v = 64, 32, 128
-    x = jax.random.normal(
-        jax.random.PRNGKey(0), (n, e), jnp.bfloat16
-    )
-    wte = jax.random.normal(
-        jax.random.PRNGKey(1), (v, e), jnp.bfloat16
-    )
+    x = jax.random.normal(jax.random.PRNGKey(0), (n, e), jnp.bfloat16)
+    wte = jax.random.normal(jax.random.PRNGKey(1), (v, e), jnp.bfloat16)
     targets = jax.random.randint(jax.random.PRNGKey(2), (n,), 0, v)
-    g_save = jax.grad(
-        lambda x, w: fused_cross_entropy(x, w, targets, 4, True),
-        argnums=(0, 1),
+    got = jax.grad(
+        lambda x, w: fused_cross_entropy(x, w, targets, 4), argnums=(0, 1)
     )(x, wte)
-    g_rec = jax.grad(
-        lambda x, w: fused_cross_entropy(x, w, targets, 4, False),
-        argnums=(0, 1),
-    )(x, wte)
-    for a, b in zip(g_save, g_rec):
+    want = jax.grad(_naive, argnums=(0, 1))(
+        x.astype(jnp.float32), wte.astype(jnp.float32), targets
+    )
+    for a, b, like in zip(got, want, (x, wte)):
+        assert a.dtype == like.dtype
         a32 = np.asarray(a, np.float32)
         b32 = np.asarray(b, np.float32)
         denom = np.maximum(np.abs(b32), 1e-4)
         assert np.median(np.abs(a32 - b32) / denom) < 0.05
 
 
-def test_fused_xent_grads_match_naive():
-    n, e, v = 32, 8, 64
-    x = jax.random.normal(jax.random.PRNGKey(0), (n, e), jnp.float32)
-    wte = jax.random.normal(jax.random.PRNGKey(1), (v, e), jnp.float32)
-    targets = jax.random.randint(jax.random.PRNGKey(2), (n,), 0, v)
-    g1 = jax.grad(
-        lambda x, w: fused_cross_entropy(x, w, targets, 4),
-        argnums=(0, 1),
-    )(x, wte)
-    g2 = jax.grad(_naive, argnums=(0, 1))(x, wte, targets)
-    for a, b in zip(g1, g2):
+def _count(jaxpr, primitive):
+    """Equations of ``primitive`` in ``jaxpr`` and every jaxpr nested
+    in it (a scan's body counts once, as the program holds it)."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        total += eqn.primitive.name == primitive
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            total += _count(sub, primitive)
+    return total
+
+
+@pytest.mark.parametrize(
+    "differentiate,products",
+    [(False, 1), (True, 3)],
+    ids=["forward-only", "value-and-grad"],
+)
+def test_products_over_the_vocabulary(differentiate, products):
+    """The forward-only call holds the logits product alone; under
+    differentiation the step holds three (logits, dx, the table's
+    gradient) and not a fourth that forms the logits again."""
+    x, wte, targets = _head_inputs(64, 16, 96, jnp.float32)
+    fn = lambda x, w: fused_cross_entropy(x, w, targets, 4)  # noqa: E731
+    if differentiate:
+        fn = jax.value_and_grad(fn, argnums=(0, 1))
+    jaxpr = jax.make_jaxpr(fn)(x, wte).jaxpr
+    assert _count(jaxpr, "dot_general") == products
+
+
+@pytest.mark.parametrize("width,once", [(64, False), (2048, True)])
+def test_cotangent_formed_once_for_a_wide_table(width, once):
+    """From a table 2048 wide the bf16 cotangent is held behind an
+    optimization barrier, so both gradient products read one array;
+    a narrow table leaves the fusion to XLA. The gradients are the
+    naive head's either way."""
+    x, wte, targets = _head_inputs(16, width, 96, jnp.float32)
+    grad = jax.grad(
+        lambda x, w: fused_cross_entropy(x, w, targets, 2), argnums=(0, 1)
+    )
+    jaxpr = jax.make_jaxpr(grad)(x, wte).jaxpr
+    assert _count(jaxpr, "optimization_barrier") == int(once)
+    want = jax.grad(_naive, argnums=(0, 1))(x, wte, targets)
+    for a, b in zip(jax.jit(grad)(x, wte), want):
         np.testing.assert_allclose(a, b, atol=2e-6, rtol=1e-4)
+
+
+def test_grads_in_forward_event():
+    """A trace under differentiation emits ``head.grads_in_forward``
+    once, with all rows and the chunk count; the forward-only call
+    emits none."""
+    x, wte, targets = _head_inputs(64, 16, 96, jnp.float32)
+    loss = lambda x, w: fused_cross_entropy(x, w, targets, 4)  # noqa: E731
+    tracer = obs.configure_tracer()
+    try:
+        def events():
+            return [
+                e for e in tracer.events()
+                if e["name"] == "head.grads_in_forward"
+            ]
+
+        jax.jit(loss).lower(x, wte)
+        assert events() == []
+        jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(x, wte)
+        (ev,) = events()
+        assert ev["rows"] == 64 and ev["chunks"] == 4
+    finally:
+        obs.disable_tracer()
 
 
 def test_gpt_fused_loss_matches_plain():

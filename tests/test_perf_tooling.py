@@ -26,19 +26,18 @@ capture_perf = importlib.import_module("capture_perf")
 
 class TestBuildSpec:
     def test_positional_and_flag_tokens(self):
-        cfg, attn_fn, batch, sl, xc = perf_sweep.build_spec(
-            "sattn,flash,20,1024,512,-,nofn,u4,xc2"
+        cfg, attn_fn, batch, xc = perf_sweep.build_spec(
+            "sattn,flash,20,1024,512,nofn,u4,xc2"
         )
         assert cfg.remat == "save_attn"
         assert cfg.scan_unroll == 4
         assert cfg.use_fused_norm is False
         assert batch == 20
-        assert sl is False
         assert xc == 2
 
     def test_flag_tokens_position_independent(self):
-        a = perf_sweep.build_spec("full,flash,18,1024,1024,-,nofn,u2")
-        b = perf_sweep.build_spec("full,flash,18,u2,1024,1024,-,nofn")
+        a = perf_sweep.build_spec("full,flash,18,1024,1024,nofn,u2")
+        b = perf_sweep.build_spec("full,flash,18,u2,1024,1024,nofn")
         assert a[0].scan_unroll == b[0].scan_unroll == 2
         assert a[2] == b[2] == 18
 
@@ -47,9 +46,9 @@ class TestBuildSpec:
         — the printed result line is labeled with the spec, so the
         measured program must match it."""
         monkeypatch.setenv("SWEEP_XENT_CHUNKS", "4")
-        assert perf_sweep.build_spec("full,flash,18,-,-,-,xc8")[4] == 8
+        assert perf_sweep.build_spec("full,flash,18,-,-,xc8")[3] == 8
         # absent token -> env fallback applies
-        assert perf_sweep.build_spec("full,flash,18")[4] == 4
+        assert perf_sweep.build_spec("full,flash,18")[3] == 4
 
     def test_remat_token_table(self):
         for tok, name in (
@@ -63,11 +62,11 @@ class TestBuildSpec:
 class TestParseAutotune:
     OUT = (
         "n_devices: 1\n"
-        "full,flash,18,1024,1024,-,nofn      step=  166.0ms "
+        "full,flash,18,1024,1024,nofn      step=  166.0ms "
         "tok/s=   111037 mfu=0.458 vs=0.924\n"
-        "sattn,flash,16,1024,1024,-,nofn,u4,xc4 step=  140.1ms "
+        "sattn,flash,16,1024,1024,nofn,u4,xc4 step=  140.1ms "
         "tok/s=   109900 mfu=0.470 vs=0.950\n"
-        "sattn,flash,20,1024,1024,-,nofn,u4,xc4 step=  172.0ms "
+        "sattn,flash,20,1024,1024,nofn,u4,xc4 step=  172.0ms "
         "tok/s=   119069 mfu=0.480 vs=0.960\n"
         "bogus,flash,18 FAILED: ValueError: nope\n"
     )
@@ -87,7 +86,7 @@ class TestParseAutotune:
 class TestWinnerEnv:
     def test_full_pin_set(self):
         env = capture_perf.winner_env(
-            "sattn,flash,20,1024,1024,-,nofn,u4,xc4", n_chips=1
+            "sattn,flash,20,1024,1024,nofn,u4,xc4", n_chips=1
         )
         assert env == {
             "BENCH_BLOCKS": "1024,1024,1024,1024",
@@ -102,16 +101,16 @@ class TestWinnerEnv:
         """Sweep batch is global across its mesh; bench.py's knob is
         per-chip. A 2-chip sweep at global 40 must pin 20/chip."""
         env = capture_perf.winner_env(
-            "sattn,flash,40,1024,1024,-,nofn", n_chips=2
+            "sattn,flash,40,1024,1024,nofn", n_chips=2
         )
         assert env["BENCH_BATCH_PER_CHIP"] == "20"
 
     def test_default_batch_not_pinned(self):
-        env = capture_perf.winner_env("full,flash,18,512,1024,-,nofn")
+        env = capture_perf.winner_env("full,flash,18,512,1024,nofn")
         assert "BENCH_BATCH_PER_CHIP" not in env
 
     def test_attn_token_maps_to_policy_name(self):
-        env = capture_perf.winner_env("attn,flash,18,512,1024,-,nofn")
+        env = capture_perf.winner_env("attn,flash,18,512,1024,nofn")
         assert env["BENCH_REMAT"] == "attention"
 
 

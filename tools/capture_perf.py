@@ -209,7 +209,7 @@ def run_bench(extra_env: dict, timeout_s: float) -> dict | None:
 def winner_env(spec: str, n_chips: int = 1) -> dict:
     """Map a perf_sweep spec (the autotune's fastest line) onto the
     BENCH_* pins bench.py reads. Field layout: perf_sweep.build_spec —
-    remat,flash,batch,bq,bk,sl[,bqb,bkb], 'nofn' strippable flag."""
+    remat,flash,batch,bq,bk[,bqb,bkb], 'nofn' strippable flag."""
     parts = spec.split(",")
     # Pin fused norms only when the winner spec forced them; an
     # unflagged spec ran the config default (off since r4), which is
@@ -240,8 +240,8 @@ def winner_env(spec: str, n_chips: int = 1) -> dict:
 
     bq = blk(3, 512)
     bk = blk(4, 1024)
-    bqb = blk(6, bq)
-    bkb = blk(7, bk)
+    bqb = blk(5, bq)
+    bkb = blk(6, bk)
     env = {"BENCH_BLOCKS": f"{bq},{bk},{bqb},{bkb}"}
     # Unit conversion: the sweep spec's batch is GLOBAL across its
     # mesh; bench.py's knob is per-chip (batch = knob * n_chips).

@@ -1,11 +1,10 @@
 """Perf sweep harness: times the GPT-2 train step across configs.
 
 Usage:
-  python tools/perf_sweep.py 'remat,flash,batch[,bq,bk[,sl[,bqb,bkb]]]'
+  python tools/perf_sweep.py 'remat,flash,batch[,bq,bk[,bqb,bkb]]'
   remat: full | attn | none | dots | offload
   flash: flash | xla | noop (noop stubs attention to measure the
          step's non-attention cost by subtraction)
-  sl: save-logits cross-entropy variant (pass "sl"; "-" to skip)
   bqb,bkb: backward-kernel block sizes (default = forward blocks)
   nofn / fn (anywhere): force the fused Pallas norms off / on
          (absent = config default, off since the r4 measurement)
@@ -51,11 +50,11 @@ def is_xent_token(p: str) -> bool:
 
 
 def build_spec(spec: str):
-    """Parse a sweep spec -> (cfg, attn_fn, batch, save_logits,
-    xent_chunks). Omitted fields
-    default to flash attention with the kernel's own autotuned block
-    sizes and batch 16; xent_chunks resolves here (xcN token, else
-    SWEEP_XENT_CHUNKS, else 8) so every caller sees one value."""
+    """Parse a sweep spec -> (cfg, attn_fn, batch, xent_chunks).
+    Omitted fields default to flash attention with the kernel's own
+    autotuned block sizes and batch 16; xent_chunks resolves here
+    (xcN token, else SWEEP_XENT_CHUNKS, else 8) so every caller sees
+    one value."""
     parts = spec.split(",")
     # "nofn"/"fn" are flag tokens, not positional: strip them before
     # the positional fields so they really work anywhere in the spec.
@@ -95,9 +94,8 @@ def build_spec(spec: str):
 
     block_q = _blk(3)
     block_k = _blk(4)
-    save_logits = len(parts) > 5 and parts[5] == "sl"
-    block_q_bwd = _blk(6)
-    block_k_bwd = _blk(7)
+    block_q_bwd = _blk(5)
+    block_k_bwd = _blk(6)
     remat = {
         "full": True, "attn": "attention", "none": False,
         "dots": "dots", "offload": "offload", "sattn": "save_attn",
@@ -123,20 +121,19 @@ def build_spec(spec: str):
             block_k=block_k, block_q_bwd=block_q_bwd,
             block_k_bwd=block_k_bwd,
         )
-    return cfg, attn_fn, batch, save_logits, xent_chunks
+    return cfg, attn_fn, batch, xent_chunks
 
 
 def run_config(mesh, spec: str) -> None:
-    cfg, attn_fn, batch, save_logits, spec_chunks = build_spec(spec)
+    cfg, attn_fn, batch, spec_chunks = build_spec(spec)
 
     optimizer = optax.adamw(3e-4, weight_decay=0.1)
-    # Fused-CE recompute granularity (bigger chunks = bigger bwd
-    # matmuls and fewer dwte accumulator round-trips, more logits HBM
-    # at once); fully resolved by build_spec.
+    # Fused-CE chunk count (bigger chunks = bigger gradient matmuls
+    # and fewer dwte accumulator round-trips, more logits HBM at
+    # once); fully resolved by build_spec.
     chunks = spec_chunks
     loss = functools.partial(
-        gpt.loss_fn_fused, cfg=cfg, attn_fn=attn_fn,
-        save_logits=save_logits, num_chunks=chunks,
+        gpt.loss_fn_fused, cfg=cfg, attn_fn=attn_fn, num_chunks=chunks,
     )
     init, _ = make_sharded_init(
         mesh,
