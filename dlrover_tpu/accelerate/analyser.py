@@ -106,11 +106,6 @@ def estimate_step_memory(
         act = act * 0.1  # boundaries live in host RAM, not HBM
     elif remat == "attention":
         act = act * 0.6  # attention internals recomputed
-    elif remat == "save_attn":
-        # full-remat residency plus the saved per-layer (o, lse):
-        # o is one T*E activation per layer, ~the same as the block
-        # boundary itself -> roughly double "full".
-        act = act * 0.4
     total = int(p_bytes + g_bytes + o_bytes + act)
     # 20% headroom for XLA temp buffers / fragmentation
     return total, total < hbm_bytes * 0.8

@@ -12,36 +12,57 @@ recomputing them — the third point of the reference's tradeoff.
 Policies (cfg.remat / Strategy.remat accept these names):
 
   "none"       keep every residual (fastest, most HBM)
-  "full"       recompute blocks; save only non-batch matmul outputs
+  "full"       recompute blocks; keep what the block names (KEPT):
+               the projections into attention, the flash forward's
+               (o, lse) and the MLP's hidden products
   "attention"  recompute only attention internals
   "dots"       recompute everything except matmul outputs
   "offload"    offload block-boundary residuals (checkpoint_name
                "block_out") to pinned host memory, save nothing else
-  "save_attn"  "full"'s saves PLUS the flash forward's (o, lse), so
-               the backward reuses them instead of re-running the
-               flash forward kernel (a dot-level policy can't see
-               inside the flash custom_vjp). Trades ~T*E bytes/layer
-               of HBM for the whole attention recompute (r5 profile:
-               the flash fwd is 8.8 ms of a 173 ms step at b18,
-               re-run a second time under "full"; the residual
-               traffic costs ~1 ms).
 
 Booleans keep working: True == "full", False == "none".
+
+What "full" keeps is chosen by name, not by primitive type, because a
+policy by type cannot see inside the flash ``custom_vjp`` (it kept
+none of the kernel's outputs, so the backward ran the forward kernel
+a second time) and cannot leave one product out. With names the
+block keeps the flash forward's ``o`` IN PLACE OF the out-projection's
+output ``att @ wo``: the same ``[B, T, E]`` bytes a layer, and the
+backward recomputes one ``E x E`` product a token instead of the
+whole attention forward. On XLA attention there is no ``flash_o``:
+attention is recomputed as before, and ``att @ wo`` with it.
 """
 
 from __future__ import annotations
 
+import threading
 from typing import Any, Callable, Optional
 
 import jax
+from jax.ad_checkpoint import checkpoint_name
+
+from dlrover_tpu import obs
 
 # residual name tagged at each transformer block boundary (models
 # call jax.ad_checkpoint.checkpoint_name on the block output)
 BLOCK_OUT = "block_out"
 
-POLICY_NAMES = (
-    "none", "full", "attention", "dots", "offload", "save_attn"
-)
+# What "full" keeps of a block, by the names the block gives them
+# (:func:`keep`): the projections into attention (GPT's ``qkv``;
+# Llama's ``q``, ``k``, ``v`` before the head repeat), the flash
+# forward's output and its row logsumexp (ops/flash_attention._kept
+# chooses their layouts), the MLP's hidden products (GPT's
+# ``wi`` product; Llama's ``gate`` and ``up``) and an expert layer's
+# router logits. NOT the out-projection's output: it is recomputed
+# from the kept ``flash_o``.
+ATTN_IN = "attn_in"
+FLASH_O = "flash_o"
+FLASH_LSE = "flash_lse"
+MLP_HIDDEN = "mlp_hidden"
+ROUTER_LOGITS = "router_logits"
+KEPT = (ATTN_IN, FLASH_O, FLASH_LSE, MLP_HIDDEN, ROUTER_LOGITS)
+
+POLICY_NAMES = ("none", "full", "attention", "dots", "offload")
 
 
 def canonical(policy: Any) -> str:
@@ -57,42 +78,43 @@ def canonical(policy: Any) -> str:
     )
 
 
-# The ONE definition of what "full" saves — save_attn is documented
-# as "full's saves plus the flash outputs", so both must build on the
-# same base or they silently diverge.
+# The names tagged while a block under "full" is being traced, for
+# the ``remat.kept`` event; None outside such a trace.
+_tracing = threading.local()
+
+
+def keep(x: jax.Array, name: str) -> jax.Array:
+    """Name ``x`` as one of the residuals "full" keeps (``name`` is
+    one of :data:`KEPT`). A no-op under every other policy."""
+    seen = getattr(_tracing, "names", None)
+    if seen is not None:
+        seen.add(name)
+    return checkpoint_name(x, name)
+
+
 def full_policy():
-    return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+    return jax.checkpoint_policies.save_only_these_names(*KEPT)
 
 
-def save_attn_policy():
-    """"full" remat's saves PLUS the flash forward kernel's outputs.
+def _announced(block_fn: Callable) -> Callable:
+    """``block_fn``, saying once a trace which of :data:`KEPT` the
+    block named: event ``remat.kept`` with ``names`` and
+    ``flash_residuals`` (the block held a flash forward, whose
+    ``(o, lse)`` the backward then takes as they are)."""
 
-    "full" here is ``dots_with_no_batch_dims_saveable`` — it already
-    saves the projection/MLP dot outputs (the scan-stacked residuals
-    in the r5 step trace); what it cannot save is the attention
-    output, because that lives INSIDE the flash custom_vjp whose
-    residuals a dot-level policy never sees. The union adds exactly
-    the pallas_call named "flash_attention_fwd": its saved (o, lse)
-    feed the flash backward kernel as residuals directly, and
-    jax.checkpoint's partial eval dead-code-eliminates the forward
-    kernel from the recompute — verified by counting pallas_call eqns
-    in the grad jaxpr (tests/test_remat_policies.py): full remat
-    traces the fwd kernel twice, this policy once, with everything
-    else saved/recomputed exactly as under "full". (Saving ONLY the
-    flash outputs — without full's dot saves — would force the
-    projection matmuls to recompute in the backward and lose more
-    than the skipped flash re-run gains.) With XLA (non-flash)
-    attention there is no matching eqn and this degrades gracefully
-    to "full"."""
+    def block(*args):
+        _tracing.names = set()
+        try:
+            out = block_fn(*args)
+        finally:
+            seen, _tracing.names = _tracing.names, None
+        obs.event(
+            "remat.kept", names=sorted(seen),
+            flash_residuals=FLASH_O in seen,
+        )
+        return out
 
-    def flash_fwd_saveable(prim, *_, **params):
-        if prim.name != "pallas_call":
-            return False
-        return params.get("name") == "flash_attention_fwd"
-
-    return jax.checkpoint_policies.save_from_both_policies(
-        full_policy(), flash_fwd_saveable
-    )
+    return block
 
 
 def offload_policy():
@@ -125,7 +147,7 @@ def apply_block_remat(
         return block_fn, jax.checkpoint(attn_fn)
     if name == "full":
         return (
-            jax.checkpoint(block_fn, policy=full_policy()),
+            jax.checkpoint(_announced(block_fn), policy=full_policy()),
             attn_fn,
         )
     if name == "dots":
@@ -139,11 +161,6 @@ def apply_block_remat(
     if name == "offload":
         return (
             jax.checkpoint(block_fn, policy=offload_policy()),
-            attn_fn,
-        )
-    if name == "save_attn":
-        return (
-            jax.checkpoint(block_fn, policy=save_attn_policy()),
             attn_fn,
         )
     raise AssertionError(name)
@@ -180,6 +197,4 @@ def wire_block(inner_block: Callable, policy: Any,
 def tag_block_output(x: jax.Array) -> jax.Array:
     """Tag a block's output residual so the offload policy can name
     it. A no-op under every other policy."""
-    from jax.ad_checkpoint import checkpoint_name
-
     return checkpoint_name(x, BLOCK_OUT)

@@ -101,9 +101,7 @@ def candidate_strategies(
     micro_batch_sizes: Tuple[int, ...] = (4, 8, 16),
     dtypes: Tuple[str, ...] = ("bfloat16",),
     optimizers: Tuple[str, ...] = ("adamw",),
-    remats: Tuple[object, ...] = (
-        False, "attention", "save_attn", True
-    ),
+    remats: Tuple[object, ...] = (False, "attention", True),
     max_tensor: int = 8,
     max_pipe: int = 8,
     seq_impls: Tuple[str, ...] = ("auto",),

@@ -219,6 +219,12 @@ def _block(x, lp, cfg: GPTConfig, attn_fn):
     module profiler (utils/module_profiler.py) attributes FLOPs /
     bytes per scope from the jaxpr, feeding the strategy engine's
     roofline prior and the TP planner's per-edge costs."""
+    # What remat="full" keeps is named here (accelerate/remat.py
+    # KEPT): the projection into attention and the MLP's hidden
+    # product. ``att @ wo`` is not: it is recomputed from the flash
+    # forward's kept output.
+    from dlrover_tpu.accelerate.remat import ATTN_IN, MLP_HIDDEN, keep
+
     B, T, E = x.shape
     H, D = cfg.n_head, cfg.head_dim
     fused = use_fused_norm(cfg)
@@ -232,7 +238,7 @@ def _block(x, lp, cfg: GPTConfig, attn_fn):
             h = fused_layer_norm(x, lp["ln1_g"], lp["ln1_b"])
         else:
             h = _layer_norm(x, lp["ln1_g"], lp["ln1_b"])
-        qkv = h @ lp["wqkv"]  # [B,T,3E]
+        qkv = keep(h @ lp["wqkv"], ATTN_IN)  # [B,T,3E]
         q, k, v = jnp.split(qkv, 3, axis=-1)
         q = q.reshape(B, T, H, D)
         k = k.reshape(B, T, H, D)
@@ -249,7 +255,7 @@ def _block(x, lp, cfg: GPTConfig, attn_fn):
         else:
             x = x + att_out
             h = _layer_norm(x, lp["ln2_g"], lp["ln2_b"])
-        h = jax.nn.gelu(h @ lp["wi"] + lp["bi"])
+        h = jax.nn.gelu(keep(h @ lp["wi"], MLP_HIDDEN) + lp["bi"])
         x = x + h @ lp["wo2"] + lp["bo2"]
     return x
 

@@ -396,6 +396,7 @@ def _block(x, lp, cfg: LlamaConfig, attn_fn, cos, sin):
     this layer's share of the router losses for MoE blocks."""
     B, T, E = x.shape
     H, Hkv, D = cfg.n_head, cfg.n_kv_head, cfg.head_dim
+    from dlrover_tpu.accelerate.remat import ATTN_IN, keep
     from dlrover_tpu.models.gpt import use_fused_norm
 
     fused = use_fused_norm(cfg)
@@ -411,7 +412,12 @@ def _block(x, lp, cfg: LlamaConfig, attn_fn, cos, sin):
             h = fused_rms_norm(x, lp["rms1"], eps=cfg.rms_eps)
         else:
             h = _rms_norm(x, lp["rms1"], cfg.rms_eps)
-        q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]
+        # Named for remat="full" (accelerate/remat.py KEPT), before
+        # the head repeat; ``att @ wo`` below is recomputed from the
+        # flash forward's kept output.
+        q, k, v = (
+            keep(h @ lp[w], ATTN_IN) for w in ("wq", "wk", "wv")
+        )
         if cfg.qkv_bias:
             q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
         if cfg.qk_norm:
@@ -456,7 +462,11 @@ def mlp_tail(x, h, lp, cfg: LlamaConfig):
 
         y, aux = moe_mlp(lp["moe"], h, cfg._moe_cfg())
         return x + y.astype(x.dtype), aux / cfg.n_layer
-    gated = jax.nn.silu(h @ lp["w_gate"]) * (h @ lp["w_up"])
+    from dlrover_tpu.accelerate.remat import MLP_HIDDEN, keep
+
+    gate = keep(h @ lp["w_gate"], MLP_HIDDEN)
+    up = keep(h @ lp["w_up"], MLP_HIDDEN)
+    gated = jax.nn.silu(gate) * up
     return x + gated @ lp["w_down"], jnp.zeros((), jnp.float32)
 
 

@@ -406,10 +406,17 @@ def moe_mlp(
     Drop-in for the dense MLP of a transformer block: add aux_loss
     (already weighted) to the training loss.
     """
+    from dlrover_tpu.accelerate.remat import ROUTER_LOGITS, keep
+
     B, T, D = x.shape
     flat = x.reshape(B * T, D)
     with jax.named_scope("moe_route"):
-        logits = router_logits(flat, params["router"])  # [n, E]
+        # Kept under remat="full", as the policy by primitive type
+        # kept them: [n, E] float32 is small, and the top-k choice is
+        # then the forward's own. The grouped products are not kept.
+        logits = keep(
+            router_logits(flat, params["router"]), ROUTER_LOGITS
+        )  # [n, E]
     mesh = jax.sharding.get_abstract_mesh()
     if not mesh.empty and mesh.shape.get("expert", 1) > 1:
         y, metrics = _onehot_moe(params, flat, logits, cfg)

@@ -399,9 +399,8 @@ def _fwd(q, k, v, causal, window, scale, block_q, block_k, seq_len,
             )
         ),
         interpret=interpret,
-        # Stable identity for jax.checkpoint policies: the save_attn
-        # remat policy (accelerate/remat.py) matches this name to
-        # save exactly (o, lse) and nothing else Pallas produces.
+        # What the device trace calls the kernel, whatever transform
+        # encloses the call (remat, shard_map, a scope).
         name="flash_attention_fwd",
     )(q, k, v)
 
@@ -601,14 +600,54 @@ def _bwd(
 # ---------------------------------------------------------------------------
 
 
+# The chip pads the minor dimension of a buffer in HBM to this many
+# lanes.
+_LANES = 128
+
+
+def _kept(o, lse):
+    """The flash forward's outputs as ``remat="full"`` keeps them
+    (accelerate/remat.py names them), and the kernel's layout of ``o``
+    back from that: (o [B,H,T,D], kept o, kept lse).
+
+    ``lse`` is kept as ``[B, H, T]``, rows along the lanes: the
+    kernel's ``[B, H, T, 1]`` column takes 128 times its bytes in HBM
+    (113 MB a layer at GPT-2's shape against 0.9). ``o`` is kept as
+    the kernel wrote it where the head size fills the lanes; at a
+    smaller head size ``[B, H, T, D]`` is padded too (twice the bytes
+    at 64: 0.34 GB of GPT-2's step, and slower than the transposition
+    it saves), so there it is kept in the model's layout
+    ``[B, T, H*D]``, which is not."""
+    from dlrover_tpu.accelerate.remat import FLASH_LSE, FLASH_O, keep
+
+    kept_lse = keep(lse[..., 0], FLASH_LSE)
+    b, h, t, d = o.shape
+    if d % _LANES == 0:
+        kept_o = keep(o, FLASH_O)
+        return kept_o, kept_o, kept_lse
+    kept_o = keep(o.transpose(0, 2, 1, 3).reshape(b, t, h * d), FLASH_O)
+    return _kernel_layout(kept_o, h), kept_o, kept_lse
+
+
+def _kernel_layout(kept_o, h):
+    """``[B, H, T, D]`` from either layout :func:`_kept` keeps."""
+    if kept_o.ndim == 4:
+        return kept_o
+    b, t, e = kept_o.shape
+    return kept_o.reshape(b, t, h, e // h).transpose(0, 2, 1, 3)
+
+
 @functools.partial(
     jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11, 12)
 )
 def _flash(q, k, v, causal, window, scale, block_q, block_k,
            block_q_bwd, block_k_bwd, seq_len, interpret, q_offset=0):
-    o, _ = _fwd(q, k, v, causal, window, scale, block_q, block_k,
-                seq_len, interpret, q_offset)
-    return o
+    o, lse = _fwd(q, k, v, causal, window, scale, block_q, block_k,
+                  seq_len, interpret, q_offset)
+    # Named here too: the forward rule is traced only later, under
+    # differentiation, and the ``remat.kept`` event reads the names
+    # while the block is traced.
+    return _kept(o, lse)[0]
 
 
 def _flash_fwd(q, k, v, causal, window, scale, block_q, block_k,
@@ -618,15 +657,20 @@ def _flash_fwd(q, k, v, causal, window, scale, block_q, block_k,
         q, k, v, causal, window, scale, block_q, block_k, seq_len,
         interpret, q_offset
     )
-    return o, (q, k, v, o, lse)
+    # The primal output and the residuals are the kept values, so a
+    # block under remat="full" hands them to the backward as they are
+    # and does not run the forward kernel again.
+    o, kept_o, kept_lse = _kept(o, lse)
+    return o, (q, k, v, kept_o, kept_lse)
 
 
 def _flash_bwd(causal, window, scale, block_q, block_k, block_q_bwd,
                block_k_bwd, seq_len, interpret, q_offset, res, g):
-    q, k, v, o, lse = res
+    q, k, v, kept_o, kept_lse = res
     return _bwd(
-        q, k, v, o, lse, g, causal, window, scale, block_q_bwd,
-        block_k_bwd, seq_len, interpret, q_offset=q_offset,
+        q, k, v, _kernel_layout(kept_o, q.shape[1]), kept_lse[..., None],
+        g, causal, window, scale, block_q_bwd, block_k_bwd, seq_len,
+        interpret, q_offset=q_offset,
     )
 
 

@@ -231,18 +231,15 @@ def summarize(costs: Dict[str, ModuleCost]) -> str:
 
 # FLOPs multiplier of rematerialization policies (recompute cost on
 # top of the fwd+bwd 3x base: full block remat re-runs the forward,
-# +1/3; attention/dots recompute a slice of it).
+# +1/3 (less the flash forward kernel where the block has one: its
+# kept (o, lse) feed the backward; about a quarter of a block's
+# forward at GPT-2's shapes); attention/dots recompute a slice of it).
 _REMAT_FLOPS_FACTOR = {
     "none": 1.0,
     "full": 4.0 / 3.0,
     "attention": 1.08,
     "dots": 1.12,
     "offload": 1.0,
-    # full recompute minus the flash forward (the saved (o, lse)
-    # skip it): the attention share of a block fwd is ~25% at GPT-2
-    # shapes (r5 profile: 8.8 of 34.9 ms), so ~1/4 of the recompute
-    # third comes back off full's 4/3.
-    "save_attn": 1.25,
 }
 
 _DTYPE_BYTES_FACTOR = {"bfloat16": 1.0, "float32": 2.0, "half": 1.0}

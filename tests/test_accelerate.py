@@ -573,10 +573,12 @@ class TestOverlapStrategy:
             )
 
 
-# What the parent commit's grid held, by the three things a candidate
-# is chosen for: (n_devices, candidates, sha256 of the sorted
-# (mesh_shape, remat, micro_batch_size) reprs, first 16 hex digits).
-_PARENT_GRID = {4: (180, "e237cb5151a06a51"), 8: (420, "f6ecd2f09c2d98e5")}
+# What the grid holds, by the three things a candidate is chosen for:
+# (n_devices, candidates, sha256 of the sorted (mesh_shape, remat,
+# micro_batch_size) reprs, first 16 hex digits). Three of the four
+# remat values PR 28's grid had (180 and 420 candidates): the fourth
+# was a name for what remat=True means since PR 33.
+_PARENT_GRID = {4: (135, "2c01bda2b9c55eac"), 8: (315, "935cd865bebf57b5")}
 _RETIRED = ("pipeline_depth", "device_prefetch", "-pd:", "-devpf:")
 
 

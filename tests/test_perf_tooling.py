@@ -27,9 +27,9 @@ capture_perf = importlib.import_module("capture_perf")
 class TestBuildSpec:
     def test_positional_and_flag_tokens(self):
         cfg, attn_fn, batch, xc = perf_sweep.build_spec(
-            "sattn,flash,20,1024,512,nofn,u4,xc2"
+            "dots,flash,20,1024,512,nofn,u4,xc2"
         )
-        assert cfg.remat == "save_attn"
+        assert cfg.remat == "dots"
         assert cfg.scan_unroll == 4
         assert cfg.use_fused_norm is False
         assert batch == 20
@@ -53,8 +53,7 @@ class TestBuildSpec:
     def test_remat_token_table(self):
         for tok, name in (
             ("full", True), ("none", False), ("attn", "attention"),
-            ("sattn", "save_attn"), ("dots", "dots"),
-            ("offload", "offload"),
+            ("dots", "dots"), ("offload", "offload"),
         ):
             assert perf_sweep.build_spec(f"{tok},flash,18")[0].remat == name
 
@@ -64,9 +63,9 @@ class TestParseAutotune:
         "n_devices: 1\n"
         "full,flash,18,1024,1024,nofn      step=  166.0ms "
         "tok/s=   111037 mfu=0.458 vs=0.924\n"
-        "sattn,flash,16,1024,1024,nofn,u4,xc4 step=  140.1ms "
+        "dots,flash,16,1024,1024,nofn,u4,xc4 step=  140.1ms "
         "tok/s=   109900 mfu=0.470 vs=0.950\n"
-        "sattn,flash,20,1024,1024,nofn,u4,xc4 step=  172.0ms "
+        "dots,flash,20,1024,1024,nofn,u4,xc4 step=  172.0ms "
         "tok/s=   119069 mfu=0.480 vs=0.960\n"
         "bogus,flash,18 FAILED: ValueError: nope\n"
     )
@@ -75,7 +74,7 @@ class TestParseAutotune:
         spec, tok_s = capture_perf.parse_autotune(self.OUT)
         # b16 has the best step time; b20 has the best throughput —
         # throughput is what bench.py reports, so b20 must win.
-        assert spec.startswith("sattn,flash,20")
+        assert spec.startswith("dots,flash,20")
         assert tok_s == 119069.0
 
     def test_failed_lines_skipped_and_empty_is_none(self):
@@ -86,7 +85,7 @@ class TestParseAutotune:
 class TestWinnerEnv:
     def test_full_pin_set(self):
         env = capture_perf.winner_env(
-            "sattn,flash,20,1024,1024,nofn,u4,xc4", n_chips=1
+            "dots,flash,20,1024,1024,nofn,u4,xc4", n_chips=1
         )
         assert env == {
             "BENCH_BLOCKS": "1024,1024,1024,1024",
@@ -94,14 +93,14 @@ class TestWinnerEnv:
             "BENCH_FUSED_NORM": "0",
             "BENCH_UNROLL": "4",
             "BENCH_XENT_CHUNKS": "4",
-            "BENCH_REMAT": "save_attn",
+            "BENCH_REMAT": "dots",
         }
 
     def test_batch_is_global_converted_per_chip(self):
         """Sweep batch is global across its mesh; bench.py's knob is
         per-chip. A 2-chip sweep at global 40 must pin 20/chip."""
         env = capture_perf.winner_env(
-            "sattn,flash,40,1024,1024,nofn", n_chips=2
+            "dots,flash,40,1024,1024,nofn", n_chips=2
         )
         assert env["BENCH_BATCH_PER_CHIP"] == "20"
 

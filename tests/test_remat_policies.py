@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from dlrover_tpu.accelerate.remat import POLICY_NAMES, canonical
-from dlrover_tpu.models import gpt
+from dlrover_tpu.models import gpt, llama
 
 
 def _cfg(remat):
@@ -52,7 +52,7 @@ class TestRematPolicies:
 
     @pytest.mark.parametrize(
         "policy",
-        ["full", "attention", "dots", "offload", "save_attn", True],
+        ["full", "attention", "dots", "offload", True],
     )
     def test_policy_matches_no_remat(self, policy):
         """Loss and every gradient identical to remat='none' — remat
@@ -109,77 +109,257 @@ class TestRematPolicies:
         ).name()
 
 
-def _pallas_outvar_counts(jaxpr, acc):
-    """Outvar count of every pallas_call eqn, recursively — the flash
-    forward has 2 outputs (o, lse), the backward 3 (dq, dk, dv)."""
+def _flash_calls(jaxpr, acc):
+    """Name of every flash ``pallas_call`` in the jaxpr, recursively
+    (an expert layer's grouped products are Pallas calls too)."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            acc.append(len(eqn.outvars))
-        for key in ("jaxpr", "call_jaxpr", "fun_jaxpr", "branches"):
-            v = eqn.params.get(key)
-            if v is None:
-                continue
+        name = str(eqn.params.get("name", ""))
+        if eqn.primitive.name == "pallas_call" and "flash" in name:
+            acc.append(name)
+        for v in eqn.params.values():
             for x in v if isinstance(v, (tuple, list)) else [v]:
-                if hasattr(x, "jaxpr"):
-                    x = x.jaxpr
+                x = getattr(x, "jaxpr", x)
                 if hasattr(x, "eqns"):
-                    _pallas_outvar_counts(x, acc)
+                    _flash_calls(x, acc)
     return acc
 
 
-class TestSaveAttnPolicy:
-    """save_attn's whole point is structural: the flash forward kernel
-    must be traced ONCE (its saved (o, lse) feed the backward), where
-    full remat traces it twice. Assert that on the jaxpr — a numerics
-    test alone would pass even if the policy silently stopped
-    working."""
+# (B, T) of the flash cases. Every family: 2 layers, E = 32, float32,
+# so that CPU gradients compare at the tolerances of the policies
+# above; shapes chosen so that no two kept residuals but ``x`` and
+# ``flash_o`` share one.
+B, T = 3, 128
 
-    def _grad_jaxpr(self, remat):
+
+def _flash_family(family, remat, **overrides):
+    """(loss_fn(params, tokens, targets), params) of a 2-layer model
+    on the flash kernel: GPT; Llama with grouped queries and a
+    window; a Llama block with an expert layer."""
+    if family == "gpt":
         cfg = dataclasses.replace(
-            _cfg(remat),
-            block_size=128,
-            use_flash_attention=True,
-            attn_blocks=(128, 128, 128, 128),
+            _cfg(remat), block_size=T, use_flash_attention=True,
+            attn_blocks=(128, 128, 128, 128), **overrides,
         )
-        params = gpt.init_params(jax.random.PRNGKey(0), cfg)
-        tokens = jnp.zeros((1, cfg.block_size), jnp.int32)
-        loss_fn = functools.partial(gpt.loss_fn, cfg=cfg)
-        return jax.make_jaxpr(jax.grad(loss_fn))(
-            params, tokens, tokens
+        return (
+            functools.partial(gpt.loss_fn, cfg=cfg),
+            gpt.init_params(jax.random.PRNGKey(0), cfg),
         )
+    cfg = llama.LlamaConfig(
+        vocab_size=128, block_size=T, n_layer=2, n_head=4, n_kv_head=2,
+        n_embd=32, intermediate=96, dtype=jnp.float32, remat=remat,
+        use_flash_attention=True, attn_blocks=(64, 64, 64, 64),
+        sliding_window=None if family == "moe" else 48,
+        n_experts=4 if family == "moe" else 0,
+    )
+    return (
+        functools.partial(llama.loss_fn, cfg=cfg),
+        llama.init_params(jax.random.PRNGKey(0), cfg),
+    )
 
-    def test_fwd_kernel_not_recomputed(self):
-        full = _pallas_outvar_counts(self._grad_jaxpr("full").jaxpr, [])
-        sa = _pallas_outvar_counts(
-            self._grad_jaxpr("save_attn").jaxpr, []
-        )
-        # full remat: fwd (2 outs) twice + bwd (3 outs) once per
-        # layer-scan trace; save_attn: fwd once + bwd once.
-        assert sorted(full) == [2, 2, 3], full
-        assert sorted(sa) == [2, 3], sa
 
-    def test_grad_parity_with_flash(self):
+def _tokens():
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (B, T), 0, 128)
+    return tokens, jnp.roll(tokens, -1, axis=1)
+
+
+@functools.lru_cache(maxsize=None)
+def _grad_jaxpr(family, remat, **overrides):
+    loss_fn, params = _flash_family(family, remat, **overrides)
+    return jax.make_jaxpr(jax.grad(loss_fn))(params, *_tokens()).jaxpr
+
+
+def _stacked_residuals(jaxpr):
+    """Shapes, without the layer dimension, of what the forward layer
+    scan stacks for the backward: its outputs of rank > 0 beyond the
+    carry, as a sorted list."""
+    scan = next(e for e in jaxpr.eqns if e.primitive.name == "scan")
+    stacked = scan.outvars[scan.params["num_carry"]:]
+    return sorted(
+        (v.aval.shape[1:], str(v.aval.dtype)) for v in stacked
+        if v.aval.ndim > 1
+    )
+
+
+FAMILIES = ["gpt", "llama_gqa_window", "moe"]
+E, H, F = 32, 4, 96
+F32 = "float32"
+# What "full" keeps of a block, per family: x (the block's input),
+# flash_o in the model's layout at these head sizes (the same shape
+# as x), flash_lse [B, H, T], and the named products. No third
+# [B, T, E]: that would be the out-projection's output.
+KEPT_SHAPES = {
+    "gpt": sorted([
+        ((B, T, 32), F32), ((B, T, 32), F32),   # x, flash_o
+        ((B, 2, T), F32),                       # flash_lse, 2 heads
+        ((B, T, 96), F32),                      # qkv
+        ((B, T, 128), F32),                     # the wi product
+    ]),
+    "llama_gqa_window": sorted([
+        ((B, T, E), F32), ((B, T, E), F32),     # x, flash_o
+        ((B, T, E), F32),                       # q
+        ((B, T, E // 2), F32), ((B, T, E // 2), F32),  # k, v: 2 of 4 heads
+        ((B, H, T), F32),                       # flash_lse
+        ((B, T, F), F32), ((B, T, F), F32),     # gate, up
+    ]),
+    "moe": sorted([
+        ((B, T, E), F32), ((B, T, E), F32), ((B, T, E), F32),
+        ((B, T, E // 2), F32), ((B, T, E // 2), F32),
+        ((B, H, T), F32),
+        ((B * T, 4), F32),                      # router logits
+    ]),
+}
+
+
+class TestFullKeepsTheFlashOutputs:
+    """What ``remat=True`` is for is structural: the flash forward
+    kernel is traced ONCE a layer (its kept (o, lse) feed the
+    backward) and the out-projection's output is not among the
+    residuals. Assert that on the jaxpr: a numerics test alone would
+    pass even if the policy silently stopped working."""
+
+    @pytest.mark.parametrize("family", FAMILIES)
+    def test_fwd_kernel_not_recomputed(self, family):
+        calls = _flash_calls(_grad_jaxpr(family, "full"), [])
+        assert sorted(calls) == [
+            "flash_attention_bwd", "flash_attention_fwd"
+        ], calls
+
+    def test_a_policy_by_type_runs_the_fwd_kernel_twice(self):
+        """The contrast, so the test above cannot pass by accident:
+        "dots" keeps by primitive type and cannot see inside the
+        flash custom_vjp."""
+        calls = _flash_calls(_grad_jaxpr("gpt", "dots"), [])
+        assert sorted(calls) == [
+            "flash_attention_bwd", "flash_attention_fwd",
+            "flash_attention_fwd",
+        ], calls
+
+    @pytest.mark.parametrize("family", FAMILIES)
+    def test_residuals_are_exactly_the_named_set(self, family):
+        got = _stacked_residuals(_grad_jaxpr(family, "full"))
+        assert got == KEPT_SHAPES[family], got
+
+    def test_kept_lse_is_compact(self):
+        """[B, H, T], rows along the lanes: the kernel's [B, H, T, 1]
+        column is padded to 128 lanes in the chip's memory."""
+        shapes = [
+            s for s, _ in _stacked_residuals(_grad_jaxpr("gpt", "full"))
+        ]
+        assert (B, 2, T) in shapes
+        assert not [s for s in shapes if s[-1] == 1]
+
+    def test_o_kept_as_the_kernel_wrote_it_at_head_size_128(self):
+        """Where the head size fills the chip's 128 lanes the kernel's
+        [B, H, T, D] is not padded, so ``o`` is kept as it is and no
+        transposition is paid; below that (the cases above) it is
+        kept in the model's layout."""
+        jaxpr = _grad_jaxpr("gpt", "full", n_embd=128, n_head=1)
+        assert _stacked_residuals(jaxpr) == sorted([
+            ((B, T, 128), F32),                  # x alone
+            ((B, 1, T, 128), F32),               # flash_o, one head
+            ((B, 1, T), F32),                    # flash_lse
+            ((B, T, 384), F32), ((B, T, 512), F32),
+        ])
+        calls = _flash_calls(jaxpr, [])
+        assert calls.count("flash_attention_fwd") == 1, calls
+
+    @pytest.mark.parametrize("family", FAMILIES)
+    def test_grads_match_no_remat_with_flash(self, family):
         def grads(remat):
-            cfg = dataclasses.replace(
-                _cfg(remat),
-                block_size=128,
-                use_flash_attention=True,
-                attn_blocks=(128, 128, 128, 128),
+            loss_fn, params = _flash_family(family, remat)
+            return jax.jit(jax.value_and_grad(loss_fn))(
+                params, *_tokens()
             )
-            params = gpt.init_params(jax.random.PRNGKey(0), cfg)
-            tokens = jax.random.randint(
-                jax.random.PRNGKey(1), (1, 128), 0, cfg.vocab_size
-            )
-            loss_fn = functools.partial(gpt.loss_fn, cfg=cfg)
-            return jax.jit(jax.grad(loss_fn))(params, tokens, tokens)
 
-        for a, b in zip(
-            jax.tree.leaves(grads("save_attn")),
-            jax.tree.leaves(grads("full")),
-        ):
+        loss, got = grads("full")
+        base_loss, want = grads("none")
+        np.testing.assert_allclose(
+            float(loss), float(base_loss), rtol=1e-6
+        )
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
             np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-4
+                np.asarray(a), np.asarray(b), atol=1e-5, rtol=1e-5
             )
+
+    def test_prefix_lm_keeps_both_calls_outputs(self):
+        """ops/prefix_lm.py calls the plain entry twice a layer (the
+        prefix's rows and the rest): each keeps its own (o, lse),
+        together one ``o``, and neither forward runs again."""
+        from dlrover_tpu.accelerate.remat import full_policy
+        from dlrover_tpu.ops.prefix_lm import prefix_lm_attention
+
+        def loss(q, k, v):
+            block = functools.partial(prefix_lm_attention, prefix_len=32)
+            out = jax.checkpoint(block, policy=full_policy())(q, k, v)
+            return out.sum()
+
+        q = jnp.ones((1, 64, 2, 16), jnp.float32)
+        jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q)
+        calls = _flash_calls(jaxpr.jaxpr, [])
+        assert calls.count("flash_attention_fwd") == 2, calls
+        assert calls.count("flash_attention_bwd") == 2, calls
+
+
+class TestRetiredNameAndEvent:
+    def test_five_policies_and_no_alias(self):
+        assert POLICY_NAMES == (
+            "none", "full", "attention", "dots", "offload"
+        )
+        # What it named is what True means now; the name has no alias.
+        with pytest.raises(ValueError, match="unknown remat"):
+            canonical("save" + "_attn")
+
+    def test_full_policy_is_by_name(self):
+        from dlrover_tpu.accelerate import remat
+
+        assert remat.KEPT == (
+            "attn_in", "flash_o", "flash_lse", "mlp_hidden",
+            "router_logits",
+        )
+        assert remat.BLOCK_OUT not in remat.KEPT
+
+    @pytest.mark.parametrize("flash", [True, False])
+    def test_remat_kept_fires_once_a_trace(self, flash):
+        from dlrover_tpu import obs
+
+        tracer = obs.configure_tracer()
+        try:
+            if flash:
+                loss_fn, params = _flash_family("gpt", "full")
+                tokens, targets = _tokens()
+            else:
+                cfg = _cfg("full")
+                loss_fn = functools.partial(gpt.loss_fn, cfg=cfg)
+                params = gpt.init_params(jax.random.PRNGKey(0), cfg)
+                tokens = targets = jnp.zeros((2, 32), jnp.int32)
+            jax.jit(jax.value_and_grad(loss_fn)).lower(
+                params, tokens, targets
+            )
+            (ev,) = [
+                e for e in tracer.events() if e["name"] == "remat.kept"
+            ]
+        finally:
+            obs.disable_tracer()
+        assert ev["flash_residuals"] is flash
+        want = ["attn_in", "mlp_hidden"]
+        if flash:
+            want = ["attn_in", "flash_lse", "flash_o", "mlp_hidden"]
+        assert ev["names"] == want
+
+    @pytest.mark.parametrize("policy", ["none", "dots", "attention"])
+    def test_remat_kept_is_fulls_alone(self, policy):
+        from dlrover_tpu import obs
+
+        tracer = obs.configure_tracer()
+        try:
+            loss_fn, params = _flash_family("gpt", policy)
+            jax.jit(jax.grad(loss_fn)).lower(params, *_tokens())
+            events = [
+                e for e in tracer.events() if e["name"] == "remat.kept"
+            ]
+        finally:
+            obs.disable_tracer()
+        assert events == []
 
 
 class TestScanUnroll:

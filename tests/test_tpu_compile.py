@@ -349,9 +349,47 @@ def _assert_fits_with_flash(compiled):
     )
 
 
+def _step_gb(compiled):
+    """What the benchmark's ``step_hbm_gb.train`` reads."""
+    mem = compiled.memory_analysis()
+    return (
+        mem.temp_size_in_bytes + mem.argument_size_in_bytes
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    ) / 1e9
+
+
+def _computations_calling(compiled, kernel):
+    """Names of the HLO computations that hold a custom call of the
+    Pallas kernel ``kernel`` (the forward layer scan's body, the
+    backward scan's body, ...)."""
+    found, current = [], None
+    for line in compiled.as_text().splitlines():
+        if line.endswith("{") and line[:1] in "%E":
+            current = line.split()[1 if line.startswith("ENTRY") else 0]
+        elif "tpu_custom_call" in line and f"/{kernel}/" in line:
+            found.append(current)
+    return found
+
+
+def _assert_flash_forward_runs_once(compiled):
+    """remat=True keeps the flash forward's (o, lse): one forward
+    call in the step, in the forward layer scan, and none beside the
+    backward kernel in the backward scan's body."""
+    fwd = _computations_calling(compiled, "flash_attention_fwd")
+    bwd = _computations_calling(compiled, "flash_attention_bwd")
+    assert len(fwd) == 1 and len(bwd) == 1, (fwd, bwd)
+    assert fwd != bwd
+
+
 def test_gpt2_train_step_compiles_on_one_chip(topo, compiled_kernels):
-    """The program chip_smoke.py's trainer runs: batch 18 x 1024."""
-    _assert_fits_with_flash(_gpt2_step(topo.devices[:1], "data", 18))
+    """The program chip_smoke.py's trainer runs: batch 18 x 1024.
+    The flash forward runs once a layer, at the memory of the tree
+    that kept the out-projection's output in place of ``o`` (PR 29's:
+    6.7917 GB compiled here, 6.7922 on the chip)."""
+    compiled = _gpt2_step(topo.devices[:1], "data", 18)
+    _assert_fits_with_flash(compiled)
+    _assert_flash_forward_runs_once(compiled)
+    assert _step_gb(compiled) < 6.7917 + 0.05
 
 
 @pytest.mark.parametrize("axis", ["data", "fsdp"])
@@ -367,6 +405,29 @@ def test_gpt2_train_step_compiles_on_four_chips(
     _assert_fits_with_flash(compiled)
     # It is one program across the mesh, not four copies of one.
     assert "all-reduce" in compiled.as_text()
+    # The kept (o, lse) are tagged inside the kernel's shard_map.
+    _assert_flash_forward_runs_once(compiled)
+    if axis == "fsdp":  # PR 29's tree: 2.8190 GB a chip
+        assert _step_gb(compiled) < 2.8190 + 0.05
+
+
+def test_mistral_block_keeps_flash_outputs_on_four_chips(
+    topo, compiled_kernels
+):
+    """The Llama block at Mistral-7B's widths (grouped queries, window
+    4096, T = 8192) on ``fsdp=4``, two layers of the eight of the
+    benchmark's ``mistral-7b-host4.fsdp4``, which has no room for an
+    ``o`` kept beside the out-projection's output (0.067 GB a layer a
+    chip): kept in its place, the step takes what PR 29's tree took
+    (7.2069 GB a chip compiled here)."""
+    cfg = dataclasses.replace(
+        llama.LlamaConfig.mistral_7b(), n_layer=2, block_size=8192,
+        use_flash_attention=True,
+    )
+    compiled = _train_step(llama, cfg, list(topo.devices), "fsdp", 4)
+    _assert_fits_with_flash(compiled)
+    _assert_flash_forward_runs_once(compiled)
+    assert _step_gb(compiled) < 7.2069 + 0.05
 
 
 def _olmoe_step(devices, axis, global_batch):

@@ -74,12 +74,6 @@ def main() -> int:
         run_config(mesh, f"full,flash,18,{bq},{bk},nofn,u{u}")
     run_config(mesh, f"none,flash,18,{bq},{bk},nofn,u4")
     run_config(mesh, f"dots,flash,18,{bq},{bk},nofn,u4")
-    # save_attn: full recompute except the flash (o, lse) — skips the
-    # second fwd-kernel run in the backward. Sweep it rolled and at
-    # the unroll points since the two compose.
-    run_config(mesh, f"sattn,flash,18,{bq},{bk},nofn")
-    for u in (2, 4):
-        run_config(mesh, f"sattn,flash,18,{bq},{bk},nofn,u{u}")
     # Fused-CE chunk count: the r5 trace prices the CE loops at
     # 35.5 ms/step with the f32 dwte accumulator re-read per chunk;
     # fewer chunks trade accumulator round-trips for logits HBM.
@@ -87,14 +81,12 @@ def main() -> int:
         run_config(mesh, f"full,flash,18,{bq},{bk},nofn,xc{xc}")
     # Lever combinations: each pair/triple, so the winner isn't
     # hostage to one lever losing on hardware.
-    run_config(mesh, f"sattn,flash,18,{bq},{bk},nofn,u4,xc4")
-    run_config(mesh, f"sattn,flash,18,{bq},{bk},nofn,xc4")
     run_config(mesh, f"full,flash,18,{bq},{bk},nofn,u4,xc4")
-    # Batch interacts with the new memory knobs (save_attn saves
-    # more residuals, small xc holds bigger logits): re-check the
-    # b18 optimum one notch up and down on the combined candidate.
-    run_config(mesh, f"sattn,flash,20,{bq},{bk},nofn,u4,xc4")
-    run_config(mesh, f"sattn,flash,16,{bq},{bk},nofn,u4,xc4")
+    # Batch interacts with the memory knobs (a small xc holds bigger
+    # logits): re-check the b18 optimum one notch up and down on the
+    # combined candidate.
+    run_config(mesh, f"full,flash,20,{bq},{bk},nofn,u4,xc4")
+    run_config(mesh, f"full,flash,16,{bq},{bk},nofn,u4,xc4")
     for bqb, bkb in candidates:
         run_config(mesh, f"full,flash,18,{bq},{bk},{bqb},{bkb},nofn")
     print("pick the fastest line; bench.py BENCH_* env then pins it")

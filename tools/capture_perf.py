@@ -259,9 +259,9 @@ def winner_env(spec: str, n_chips: int = 1) -> dict:
         # bench.py defaults to full remat; pin any other winner.
         # Sweep tokens are build_spec's grammar ("attn" etc.); bench
         # wants remat.py policy names, so map through the same table.
-        env["BENCH_REMAT"] = {
-            "attn": "attention", "sattn": "save_attn"
-        }.get(parts[0], parts[0])
+        env["BENCH_REMAT"] = {"attn": "attention"}.get(
+            parts[0], parts[0]
+        )
     return env
 
 

@@ -98,7 +98,7 @@ def build_spec(spec: str):
     block_k_bwd = _blk(6)
     remat = {
         "full": True, "attn": "attention", "none": False,
-        "dots": "dots", "offload": "offload", "sattn": "save_attn",
+        "dots": "dots", "offload": "offload",
     }[remat_s]
     use_flash = flash_s == "flash"
 

@@ -163,7 +163,7 @@ for i in $(seq 1 400); do
       # loads — the single highest-leverage stage for the headline
       # if the window is short. AGD already has an acceptable labeled
       # CPU-fallback artifact, so it yields its slot to the tune.
-      # The sweep covers scan-unroll, save_attn, and xent-chunk axes
+      # The sweep covers scan-unroll, remat and xent-chunk axes
       # besides the bwd blocks.
       # Capped at 2 failed attempts here (a window shorter than the
       # sweep would otherwise starve longctx/decode forever); a
