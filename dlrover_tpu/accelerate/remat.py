@@ -14,7 +14,9 @@ Policies (cfg.remat / Strategy.remat accept these names):
   "none"       keep every residual (fastest, most HBM)
   "full"       recompute blocks; keep what the block names (KEPT):
                the projections into attention, the flash forward's
-               (o, lse) and the MLP's hidden products
+               (o, lse), the MLP's hidden products, and of a
+               state-space mixer its projection, the scan's output
+               and the chunk states
   "attention"  recompute only attention internals
   "dots"       recompute everything except matmul outputs
   "offload"    offload block-boundary residuals (checkpoint_name
@@ -54,13 +56,20 @@ BLOCK_OUT = "block_out"
 # chooses their layouts), the MLP's hidden products (GPT's
 # ``wi`` product; Llama's ``gate`` and ``up``) and an expert layer's
 # router logits. NOT the out-projection's output: it is recomputed
-# from the kept ``flash_o``.
+# from the kept ``flash_o``. Of a state-space mixer
+# (models/granite_hybrid.py): the projection into it, and the scan's
+# output with the state every chunk starts from (ops/ssd.py), which
+# are all its backward kernel takes from the forward one.
 ATTN_IN = "attn_in"
 FLASH_O = "flash_o"
 FLASH_LSE = "flash_lse"
 MLP_HIDDEN = "mlp_hidden"
 ROUTER_LOGITS = "router_logits"
-KEPT = (ATTN_IN, FLASH_O, FLASH_LSE, MLP_HIDDEN, ROUTER_LOGITS)
+SSM_IN = "ssm_in"
+SSD_Y = "ssd_y"
+SSD_STATES = "ssd_states"
+KEPT = (ATTN_IN, FLASH_O, FLASH_LSE, MLP_HIDDEN, ROUTER_LOGITS,
+        SSM_IN, SSD_Y, SSD_STATES)
 
 POLICY_NAMES = ("none", "full", "attention", "dots", "offload")
 

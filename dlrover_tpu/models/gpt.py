@@ -184,11 +184,13 @@ def use_fused_norm(cfg) -> bool:
     return False
 
 
-def _default_attention(q, k, v, causal=True, window=None):
+def _default_attention(q, k, v, causal=True, window=None, scale=None):
     """Plain fused attention (single-shard fallback; the sharded path
     comes from parallel.ring_attention.make_sharded_attention).
     ``window`` applies the same Mistral-style sliding-window band as
-    the flash kernel (query i sees keys (i-window, i])."""
+    the flash kernel (query i sees keys (i-window, i]); ``scale``
+    multiplies the scores, as the flash kernel's does (None: one over
+    the root of the head size)."""
     if window is not None and not causal:
         # Same contract as flash_attention: a one-sided band with
         # bidirectional attention would mean different models per
@@ -199,7 +201,8 @@ def _default_attention(q, k, v, causal=True, window=None):
     b, lq, h, d = q.shape
     s = jnp.einsum(
         "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
-    ) / np.sqrt(d)
+    )
+    s = s / np.sqrt(d) if scale is None else s * scale
     if causal or window is not None:
         pos = jnp.arange(lq)
         mask = jnp.ones((lq, lq), bool)

@@ -391,12 +391,49 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     return jnp.concatenate(parts, axis=-1)
 
 
+def attention_half(h, lp, cfg: LlamaConfig, attn_fn, cos, sin):
+    """The attention half of a block on the normed input ``h``:
+    projections, rotation, attention, out-projection. Returns
+    ``att @ wo`` WITHOUT the residual, so that a family that scales
+    the branch (models/granite_hybrid.py) can put its multiplier
+    between. ``cos`` None leaves queries and keys unrotated (no
+    positional embedding)."""
+    B, T, E = h.shape
+    H, Hkv, D = cfg.n_head, cfg.n_kv_head, cfg.head_dim
+    from dlrover_tpu.accelerate.remat import ATTN_IN, keep
+
+    # Named for remat="full" (accelerate/remat.py KEPT), before
+    # the head repeat; ``att @ wo`` below is recomputed from the
+    # flash forward's kept output.
+    q, k, v = (
+        keep(h @ lp[w], ATTN_IN) for w in ("wq", "wk", "wv")
+    )
+    if cfg.qkv_bias:
+        q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+    if cfg.qk_norm:
+        q = _rms_norm(q, lp["q_norm"], cfg.rms_eps)
+        k = _rms_norm(k, lp["k_norm"], cfg.rms_eps)
+    q = q.reshape(B, T, H, D)
+    k = k.reshape(B, T, Hkv, D)
+    v = v.reshape(B, T, Hkv, D)
+    if cos is not None:
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
+    if Hkv != H and not getattr(attn_fn, "supports_gqa", False):
+        # grouped-query: broadcast each kv head over its query
+        # group. GQA-aware attention (the seq-parallel
+        # constructors) takes the COMPACT k/v instead — the
+        # ring/a2a then move 1/q_per_kv the bytes and broadcast
+        # per block on-device.
+        k = jnp.repeat(k, cfg.q_per_kv, axis=2)
+        v = jnp.repeat(v, cfg.q_per_kv, axis=2)
+    att = attn_fn(q, k, v).reshape(B, T, E)
+    return att @ lp["wo"]
+
+
 def _block(x, lp, cfg: LlamaConfig, attn_fn, cos, sin):
     """One block. Returns (x, aux_loss) — aux is 0 for dense MLPs,
     this layer's share of the router losses for MoE blocks."""
-    B, T, E = x.shape
-    H, Hkv, D = cfg.n_head, cfg.n_kv_head, cfg.head_dim
-    from dlrover_tpu.accelerate.remat import ATTN_IN, keep
     from dlrover_tpu.models.gpt import use_fused_norm
 
     fused = use_fused_norm(cfg)
@@ -412,32 +449,7 @@ def _block(x, lp, cfg: LlamaConfig, attn_fn, cos, sin):
             h = fused_rms_norm(x, lp["rms1"], eps=cfg.rms_eps)
         else:
             h = _rms_norm(x, lp["rms1"], cfg.rms_eps)
-        # Named for remat="full" (accelerate/remat.py KEPT), before
-        # the head repeat; ``att @ wo`` below is recomputed from the
-        # flash forward's kept output.
-        q, k, v = (
-            keep(h @ lp[w], ATTN_IN) for w in ("wq", "wk", "wv")
-        )
-        if cfg.qkv_bias:
-            q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-        if cfg.qk_norm:
-            q = _rms_norm(q, lp["q_norm"], cfg.rms_eps)
-            k = _rms_norm(k, lp["k_norm"], cfg.rms_eps)
-        q = q.reshape(B, T, H, D)
-        k = k.reshape(B, T, Hkv, D)
-        v = v.reshape(B, T, Hkv, D)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        if Hkv != H and not getattr(attn_fn, "supports_gqa", False):
-            # grouped-query: broadcast each kv head over its query
-            # group. GQA-aware attention (the seq-parallel
-            # constructors) takes the COMPACT k/v instead — the
-            # ring/a2a then move 1/q_per_kv the bytes and broadcast
-            # per block on-device.
-            k = jnp.repeat(k, cfg.q_per_kv, axis=2)
-            v = jnp.repeat(v, cfg.q_per_kv, axis=2)
-        att = attn_fn(q, k, v).reshape(B, T, E)
-        att_out = att @ lp["wo"]
+        att_out = attention_half(h, lp, cfg, attn_fn, cos, sin)
     with jax.named_scope("mlp"):
         if fused:
             # Attention residual add fused into the second norm's
@@ -451,6 +463,18 @@ def _block(x, lp, cfg: LlamaConfig, attn_fn, cos, sin):
         return mlp_tail(x, h, lp, cfg)
 
 
+def swiglu(h, lp):
+    """The dense SwiGLU MLP on the normed input ``h``, WITHOUT the
+    residual (a family that scales the branch puts its multiplier
+    between, models/granite_hybrid.py)."""
+    from dlrover_tpu.accelerate.remat import MLP_HIDDEN, keep
+
+    gate = keep(h @ lp["w_gate"], MLP_HIDDEN)
+    up = keep(h @ lp["w_up"], MLP_HIDDEN)
+    gated = jax.nn.silu(gate) * up
+    return gated @ lp["w_down"]
+
+
 def mlp_tail(x, h, lp, cfg: LlamaConfig):
     """Dense-SwiGLU or expert-routed MLP tail of a block. Shared by
     the training block and the decode paths (models/generate.py).
@@ -462,12 +486,7 @@ def mlp_tail(x, h, lp, cfg: LlamaConfig):
 
         y, aux = moe_mlp(lp["moe"], h, cfg._moe_cfg())
         return x + y.astype(x.dtype), aux / cfg.n_layer
-    from dlrover_tpu.accelerate.remat import MLP_HIDDEN, keep
-
-    gate = keep(h @ lp["w_gate"], MLP_HIDDEN)
-    up = keep(h @ lp["w_up"], MLP_HIDDEN)
-    gated = jax.nn.silu(gate) * up
-    return x + gated @ lp["w_down"], jnp.zeros((), jnp.float32)
+    return x + swiglu(h, lp), jnp.zeros((), jnp.float32)
 
 
 def head_logits(params: Params, x: jax.Array) -> jax.Array:

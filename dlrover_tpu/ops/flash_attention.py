@@ -52,7 +52,7 @@ from jax.sharding import PartitionSpec as P
 NEG_INF = -1e30
 
 
-def _use_interpret() -> bool:
+def use_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
@@ -809,7 +809,7 @@ def flash_attention(
     Requires ``causal=True``.
     """
     if interpret is None:
-        interpret = _use_interpret()
+        interpret = use_interpret()
     b, t, h, d = q.shape
     if window is not None:
         if not causal:
@@ -928,7 +928,7 @@ def flash_attention_rect(
     square :func:`flash_attention` (same kernels, tuned defaults).
     """
     if interpret is None:
-        interpret = _use_interpret()
+        interpret = use_interpret()
     b, tq0, h, d = q.shape
     tk0 = k.shape[1]
     if q_offset is None:

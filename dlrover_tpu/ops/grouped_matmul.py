@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dlrover_tpu.ops.flash_attention import _use_interpret
+from dlrover_tpu.ops.flash_attention import use_interpret
 
 # Rows a visit: the smaller the tile, the less of a boundary tile is
 # computed for rows of another group, and the expert's matrix is
@@ -99,7 +99,7 @@ def moe_gmm(lhs, rhs, group_sizes, transpose_rhs=False, interpret=None):
     """[m, k] x [g, k, n] (or [g, n, k] with ``transpose_rhs``) ->
     [m, n] in ``lhs``'s dtype."""
     if interpret is None:
-        interpret = _use_interpret()
+        interpret = use_interpret()
     tm = _row_tile(lhs.shape[0])
     lhs, m = _pad_rows(lhs, tm)
     k = lhs.shape[1]
@@ -151,7 +151,7 @@ def moe_tgmm(lhs, grad, group_sizes, out_dtype=None, interpret=None):
     """[m, k]^T x [m, n], group by group -> [g, k, n]; an empty
     group's block is zeros."""
     if interpret is None:
-        interpret = _use_interpret()
+        interpret = use_interpret()
     tm = _row_tile(lhs.shape[0])
     lhs, _ = _pad_rows(lhs, tm)
     grad, _ = _pad_rows(grad, tm)

@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dlrover_tpu.ops.flash_attention import _use_interpret, per_device
+from dlrover_tpu.ops.flash_attention import use_interpret, per_device
 
 DEFAULT_BLOCK_ROWS = 256
 # Per-ref VMEM budget for a [block_rows, E] f32 block. The backward
@@ -382,7 +382,7 @@ def fused_layer_norm(
     dtype. Differentiable (custom VJP, single fused backward kernel).
     """
     if interpret is None:
-        interpret = _use_interpret()
+        interpret = use_interpret()
     if block_rows is None:
         block_rows = pick_block_rows(x.shape[-1])
     return _norm_per_device(
@@ -399,7 +399,7 @@ def fused_rms_norm(
 ) -> jax.Array:
     """RMSNorm over the last axis (Llama family)."""
     if interpret is None:
-        interpret = _use_interpret()
+        interpret = use_interpret()
     if block_rows is None:
         block_rows = pick_block_rows(x.shape[-1])
     return _norm_per_device(
@@ -423,7 +423,7 @@ def fused_add_layer_norm(
     the input to the NEXT residual add.
     """
     if interpret is None:
-        interpret = _use_interpret()
+        interpret = use_interpret()
     if block_rows is None:
         block_rows = pick_block_rows(x.shape[-1])
     return _norm_per_device(
@@ -442,7 +442,7 @@ def fused_add_rms_norm(
 ) -> Tuple[jax.Array, jax.Array]:
     """(rmsnorm(x + residual), x + residual) — Llama residual spine."""
     if interpret is None:
-        interpret = _use_interpret()
+        interpret = use_interpret()
     if block_rows is None:
         block_rows = pick_block_rows(x.shape[-1])
     return _norm_per_device(

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dlrover_tpu.ops.flash_attention import _use_interpret
+from dlrover_tpu.ops.flash_attention import use_interpret
 
 DEFAULT_BLOCK = 1024
 # Rows of blocks processed per kernel grid step (sublane packing).
@@ -75,7 +75,7 @@ def _quant_call(kernel, x2, out_width, out_dtype, name):
             jax.ShapeDtypeStruct((x2.shape[0], out_width), out_dtype),
             jax.ShapeDtypeStruct((x2.shape[0], 1), jnp.float32),
         ],
-        interpret=_use_interpret(),
+        interpret=use_interpret(),
         name=name,
     )(x2)
 
@@ -94,7 +94,7 @@ def _dequant_call(kernel, q, scales, block_size, dtype, name):
         in_specs=[_row_spec(q.shape[1]), _row_spec(1)],
         out_specs=_row_spec(block_size),
         out_shape=jax.ShapeDtypeStruct((q.shape[0], block_size), dtype),
-        interpret=_use_interpret(),
+        interpret=use_interpret(),
         name=name,
     )(q, scales)
 

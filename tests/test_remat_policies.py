@@ -314,7 +314,7 @@ class TestRetiredNameAndEvent:
 
         assert remat.KEPT == (
             "attn_in", "flash_o", "flash_lse", "mlp_hidden",
-            "router_logits",
+            "router_logits", "ssm_in", "ssd_y", "ssd_states",
         )
         assert remat.BLOCK_OUT not in remat.KEPT
 

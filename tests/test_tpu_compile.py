@@ -24,8 +24,9 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from dlrover_tpu.models import gpt, llama
+from dlrover_tpu.models import gpt, granite_hybrid, llama
 from dlrover_tpu.ops import grouped_matmul
+from dlrover_tpu.ops import ssd as ssd_ops
 from dlrover_tpu.ops.flash_attention import (
     flash_attention,
     flash_attention_rect,
@@ -82,14 +83,14 @@ def one_chip(topo):
 @pytest.fixture
 def compiled_kernels(monkeypatch):
     """Entry points without an ``interpret`` argument ask
-    ``_use_interpret()``, which sees this process's CPU backend; here
+    ``use_interpret()``, which sees this process's CPU backend; here
     the answer is the chip's. ``dlrover_tpu.ops.flash_attention`` the
     attribute is the re-exported function, so go through sys.modules."""
     for name in ("flash_attention", "layer_norm", "quantization",
-                 "grouped_matmul"):
+                 "grouped_matmul", "ssd"):
         monkeypatch.setattr(
             sys.modules[f"dlrover_tpu.ops.{name}"],
-            "_use_interpret",
+            "use_interpret",
             lambda: False,
         )
 
@@ -457,3 +458,123 @@ def test_olmoe_train_step_compiles_on_four_chips(topo, compiled_kernels):
     _assert_fits_with_flash(compiled)
     text = compiled.as_text()
     assert "moe_gmm" in text and "all-gather" in text
+
+
+# -- the chunked state-space scan and the hybrid stack that runs it ------
+
+
+def _ssd_operands(sharding, bsz, rows_sharding=None):
+    """ops/ssd.py's operands at Granite 4.0-H's widths: 64 heads of
+    64, state 128, one B/C group, 4096 tokens, bf16 with float32
+    steps and per-head scalars."""
+    rows = rows_sharding or sharding
+    f32 = lambda shape, s: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=s)
+    return (
+        _bf16(rows, bsz, 4096, 4096), f32((bsz, 4096, 64), rows),
+        f32((64,), sharding), _bf16(rows, bsz, 4096, 1, 128),
+        _bf16(rows, bsz, 4096, 1, 128), f32((64,), sharding),
+    )
+
+
+def _ssd_grad(*args):
+    return jax.grad(
+        lambda *a: ssd_ops.ssd(*a, chunk=256).astype(jnp.float32).sum(),
+        argnums=range(6),
+    )(*args)
+
+
+def test_ssd_fwd_bwd_compiles_at_granite_widths(one_chip, compiled_kernels):
+    """Blocks of 8 heads of 64 (lane offsets of 64 inside a 512-lane
+    block), a head a column of a lane-sparse block, every head's state
+    in VMEM scratch along the sequential chunk axis, the declared
+    ``vmem_limit_bytes``: Mosaic takes both kernels."""
+    text = _compile(_ssd_grad, *_ssd_operands(one_chip, 1)).as_text()
+    assert "ssd_fwd" in text and "ssd_bwd" in text
+    assert text.count("tpu_custom_call") >= 2
+
+
+def test_ssd_splits_itself_over_a_mesh(topo, compiled_kernels):
+    """Under fsdp=4 each chip scans its own batch row; the per-head
+    parameters' gradients are summed over the mesh."""
+    from dlrover_tpu.parallel.mesh import under_mesh
+
+    mesh = build_mesh(MeshConfig(fsdp=4), devices=list(topo.devices))
+    args = _ssd_operands(
+        NamedSharding(mesh, P()), 4, NamedSharding(mesh, P("fsdp"))
+    )
+    text = _compile(under_mesh(_ssd_grad, mesh), *args).as_text()
+    assert "ssd_fwd" in text and "ssd_bwd" in text
+    assert "bf16[1,4096,4096]" in text  # a chip's own row
+    assert "all-reduce" in text and "all-gather" not in text
+
+
+def test_granite_train_step_compiles_on_one_chip(topo, compiled_kernels):
+    """The program of the benchmark's ``granite-4.0-h-micro.steady``:
+    one period of the published pattern (5 Mamba-2, attention, 4
+    Mamba-2) at published widths with a quarter of the tied table,
+    1 x 4096 tokens, ``ElasticTrainer``'s accumulate-then-update step
+    (the float32 gradient accumulator is a quarter of the arguments'
+    weight). It fits; ``ssd_fwd`` is in the two forward scan bodies
+    and not beside ``ssd_bwd`` (the scan's output and chunk states
+    are kept); the flash forward runs once. 15.589 GB compiled here,
+    15.589 on the chip (PERF.md, PR 34)."""
+    from dlrover_tpu.trainer.elastic_trainer import ElasticTrainer
+
+    model = granite_hybrid
+    cfg = dataclasses.replace(
+        model.GraniteHybridConfig(
+            vocab_size=25088, layer_types=model.GraniteHybridConfig().period,
+            remat="full",
+        ),
+        use_flash_attention=True,
+    )
+    mesh = build_mesh(MeshConfig(data=1), devices=topo.devices[:1])
+    optimizer = optax.adamw(6e-4)
+    trainer = ElasticTrainer(
+        mesh, functools.partial(model.loss_fn_fused, cfg=cfg), optimizer,
+        global_batch_size=1, micro_batch_size=1,
+    )
+    param_shapes = jax.eval_shape(
+        functools.partial(model.init_params, cfg=cfg), jax.random.PRNGKey(0)
+    )
+    param_shardings = jax.tree.map(
+        lambda s: NamedSharding(mesh, s),
+        prune_specs_to_mesh(
+            mesh, tree_specs(model.param_logical_axes(cfg), None)
+        ),
+        is_leaf=lambda x: isinstance(x, P),
+    )
+    opt_shapes = jax.eval_shape(
+        functools.partial(init_opt_state, optimizer), param_shapes
+    )
+    opt_shardings = _match_opt_sharding(
+        opt_shapes, param_shapes, param_shardings, mesh
+    )
+
+    def with_shardings(shapes, shardings):
+        return jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            shapes, shardings,
+        )
+
+    tokens = jax.ShapeDtypeStruct(
+        (1, 1, cfg.block_size), jnp.int32,
+        sharding=NamedSharding(mesh, trainer._mb_spec),
+    )
+    compiled = trainer._compiled.lower(
+        with_shardings(param_shapes, param_shardings),
+        with_shardings(opt_shapes, opt_shardings), tokens, tokens,
+    ).compile()
+    _assert_fits_with_flash(compiled)
+    # One attention layer, outside the layer scans: one call each.
+    assert len(_computations_calling(compiled, "flash_attention_fwd")) == 1
+    assert len(_computations_calling(compiled, "flash_attention_bwd")) == 1
+    fwd = _computations_calling(compiled, "ssd_fwd")
+    bwd = _computations_calling(compiled, "ssd_bwd")
+    assert len(fwd) == 2 and len(bwd) == 2 and not set(fwd) & set(bwd), (
+        fwd, bwd
+    )
+    mem = compiled.memory_analysis()
+    assert (
+        mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    ) / 1e9 < 15.589 + 0.05

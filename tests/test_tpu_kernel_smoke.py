@@ -1,0 +1,43 @@
+"""tools/tpu_kernel_smoke.py's scan checks on the CPU (small shapes,
+interpreted): they pass the kernels as they are, and the float32 one
+refuses a scan broken as benchmark/controls/granite_hybrid.py breaks
+it, which is what makes the full run on the chip a guard for
+``ssd_fwd`` / ``ssd_bwd`` there."""
+
+import os
+import sys
+
+import pytest
+
+from benchmark.controls import granite_hybrid as controls
+from dlrover_tpu.ops import ssd as ssd_module
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
+import tpu_kernel_smoke  # noqa: E402
+
+
+def _scan_checks(monkeypatch):
+    monkeypatch.setattr(tpu_kernel_smoke, "SMALL", True)
+    tpu_kernel_smoke.RESULTS.clear()
+    tpu_kernel_smoke.ssd_checks()
+    return {r["kernel"]: r for r in tpu_kernel_smoke.RESULTS}
+
+
+def test_scan_checks_pass_the_kernels_as_they_are(monkeypatch):
+    got = _scan_checks(monkeypatch)
+    assert set(got) == {"ssd_fwd_bwd_f32", "ssd_fwd_bwd_bf16"}
+    assert all(r["ok"] for r in got.values()), got
+    assert got["ssd_fwd_bwd_f32"]["max_abs_err"] < 1e-5
+
+
+@pytest.mark.parametrize("name,attribute,broken", [
+    ("no_carry", "ssd", controls._scan_without_carry),
+    ("state_bf16", "_fwd_kernel", controls._kernel_with_bf16_state),
+])
+def test_float32_check_refuses_a_broken_scan(monkeypatch, name, attribute, broken):
+    monkeypatch.setattr(
+        ssd_module, attribute, broken(getattr(ssd_module, attribute))
+    )
+    got = _scan_checks(monkeypatch)
+    assert not got["ssd_fwd_bwd_f32"]["ok"], (name, got)
+    assert "relative error" in got["ssd_fwd_bwd_f32"]["error"]

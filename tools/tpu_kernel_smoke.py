@@ -365,6 +365,91 @@ def xent_checks():
     check("fused_xent", run)
 
 
+def _close_rel(got, want, tol):
+    """Largest difference over the largest wanted magnitude."""
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    err = float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+    _ERRS.append(err)
+    assert err <= tol, f"relative error {err:.3g} over {tol:.3g}"
+
+
+def _ssd_recurrence(x, dt, a, b, c, d):
+    """The state-space recurrence one token a step, float32: x
+    [B, T, H*P], dt [B, T, H], a, d [H], b, c [B, T, 1, N]."""
+    bsz, t, heads = dt.shape
+    x = x.astype(jnp.float32).reshape(bsz, t, heads, -1)
+    b, c = b.astype(jnp.float32)[:, :, 0], c.astype(jnp.float32)[:, :, 0]
+
+    def token(state, inputs):
+        x_t, dt_t, b_t, c_t = inputs
+        state = (
+            jnp.exp(dt_t * a)[..., None, None] * state
+            + (dt_t[..., None] * x_t)[..., None] * b_t[:, None, None, :]
+        )
+        y_t = jnp.einsum("bhpn,bn->bhp", state, c_t) + d[:, None] * x_t
+        return state, y_t
+
+    state = jnp.zeros((bsz, heads, x.shape[-1], b.shape[-1]), jnp.float32)
+    _, y = jax.lax.scan(
+        token, state, [jnp.moveaxis(v, 1, 0) for v in (x, dt, b, c)]
+    )
+    return jnp.moveaxis(y, 0, 1).reshape(bsz, t, -1)
+
+
+def ssd_checks():
+    """``ssd_fwd`` and ``ssd_bwd`` against the recurrence one token a
+    step, at the published Mamba-2 widths over four chunks, with
+    memories from a few tokens to several chunks. The float32
+    tolerance lies between what the chip reads for the kernels as
+    they are (2.4e-4) and with the running state rounded to bf16
+    (1.4e-3; a state that does not cross a chunk boundary reads 0.74;
+    PERF.md section 6, PR 34; tests/test_tpu_kernel_smoke.py tries
+    both on the CPU); the bf16 one is the operands' own rounding
+    (5.8e-3 on the chip)."""
+    from dlrover_tpu.ops.ssd import ssd
+
+    t, heads, p, n, chunk = (
+        (64, 8, 16, 32, 16) if SMALL else (1024, 64, 64, 128, 256)
+    )
+    keys = jax.random.split(jax.random.PRNGKey(8), 7)
+    x = jax.random.normal(keys[0], (1, t, heads * p))
+    dt = jax.nn.softplus(jax.random.normal(keys[1], (1, t, heads)) - 2.0)
+    a = -jnp.linspace(0.02, 1.0, heads)
+    b = jax.random.normal(keys[2], (1, t, 1, n)) * 0.3
+    c = jax.random.normal(keys[3], (1, t, 1, n)) * 0.3
+    d = 1.0 + 0.1 * jax.random.normal(keys[4], (heads,))
+    w = jax.random.normal(keys[5], (1, t, heads * p))
+
+    def both(dtype, tol):
+        xs, bs, cs = (v.astype(dtype) for v in (x, b, c))
+
+        def run():
+            def scalar(scan):
+                return lambda *args: jnp.sum(
+                    scan(*args).astype(jnp.float32) * w
+                )
+
+            grads = lambda scan: jax.jit(jax.value_and_grad(
+                scalar(scan), argnums=(0, 1, 2, 3, 4, 5)
+            ))(xs, dt, a, bs, cs, d)
+            with _prec("f32" if dtype == jnp.float32 else "bf16"):
+                y = jax.jit(lambda *args: ssd(*args, chunk=chunk))(
+                    xs, dt, a, bs, cs, d
+                )
+                _close_rel(y, _ssd_recurrence(xs, dt, a, bs, cs, d), tol)
+                (_, got), (_, want) = (
+                    grads(lambda *args: ssd(*args, chunk=chunk)),
+                    grads(_ssd_recurrence),
+                )
+            for g, r in zip(got, want):
+                _close_rel(g, r, tol)
+
+        return run
+
+    check("ssd_fwd_bwd_f32", both(jnp.float32, 5e-4))
+    check("ssd_fwd_bwd_bf16", both(jnp.bfloat16, 2e-2))
+
+
 def run(small: bool) -> list:
     """Every check, at the full or the small shapes; returns the
     result records (``ok`` False on a compile error or parity miss)."""
@@ -377,6 +462,7 @@ def run(small: bool) -> list:
     norm_checks()
     quant_checks()
     xent_checks()
+    ssd_checks()
     return list(RESULTS)
 
 
