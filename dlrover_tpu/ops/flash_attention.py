@@ -7,14 +7,20 @@ one Pallas kernel family designed for the MXU:
 
 * O(T) memory: scores never materialize in HBM; online softmax keeps a
   running (max, sum, acc) per query block in VMEM scratch that persists
-  across the sequential kv grid dimension.
+  across the sequential kv grid dimension (a sequence of one kv block
+  carries nothing and leaves the scratch alone).
 * layout-native: kernels block directly over the model's
   [batch, seq, heads, head_dim] arrays (grid over batch x heads), so no
   HBM transpose/reshape passes are spent on either side of the call —
   measured ~0.7ms/layer of pure relayout traffic saved at GPT-2 size.
 * bf16 inputs feed the 128x128 MXU; all softmax statistics and
-  accumulators are float32; stats are [block_q, 1] columns (one lane),
-  not lane-replicated tiles.
+  accumulators are float32; the forward's running stats are
+  [block_q, 1] columns in VMEM (one lane, not lane-replicated tiles).
+  Between the kernels a row statistic (``lse``, ``delta``) is a
+  lane-dense row, [B, H, 1, T]: the chip pads a buffer's minor
+  dimension to 128 lanes, so a [B, H, T, 1] column takes 128 times its
+  bytes. The forward kernel writes ``lse`` as the column its stats
+  are, and ``_fwd`` slices it to the row at once.
 * causal masking skips fully-masked kv blocks (no MXU work issued) and
   only diagonal-crossing blocks pay for mask generation at all —
   interior blocks run a maskless fast path (softmax bookkeeping is
@@ -31,7 +37,10 @@ one Pallas kernel family designed for the MXU:
   budget between 4k and 8k (the v5e compiler reports 16.04 MiB at 8k,
   D=128), so the backward declares what it needs
   (``_bwd_vmem_limit``) out of the chip's 128 MiB.
-  delta = rowsum(dO * O) is precomputed by XLA.
+  delta = rowsum(dO * O) is precomputed by XLA, as a row like lse. The
+  backward holds its score tile key-major (s^T = k q^T), so a
+  [1, block_q] row of lse or delta broadcasts down the sublanes as it
+  is read and no statistic changes layout inside the loop.
 
 On non-TPU backends kernels run in interpreter mode so the same code
 path is unit-testable on CPU.
@@ -166,7 +175,7 @@ def _bwd_vmem_limit(tq, d, itemsize, block_q, block_k):
     dq = tq * dpad * (4 + 2 * itemsize)
     operands = (
         2 * 2 * (block_q + 2 * block_k) * dpad * itemsize  # q do k v dk dv
-        + 2 * 2 * block_q * 128 * 4  # lse, delta: one column, lane-padded
+        + 2 * 2 * 8 * block_q * 4  # lse, delta: one row, sublane-padded
         + 2 * block_k * dpad * 4  # dk/dv accumulators
     )
     spill = 2 * block_q * block_k * 4  # f32 score tiles Mosaic spills
@@ -175,18 +184,24 @@ def _bwd_vmem_limit(tq, d, itemsize, block_q, block_k):
 
 
 def _block_mask(iq, jk, block_q, block_k, causal, seq_len, pad,
-                window, q_offset=0):
+                window, q_offset=0, key_major=False):
     """Mask for block (iq, jk) — only called for blocks that cross the
     diagonal, the sliding-window band edge, or the padding edge;
     interior blocks never generate iotas/compares. ``q_offset``
     (static) shifts q rows to their global positions — the
     rectangular case where q is a chunk of a longer sequence
-    (chunked prefill, prefix-LM suffix rows); 0 for square calls."""
+    (chunked prefill, prefix-LM suffix rows); 0 for square calls.
+    [block_q, block_k] as the forward holds its scores, or
+    ``key_major`` [block_k, block_q] as the backward does."""
+    shape, q_dim, k_dim = (
+        ((block_k, block_q), 1, 0) if key_major
+        else ((block_q, block_k), 0, 1)
+    )
     q_pos = q_offset + iq * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0
+        jnp.int32, shape, q_dim
     )
     k_pos = jk * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1
+        jnp.int32, shape, k_dim
     )
     mask = None
     if pad:
@@ -280,12 +295,21 @@ def _fwd_kernel(
 ):
     iq = pl.program_id(2)
     jk = pl.program_id(3)
+    # One kv block with no band to skip it: the block's softmax is the
+    # row's, so nothing is carried and the scratch is not touched.
+    carried = num_kv > 1 or window is not None
 
-    @pl.when(jk == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    if carried:
+        @pl.when(jk == 0)
+        def _init():
+            m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[:] = jnp.zeros_like(l_scr)
+            acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    def _write(m, l, acc):
+        l_safe = jnp.maximum(l, 1e-30)  # fully-masked rows (padding)
+        o_ref[0, 0] = (acc / l_safe).astype(o_ref.dtype)
+        lse_ref[0, 0] = m + jnp.log(l_safe)  # a column, as the stats are
 
     def _accumulate(masked: bool):
         q = q_ref[0, 0]
@@ -305,10 +329,11 @@ def _fwd_kernel(
             )
             s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_scr[:]  # (block_q, 1)
-        l_prev = l_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)  # (block_q, 1): 1-lane exps
+        m_new = jnp.max(s, axis=1, keepdims=True)  # (block_q, 1)
+        if carried:
+            m_prev = m_scr[:]
+            m_new = jnp.maximum(m_prev, m_new)
+            alpha = jnp.exp(m_prev - m_new)  # (block_q, 1): 1-lane exps
         p = jnp.exp(s - m_new)
         if masked and (pad or window is not None):
             # Padding — and sliding windows — can leave a row with no
@@ -319,14 +344,19 @@ def _fwd_kernel(
             # executed row has a finite m_new, so exp(NEG_INF - m_new)
             # already underflows to exactly 0 and the select is waste.
             p = jnp.where(mask, p, 0.0)
-        l_scr[:] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        m_scr[:] = m_new
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+        l_new = jnp.sum(p, axis=1, keepdims=True)
+        pv = jax.lax.dot_general(
             p.astype(v_ref.dtype),
             v_ref[0, 0],
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+        if carried:
+            l_scr[:] = l_scr[:] * alpha + l_new
+            m_scr[:] = m_new
+            acc_scr[:] = acc_scr[:] * alpha + pv
+        else:
+            _write(m_new, l_new, pv)
 
     _dispatch_block(
         iq, jk, _accumulate, causal=causal, pad=pad, block_q=block_q,
@@ -334,21 +364,17 @@ def _fwd_kernel(
         q_offset=q_offset,
     )
 
-    @pl.when(jk == num_kv - 1)
-    def _finalize():
-        l = l_scr[:]
-        l_safe = jnp.maximum(l, 1e-30)  # fully-masked rows (padding)
-        o_ref[0, 0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
-        # lse stored as a [block_q, 1] column: native sublane layout,
-        # read back broadcast-ready in the backward kernel.
-        lse_ref[0, 0] = m_scr[:] + jnp.log(l_safe)
+    if carried:
+        @pl.when(jk == num_kv - 1)
+        def _finalize():
+            _write(m_scr[:], l_scr[:], acc_scr[:])
 
 
 def _fwd(q, k, v, causal, window, scale, block_q, block_k, seq_len,
          interpret, q_offset=0):
     """q: [B, H, Tq, D]; k/v: [B, H, Tk, D] (each padded to its block
     multiple — Tq == Tk for the square call). Returns (o [B,H,Tq,D],
-    lse [B,H,Tq,1]). ``seq_len`` is the true KEY length: keys beyond
+    lse [B,H,1,Tq]). ``seq_len`` is the true KEY length: keys beyond
     it are masked out. ``q_offset`` is the global position of q row 0
     (causal/window comparisons happen in key coordinates)."""
     b, h, tq, d = q.shape
@@ -367,7 +393,7 @@ def _fwd(q, k, v, causal, window, scale, block_q, block_k, seq_len,
         pad=seq_len < tk,
         q_offset=q_offset,
     )
-    return pl.pallas_call(
+    o, lse = pl.pallas_call(
         kernel,
         grid=(b, h, num_q, num_kv),
         in_specs=[
@@ -403,6 +429,12 @@ def _fwd(q, k, v, causal, window, scale, block_q, block_k, seq_len,
         # encloses the call (remat, shard_map, a scope).
         name="flash_attention_fwd",
     )(q, k, v)
+    # The kernel's column is lane-padded 128 times in HBM; everything
+    # after this line holds the rows along the lanes. (A row written
+    # by the kernel itself measured 0.5% faster for GPT-2 and 2.1%
+    # slower for Granite, whose scan bodies XLA then lays out
+    # otherwise: PERF.md, PR 35.)
+    return o, lse[..., 0][:, :, None]
 
 
 # ---------------------------------------------------------------------------
@@ -415,8 +447,8 @@ def _bwd_kernel(
     k_ref,      # (1, 1, block_k, d)
     v_ref,
     do_ref,     # (1, 1, block_q, d)
-    lse_ref,    # (1, 1, block_q, 1)
-    delta_ref,  # (1, 1, block_q, 1)
+    lse_ref,    # (1, 1, 1, block_q)
+    delta_ref,  # (1, 1, 1, block_q)
     dq_ref,     # (1, 1, t, d) — whole-sequence block, written once
     dk_ref,     # (1, 1, block_k, d)
     dv_ref,
@@ -452,48 +484,50 @@ def _bwd_kernel(
         k = k_ref[0, 0]
         v = v_ref[0, 0]
         do = do_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+        # Every tile below is key-major, [block_k, block_q]: the
+        # [1, block_q] rows of lse and delta broadcast down the
+        # sublanes, and dV and dK are plain products.
+        # S^T = K Q^T
+        st = jax.lax.dot_general(
+            k, q, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         if scale != 1.0:
-            s = s * scale
-        lse = lse_ref[0, 0]  # (block_q, 1)
-        p = jnp.exp(s - lse)
+            st = st * scale
+        pt = jnp.exp(st - lse_ref[0, 0])
         if masked:
             mask = _block_mask(
                 iq, jk, block_q, block_k, causal, seq_len, pad,
-                window, q_offset,
+                window, q_offset, key_major=True,
             )
-            p = jnp.where(mask, p, 0.0)
-        pt = p.astype(do.dtype)
+            pt = jnp.where(mask, pt, 0.0)
         # dV += P^T dO
         dv_scr[:] += jax.lax.dot_general(
-            pt, do, (((0,), (0,)), ((), ())),
+            pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        # dP = dO V^T ; dS = P * (dP - delta) * scale
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
+        # dP^T = V dO^T ; dS^T = P^T * (dP^T - delta) * scale
+        dpt = jax.lax.dot_general(
+            v, do, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        delta = delta_ref[0, 0]
         # delta folds BOTH cotangents: rowsum(dO*O) from the output
         # and -g_lse from the logsumexp (dlse/ds_j = p_j), see _bwd.
-        ds = p * (dp - delta)
+        dst = pt * (dpt - delta_ref[0, 0])
         if scale != 1.0:
-            ds = ds * scale
-        ds = ds.astype(q.dtype)
+            dst = dst * scale
+        dst = dst.astype(q.dtype)
         # dK += dS^T Q
         dk_scr[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
+            dst, q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        # dQ[iq] += dS K — accumulated across the outer kv loop in the
-        # full-sequence scratch (no second recompute pass).
+        # dQ[iq] += dS K, the one product that contracts over the
+        # tiles' leading dimension — accumulated across the outer kv
+        # loop in the full-sequence scratch (no second recompute pass).
         sl = pl.dslice(iq * block_q, block_q)
         dq_scr[sl, :] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
+            dst, k, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
@@ -523,10 +557,8 @@ def _bwd(
     num_kv = tk // block_k
     pad = seq_len < tk
     delta = jnp.sum(
-        do.astype(jnp.float32) * o.astype(jnp.float32),
-        axis=-1,
-        keepdims=True,
-    )  # [B, H, T, 1]; XLA fuses this rowsum
+        do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
+    )[:, :, None]  # [B, H, 1, T] like lse; XLA fuses this rowsum
     if g_lse is not None:
         # lse cotangent: dlse/ds_j = p_j, so dS gains p * g_lse — the
         # same rank-1 shape as the delta term, folded in host-side.
@@ -557,10 +589,10 @@ def _bwd(
                          lambda b, h, j, i: (b, h, j, 0)),
             pl.BlockSpec((1, 1, block_q, d),
                          lambda b, h, j, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_q, 1),
-                         lambda b, h, j, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_q, 1),
-                         lambda b, h, j, i: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, 1, block_q),
+                         lambda b, h, j, i: (b, h, 0, i)),
+            pl.BlockSpec((1, 1, 1, block_q),
+                         lambda b, h, j, i: (b, h, 0, i)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, tq, d), lambda b, h, j, i: (b, h, 0, 0)),
@@ -610,9 +642,10 @@ def _kept(o, lse):
     (accelerate/remat.py names them), and the kernel's layout of ``o``
     back from that: (o [B,H,T,D], kept o, kept lse).
 
-    ``lse`` is kept as ``[B, H, T]``, rows along the lanes: the
-    kernel's ``[B, H, T, 1]`` column takes 128 times its bytes in HBM
-    (113 MB a layer at GPT-2's shape against 0.9). ``o`` is kept as
+    ``lse`` is kept as ``_fwd`` returns it and the backward kernel
+    reads it, ``[B, H, 1, T]`` with the rows along the lanes (0.9 MB
+    a layer at GPT-2's shape; a ``[B, H, T, 1]`` column is padded to
+    113). ``o`` is kept as
     the kernel wrote it where the head size fills the lanes; at a
     smaller head size ``[B, H, T, D]`` is padded too (twice the bytes
     at 64: 0.34 GB of GPT-2's step, and slower than the transposition
@@ -620,7 +653,7 @@ def _kept(o, lse):
     ``[B, T, H*D]``, which is not."""
     from dlrover_tpu.accelerate.remat import FLASH_LSE, FLASH_O, keep
 
-    kept_lse = keep(lse[..., 0], FLASH_LSE)
+    kept_lse = keep(lse, FLASH_LSE)
     b, h, t, d = o.shape
     if d % _LANES == 0:
         kept_o = keep(o, FLASH_O)
@@ -668,8 +701,8 @@ def _flash_bwd(causal, window, scale, block_q, block_k, block_q_bwd,
                block_k_bwd, seq_len, interpret, q_offset, res, g):
     q, k, v, kept_o, kept_lse = res
     return _bwd(
-        q, k, v, _kernel_layout(kept_o, q.shape[1]), kept_lse[..., None],
-        g, causal, window, scale, block_q_bwd, block_k_bwd, seq_len,
+        q, k, v, _kernel_layout(kept_o, q.shape[1]), kept_lse, g,
+        causal, window, scale, block_q_bwd, block_k_bwd, seq_len,
         interpret, q_offset=q_offset,
     )
 
@@ -801,7 +834,10 @@ def flash_attention(
     ``block_q_bwd``/``block_k_bwd`` tune the backward kernel's blocks
     independently of the forward's (they default to the forward
     blocks); the backward's access pattern (kv-outer grid, dq
-    full-sequence scratch) can favor different tiles.
+    full-sequence scratch) can favor different tiles. The backward
+    reads a q block's ``lse`` and ``delta`` along the lanes, so on the
+    chip its q block is a multiple of 128 rows or the whole padded
+    sequence (Mosaic's block rule; the defaults are).
 
     ``window`` enables Mistral-style sliding-window attention: query
     i attends to keys (i-window, i], and kv blocks entirely below the
@@ -882,7 +918,7 @@ def flash_attention(
     if return_lse:
         o, lse = _per_device_flash(_flash_lse, qk, kk, vk, static)
         o = o[:, :, :t].transpose(0, 2, 1, 3)
-        return o.astype(q.dtype), lse[:, :, :t, 0]
+        return o.astype(q.dtype), lse[:, :, 0, :t]
     o = _per_device_flash(_flash, qk, kk, vk, static)
     o = o[:, :, :t].transpose(0, 2, 1, 3)
     return o.astype(q.dtype)
@@ -1003,7 +1039,7 @@ def flash_attention_rect(
     if return_lse:
         o, lse = _per_device_flash(_flash_lse, qk, kk_, vk, static)
         o = o[:, :, :tq0].transpose(0, 2, 1, 3)
-        return o.astype(q.dtype), lse[:, :, :tq0, 0]
+        return o.astype(q.dtype), lse[:, :, 0, :tq0]
     o = _per_device_flash(_flash, qk, kk_, vk, static)
     return o[:, :, :tq0].transpose(0, 2, 1, 3).astype(q.dtype)
 

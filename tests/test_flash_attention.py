@@ -294,3 +294,171 @@ def test_cfg_attn_blocks_pin_flows_to_kernel():
     out = attn(q, k, v, interpret=True)
     ref = _default_attention(q, k, v, causal=True)
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+
+
+# -- the row statistics' layout ------------------------------------------
+# lse leaves ``_fwd``, and lse and delta enter the backward kernel, as
+# [B, H, 1, T] rows along the lanes; the backward's tiles are
+# key-major so the rows broadcast as they are read. Held to plain
+# attention: o, lse and all three gradients, a cotangent on lse too.
+
+
+def _masked_scores(q, k, causal, window=None, q_offset=0, scale=None):
+    """Plain scaled scores [B, H, Tq, Tk], -1e30 where masked; q row i
+    sits at key position ``q_offset + i``."""
+    s = jnp.einsum(
+        "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
+    ) * (q.shape[-1] ** -0.5 if scale is None else scale)
+    qp = q_offset + jnp.arange(q.shape[1])[:, None]
+    kp = jnp.arange(k.shape[1])[None, :]
+    mask = jnp.ones(s.shape[-2:], bool)
+    if causal:
+        mask &= qp >= kp
+    if window is not None:
+        mask &= (qp - kp) < window
+    return jnp.where(mask[None, None], s, -1e30)
+
+
+def _dense_lse(*args, **kwargs):
+    """Plain logsumexp of ``_masked_scores``, [B, H, Tq]."""
+    return jax.nn.logsumexp(_masked_scores(*args, **kwargs), axis=-1)
+
+
+def _loss_through_o_and_lse(fn):
+    """A scalar with a cotangent on both outputs of ``fn -> (o, lse)``."""
+    def loss(*args):
+        o, lse = fn(*args)
+        return jnp.sum(jnp.sin(o)) + jnp.sum(jnp.cos(lse))
+    return loss
+
+
+LAYOUT_CASES = {
+    # t, flash keywords
+    "causal": (256, dict(causal=True, block_q=64, block_k=128)),
+    "full": (256, dict(causal=False, block_q=128, block_k=64)),
+    "window": (256, dict(causal=True, window=48, block_q=64, block_k=64)),
+    "padded": (200, dict(causal=True, block_q=64, block_k=64)),
+    "one_block": (200, dict(causal=True)),
+    "bwd_blocks": (256, dict(
+        causal=True, block_q=128, block_k=128, block_q_bwd=64,
+        block_k_bwd=32,
+    )),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LAYOUT_CASES))
+def test_o_lse_and_gradients_match_plain_attention(case):
+    t, kw = LAYOUT_CASES[case]
+    causal, window = kw["causal"], kw.get("window")
+    q, k, v = _rand_qkv(jax.random.PRNGKey(21), 2, t, 3, 32)
+
+    def flash(q, k, v):
+        return flash_attention(
+            q, k, v, interpret=True, return_lse=True, **kw
+        )
+
+    def plain(q, k, v):
+        return (
+            _default_attention(q, k, v, causal=causal, window=window),
+            _dense_lse(q, k, causal, window),
+        )
+
+    loss = _loss_through_o_and_lse
+    o, lse = flash(q, k, v)
+    want_o, want_lse = plain(q, k, v)
+    assert lse.shape == (2, 3, t) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(o, want_o, atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(lse, want_lse, atol=2e-5, rtol=2e-5)
+    got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(plain), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=5e-5, rtol=1e-4)
+
+
+def _flash_pallas_calls(jaxpr):
+    """{name: equation} of the flash ``pallas_call``s in the jaxpr."""
+    from tests.test_remat_policies import _eqns
+
+    return {
+        str(eqn.params["name"]): eqn for eqn in _eqns(jaxpr)
+        if eqn.primitive.name == "pallas_call"
+    }
+
+
+@pytest.mark.parametrize("return_lse", [False, True])
+@pytest.mark.parametrize("rect", [False, True])
+def test_rows_from_the_forward_kernel_to_the_backward_one(return_lse, rect):
+    """The chip pads a buffer's minor dimension to 128 lanes: a
+    [B, H, T, 1] statistic is 128 times its bytes in HBM. The backward
+    kernel takes lse and delta as [B, H, 1, T] rows, whichever entry
+    point and custom_vjp the call came through; the one column left
+    is the forward kernel's own result, compacted to a row at once
+    (PERF.md, PR 35, says why that one stays)."""
+    from dlrover_tpu.ops.flash_attention import flash_attention_rect
+    from tests.test_remat_policies import _eqns
+
+    b, t, h, d = 2, 128, 3, 32
+    q, k, v = _rand_qkv(jax.random.PRNGKey(22), b, t, h, d)
+    fn = flash_attention_rect if rect else flash_attention
+
+    def loss(q, k, v):
+        out = fn(
+            q, k, v, causal=True, block_q=64, block_k=64,
+            interpret=True, return_lse=return_lse,
+        )
+        return sum(jnp.sum(x) for x in jax.tree.leaves(out))
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+    calls = _flash_pallas_calls(jaxpr.jaxpr)
+    assert sorted(calls) == ["flash_attention_bwd", "flash_attention_fwd"]
+    bwd = calls["flash_attention_bwd"]
+    shapes = [x.aval.shape for x in (*bwd.invars, *bwd.outvars)]
+    assert not [s for s in shapes if s[-1] == 1], shapes
+    assert len([s for s in shapes if s == (b, h, 1, t)]) == 2, shapes
+    columns = [
+        eqn.primitive.name for eqn in _eqns(jaxpr.jaxpr)
+        for x in eqn.outvars if x.aval.shape == (b, h, t, 1)
+    ]
+    # The kernel's result and the slice that drops its unit lane.
+    assert columns == ["pallas_call", "slice"], columns
+
+
+def test_bwd_vmem_limit_charges_rows_not_lane_padded_columns():
+    """Two row blocks of 1024 float32 (8 sublanes each, double
+    buffered) are 128 KiB, not the 2 MiB two lane-padded columns
+    were: the 8k backward still declares its need, by that much less."""
+    from dlrover_tpu.ops.flash_attention import _bwd_vmem_limit
+
+    assert _bwd_vmem_limit(1024, 64, 2, 1024, 1024) is None
+    need = _bwd_vmem_limit(8192, 128, 2, 1024, 1024)
+    dq = 8192 * 128 * (4 + 2 * 2)
+    blocks = 2 * 2 * 3 * 1024 * 128 * 2 + 2 * 1024 * 128 * 4
+    rows = 2 * 2 * 8 * 1024 * 4
+    assert need == dq + blocks + rows + 2 * 1024 * 1024 * 4
+
+
+@pytest.mark.parametrize(
+    "t,window,carried", [(128, None, False), (256, None, True),
+                         (128, 48, True)],
+)
+def test_one_kv_block_carries_nothing(t, window, carried):
+    """A sequence of one kv block with no band: the block's softmax is
+    the row's, so the forward kernel neither fills nor reads its
+    running (max, sum, acc) scratch, and writes ``o`` and ``lse`` from
+    the block's own values (GPT-2's T=1024 is one 1024-block: there
+    the finalize is paid every grid step, so nothing amortises it).
+    Two kv blocks, or a band that can skip the one, carry as before."""
+    q, k, v = _rand_qkv(jax.random.PRNGKey(23), 1, t, 2, 32)
+    jaxpr = jax.make_jaxpr(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window, block_q=128, block_k=128,
+        interpret=True,
+    ))(q, k, v)
+    (eqn,) = _flash_pallas_calls(jaxpr.jaxpr).values()
+    kernel = eqn.params["jaxpr"]
+    scratch = set(kernel.invars[-3:])  # m, l, acc
+    assert all(len(s.aval.shape) == 2 for s in scratch)
+    touched = [
+        e.primitive.name for e in kernel.eqns
+        if scratch & {x for x in e.invars if not hasattr(x, "val")}
+    ]
+    assert bool(touched) == carried, touched

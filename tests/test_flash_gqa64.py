@@ -15,6 +15,7 @@ import pytest
 from dlrover_tpu.accelerate import remat
 from dlrover_tpu.models import gpt, llama
 from dlrover_tpu.ops.flash_attention import flash_attention
+from tests.test_flash_attention import _dense_lse, _loss_through_o_and_lse
 
 B, T, H, HKV, D = 2, 256, 32, 8, 64
 SCALE = 1.0 / 64
@@ -113,6 +114,41 @@ def test_kept_o_is_in_the_models_layout_and_compact_kv_gets_its_gradient(case):
 
     found = names(jaxpr.jaxpr, set())
     assert ("flash_o", (B, T, H * D)) in found
-    assert ("flash_lse", (B, H, T)) in found
+    assert ("flash_lse", (B, H, 1, T)) in found
     assert ("attn_in", (B, T, HKV * D)) in found  # k, v kept compact
     assert not [s for n, s in found if n == "flash_o" and len(s) == 4]
+
+
+def test_lse_and_its_cotangent_at_head_size_64_with_grouped_queries(case):
+    """``return_lse=True`` at this call's shape (32 query heads over 8
+    key-value heads, scale 1/64): lse against plain attention's, and
+    dq, dk, dv with a cotangent on lse, which the backward kernel
+    takes folded into its ``delta`` row."""
+    _, _, h, _ = case
+    q = h[..., : H * D].reshape(B, T, H, D)
+    kv = h[..., : HKV * D].reshape(B, T, HKV, D)
+    repeat = functools.partial(jnp.repeat, repeats=H // HKV, axis=2)
+
+    def plain(q, kv):
+        k = repeat(kv)
+        return (
+            gpt._default_attention(q, k, k, scale=SCALE),
+            _dense_lse(q, k, True, scale=SCALE),
+        )
+
+    def flash(q, kv):
+        k = repeat(kv)
+        return flash_attention(
+            q, k, k, scale=SCALE, interpret=True, return_lse=True
+        )
+
+    loss = _loss_through_o_and_lse
+    with jax.default_matmul_precision("highest"):
+        lse = flash(q, kv)[1]
+        want_lse = plain(q, kv)[1]
+        got = jax.jit(jax.grad(loss(flash), argnums=(0, 1)))(q, kv)
+        want = jax.jit(jax.grad(loss(plain), argnums=(0, 1)))(q, kv)
+    assert lse.shape == (B, H, T)
+    np.testing.assert_allclose(lse, want_lse, atol=2e-5, rtol=2e-5)
+    for a, b in zip(got, want):
+        assert _rel(a, b) < 2e-5

@@ -15,6 +15,10 @@ from dlrover_tpu.ops.flash_attention import (
     flash_attention,
     flash_attention_rect,
 )
+from tests.test_flash_attention import (
+    _loss_through_o_and_lse,
+    _masked_scores,
+)
 
 
 def _qkv(key, tq, tk, b=2, h=3, d=16):
@@ -170,3 +174,44 @@ def test_rect_window_matches_dense():
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), atol=2e-5, rtol=1e-4
     )
+
+
+@pytest.mark.parametrize(
+    "tq,tk,offset,window",
+    [
+        (24, 64, None, None),   # the tail of the keys, both sides pad
+        (32, 96, 16, None),     # a chunk in the middle
+        (17, 51, None, None),   # odd sizes
+        (32, 96, None, 24),     # a band across the offset
+    ],
+)
+def test_rect_o_lse_and_gradients_with_a_cotangent_on_lse(
+    tq, tk, offset, window
+):
+    """The rectangular call's row statistics ([B, H, 1, Tq] between
+    the kernels, q rows at ``q_offset``): o, lse and dq, dk, dv
+    against plain attention with a cotangent on both outputs."""
+    q, k, v = _qkv(jax.random.PRNGKey(5), tq, tk, b=2, h=2, d=16)
+    eff = (tk - tq) if offset is None else offset
+
+    def plain(q, k, v):
+        s = _masked_scores(q, k, True, window, q_offset=eff)
+        o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
+        return o, jax.scipy.special.logsumexp(s, axis=-1)
+
+    def flash(q, k, v):
+        return flash_attention_rect(
+            q, k, v, causal=True, q_offset=offset, window=window,
+            block_q=16, block_k=32, interpret=True, return_lse=True,
+        )
+
+    loss = _loss_through_o_and_lse
+    o, lse = flash(q, k, v)
+    want_o, want_lse = plain(q, k, v)
+    assert lse.shape == (2, 2, tq)
+    np.testing.assert_allclose(o, want_o, atol=2e-5, rtol=1e-4)
+    np.testing.assert_allclose(lse, want_lse, atol=2e-5, rtol=1e-5)
+    got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(plain), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=5e-5, rtol=1e-3)

@@ -109,18 +109,24 @@ class TestRematPolicies:
         ).name()
 
 
-def _flash_calls(jaxpr, acc):
-    """Name of every flash ``pallas_call`` in the jaxpr, recursively
-    (an expert layer's grouped products are Pallas calls too)."""
+def _eqns(jaxpr):
+    """Every equation of the jaxpr and of the jaxprs inside it."""
     for eqn in jaxpr.eqns:
-        name = str(eqn.params.get("name", ""))
-        if eqn.primitive.name == "pallas_call" and "flash" in name:
-            acc.append(name)
+        yield eqn
         for v in eqn.params.values():
             for x in v if isinstance(v, (tuple, list)) else [v]:
                 x = getattr(x, "jaxpr", x)
                 if hasattr(x, "eqns"):
-                    _flash_calls(x, acc)
+                    yield from _eqns(x)
+
+
+def _flash_calls(jaxpr, acc):
+    """Name of every flash ``pallas_call`` in the jaxpr, recursively
+    (an expert layer's grouped products are Pallas calls too)."""
+    for eqn in _eqns(jaxpr):
+        name = str(eqn.params.get("name", ""))
+        if eqn.primitive.name == "pallas_call" and "flash" in name:
+            acc.append(name)
     return acc
 
 
@@ -185,12 +191,13 @@ E, H, F = 32, 4, 96
 F32 = "float32"
 # What "full" keeps of a block, per family: x (the block's input),
 # flash_o in the model's layout at these head sizes (the same shape
-# as x), flash_lse [B, H, T], and the named products. No third
+# as x), flash_lse [B, H, 1, T] as the kernels write and read it, and
+# the named products. No third
 # [B, T, E]: that would be the out-projection's output.
 KEPT_SHAPES = {
     "gpt": sorted([
         ((B, T, 32), F32), ((B, T, 32), F32),   # x, flash_o
-        ((B, 2, T), F32),                       # flash_lse, 2 heads
+        ((B, 2, 1, T), F32),                    # flash_lse, 2 heads
         ((B, T, 96), F32),                      # qkv
         ((B, T, 128), F32),                     # the wi product
     ]),
@@ -198,13 +205,13 @@ KEPT_SHAPES = {
         ((B, T, E), F32), ((B, T, E), F32),     # x, flash_o
         ((B, T, E), F32),                       # q
         ((B, T, E // 2), F32), ((B, T, E // 2), F32),  # k, v: 2 of 4 heads
-        ((B, H, T), F32),                       # flash_lse
+        ((B, H, 1, T), F32),                    # flash_lse
         ((B, T, F), F32), ((B, T, F), F32),     # gate, up
     ]),
     "moe": sorted([
         ((B, T, E), F32), ((B, T, E), F32), ((B, T, E), F32),
         ((B, T, E // 2), F32), ((B, T, E // 2), F32),
-        ((B, H, T), F32),
+        ((B, H, 1, T), F32),
         ((B * T, 4), F32),                      # router logits
     ]),
 }
@@ -240,13 +247,31 @@ class TestFullKeepsTheFlashOutputs:
         assert got == KEPT_SHAPES[family], got
 
     def test_kept_lse_is_compact(self):
-        """[B, H, T], rows along the lanes: the kernel's [B, H, T, 1]
-        column is padded to 128 lanes in the chip's memory."""
+        """[B, H, 1, T], rows along the lanes, as the forward kernel
+        wrote it: a [B, H, T, 1] column would be padded to 128 lanes
+        in the chip's memory."""
         shapes = [
             s for s, _ in _stacked_residuals(_grad_jaxpr("gpt", "full"))
         ]
-        assert (B, 2, T) in shapes
+        assert (B, 2, 1, T) in shapes
         assert not [s for s in shapes if s[-1] == 1]
+
+    @pytest.mark.parametrize("family", FAMILIES)
+    def test_no_column_between_the_kernels(self, family):
+        """Out of the forward kernel, through the scan's stack, into
+        the backward kernel the rows stay rows: the only
+        [B, H, T, 1] value of the block's gradient is the forward
+        kernel's own result (compacted at once); XLA spent two
+        copies a layer on the backward's columns."""
+        jaxpr = _grad_jaxpr(family, "full")
+        heads = 2 if family == "gpt" else H
+        columns = [
+            eqn.primitive.name
+            for eqn in _eqns(jaxpr) for v in eqn.outvars
+            if v.aval.shape[-3:] == (heads, T, 1)
+        ]
+        # The kernel's result and the slice that drops its unit lane.
+        assert columns == ["pallas_call", "slice"], columns
 
     def test_o_kept_as_the_kernel_wrote_it_at_head_size_128(self):
         """Where the head size fills the chip's 128 lanes the kernel's
@@ -257,7 +282,7 @@ class TestFullKeepsTheFlashOutputs:
         assert _stacked_residuals(jaxpr) == sorted([
             ((B, T, 128), F32),                  # x alone
             ((B, 1, T, 128), F32),               # flash_o, one head
-            ((B, 1, T), F32),                    # flash_lse
+            ((B, 1, 1, T), F32),                 # flash_lse
             ((B, T, 384), F32), ((B, T, 512), F32),
         ])
         calls = _flash_calls(jaxpr, [])

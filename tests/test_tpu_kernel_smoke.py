@@ -41,3 +41,37 @@ def test_float32_check_refuses_a_broken_scan(monkeypatch, name, attribute, broke
     got = _scan_checks(monkeypatch)
     assert not got["ssd_fwd_bwd_f32"]["ok"], (name, got)
     assert "relative error" in got["ssd_fwd_bwd_f32"]["error"]
+
+
+def _flash_checks(monkeypatch):
+    monkeypatch.setattr(tpu_kernel_smoke, "SEQ", 128)
+    tpu_kernel_smoke.RESULTS.clear()
+    tpu_kernel_smoke.flash_checks()
+    return {r["kernel"]: r for r in tpu_kernel_smoke.RESULTS}
+
+
+def test_flash_checks_pass_and_hold_lse(monkeypatch):
+    got = _flash_checks(monkeypatch)
+    assert all(r["ok"] for r in got.values()), got
+    assert got["flash_lse_fwd_bwd"]["max_abs_err"] < 1e-4
+
+
+def test_lse_check_refuses_a_backward_that_drops_the_lse_cotangent(
+    monkeypatch,
+):
+    """``flash_lse_fwd_bwd`` is the one check that reads ``lse`` and
+    sends a cotangent through it (the backward kernel's ``delta``
+    row): a backward that loses that cotangent passes every other
+    flash check and fails this one."""
+    # ``dlrover_tpu.ops.flash_attention`` the attribute is the
+    # re-exported function; the module is in sys.modules.
+    fa = sys.modules["dlrover_tpu.ops.flash_attention"]
+    real = fa._bwd
+
+    def without_g_lse(*args, g_lse=None, **kwargs):
+        return real(*args, g_lse=None, **kwargs)
+
+    monkeypatch.setattr(fa, "_bwd", without_g_lse)
+    got = _flash_checks(monkeypatch)
+    assert not got.pop("flash_lse_fwd_bwd")["ok"]
+    assert all(r["ok"] for r in got.values()), got

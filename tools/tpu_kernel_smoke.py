@@ -157,6 +157,38 @@ def flash_checks():
                 q, k, v, atol=2e-2,
             ),
         )
+    # The row statistics themselves: lse, and gradients with a
+    # cotangent on lse (folded into delta, the backward kernel's other
+    # row operand). No other check reads lse, and how the backward's
+    # key-major tiles take a [1, block_q] row is Mosaic's, which
+    # interpret mode does not run.
+    def dense_lse(q_, k_, v_):
+        s = jnp.einsum(
+            "bqhd,bkhd->bhqk", q_, k_,
+            preferred_element_type=jnp.float32,
+        ) / (q_.shape[-1] ** 0.5)
+        pos = jnp.arange(q_.shape[1])
+        s = jnp.where(pos[:, None] >= pos[None, :], s, -1e30)
+        return dense(q_, k_, v_, True), jax.nn.logsumexp(s, axis=-1)
+
+    def flash_lse(q_, k_, v_):
+        return flash_attention(q_, k_, v_, causal=True, return_lse=True)
+
+    def with_lse_cotangent(f):
+        def out(*a):
+            o, lse = f(*a)
+            return o + jnp.sin(lse).transpose(0, 2, 1)[..., None]
+        return out
+
+    def lse_check():
+        _close(flash_lse(q, k, v)[1], dense_lse(q, k, v)[1], 2e-3)
+        grad_check(
+            with_lse_cotangent(flash_lse), with_lse_cotangent(dense_lse),
+            q, k, v, atol=2e-2,
+        )
+
+    with _prec("f32"):
+        check("flash_lse_fwd_bwd", lse_check)
     # Sliding window (Mistral band) + non-1024 sequence (512 tiles),
     # gradients included (the banded bwd has its own dispatch) — in
     # bf16 too (the production decode dtype; its tile floors are 2x
