@@ -218,10 +218,14 @@ def _default_attention(q, k, v, causal=True, window=None, scale=None):
 def _block(x, lp, cfg: GPTConfig, attn_fn):
     """One transformer block. lp = this layer's param slice.
 
-    The ``jax.named_scope`` annotations are load-bearing: the
-    module profiler (utils/module_profiler.py) attributes FLOPs /
-    bytes per scope from the jaxpr, feeding the strategy engine's
-    roofline prior and the TP planner's per-edge costs."""
+    The ``jax.named_scope`` annotations are load-bearing, for two
+    readers: the module profiler (utils/module_profiler.py)
+    attributes FLOPs / bytes per scope from the jaxpr, feeding the
+    strategy engine's roofline prior and the TP planner's per-edge
+    costs; ``obs.profiling.compiled_scopes`` reads them back from the
+    compiled step's ``op_name`` metadata, so that a device profile
+    can be read by scope (docs/OBSERVABILITY.md, "Device time by
+    scope")."""
     # What remat="full" keeps is named here (accelerate/remat.py
     # KEPT): the projection into attention and the MLP's hidden
     # product. ``att @ wo`` is not: it is recomputed from the flash
@@ -321,9 +325,13 @@ def backbone(
     def scan_body(x, lp):
         return block(x, lp), None
 
-    x, _ = jax.lax.scan(
-        scan_body, x, params["blocks"], unroll=cfg.scan_unroll
-    )
+    # "layers" owns what the scan itself costs (slicing the stacked
+    # parameters, stacking residuals, the while): obs.profiling
+    # compiled_scopes puts device time down to it.
+    with jax.named_scope("layers"):
+        x, _ = jax.lax.scan(
+            scan_body, x, params["blocks"], unroll=cfg.scan_unroll
+        )
     return _layer_norm(x, params["lnf_g"], params["lnf_b"])
 
 

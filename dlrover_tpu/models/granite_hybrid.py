@@ -394,13 +394,15 @@ def backbone(
             if n == 1:
                 x = layer[kind](x, jax.tree.map(lambda a: a[0], lp))
             else:
-                x, _ = jax.lax.scan(
-                    lambda x, lp, kind=kind: (layer[kind](x, lp), None),
-                    x, lp,
-                )
+                with jax.named_scope("layers"):
+                    x, _ = jax.lax.scan(
+                        lambda x, lp, kind=kind: (layer[kind](x, lp), None),
+                        x, lp,
+                    )
         return x, None
 
-    x, _ = jax.lax.scan(one_period, x, params["runs"])
+    with jax.named_scope("layers"):
+        x, _ = jax.lax.scan(one_period, x, params["runs"])
     return llama._rms_norm(x, params["rmsf"], cfg.rms_eps)
 
 

@@ -537,9 +537,10 @@ def backbone_with_aux(
         x, aux = block(x, lp)
         return (x, aux_sum + aux), None
 
-    (x, aux), _ = jax.lax.scan(
-        scan_body, (x, jnp.zeros((), jnp.float32)), params["blocks"]
-    )
+    with jax.named_scope("layers"):
+        (x, aux), _ = jax.lax.scan(
+            scan_body, (x, jnp.zeros((), jnp.float32)), params["blocks"]
+        )
     return _rms_norm(x, params["rmsf"], cfg.rms_eps), aux
 
 

@@ -355,12 +355,17 @@ class ElasticTrainer:
                 )
                 return (grad_acc, loss_acc + loss), None
 
-            zeros = jax.tree.map(
-                lambda p: jnp.zeros(p.shape, acc_dtype), params
-            )
-            (grads, loss_sum), _ = jax.lax.scan(
-                micro, (zeros, 0.0), (tokens, targets)
-            )
+            # "accumulate" owns the microbatch scan's own cost (the
+            # accumulator's zeros and scaled add, the slicing, the
+            # while); the model's scopes inside it keep theirs
+            # (obs.profiling.compiled_scopes).
+            with jax.named_scope("accumulate"):
+                zeros = jax.tree.map(
+                    lambda p: jnp.zeros(p.shape, acc_dtype), params
+                )
+                (grads, loss_sum), _ = jax.lax.scan(
+                    micro, (zeros, 0.0), (tokens, targets)
+                )
             with jax.named_scope("optimizer"):
                 updates, opt_state = optimizer.update(
                     grads, opt_state, params
@@ -438,12 +443,13 @@ class ElasticTrainer:
                 )
                 return (grad_acc, loss_acc + loss), None
 
-            zeros = jax.tree.map(
-                lambda p: jnp.zeros(p.shape, acc_dtype), params
-            )
-            (grads, loss_sum), _ = jax.lax.scan(
-                micro, (zeros, 0.0), (tokens, targets)
-            )
+            with jax.named_scope("accumulate"):
+                zeros = jax.tree.map(
+                    lambda p: jnp.zeros(p.shape, acc_dtype), params
+                )
+                (grads, loss_sum), _ = jax.lax.scan(
+                    micro, (zeros, 0.0), (tokens, targets)
+                )
             # Per-shard losses are local means; pmean makes the
             # returned scalar the global-batch mean, matching the
             # serial step's replicated loss.
@@ -646,15 +652,16 @@ class ElasticTrainer:
                     self._compiled, params, opt_state, tokens, targets
                 )
             )
+        args = (params, opt_state, tokens, targets)
         t0 = time.perf_counter()
         with obs.span(
             "trainer.dispatch", step=self.step_num + 1
         ) as span:
-            params, opt_state, loss = self._compiled(
-                params, opt_state, tokens, targets
-            )
+            params, opt_state, loss = self._compiled(*args)
             now = time.perf_counter()
-            compiled_now = self._compile_tracker.observe_call(now - t0)
+            compiled_now = self._compile_tracker.observe_call(
+                now - t0, args
+            )
             span.set(compiled=compiled_now)
         if self.profiler is not None:
             self.profiler.note_dispatch(now - t0, compiled=compiled_now)

@@ -103,8 +103,14 @@ def _conv_flops(eqn) -> float:
 # 'rematted_computation' is the scope jax.checkpoint's transposition
 # inserts around the recompute; cost-wise it belongs to the original
 # module scopes nested under it.
+# 'layers' and 'accumulate' are the program's own scopes around its
+# scans (models/, trainer/elastic_trainer.py), there for the device
+# profile's reader (obs.profiling.compiled_scopes); here cost belongs
+# to the module scopes inside them, under the keys it always had.
 _TRANSFORM_RE = re.compile(r"\b(?:jvp|transpose|vmap|mask)\(")
-_SYNTH_SCOPES = ("rematted_computation", "checkpoint")
+_SYNTH_SCOPES = (
+    "rematted_computation", "checkpoint", "layers", "accumulate",
+)
 
 
 def _user_scope(name_stack: Any) -> str:

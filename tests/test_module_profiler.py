@@ -98,6 +98,13 @@ class TestAttribution:
         )
         for scope in ("head", "mlp", "attn", "embed"):
             assert scope in costs, costs.keys()
+        # The scan's own scope ("layers", there for the device
+        # profile's reader) moves no key: a block's cost is its
+        # module's, under the name the strategy engine knows.
+        assert "layers" not in costs
+        nested = profile_modules(loss, params, tok, tok, grad=True)
+        assert "attn" in nested and "mlp" in nested
+        assert not any("layers" in k.split("/") for k in nested)
         # nano GPT: the vocab head dominates, mlp has 2x the matmul
         # volume of attention projections.
         assert costs["head"].flops > costs["mlp"].flops
