@@ -40,7 +40,13 @@ one Pallas kernel family designed for the MXU:
   delta = rowsum(dO * O) is precomputed by XLA, as a row like lse. The
   backward holds its score tile key-major (s^T = k q^T), so a
   [1, block_q] row of lse or delta broadcasts down the sublanes as it
-  is read and no statistic changes layout inside the loop.
+  is read and no statistic changes layout inside the loop. A block the
+  mask crosses runs as sub-tiles (``_BWD_SPLIT`` a side), and only
+  those that hold a live pair: two rolled loops whose bounds are
+  integer arithmetic on the block's indices, so the arithmetic is
+  traced once more, not once a sub-tile (event ``flash.bwd_area``
+  says, once a traced backward, what area a head visits, runs and
+  needs). GPT-2's one 1024 x 1024 block runs 3 of its 4 squares.
 
 On non-TPU backends kernels run in interpreter mode so the same code
 path is unit-testable on CPU.
@@ -57,6 +63,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
+
+from dlrover_tpu import obs
 
 NEG_INF = -1e30
 
@@ -158,6 +166,10 @@ def per_device(call, *operands, split, heads_dim=None, summed=(),
         check_vma=False,
     )(*operands)
 
+
+# The chip pads the minor dimension of a buffer in HBM to this many
+# lanes.
+_LANES = 128
 
 # Mosaic's default scoped-VMEM budget for one kernel on a v5e; a
 # kernel that needs more must say so in its compiler parameters.
@@ -442,6 +454,107 @@ def _fwd(q, k, v, causal, window, scale, block_q, block_k, seq_len,
 # ---------------------------------------------------------------------------
 
 
+# Sub-tiles along each side of a backward block that the mask crosses
+# (the diagonal, the band's edge, the key padding): the kernel runs
+# only those of its n x n sub-tiles that hold a live (query, key)
+# pair. 2 is 512 x 512 on the chip's 1024 blocks, 3 of GPT-2's 4.
+# Chosen on the chip (PERF.md, PR 39): GPT-2's backward kernel takes
+# 15.88 ms a step at 2 and 24.07 at 4 (256 x 256, 10 of 16 sub-tiles),
+# 20.79 whole.
+_BWD_SPLIT = 2
+
+
+def _bwd_sub(block_q, block_k, interpret):
+    """The side of a crossing backward block's sub-tiles: the blocks'
+    common divisor split :data:`_BWD_SPLIT` ways, or fewer where that
+    leaves no whole number of lanes (the kernel slices a q block's
+    ``lse`` and ``delta`` rows along the lanes; interpreted, any
+    multiple of 8 does). The whole block where nothing does."""
+    whole = math.gcd(block_q, block_k)
+    align = 8 if interpret else _LANES
+    n = _BWD_SPLIT
+    while n > 1 and (whole % n or (whole // n) % align):
+        n //= 2
+    return whole // n
+
+
+def _live_q_tiles(k0, q0, sub, n_q, causal, window, seq_len):
+    """[lo, hi): the query sub-tiles b (rows ``q0 + b * sub`` on, in key
+    coordinates) that hold a live pair with the keys ``k0`` to
+    ``k0 + sub - 1``, of which only those below ``seq_len`` are real
+    (None: all). The differences row - key over such a tile are every
+    whole number between their extremes, so the tile is live when that
+    range meets [0, window). On Python ints (:func:`bwd_area`) and on
+    the kernel's traced scalars alike."""
+    ints = isinstance(k0, int) and isinstance(q0, int)
+    most, least = (max, min) if ints else (jnp.maximum, jnp.minimum)
+    k_end = k0 + sub if seq_len is None else least(k0 + sub, seq_len)
+    lo, hi = 0, n_q
+    if causal:  # the tile's last row is at or after its first key
+        lo = most(k0 - q0, 0) // sub
+    if window is not None:  # its first row sees its last real key
+        hi = least(hi, most(k_end - 1 + window - 1 - q0 + sub, 0) // sub)
+    if seq_len is not None:  # no real key, no tile
+        hi = least(hi, most(seq_len - k0, 0) * n_q)
+    return lo, hi
+
+
+def _bwd_blocks(tq, tk, block_q, block_k, sub, causal, window, seq_len,
+                q_offset=0):
+    """What one head of the backward kernel executes, from its static
+    arguments: ``(iq, jk, tiles)`` for every block ``_dispatch_block``
+    runs, ``tiles`` None where it runs whole and unmasked, else the
+    live sub-tiles ``(b, a)`` (query, key) of a block the mask
+    crosses."""
+    for iq in range(tq // block_q):
+        q0 = q_offset + iq * block_q
+        for jk in range(tk // block_k):
+            k0 = jk * block_k
+            # _dispatch_block's conditions, on ints.
+            runs = not causal or k0 <= q0 + block_q - 1
+            crosses = causal and k0 + block_k - 1 > q0
+            crosses = crosses or k0 + block_k > seq_len
+            if window is not None:
+                runs = runs and k0 + block_k - 1 >= q0 - window + 1
+                crosses = crosses or k0 < q0 + block_q - window
+            if not runs:
+                continue
+            if not crosses:
+                yield iq, jk, None
+                continue
+            tiles = []
+            for a in range(block_k // sub):
+                lo, hi = _live_q_tiles(
+                    k0 + a * sub, q0, sub, block_q // sub, causal,
+                    window, seq_len if seq_len < tk else None,
+                )
+                tiles += [(b, a) for b in range(lo, hi)]
+            yield iq, jk, tiles
+
+
+def bwd_area(tq, tk, block_q, block_k, sub, causal, window, seq_len,
+             q_offset=0):
+    """(query, key) pairs a head of one backward call: ``visited``,
+    the area of the blocks the kernel executes (all of it computed
+    before the sub-tiles); ``run``, what it computes: a block the
+    mask does not cross whole, a crossing block's live sub-tiles;
+    ``required``, the pairs the mask admits (a square causal call:
+    ``t * (t + 1) / 2``). ``tq`` and ``tk`` are the padded lengths,
+    ``seq_len`` the true key length."""
+    visited = run = 0
+    for _, _, tiles in _bwd_blocks(
+        tq, tk, block_q, block_k, sub, causal, window, seq_len, q_offset
+    ):
+        visited += block_q * block_k
+        run += block_q * block_k if tiles is None else len(tiles) * sub * sub
+    required = 0
+    for row in range(q_offset, q_offset + tq):
+        last = min(row, seq_len - 1) if causal else seq_len - 1
+        first = max(row - window + 1, 0) if window is not None else 0
+        required += max(last - first + 1, 0)
+    return {"visited": visited, "run": run, "required": required}
+
+
 def _bwd_kernel(
     q_ref,      # (1, 1, block_q, d)
     k_ref,      # (1, 1, block_k, d)
@@ -466,6 +579,7 @@ def _bwd_kernel(
     seq_len: int,
     pad: bool,
     q_offset: int,
+    sub: int,
 ):
     jk = pl.program_id(2)  # kv block (outer)
     iq = pl.program_id(3)  # q block (inner)
@@ -479,14 +593,19 @@ def _bwd_kernel(
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def _accumulate(masked: bool):
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0]
-        # Every tile below is key-major, [block_k, block_q]: the
-        # [1, block_q] rows of lse and delta broadcast down the
-        # sublanes, and dV and dK are plain products.
+    whole_q, whole_k = pl.ds(0, block_q), pl.ds(0, block_k)
+
+    def _accumulate(rows, cols, mask):
+        """dQ, dK and dV gain what the q rows ``rows`` and the keys
+        ``cols`` of this block give (``pl.ds`` slices; ``mask`` is the
+        [keys, rows] element mask, or None where every pair is live)."""
+        q = q_ref[0, 0, rows, :]
+        k = k_ref[0, 0, cols, :]
+        v = v_ref[0, 0, cols, :]
+        do = do_ref[0, 0, rows, :]
+        # Every tile below is key-major, [keys, rows]: the [1, rows]
+        # rows of lse and delta broadcast down the sublanes, and dV
+        # and dK are plain products.
         # S^T = K Q^T
         st = jax.lax.dot_general(
             k, q, (((1,), (1,)), ((), ())),
@@ -494,15 +613,11 @@ def _bwd_kernel(
         )
         if scale != 1.0:
             st = st * scale
-        pt = jnp.exp(st - lse_ref[0, 0])
-        if masked:
-            mask = _block_mask(
-                iq, jk, block_q, block_k, causal, seq_len, pad,
-                window, q_offset, key_major=True,
-            )
+        pt = jnp.exp(st - lse_ref[0, 0, :, rows])
+        if mask is not None:
             pt = jnp.where(mask, pt, 0.0)
         # dV += P^T dO
-        dv_scr[:] += jax.lax.dot_general(
+        dv_scr[cols, :] += jax.lax.dot_general(
             pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
@@ -513,26 +628,71 @@ def _bwd_kernel(
         )
         # delta folds BOTH cotangents: rowsum(dO*O) from the output
         # and -g_lse from the logsumexp (dlse/ds_j = p_j), see _bwd.
-        dst = pt * (dpt - delta_ref[0, 0])
+        dst = pt * (dpt - delta_ref[0, 0, :, rows])
         if scale != 1.0:
             dst = dst * scale
         dst = dst.astype(q.dtype)
         # dK += dS^T Q
-        dk_scr[:] += jax.lax.dot_general(
+        dk_scr[cols, :] += jax.lax.dot_general(
             dst, q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         # dQ[iq] += dS K, the one product that contracts over the
         # tiles' leading dimension — accumulated across the outer kv
         # loop in the full-sequence scratch (no second recompute pass).
-        sl = pl.dslice(iq * block_q, block_q)
+        sl = pl.ds(iq * block_q + rows.start, rows.size)
         dq_scr[sl, :] += jax.lax.dot_general(
             dst, k, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
+    def _tile_mask(b, a):
+        # Sub-tile (b, a) of this block is block (iq * n + b, jk * n + a)
+        # of a grid of ``sub`` x ``sub`` blocks.
+        return _block_mask(
+            iq * (block_q // sub) + b, jk * (block_k // sub) + a, sub,
+            sub, causal, seq_len, pad, window, q_offset, key_major=True,
+        )
+
+    def _crossing():
+        """A block the mask crosses: only its live sub-tiles, in two
+        rolled loops, so the arithmetic is traced once however many
+        sub-tiles a block has."""
+        if sub == block_q == block_k:
+            _accumulate(whole_q, whole_k, _tile_mask(0, 0))
+            return
+        q0 = q_offset + iq * block_q
+
+        def key_tiles(a, carry):
+            k0 = jk * block_k + a * sub
+            lo, hi = _live_q_tiles(
+                k0, q0, sub, block_q // sub, causal, window,
+                seq_len if pad else None,
+            )
+
+            def query_tiles(b, carry):
+                # Masked whether or not the diagonal crosses this one:
+                # a second, maskless copy of the arithmetic for the
+                # sub-tiles that are wholly live read the same time.
+                _accumulate(
+                    pl.ds(pl.multiple_of(b * sub, sub), sub),
+                    pl.ds(pl.multiple_of(a * sub, sub), sub),
+                    _tile_mask(b, a),
+                )
+                return carry
+
+            return jax.lax.fori_loop(lo, hi, query_tiles, carry)
+
+        jax.lax.fori_loop(0, block_k // sub, key_tiles, 0)
+
+    def _block(masked: bool):
+        if masked:
+            _crossing()
+        else:
+            _accumulate(whole_q, whole_k, None)
+
     _dispatch_block(
-        iq, jk, _accumulate, causal=causal, pad=pad, block_q=block_q,
+        iq, jk, _block, causal=causal, pad=pad, block_q=block_q,
         block_k=block_k, seq_len=seq_len, window=window,
         q_offset=q_offset,
     )
@@ -556,6 +716,13 @@ def _bwd(
     num_q = tq // block_q
     num_kv = tk // block_k
     pad = seq_len < tk
+    sub = _bwd_sub(block_q, block_k, interpret)
+    obs.event(
+        "flash.bwd_area", t=seq_len, block_q=block_q, block_k=block_k,
+        sub=sub, window=window,
+        **bwd_area(tq, tk, block_q, block_k, sub, causal, window,
+                   seq_len, q_offset),
+    )
     delta = jnp.sum(
         do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
     )[:, :, None]  # [B, H, 1, T] like lse; XLA fuses this rowsum
@@ -576,6 +743,7 @@ def _bwd(
         seq_len=seq_len,
         pad=pad,
         q_offset=q_offset,
+        sub=sub,
     )
     dq, dk, dv = pl.pallas_call(
         kernel,
@@ -630,11 +798,6 @@ def _bwd(
 # ---------------------------------------------------------------------------
 # custom_vjp plumbing on the [B, H, T, D] layout
 # ---------------------------------------------------------------------------
-
-
-# The chip pads the minor dimension of a buffer in HBM to this many
-# lanes.
-_LANES = 128
 
 
 def _kept(o, lse):
@@ -781,13 +944,22 @@ def _check_block_chain(blocks, t: int) -> int:
 
 
 def default_block_sizes(t: int) -> tuple:
-    """Autotuned (block_q, block_k) by sequence length (measured on
-    v5e, GPT-2 train step): 512 blocks beat 128 by ~2.5x at T=1024
-    (fewer grid steps, less per-block softmax bookkeeping), and the
-    r4 sweep (tools/autotune_bwd_blocks.py + perf_sweep) moved the
-    optimum to 1024x1024 — 158.8 ms vs 165.2 ms at 512x1024 on the
-    16x1024 step (fused norms off in both), 0.902 vs 0.867
-    vs_baseline. The f32 score tile is
+    """(block_q, block_k) by sequence length, measured on a v5e: 512
+    blocks beat 128 by ~2.5x at T=1024 (fewer grid steps, less
+    per-block softmax bookkeeping), and 1024 x 1024 beats 512 from
+    4k context up. PR 39 read the backward at both on PR 35's
+    key-major body, kernel alone (ms a call, 1024 against 512
+    backward blocks): Mistral's T=8192 with window 4096 8.16 against
+    9.12, OLMoE's T=4096 5.32 against 5.80, Granite's 2.95 against
+    3.17; GPT-2's T=1024, one block that is all diagonal, is the
+    exception, 2.58 against 2.38 and in the 18 x 1024 step
+    ``flash_bwd_ms_per_step.train`` 20.79 against 19.41, 115,766
+    against 116,753 tokens/s, because 512 blocks skip the dead
+    quarter. The backward now runs a crossing block as 512 x 512
+    sub-tiles and skips the dead ones itself (15.88 ms a step for
+    GPT-2), which smaller blocks with their own sub-tiles do not
+    beat (512 blocks, 256 sub-tiles: 2.71 ms a call against 2.17).
+    The f32 score tile is
     [block_q, block_k] (4 MB at 1024x1024), VMEM-safe alongside the
     q/k/v/o blocks at head dims up to 128. Below 1024 context the
     block covers the sequence; block_k doubles only when the
@@ -795,7 +967,7 @@ def default_block_sizes(t: int) -> tuple:
     would pad to lcm(block_q, block_k), which explodes for lengths
     like 520 (lcm(512, 520) = 33280)."""
     if t % 1024 == 0:
-        # The measured r4 optimum — only where it costs no padding
+        # The measured optimum — only where it costs no padding
         # (t=1536 would pad to 2048, +33% kernel work; t=516 would
         # yield a sublane-misaligned 516 block).
         return 1024, 1024

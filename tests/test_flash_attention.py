@@ -4,6 +4,9 @@ Runs the kernel in interpreter mode on the CPU test mesh (conftest sets
 JAX_PLATFORMS=cpu), exercising the exact code path that compiles on TPU.
 """
 
+import importlib
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,6 +14,10 @@ import pytest
 
 from dlrover_tpu.models.gpt import _default_attention
 from dlrover_tpu.ops.flash_attention import flash_attention
+
+# ``dlrover_tpu.ops.flash_attention`` the attribute is the re-exported
+# function; this is the module.
+flash_module = importlib.import_module("dlrover_tpu.ops.flash_attention")
 
 
 def _rand_qkv(key, b, t, h, d, dtype=jnp.float32):
@@ -462,3 +469,226 @@ def test_one_kv_block_carries_nothing(t, window, carried):
         if scratch & {x for x in e.invars if not hasattr(x, "val")}
     ]
     assert bool(touched) == carried, touched
+
+
+# The backward kernel runs a block the mask crosses (the diagonal, the
+# band's edge, the key padding) as sub-tiles, and only those that hold
+# a live pair. ``_BWD_SPLIT`` sub-tiles a side: the shipped value and
+# twice it, so that crossing blocks have 2 x 2 and 4 x 4.
+
+
+def _bwd_area_events(split, monkeypatch, fn, *args):
+    """``fn(*args)`` with the split pinned; its result and the
+    ``flash.bwd_area`` events it fired."""
+    from dlrover_tpu import obs
+
+    monkeypatch.setattr(flash_module, "_BWD_SPLIT", split)
+    tracer = obs.configure_tracer()
+    try:
+        out = fn(*args)
+        events = [
+            e for e in tracer.events() if e["name"] == "flash.bwd_area"
+        ]
+    finally:
+        obs.disable_tracer()
+    return out, events
+
+
+SUBTILE_CASES = {
+    # t, flash keywords
+    "one_block": (64, dict(causal=True, block_q=64, block_k=64)),
+    "several_blocks": (256, dict(causal=True, block_q=64, block_k=64)),
+    "window_edge": (
+        256, dict(causal=True, window=80, block_q=64, block_k=64)),
+    # The last rows' bands start several kv blocks after the first one
+    # the block-level skip admits for their q block.
+    "window_band_beyond_first_block": (
+        256, dict(causal=True, window=32, block_q=128, block_k=32)),
+    "padded_520": (520, dict(causal=True)),
+    # One block whose half is no multiple of 8: it runs whole, masked.
+    "odd_block": (520, dict(causal=True, block_q=520, block_k=520)),
+    "padded_in_blocks": (200, dict(causal=True, block_q=64, block_k=64)),
+    "full_padded": (200, dict(causal=False, block_q=64, block_k=64)),
+    "bwd_blocks": (256, dict(
+        causal=True, block_q=64, block_k=64, block_q_bwd=128,
+        block_k_bwd=64,
+    )),
+}
+
+
+@pytest.mark.parametrize("split", [2, 4])
+@pytest.mark.parametrize("case", sorted(SUBTILE_CASES))
+def test_gradients_over_live_subtiles_match_plain_attention(
+    case, split, monkeypatch
+):
+    t, kw = SUBTILE_CASES[case]
+    causal, window = kw["causal"], kw.get("window")
+    q, k, v = _rand_qkv(jax.random.PRNGKey(39), 1, t, 2, 32)
+
+    def flash(q, k, v):
+        return flash_attention(
+            q, k, v, interpret=True, return_lse=True, **kw
+        )
+
+    def plain(q, k, v):
+        return (
+            _default_attention(q, k, v, causal=causal, window=window),
+            _dense_lse(q, k, causal, window),
+        )
+
+    loss = _loss_through_o_and_lse  # a cotangent on lse too
+    got, (ev,) = _bwd_area_events(
+        split, monkeypatch, jax.grad(loss(flash), argnums=(0, 1, 2)),
+        q, k, v,
+    )
+    want = jax.grad(loss(plain), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=5e-5, rtol=1e-4)
+    whole = math.gcd(ev["block_q"], ev["block_k"])
+    assert ev["sub"] == (whole if case == "odd_block" else whole // split)
+    assert ev["required"] <= ev["run"] <= ev["visited"]
+    if causal and case != "odd_block":
+        assert ev["run"] < ev["visited"]  # a diagonal block's far corner
+
+
+def _element_mask(tq, tk, causal, window, seq_len, q_offset, pad=True):
+    """The kernel's element mask over the padded lengths, by brute
+    force: [tq, tk] bools."""
+    rows = q_offset + np.arange(tq)[:, None]
+    keys = np.arange(tk)[None, :]
+    mask = np.ones((tq, tk), bool)
+    if pad:
+        mask &= keys < seq_len
+    if causal:
+        mask &= rows >= keys
+    if window is not None:
+        mask &= (rows - keys) < window
+    return mask
+
+
+AREA_SWEEP = [
+    # tq, tk, block_q, block_k, sub, causal, window, seq_len, q_offset
+    (64, 64, 64, 64, 32, True, None, 64, 0),
+    (64, 64, 64, 64, 16, True, None, 64, 0),
+    (256, 256, 64, 64, 16, True, None, 256, 0),
+    (256, 256, 64, 64, 32, True, 80, 256, 0),
+    (256, 256, 64, 64, 16, True, 48, 256, 0),
+    (256, 256, 128, 32, 16, True, 32, 256, 0),
+    (256, 256, 64, 128, 32, True, 100, 250, 0),
+    (256, 256, 64, 64, 16, True, None, 200, 0),
+    (256, 256, 64, 64, 32, False, None, 200, 0),
+    (256, 256, 64, 64, 64, True, 70, 231, 0),
+    (256, 512, 128, 128, 32, True, None, 500, 0),  # lcm padding: a dead block
+    (64, 256, 64, 64, 16, True, None, 256, 192),   # rect: the last rows
+    (64, 256, 32, 64, 16, True, 90, 250, 75),
+    (128, 256, 64, 64, 32, True, 17, 256, 100),
+    (32, 256, 32, 128, 8, True, None, 243, 211),
+    (64, 128, 64, 64, 16, False, None, 128, 0),    # nothing dead
+    (1024, 1024, 1024, 1024, 512, True, None, 1024, 0),
+    (1024, 1024, 1024, 1024, 256, True, None, 1024, 0),
+]
+
+
+@pytest.mark.parametrize("args", AREA_SWEEP, ids=lambda a: "-".join(map(str, a)))
+def test_live_subtiles_are_those_the_element_mask_finds(args):
+    tq, tk, bq, bk, sub, causal, window, seq_len, q_offset = args
+    mask = _element_mask(tq, tk, causal, window, seq_len, q_offset)
+    # The block-level skip looks at the diagonal and the band alone.
+    skip = _element_mask(tq, tk, causal, window, seq_len, q_offset, pad=False)
+    blocks = {
+        (iq, jk): tiles for iq, jk, tiles in flash_module._bwd_blocks(*args)
+    }
+    dead = 0
+    for iq in range(tq // bq):
+        for jk in range(tk // bk):
+            rows = slice(iq * bq, (iq + 1) * bq)
+            keys = slice(jk * bk, (jk + 1) * bk)
+            if not skip[rows, keys].any():
+                assert (iq, jk) not in blocks
+                continue
+            tiles = blocks[iq, jk]
+            if mask[rows, keys].all():
+                assert tiles is None  # whole and unmasked
+                continue
+            live = {
+                (b, a)
+                for b in range(bq // sub) for a in range(bk // sub)
+                if mask[rows, keys][
+                    b * sub:(b + 1) * sub, a * sub:(a + 1) * sub
+                ].any()
+            }
+            assert set(tiles) == live and len(tiles) == len(live)
+            dead += (bq // sub) * (bk // sub) - len(live)
+    area = flash_module.bwd_area(*args)
+    assert area["visited"] == len(blocks) * bq * bk
+    assert area["required"] == int(mask.sum())
+    assert area["required"] <= area["run"] <= area["visited"]
+    assert area["run"] == area["visited"] - dead * sub * sub
+    assert (area["run"] == area["visited"]) == (dead == 0)
+
+
+@pytest.mark.parametrize("name,args,want", [
+    # a head of the benchmark's cells, at the chip's blocks
+    ("gpt2", (1024, 1024, 1024, 1024, 512, True, None, 1024),
+     (1048576, 786432, 524800)),
+    ("gpt2_sub256", (1024, 1024, 1024, 1024, 256, True, None, 1024),
+     (1048576, 655360, 524800)),
+    ("mistral", (8192, 8192, 1024, 1024, 512, True, 4096, 8192),
+     (30 << 20, 27 << 20, 25167872)),
+    ("olmoe_granite", (4096, 4096, 1024, 1024, 512, True, None, 4096),
+     (10 << 20, 9 << 20, 8390656)),
+])
+def test_bwd_area_of_the_cells(name, args, want):
+    area = flash_module.bwd_area(*args)
+    assert (area["visited"], area["run"], area["required"]) == want
+
+
+def test_bwd_sub_keeps_whole_lanes_on_the_chip():
+    sub = flash_module._bwd_sub
+    split = flash_module._BWD_SPLIT
+    assert sub(1024, 1024, False) == 1024 // split
+    assert sub(512, 1024, False) == 512 // split
+    assert sub(520, 520, False) == 520  # 260 is no whole number of lanes
+    assert sub(128, 128, False) == 128
+    assert sub(64, 64, True) == 64 // split  # interpreted: multiples of 8
+    assert sub(520, 520, True) == 520
+
+
+@pytest.mark.parametrize("split", [1, 2, 4, 8])
+def test_backward_body_holds_two_copies_of_the_arithmetic(split, monkeypatch):
+    """What refused PR 32: a copy of the five products for every live
+    sub-tile, traced and lowered at every start. The loops over the
+    sub-tiles are rolled, so the kernel's body holds the products
+    twice (the whole unmasked block, one masked sub-tile) however
+    many sub-tiles a side, as it did when the second copy was the
+    whole masked block; and the forward kernel's body is the same
+    whatever the split."""
+    from tests.test_remat_policies import _eqns
+
+    monkeypatch.setattr(flash_module, "_BWD_SPLIT", split)
+    q, k, v = _rand_qkv(jax.random.PRNGKey(24), 1, 256, 2, 32)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, causal=True, window=96, block_q=128, block_k=128,
+            interpret=True,
+        ))
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+    calls = _flash_pallas_calls(jaxpr.jaxpr)
+
+    def count(name, primitive):
+        return len([
+            e for e in _eqns(calls[name].params["jaxpr"])
+            if e.primitive.name == primitive
+        ])
+
+    assert count("flash_attention_bwd", "dot_general") == 10
+    assert count("flash_attention_bwd", "exp") == 2
+    # Key sub-tiles: a fixed count, so a scan; the live query
+    # sub-tiles of each: bounds computed in the kernel, so a while.
+    rolled = 1 if split > 1 else 0
+    assert count("flash_attention_bwd", "scan") == rolled
+    assert count("flash_attention_bwd", "while") == rolled
+    assert count("flash_attention_fwd", "dot_general") == 4
+    assert count("flash_attention_fwd", "while") == 0

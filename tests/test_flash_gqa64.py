@@ -15,7 +15,11 @@ import pytest
 from dlrover_tpu.accelerate import remat
 from dlrover_tpu.models import gpt, llama
 from dlrover_tpu.ops.flash_attention import flash_attention
-from tests.test_flash_attention import _dense_lse, _loss_through_o_and_lse
+from tests.test_flash_attention import (
+    _dense_lse,
+    _loss_through_o_and_lse,
+    flash_module,
+)
 
 B, T, H, HKV, D = 2, 256, 32, 8, 64
 SCALE = 1.0 / 64
@@ -119,11 +123,17 @@ def test_kept_o_is_in_the_models_layout_and_compact_kv_gets_its_gradient(case):
     assert not [s for n, s in found if n == "flash_o" and len(s) == 4]
 
 
-def test_lse_and_its_cotangent_at_head_size_64_with_grouped_queries(case):
+@pytest.mark.parametrize("split", [1, 2, 4])
+def test_lse_and_its_cotangent_at_head_size_64_with_grouped_queries(
+    case, split, monkeypatch
+):
     """``return_lse=True`` at this call's shape (32 query heads over 8
     key-value heads, scale 1/64): lse against plain attention's, and
     dq, dk, dv with a cotangent on lse, which the backward kernel
-    takes folded into its ``delta`` row."""
+    takes folded into its ``delta`` row. The sequence is one block
+    that the diagonal crosses: run whole (``split`` 1) and as the live
+    ones of its 2 x 2 and 4 x 4 sub-tiles."""
+    monkeypatch.setattr(flash_module, "_BWD_SPLIT", split)
     _, _, h, _ = case
     q = h[..., : H * D].reshape(B, T, H, D)
     kv = h[..., : HKV * D].reshape(B, T, HKV, D)

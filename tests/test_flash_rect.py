@@ -215,3 +215,48 @@ def test_rect_o_lse_and_gradients_with_a_cotangent_on_lse(
     want = jax.grad(loss(plain), argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, atol=5e-5, rtol=1e-3)
+
+
+@pytest.mark.parametrize("split", [2, 4])
+@pytest.mark.parametrize(
+    "tq,tk,offset,window,bq,bk",
+    [
+        (64, 256, None, None, 64, 64),   # the last rows: one diagonal block
+        (64, 250, 75, 90, 32, 64),       # offset off every tile's edge
+        (128, 256, 100, 17, 64, 64),     # a band narrower than a sub-tile
+        (32, 243, 211, None, 32, 128),   # padded keys under the diagonal
+    ],
+)
+def test_rect_gradients_over_live_subtiles(
+    tq, tk, offset, window, bq, bk, split, monkeypatch
+):
+    """The backward's sub-tiles of a crossing block with the q rows at
+    ``q_offset``: dq, dk, dv against plain attention with a cotangent
+    on lse too, crossing blocks split 2 x 2 and 4 x 4 (and unequally
+    where the blocks are unequal)."""
+    from tests.test_flash_attention import _bwd_area_events
+
+    q, k, v = _qkv(jax.random.PRNGKey(39), tq, tk, b=1, h=2, d=16)
+    eff = (tk - tq) if offset is None else offset
+
+    def plain(q, k, v):
+        s = _masked_scores(q, k, True, window, q_offset=eff)
+        o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
+        return o, jax.scipy.special.logsumexp(s, axis=-1)
+
+    def flash(q, k, v):
+        return flash_attention_rect(
+            q, k, v, causal=True, q_offset=offset, window=window,
+            block_q=bq, block_k=bk, interpret=True, return_lse=True,
+        )
+
+    loss = _loss_through_o_and_lse
+    got, (ev,) = _bwd_area_events(
+        split, monkeypatch, jax.grad(loss(flash), argnums=(0, 1, 2)),
+        q, k, v,
+    )
+    want = jax.grad(loss(plain), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=5e-5, rtol=1e-3)
+    assert ev["sub"] == min(bq, bk) // split
+    assert ev["required"] <= ev["run"] < ev["visited"]

@@ -75,3 +75,26 @@ def test_lse_check_refuses_a_backward_that_drops_the_lse_cotangent(
     got = _flash_checks(monkeypatch)
     assert not got.pop("flash_lse_fwd_bwd")["ok"]
     assert all(r["ok"] for r in got.values()), got
+
+
+@pytest.mark.parametrize("lost", ["first", "last"])
+def test_subtile_check_refuses_a_backward_that_skips_a_live_subtile(
+    monkeypatch, lost
+):
+    """``flash_bwd_subtiles`` runs crossing blocks at every shape the
+    cells have (one diagonal block, several, a band's edge, padding,
+    an offset): a kernel whose loop over a key sub-tile's live query
+    sub-tiles starts one late, or ends one early, fails it."""
+    fa = sys.modules["dlrover_tpu.ops.flash_attention"]
+    real = fa._live_q_tiles
+
+    def one_short(k0, q0, *args):
+        lo, hi = real(k0, q0, *args)
+        if isinstance(k0, int) and isinstance(q0, int):
+            return lo, hi  # the area the event reports stays right
+        return (lo + 1, hi) if lost == "first" else (lo, hi - 1)
+
+    monkeypatch.setattr(fa, "_live_q_tiles", one_short)
+    got = _flash_checks(monkeypatch)
+    assert not got["flash_bwd_subtiles"]["ok"]
+    assert "Mismatched elements" in got["flash_bwd_subtiles"]["error"]

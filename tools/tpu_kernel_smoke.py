@@ -287,6 +287,70 @@ def flash_checks():
                 ),
             )
 
+    # The backward's sub-tiles of a block the mask crosses, at the
+    # chip's real blocks (SEQ x SEQ, the default at 1024): the rolled
+    # loops' bounds, the slices of the q block's rows along the lanes
+    # and of the scratch accumulators are Mosaic's, which interpret
+    # mode does not run. One diagonal block; ten blocks, four on the
+    # diagonal; the band's edge across blocks at head size 128 with
+    # grouped queries; key padding; q rows at an offset.
+    from dlrover_tpu import obs
+
+    def subtile_check():
+        def causal(window=None, block=SEQ):
+            return (
+                lambda q_, k_, v_: flash_attention(
+                    q_, k_, v_, causal=True, window=window,
+                    block_q=block, block_k=block,
+                ),
+                lambda q_, k_, v_: dense(q_, k_, v_, True, window=window),
+            )
+
+        def grouped(f):
+            return lambda q_, kv_, vv_: f(
+                q_, jnp.repeat(kv_, 2, axis=2), jnp.repeat(vv_, 2, axis=2)
+            )
+
+        def rand(t, h, d, dt, seed):
+            return (
+                jax.random.normal(kk, (1, t, h, d), jnp.float32).astype(dt)
+                for kk in jax.random.split(jax.random.PRNGKey(seed), 3)
+            )
+
+        tracer = obs.configure_tracer()
+        try:
+            with _prec("f32"):
+                grad_check(*causal(), q, k, v, atol=2e-2)
+                grad_check(
+                    *causal(), *rand(4 * SEQ, 2, 64, jnp.float32, 1),
+                    atol=2e-2,
+                )
+                # t=520 in the blocks it gets by default, padded to two
+                grad_check(*causal(block=half), qo, ko, vo, atol=2e-2)
+                grad_check(
+                    lambda q_, k_, v_: flash_attention_rect(
+                        q_, k_, v_, causal=True
+                    ),
+                    dense_rect, q[:, -tq:], k, v, atol=2e-2,
+                )
+            q8, k8, v8 = rand(8 * SEQ, 4, 128, jnp.bfloat16, 2)
+            grad_check(
+                *map(grouped, causal(window=4 * SEQ)),
+                q8, k8[:, :, :2], v8[:, :, :2], atol=0.5,
+            )
+            areas = [
+                e for e in tracer.events() if e["name"] == "flash.bwd_area"
+            ]
+        finally:
+            obs.disable_tracer()
+        assert len(areas) == 5, areas
+        for e in areas:  # every call met crossing blocks, and split them
+            assert e["sub"] < e["block_q"], e
+            assert e["required"] <= e["run"] < e["visited"], e
+
+    check("flash_bwd_subtiles", subtile_check)
+
+
 
 def norm_checks():
     from dlrover_tpu.ops.layer_norm import (
