@@ -14,9 +14,11 @@ Policies (cfg.remat / Strategy.remat accept these names):
   "none"       keep every residual (fastest, most HBM)
   "full"       recompute blocks; keep what the block names (KEPT):
                the projections into attention, the flash forward's
-               (o, lse), the MLP's hidden products, and of a
-               state-space mixer its projection, the scan's output
-               and the chunk states
+               (o, lse), the MLP's hidden products, of a sorted
+               expert layer the router's logits, the sorted order,
+               the rows into and out of the experts and their hidden
+               products, and of a state-space mixer its projection,
+               the scan's output and the chunk states
   "attention"  recompute only attention internals
   "dots"       recompute everything except matmul outputs
   "offload"    offload block-boundary residuals (checkpoint_name
@@ -33,6 +35,15 @@ output ``att @ wo``: the same ``[B, T, E]`` bytes a layer, and the
 backward recomputes one ``E x E`` product a token instead of the
 whole attention forward. On XLA attention there is no ``flash_o``:
 attention is recomputed as before, and ``att @ wo`` with it.
+
+The sorted expert layer (models/moe.py) is the same case three times
+over: its grouped products and both its permutations are
+``custom_vjp``s, so whatever their backward rules hold has to be
+named where it enters them, or the backward sorts, gathers and
+multiplies the experts a second time (three of nine ``moe_gmm`` calls
+and two of six row gathers a layer, before the names). It runs in a
+call of its own, so that ``jax.checkpoint`` does not round what it
+keeps a second time (models/moe._sorted_moe says why).
 """
 
 from __future__ import annotations
@@ -54,9 +65,15 @@ BLOCK_OUT = "block_out"
 # Llama's ``q``, ``k``, ``v`` before the head repeat), the flash
 # forward's output and its row logsumexp (ops/flash_attention._kept
 # chooses their layouts), the MLP's hidden products (GPT's
-# ``wi`` product; Llama's ``gate`` and ``up``) and an expert layer's
-# router logits. NOT the out-projection's output: it is recomputed
-# from the kept ``flash_o``. Of a state-space mixer
+# ``wi`` product; Llama's ``gate`` and ``up``; an expert layer's up
+# and gate products as the grouped product returns them). NOT the
+# out-projection's output: it is recomputed from the kept ``flash_o``.
+# Of a sorted expert layer (models/moe._sorted_experts) besides: the
+# router's logits, the sorted order (``order``, ``inverse``,
+# ``group_sizes``, int32), the rows gathered into expert order and the
+# down product's rows back in token order: what the permutations', the
+# grouped products' and the weighted sum's backward take from the
+# forward. NOT the activation: one elementwise pass. Of a state-space mixer
 # (models/granite_hybrid.py): the projection into it, and the scan's
 # output with the state every chunk starts from (ops/ssd.py), which
 # are all its backward kernel takes from the forward one.
@@ -65,11 +82,14 @@ FLASH_O = "flash_o"
 FLASH_LSE = "flash_lse"
 MLP_HIDDEN = "mlp_hidden"
 ROUTER_LOGITS = "router_logits"
+MOE_ORDER = "moe_order"
+MOE_IN = "moe_in"
+MOE_OUT = "moe_out"
 SSM_IN = "ssm_in"
 SSD_Y = "ssd_y"
 SSD_STATES = "ssd_states"
 KEPT = (ATTN_IN, FLASH_O, FLASH_LSE, MLP_HIDDEN, ROUTER_LOGITS,
-        SSM_IN, SSD_Y, SSD_STATES)
+        MOE_ORDER, MOE_IN, MOE_OUT, SSM_IN, SSD_Y, SSD_STATES)
 
 POLICY_NAMES = ("none", "full", "attention", "dots", "offload")
 

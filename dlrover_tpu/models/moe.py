@@ -229,8 +229,18 @@ _to_token_order.defvjp(_to_token_order_fwd, _to_token_order_bwd)
 def _sorted_experts(flat, experts, weights, wi, wo, wg, *, n_experts):
     """One device's tokens through their experts. flat [n, D],
     experts / weights [n, k] -> [n, D] float32."""
+    from dlrover_tpu.accelerate.remat import (
+        MLP_HIDDEN, MOE_IN, MOE_ORDER, MOE_OUT, keep,
+    )
     from dlrover_tpu.ops.grouped_matmul import gmm
 
+    # Under remat="full" the layer keeps, by name, what its backward
+    # takes from its forward, each value named where it flows into a
+    # ``custom_vjp``'s residuals: the three index vectors, the rows in
+    # expert order (``gmm``'s ``lhs`` for two weight gradients), the
+    # up and gate products and the down product's rows back in token
+    # order. Neither sort, no gather and no grouped product then runs
+    # a second time; the activation does, one elementwise pass.
     n, d = flat.shape
     k = experts.shape[1]
     with jax.named_scope("moe_route"):
@@ -240,24 +250,26 @@ def _sorted_experts(flat, experts, weights, wi, wo, wg, *, n_experts):
         # inverse[p]: at which row pair p stands.
         _, order = jax.lax.sort((pair_expert, pairs), num_keys=1, is_stable=True)
         _, inverse = jax.lax.sort((order, pairs), num_keys=1)
-        group_sizes = expert_counts(experts, n_experts)
-        xs = _to_expert_order(flat, order, inverse)
+        order, inverse = keep(order, MOE_ORDER), keep(inverse, MOE_ORDER)
+        group_sizes = keep(expert_counts(experts, n_experts), MOE_ORDER)
+        xs = keep(_to_expert_order(flat, order, inverse), MOE_IN)
     with jax.named_scope("moe_experts"):
         # Grouped products over the ragged groups: Pallas kernels
         # ``moe_gmm`` (and, backward, ``moe_tgmm``). They measured 1.4
         # to 1.75 times XLA's own ``jax.lax.ragged_dot`` at 131,072
         # rows in 64 groups on a v5e (PERF.md, PR 26).
-        h = gmm(xs, wi, group_sizes)
+        h = keep(gmm(xs, wi, group_sizes), MLP_HIDDEN)
         if wg is not None:
-            g = gmm(xs, wg, group_sizes)
+            g = keep(gmm(xs, wg, group_sizes), MLP_HIDDEN)
             h = (jax.nn.silu(g.astype(jnp.float32)) * h).astype(xs.dtype)
         else:
             h = jax.nn.gelu(h.astype(jnp.float32)).astype(xs.dtype)
         out = gmm(h, wo, group_sizes)
     with jax.named_scope("moe_combine"):
-        back = _to_token_order(out, order, inverse).reshape(n, k, d)
+        back = keep(_to_token_order(out, order, inverse), MOE_OUT)
         return jnp.einsum(
-            "nk,nkd->nd", weights, back.astype(jnp.float32)
+            "nk,nkd->nd", weights,
+            back.reshape(n, k, d).astype(jnp.float32),
         )
 
 
@@ -389,8 +401,18 @@ def _sorted_moe(params, flat, logits, cfg: MoEConfig):
             flat, experts, weights, wi, wo, wg, n_experts=cfg.n_experts
         )
 
+    # A call of its own (``jax.jit``) for what ``jax.checkpoint`` does
+    # to a kept value: where the block's own equations consume one, it
+    # rounds it once more (``reduce_precision``, against XLA's excess
+    # precision between a forward and its recompute). What the layer
+    # keeps is bf16 in memory as a Pallas call or a gather wrote it,
+    # neither of which the chip fuses a rounding into, so each was a
+    # pass of its own over every row (four, 4.9 ms of OLMoE's step). A
+    # kept value that leaves a call is left as it is, as inside the
+    # mesh path's ``shard_map``. Made here, once a trace, so that
+    # every trace of a block runs ``keep`` and says what it named.
     y = per_device(
-        call, *operands,
+        jax.jit(call), *operands,
         split=(True, True, True) + (False,) * (len(operands) - 3),
     )
     return y, metrics
@@ -413,7 +435,8 @@ def moe_mlp(
     with jax.named_scope("moe_route"):
         # Kept under remat="full", as the policy by primitive type
         # kept them: [n, E] float32 is small, and the top-k choice is
-        # then the forward's own. The grouped products are not kept.
+        # then the forward's own. What the sorted path keeps besides
+        # is named in ``_sorted_experts``.
         logits = keep(
             router_logits(flat, params["router"]), ROUTER_LOGITS
         )  # [n, E]

@@ -707,6 +707,42 @@ class TestShardedTraining:
             assert not wqkv.sharding.is_fully_replicated
         assert n_shards == 8  # placed on every device
 
+    def _full_against_none_on_fsdp8(self, model, make_cfg, tokens):
+        """The gradient of ``model``'s loss on the 8-device ``fsdp``
+        mesh under remat "full" and "none": asserts equal loss and
+        gradients, returns "full"'s jaxpr."""
+        from dlrover_tpu.parallel.mesh import under_mesh
+
+        mesh = build_mesh(MeshConfig(fsdp=8))
+        tokens, targets = shard_batch(
+            mesh, tokens, jnp.roll(tokens, -1, axis=1)
+        )
+        out, jaxprs = {}, {}
+        for remat in ("full", "none"):
+            cfg = make_cfg(remat)
+            init, _ = make_sharded_init(
+                mesh,
+                functools.partial(model.init_params, cfg=cfg),
+                model.param_logical_axes(cfg),
+                optax.adamw(1e-3),
+            )
+            params, _ = init(jax.random.PRNGKey(0))
+            grad = jax.value_and_grad(
+                under_mesh(functools.partial(model.loss_fn, cfg=cfg), mesh)
+            )
+            out[remat] = jax.jit(grad)(params, tokens, targets)
+            jaxprs[remat] = jax.make_jaxpr(grad)(params, tokens, targets)
+        np.testing.assert_allclose(
+            float(out["full"][0]), float(out["none"][0]), rtol=1e-6
+        )
+        for a, b in zip(
+            jax.tree.leaves(out["full"][1]), jax.tree.leaves(out["none"][1])
+        ):
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), atol=1e-5, rtol=1e-5
+            )
+        return jaxprs["full"]
+
     def test_full_remat_keeps_flash_outputs_when_sharded(self):
         """remat=True on the 8-device mesh (fsdp) with the flash
         kernel forced: the (o, lse) "full" keeps are tagged inside
@@ -715,50 +751,58 @@ class TestShardedTraining:
         its own rows' outputs, and loss and gradients are remat
         "none"'s (tests/test_remat_policies.py proves the
         single-device structure; this proves the mesh path)."""
-        from dlrover_tpu.parallel.mesh import under_mesh
         from tests.test_remat_policies import _flash_calls
 
-        mesh = build_mesh(MeshConfig(fsdp=8))
-        tokens = jax.random.randint(
-            jax.random.PRNGKey(1), (8, 128), 0, 256
-        )
-        tokens, targets = shard_batch(
-            mesh, tokens, jnp.roll(tokens, -1, axis=1)
-        )
-        out = {}
-        for remat in (True, False):
-            cfg = _tiny_cfg(
+        jaxpr = self._full_against_none_on_fsdp8(
+            gpt,
+            lambda remat: _tiny_cfg(
                 remat=remat,
                 use_flash_attention=True,  # forces flash off-TPU too
                 block_size=128,
                 attn_blocks=(128, 128, 128, 128),
-            )
-            init, _ = make_sharded_init(
-                mesh,
-                functools.partial(gpt.init_params, cfg=cfg),
-                gpt.param_logical_axes(cfg),
-                optax.adamw(1e-3),
-            )
-            params, _ = init(jax.random.PRNGKey(0))
-            grad = jax.value_and_grad(
-                under_mesh(functools.partial(gpt.loss_fn, cfg=cfg), mesh)
-            )
-            out[remat] = jax.jit(grad)(params, tokens, targets)
-            if remat:
-                jaxpr = jax.make_jaxpr(grad)(params, tokens, targets)
+            ),
+            jax.random.randint(jax.random.PRNGKey(1), (8, 128), 0, 256),
+        )
         calls = _flash_calls(jaxpr.jaxpr, [])
         assert sorted(calls) == [
             "flash_attention_bwd", "flash_attention_fwd"
         ], calls
-        np.testing.assert_allclose(
-            float(out[True][0]), float(out[False][0]), rtol=1e-6
+
+    def test_full_remat_keeps_the_expert_layers_values_when_sharded(self):
+        """The same with an expert layer: the sorted path runs inside
+        ``per_device``'s shard_map, each device on its own tokens, and
+        what it names there is kept as on one device: the stacked
+        residuals are the one-device set, and no grouped product and
+        no sort runs twice."""
+        from dlrover_tpu.models import llama
+        from tests.test_remat_policies import (
+            _expert_layer_calls,
+            _stacked_residuals,
         )
-        for a, b in zip(
-            jax.tree.leaves(out[True][1]), jax.tree.leaves(out[False][1])
-        ):
-            np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), atol=1e-5, rtol=1e-5
-            )
+
+        jaxpr = self._full_against_none_on_fsdp8(
+            llama,
+            lambda remat: llama.LlamaConfig(
+                vocab_size=128, block_size=64, n_layer=2, n_head=4,
+                n_kv_head=2, n_embd=32, intermediate=96,
+                dtype=jnp.float32, remat=remat, n_experts=4,
+            ),
+            jax.random.randint(jax.random.PRNGKey(1), (8, 64), 0, 128),
+        )
+        assert "shard_map" in str(jaxpr)
+        assert _expert_layer_calls(jaxpr.jaxpr) == (6, 3, 2)
+        # Global shapes: 8 x 64 tokens, 2 choices each, 4 experts a
+        # device (a device's group sizes are its own, so [8 x 4]).
+        kept = _stacked_residuals(jaxpr.jaxpr)
+        rows, f32 = 8 * 64 * 2, "float32"
+        assert kept == sorted([
+            ((8, 64, 32), f32), ((8, 64, 32), f32),    # x, q
+            ((8, 64, 16), f32), ((8, 64, 16), f32),    # k, v
+            ((8 * 64, 4), f32),                        # router logits
+            ((rows,), "int32"), ((rows,), "int32"), ((8 * 4,), "int32"),
+            ((rows, 32), f32), ((rows, 32), f32),      # rows in, rows out
+            ((rows, 96), f32), ((rows, 96), f32),      # up, gate
+        ]), kept
 
     @pytest.mark.slow
     def test_seq_parallel_with_ring_attention(self):

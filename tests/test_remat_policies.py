@@ -208,13 +208,47 @@ KEPT_SHAPES = {
         ((B, H, 1, T), F32),                    # flash_lse
         ((B, T, F), F32), ((B, T, F), F32),     # gate, up
     ]),
+    # The expert layer: 4 experts, 2 a token, so B * T * 2 (token,
+    # choice) rows; what ``models/moe._sorted_experts`` names.
     "moe": sorted([
         ((B, T, E), F32), ((B, T, E), F32), ((B, T, E), F32),
         ((B, T, E // 2), F32), ((B, T, E // 2), F32),
         ((B, H, 1, T), F32),
         ((B * T, 4), F32),                      # router logits
+        ((B * T * 2,), "int32"), ((B * T * 2,), "int32"),  # order, inverse
+        ((4,), "int32"),                        # group sizes
+        ((B * T * 2, E), F32),                  # rows in expert order
+        ((B * T * 2, F), F32), ((B * T * 2, F), F32),  # up, gate products
+        ((B * T * 2, E), F32),                  # rows back in token order
     ]),
 }
+MOE_NAMES = ["moe_in", "moe_order", "moe_out"]
+
+
+def _expert_layer_calls(jaxpr):
+    """(``moe_gmm`` calls, ``moe_tgmm`` calls, ``sort`` equations) of
+    the one layer body each scan of the gradient's jaxpr holds."""
+    eqns = list(_eqns(jaxpr))
+    kernels = [
+        str(e.params.get("name")) for e in eqns
+        if e.primitive.name == "pallas_call"
+    ]
+    return (
+        kernels.count("moe_gmm"), kernels.count("moe_tgmm"),
+        sum(e.primitive.name == "sort" for e in eqns),
+    )
+
+
+def _kept_events(loss_fn, params, *batch):
+    """The ``remat.kept`` events of one trace of the loss's gradient."""
+    from dlrover_tpu import obs
+
+    tracer = obs.configure_tracer()
+    try:
+        jax.jit(jax.value_and_grad(loss_fn)).lower(params, *batch)
+        return [e for e in tracer.events() if e["name"] == "remat.kept"]
+    finally:
+        obs.disable_tracer()
 
 
 class TestFullKeepsTheFlashOutputs:
@@ -245,6 +279,37 @@ class TestFullKeepsTheFlashOutputs:
     def test_residuals_are_exactly_the_named_set(self, family):
         got = _stacked_residuals(_grad_jaxpr(family, "full"))
         assert got == KEPT_SHAPES[family], got
+
+    def test_expert_layer_runs_no_product_twice(self):
+        """A gated expert layer needs three grouped products forward,
+        three input gradients (``moe_gmm``) and three weight gradients
+        (``moe_tgmm``), and its two sorts once: with the named values
+        kept, the backward holds no second forward of them."""
+        assert _expert_layer_calls(_grad_jaxpr("moe", "full")) == (6, 3, 2)
+
+    def test_a_policy_by_type_runs_the_expert_forward_twice(self):
+        """The contrast: "dots" cannot see inside ``gmm``'s custom_vjp
+        either, and runs the three forward products and both sorts a
+        second time inside the backward."""
+        assert _expert_layer_calls(_grad_jaxpr("moe", "dots")) == (9, 3, 4)
+
+    def test_kept_expert_values_are_not_rounded_a_second_time(self):
+        """``jax.checkpoint`` puts a ``reduce_precision`` behind a kept
+        float value that its block's own equations consume (the dense
+        MLP's products, where XLA fuses it into the product). What
+        the expert layer keeps comes out of Pallas calls and gathers,
+        which take no such fusion: on the chip each was a pass of its
+        own over every row. Inside the layer's own call
+        (``models/moe._sorted_moe``) none is inserted."""
+        def rounded(family):
+            return [
+                e.outvars[0].aval.shape
+                for e in _eqns(_grad_jaxpr(family, "full"))
+                if e.primitive.name == "reduce_precision"
+            ]
+
+        assert (B, T, F) in rounded("llama_gqa_window")
+        assert not [s for s in rounded("moe") if s[0] == B * T * 2]
 
     def test_kept_lse_is_compact(self):
         """[B, H, 1, T], rows along the lanes, as the forward kernel
@@ -339,52 +404,45 @@ class TestRetiredNameAndEvent:
 
         assert remat.KEPT == (
             "attn_in", "flash_o", "flash_lse", "mlp_hidden",
-            "router_logits", "ssm_in", "ssd_y", "ssd_states",
+            "router_logits", "moe_order", "moe_in", "moe_out",
+            "ssm_in", "ssd_y", "ssd_states",
         )
         assert remat.BLOCK_OUT not in remat.KEPT
 
     @pytest.mark.parametrize("flash", [True, False])
     def test_remat_kept_fires_once_a_trace(self, flash):
-        from dlrover_tpu import obs
-
-        tracer = obs.configure_tracer()
-        try:
-            if flash:
-                loss_fn, params = _flash_family("gpt", "full")
-                tokens, targets = _tokens()
-            else:
-                cfg = _cfg("full")
-                loss_fn = functools.partial(gpt.loss_fn, cfg=cfg)
-                params = gpt.init_params(jax.random.PRNGKey(0), cfg)
-                tokens = targets = jnp.zeros((2, 32), jnp.int32)
-            jax.jit(jax.value_and_grad(loss_fn)).lower(
-                params, tokens, targets
-            )
-            (ev,) = [
-                e for e in tracer.events() if e["name"] == "remat.kept"
-            ]
-        finally:
-            obs.disable_tracer()
+        if flash:
+            loss_fn, params = _flash_family("gpt", "full")
+            tokens, targets = _tokens()
+        else:
+            cfg = _cfg("full")
+            loss_fn = functools.partial(gpt.loss_fn, cfg=cfg)
+            params = gpt.init_params(jax.random.PRNGKey(0), cfg)
+            tokens = targets = jnp.zeros((2, 32), jnp.int32)
+        (ev,) = _kept_events(loss_fn, params, tokens, targets)
         assert ev["flash_residuals"] is flash
         want = ["attn_in", "mlp_hidden"]
         if flash:
             want = ["attn_in", "flash_lse", "flash_o", "mlp_hidden"]
         assert ev["names"] == want
 
+    def test_remat_kept_lists_the_expert_layers_names(self):
+        (ev,) = _kept_events(*_flash_family("moe", "full"), *_tokens())
+        assert ev["names"] == sorted(
+            ["attn_in", "flash_lse", "flash_o", "mlp_hidden",
+             "router_logits"] + MOE_NAMES
+        )
+
+    @pytest.mark.parametrize("family", ["gpt", "llama_gqa_window"])
+    def test_a_block_with_no_expert_layer_lists_none_of_them(self, family):
+        (ev,) = _kept_events(*_flash_family(family, "full"), *_tokens())
+        assert "mlp_hidden" in ev["names"]
+        assert not set(ev["names"]) & {"router_logits", *MOE_NAMES}
+
     @pytest.mark.parametrize("policy", ["none", "dots", "attention"])
     def test_remat_kept_is_fulls_alone(self, policy):
-        from dlrover_tpu import obs
-
-        tracer = obs.configure_tracer()
-        try:
-            loss_fn, params = _flash_family("gpt", policy)
-            jax.jit(jax.grad(loss_fn)).lower(params, *_tokens())
-            events = [
-                e for e in tracer.events() if e["name"] == "remat.kept"
-            ]
-        finally:
-            obs.disable_tracer()
-        assert events == []
+        loss_fn, params = _flash_family("gpt", policy)
+        assert _kept_events(loss_fn, params, *_tokens()) == []
 
 
 class TestScanUnroll:
