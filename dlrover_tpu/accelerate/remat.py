@@ -137,6 +137,7 @@ def _announced(block_fn: Callable) -> Callable:
             out = block_fn(*args)
         finally:
             seen, _tracing.names = _tracing.names, None
+        _tracing.last = tuple(sorted(seen))
         obs.event(
             "remat.kept", names=sorted(seen),
             flash_residuals=FLASH_O in seen,
@@ -144,6 +145,12 @@ def _announced(block_fn: Callable) -> Callable:
         return out
 
     return block
+
+
+def last_kept() -> tuple:
+    """The names the block this thread traced last under "full" gave
+    (what ``remat.kept`` said of it); empty before any."""
+    return getattr(_tracing, "last", ())
 
 
 def offload_policy():

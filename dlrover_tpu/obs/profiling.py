@@ -300,11 +300,13 @@ class CompileTracker:
 
 # The program's jax.named_scope vocabulary (models/, trainer/), as
 # compiled_scopes reports it. "layers" and "accumulate" are the two
-# scans' own scopes: they own what no layer scope inside them does.
+# scans' own scopes: they own what no layer scope inside them does;
+# "ut_loop" is a looped model's scan over its passes (models/ouro.py),
+# "exit_gate" its gate, exit distribution and entropy.
 SCOPES = frozenset((
     "accumulate", "layers", "embed", "attn", "mlp", "ssm", "head",
     "optimizer", "moe_route", "moe_experts", "moe_combine", "ssm_conv",
-    "ssd", "ssm_norm",
+    "ssd", "ssm_norm", "ut_loop", "exit_gate",
 ))
 _HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
 _HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')

@@ -21,7 +21,13 @@ cotangents (quarter-rate on the MXU). This implementation:
 * under a mesh runs once per device on that device's own rows
   (``_on_own_rows``), so the logits never cross a link: the table is
   gathered once a step, its float32 gradient summed over the batch
-  axes once a step.
+  axes once a step;
+* takes an optional float32 weight a row (``weights``): the loss is
+  then ``sum_i w_i nll_i`` in place of the mean, both gradients are
+  formed with ``w_i`` where the mean has ``1/N``, in the same single
+  pass over the logits, and the rows' losses go back as the weights'
+  gradient (models/ouro.py weighs four passes' rows by a learned exit
+  distribution). With no weights the traced program is what it was.
 """
 
 from __future__ import annotations
@@ -67,12 +73,14 @@ def _loss_terms(x_c, t_c, wte):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def fused_cross_entropy(x, wte, targets, num_chunks: int = 8):
-    """Mean token cross-entropy of ``x @ wte^T`` against targets.
+def fused_cross_entropy(x, wte, targets, num_chunks: int = 8, weights=None):
+    """Mean token cross-entropy of ``x @ wte^T`` against targets, or
+    with ``weights`` the weighted sum ``sum_i weights[i] * nll[i]``.
 
     x: [N, E] (activations, bf16 ok); wte: [V, E] tied embedding;
-    targets: [N] int. N must be divisible by num_chunks (pad or pick a
-    divisor; model code uses B*T which is a power of two).
+    targets: [N] int; weights: None or [N] float32, differentiable.
+    N must be divisible by num_chunks (pad or pick a divisor; model
+    code uses B*T which is a power of two).
 
     This body is the call nothing differentiates (evaluation, a
     reference check): the loss alone, one product a chunk. Under
@@ -89,9 +97,16 @@ def fused_cross_entropy(x, wte, targets, num_chunks: int = 8):
     nll = _on_own_rows(
         rows_fn, (x, targets), (wte,), num_chunks, announce=True
     )
-    # Every device holds the same number of rows: the mean over all
-    # rows of all devices.
-    return jnp.mean(nll)
+    return _reduced(nll, weights)
+
+
+def _reduced(nll, weights):
+    """The rows' losses to one: their mean (every device holds the same
+    number of rows, so over all rows of all devices), or with weights
+    their weighted sum."""
+    if weights is None:
+        return jnp.mean(nll)
+    return jnp.sum(weights.astype(jnp.float32) * nll)
 
 
 def _on_own_rows(rows_fn, by_row, whole, num_chunks, summed=(),
@@ -127,19 +142,26 @@ def _on_own_rows(rows_fn, by_row, whole, num_chunks, summed=(),
     )
 
 
-def _fwd(x, wte, targets, num_chunks):
+def _fwd(x, wte, targets, num_chunks, weights=None):
     """The loss and, while each chunk's logits are there, its share of
     ``dx`` and of the table's gradient for an upstream cotangent of 1:
-    the backward needs no logits, so it does not form them again."""
+    the backward needs no logits, so it does not form them again. A
+    row's share carries its weight, ``1/N`` of the mean with none."""
     inv_rows = 1.0 / x.shape[0]  # the mean is over every device's rows
+    by_row = (x, targets)
+    if weights is not None:
+        by_row += (weights.astype(jnp.float32),)
 
-    def rows_fn(x, targets, wte):
+    def rows_fn(x, targets, *rest):
+        *row_weights, wte = rest
+
         def chunk(dwte, args):
-            x_c, t_c = args
+            x_c, t_c, *w_c = args
             logits, lse, gold = _loss_terms(x_c, t_c, wte)
             p = jnp.exp(logits - lse[:, None])
             dlogits = p - jax.nn.one_hot(t_c, wte.shape[0], dtype=p.dtype)
-            dlogits = (dlogits * inv_rows).astype(x.dtype)  # bf16 cotangent
+            scale = w_c[0][:, None] if w_c else inv_rows
+            dlogits = (dlogits * scale).astype(x.dtype)  # bf16 cotangent
             if wte.shape[1] >= _COTANGENT_ONCE_FROM:
                 dlogits = jax.lax.optimization_barrier(dlogits)
             dx_c = jnp.einsum("cv,ve->ce", dlogits, wte).astype(x.dtype)
@@ -151,24 +173,36 @@ def _fwd(x, wte, targets, num_chunks):
 
         dwte, (nll, dx) = jax.lax.scan(
             chunk, jnp.zeros(wte.shape, jnp.float32),
-            _chunks(x, targets, num_chunks),
+            _chunks(x, targets, num_chunks) + tuple(
+                w.reshape(num_chunks, -1) for w in row_weights
+            ),
         )
         return nll.reshape(-1), dx.reshape(x.shape), dwte
 
     # The table's gradient: float32 over the chunks, summed over the
     # devices in float32 (``summed``), cast once, in ``_bwd``.
     nll, dx, dwte = _on_own_rows(
-        rows_fn, (x, targets), (wte,), num_chunks,
+        rows_fn, by_row, (wte,), num_chunks,
         summed=(False, False, True), announce=True,
     )
     obs.event("head.grads_in_forward", rows=x.shape[0], chunks=num_chunks)
     # The empty array carries the table's dtype to ``_bwd``.
-    return jnp.mean(nll), (dx, dwte, jnp.zeros((0,), wte.dtype))
+    res = (dx, dwte, jnp.zeros((0,), wte.dtype))
+    if weights is not None:
+        obs.event("head.weighted_rows", rows=x.shape[0], chunks=num_chunks)
+        # The rows' losses are the weights' gradient; the empty array
+        # carries their dtype.
+        res += (nll, jnp.zeros((0,), weights.dtype))
+    return _reduced(nll, weights), res
 
 
 def _bwd(num_chunks, res, g):
-    dx, dwte, like_wte = res
-    return (g * dx).astype(dx.dtype), (g * dwte).astype(like_wte.dtype), None
+    dx, dwte, like_wte, *weighted = res
+    grads = (g * dx).astype(dx.dtype), (g * dwte).astype(like_wte.dtype), None
+    if not weighted:
+        return grads + (None,)
+    nll, like_weights = weighted
+    return grads + ((g * nll).astype(like_weights.dtype),)
 
 
 fused_cross_entropy.defvjp(_fwd, _bwd)

@@ -359,3 +359,154 @@ def test_head_per_device_event():
         assert len(events()) == 1
     finally:
         obs.disable_tracer()
+
+
+# -- a weight a row (ISSUE 44): sum_i w_i nll_i --------------------------------
+
+
+def _naive_weighted(x, wte, targets, weights):
+    logits = jnp.einsum(
+        "ne,ve->nv", x.astype(jnp.float32), wte.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    nll = -jnp.take_along_axis(logp, targets[:, None], axis=-1)[:, 0]
+    return jnp.sum(weights * nll), nll
+
+
+def _row_weights(n):
+    """Positive, summing to 1, uneven: an exit distribution's shares."""
+    return jax.nn.softmax(2.0 * jax.random.normal(jax.random.PRNGKey(7), (n,)))
+
+
+@pytest.mark.parametrize("differentiate", [False, True])
+@pytest.mark.parametrize("num_chunks", [1, 4])
+def test_weighted_loss_matches_dense(differentiate, num_chunks):
+    x, wte, targets = _head_inputs(64, 16, 96, jnp.float32)
+    weights = _row_weights(64)
+    want, _ = _naive_weighted(x, wte, targets, weights)
+    loss = lambda x: fused_cross_entropy(  # noqa: E731
+        x, wte, targets, num_chunks, weights
+    )
+    got = jax.value_and_grad(loss)(x)[0] if differentiate else loss(x)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+
+
+@pytest.mark.parametrize("g", [1.0, 0.25])
+def test_weighted_grads_match_dense(g):
+    """dx and the table's gradient carry each row's weight where the
+    mean has 1/N; the weights' gradient is the rows' losses."""
+    x, wte, targets = _head_inputs(64, 16, 96, jnp.float32)
+    weights = _row_weights(64)
+    got = jax.grad(
+        lambda x, w, wt: g * fused_cross_entropy(x, w, targets, 4, wt),
+        argnums=(0, 1, 2),
+    )(x, wte, weights)
+    want = jax.grad(
+        lambda x, w, wt: g * _naive_weighted(x, w, targets, wt)[0],
+        argnums=(0, 1, 2),
+    )(x, wte, weights)
+    for a, b in zip(got, want):
+        assert _rel(a, b) < 1e-5
+    _, nll = _naive_weighted(x, wte, targets, weights)
+    np.testing.assert_allclose(got[2], g * nll, rtol=1e-5)
+    assert got[2].dtype == weights.dtype
+
+
+def test_uniform_weights_are_the_mean():
+    x, wte, targets = _head_inputs(64, 16, 96, jnp.float32)
+    grad = functools.partial(jax.value_and_grad, argnums=(0, 1))
+    want, want_g = grad(lambda x, w: fused_cross_entropy(x, w, targets, 4))(
+        x, wte
+    )
+    got, got_g = grad(lambda x, w: fused_cross_entropy(
+        x, w, targets, 4, jnp.full((64,), 1.0 / 64)
+    ))(x, wte)
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    for a, b in zip(got_g, want_g):
+        assert _rel(a, b) < 1e-6
+
+
+def test_weighted_bf16_cotangents_stay_bf16():
+    x, wte, targets = _head_inputs(64, 16, 96, jnp.bfloat16)
+    weights = _row_weights(64)
+    dx, dw, dwt = jax.grad(
+        lambda x, w, wt: fused_cross_entropy(x, w, targets, 4, wt),
+        argnums=(0, 1, 2),
+    )(x, wte, weights)
+    assert dx.dtype == dw.dtype == jnp.bfloat16 and dwt.dtype == jnp.float32
+    want = jax.grad(
+        lambda x, w, wt: _naive_weighted(x, w, targets, wt)[0],
+        argnums=(0, 1, 2),
+    )(x, wte, weights)
+    assert _rel(dx, want[0]) < 2e-2 and _rel(dw, want[1]) < 2e-2
+    assert _rel(dwt, want[2]) < 1e-5
+
+
+def test_without_weights_the_trace_is_what_it_was():
+    """No weights: the same jaxpr as the call that never had the
+    operand (no multiply by a row's weight, no second residual pair),
+    one event fewer; three products over the vocabulary either way."""
+    x, wte, targets = _head_inputs(64, 16, 96, jnp.float32)
+    weights = _row_weights(64)
+    grad = functools.partial(jax.value_and_grad, argnums=(0, 1))
+    plain = jax.make_jaxpr(grad(
+        lambda x, w: fused_cross_entropy(x, w, targets, 4)
+    ))(x, wte)
+    none = jax.make_jaxpr(grad(
+        lambda x, w: fused_cross_entropy(x, w, targets, 4, None)
+    ))(x, wte)
+    assert str(plain) == str(none)
+    weighted = jax.make_jaxpr(grad(
+        lambda x, w: fused_cross_entropy(x, w, targets, 4, weights)
+    ))(x, wte)
+    count = lambda j: str(j).count("dot_general")  # noqa: E731
+    assert count(plain) == count(weighted) == 3
+    tracer = obs.configure_tracer()
+    try:
+        names = lambda: [e["name"] for e in tracer.events()]  # noqa: E731
+        jax.jit(grad(lambda x, w: fused_cross_entropy(x, w, targets, 4))).lower(
+            x, wte
+        )
+        assert "head.weighted_rows" not in names()
+        jax.jit(grad(
+            lambda x, w: fused_cross_entropy(x, w, targets, 4, weights)
+        )).lower(x, wte)
+        (ev,) = [e for e in tracer.events() if e["name"] == "head.weighted_rows"]
+        assert ev["rows"] == 64 and ev["chunks"] == 4
+    finally:
+        obs.disable_tracer()
+
+
+@pytest.mark.parametrize("axes,table_spec", [
+    ({"fsdp": 4}, P(None, "fsdp")),
+    ({"data": 2, "fsdp": 2}, P(None, "fsdp")),
+], ids=["fsdp4", "data2xfsdp2"])
+def test_per_device_weights_go_with_their_rows(axes, table_spec):
+    """Under a mesh each device runs on its own rows with their
+    weights: loss and all three gradients as on one device."""
+    rows, e, v = 256, 64, 384
+    x, wte, targets = _head_inputs(rows, e, v, jnp.float32)
+    weights = _row_weights(rows)
+
+    def loss(x, wte, weights, targets):
+        return fused_cross_entropy(x, wte, targets, 8, weights)
+
+    grad = functools.partial(jax.value_and_grad, argnums=(0, 1, 2))
+    want, want_g = jax.jit(grad(loss))(x, wte, weights, targets)
+    mesh = _mesh(**axes)
+    rows_spec = P(batch_spec(mesh)[0])
+    put = lambda a, s: jax.device_put(a, NamedSharding(mesh, s))  # noqa: E731
+    tracer = obs.configure_tracer()
+    try:
+        got, got_g = jax.jit(grad(under_mesh(loss, mesh)))(
+            put(x, P(*rows_spec, None)), put(wte, table_spec),
+            put(weights, rows_spec), put(targets, rows_spec),
+        )
+        assert [e["rows_per_device"] for e in tracer.events()
+                if e["name"] == "head.per_device"] == [64]
+    finally:
+        obs.disable_tracer()
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
+    for a, b in zip(got_g, want_g):
+        assert _rel(a, b) < 1e-6
