@@ -19,11 +19,14 @@ pass and what the next pass starts from. ``nll_t`` is the
 cross-entropy of ``h_t @ lm_head^T``; ``H`` the entropy of the
 ``ut_steps``-way exit distribution.
 
-* the passes are a ``lax.scan`` of length ``ut_steps`` whose body is the
-  layers' ``lax.scan`` over the stacked parameters, closed over: the
-  backward sums each weight's gradient over the passes in the outer
-  scan's carry, and what a block keeps under ``remat="full"``
-  (accelerate/remat.py ``KEPT``) is stacked ``[ut_steps, n_layer, ...]``;
+* the passes are ``ut_steps`` calls in a row of ONE jitted pass (the
+  layers' ``lax.scan`` over the stacked parameters, then the closing
+  norm), traced and lowered once whatever ``ut_steps``: autodiff sums
+  each weight's gradient over the passes, and what a block keeps
+  under ``remat="full"`` (accelerate/remat.py ``KEPT``) is stacked
+  once, ``[n_layer, ...]`` a pass, by the scan whose backward reads it
+  (a ``lax.scan`` over the passes would stack it again, ``[ut_steps,
+  n_layer, ...]``, a copy of every kept byte in and another out);
 * the attention half, the SwiGLU and the rotary tables are
   models/llama.py's, the attention chooser every family's (flash on
   the TPU from 512 tokens up);
@@ -216,21 +219,32 @@ def passes(
         cfg.remat, attn_fn,
     )
 
-    def one_pass(x, _):
+    # One call (``jax.jit``), made here, once a trace of the loss: the
+    # block and both flash kernels are traced and lowered once whatever
+    # ``ut_steps``, and what stands in the module's place of
+    # ``_close_pass`` while the loss is traced is what the program runs.
+    @jax.jit
+    def one_pass(x, blocks, closing):
         with jax.named_scope("layers"):
             x, _ = jax.lax.scan(
-                lambda x, lp: (block(x, lp), None), x, params["blocks"]
+                lambda x, lp: (block(x, lp), None), x, blocks
             )
-        return _close_pass(x, params, cfg)
+        return _close_pass(x, closing, cfg)
 
+    closing = {"rmsf": params["rmsf"]}
+    hs = []
     with jax.named_scope("ut_loop"):
-        _, hs = jax.lax.scan(one_pass, x, None, length=cfg.ut_steps)
+        for _ in range(cfg.ut_steps):
+            x, h = one_pass(x, params["blocks"], closing)
+            hs.append(h)
+        hs = jnp.stack(hs, axis=1)
     full = remat.canonical(cfg.remat) == "full"
     obs.event(
         "ouro.loop", ut_steps=cfg.ut_steps, layers=cfg.n_layer,
+        layer_scans=cfg.ut_steps,
         kept_names=list(remat.last_kept()) if full else [],
     )
-    return jnp.moveaxis(hs, 0, 1)
+    return hs
 
 
 def exit_distribution(params: Params, hs: jax.Array):

@@ -301,8 +301,10 @@ class CompileTracker:
 # The program's jax.named_scope vocabulary (models/, trainer/), as
 # compiled_scopes reports it. "layers" and "accumulate" are the two
 # scans' own scopes: they own what no layer scope inside them does;
-# "ut_loop" is a looped model's scan over its passes (models/ouro.py),
-# "exit_gate" its gate, exit distribution and entropy.
+# "ut_loop" is a looped model's passes (models/ouro.py: the calls of
+# its one jitted pass in a row; its own is the norm that closes a pass
+# and the stacking of the passes' outputs), "exit_gate" its gate, exit
+# distribution and entropy.
 SCOPES = frozenset((
     "accumulate", "layers", "embed", "attn", "mlp", "ssm", "head",
     "optimizer", "moe_route", "moe_experts", "moe_combine", "ssm_conv",

@@ -217,24 +217,80 @@ def test_full_remat_keeps_the_blocks_names_and_the_loop_says_so(toy):
         obs.disable_tracer()
 
 
+def _avals(jaxpr):
+    """Every array of ``jaxpr`` and of the jaxprs its equations call
+    (the jitted pass, the scans' bodies, the checkpointed block)."""
+    for v in (*jaxpr.invars, *jaxpr.constvars):
+        yield v.aval
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            yield v.aval
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _avals(sub)
+
+
 def test_the_gradient_keeps_the_named_residuals_a_pass_a_layer(toy):
-    """Under "full" the kept residuals are stacked over passes and
-    layers: the backward's outer scan reads arrays ``[ut_steps,
+    """Under "full" a layer pass's kept residuals are stacked once,
+    ``[n_layer, ...]`` by the layers' scan whose backward reads them,
+    a set a pass: no array anywhere in the gradient is ``[ut_steps,
     n_layer, ...]``."""
     cfg, params, tok, tgt = toy
     full = dataclasses.replace(cfg, remat="full")
     jaxpr = jax.make_jaxpr(jax.grad(
         functools.partial(ouro.loss_fn_fused, cfg=full)
     ))(params, tok, tgt)
-    stacked = (cfg.ut_steps, cfg.n_layer, tok.shape[0], tok.shape[1])
-    scans = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
-    widths = {
-        v.aval.shape[4:] for e in scans for v in e.invars
-        if hasattr(v.aval, "shape") and v.aval.shape[:4] == stacked
-    }
+    shapes = [getattr(a, "shape", ()) for a in _avals(jaxpr.jaxpr)]
+    twice = (cfg.ut_steps, cfg.n_layer, *tok.shape)
+    assert not [s for s in shapes if s[:4] == twice]
+    once = (cfg.n_layer, *tok.shape)
+    widths = {s[3:] for s in shapes if s[:3] == once}
     # q, k, v and the carried input at the hidden width; gate and up
     # at the MLP's.
     assert {(cfg.n_embd,), (cfg.intermediate,)} <= widths, widths
+
+
+@pytest.mark.parametrize("ut_steps", [1, 2, 4])
+def test_the_block_is_traced_once_whatever_the_passes(toy, ut_steps):
+    """``ut_steps`` layer scans in the program, one trace of the block:
+    ``remat.kept`` fires once a trace of the loss and ``ouro.loop``
+    counts the scans."""
+    cfg, params, tok, tgt = toy
+    cfg = dataclasses.replace(cfg, remat="full", ut_steps=ut_steps)
+    tracer = obs.configure_tracer()
+    try:
+        jaxpr = jax.make_jaxpr(jax.grad(
+            functools.partial(ouro.loss_fn_fused, cfg=cfg)
+        ))(params, tok, tgt)
+        assert len(_events(tracer, "remat.kept")) == 1
+        (loop,) = _events(tracer, "ouro.loop")
+    finally:
+        obs.disable_tracer()
+    assert loop["layer_scans"] == loop["ut_steps"] == ut_steps
+    # One forward and one backward call of the pass for each.
+    calls = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "jit"
+             and e.params["name"] == "one_pass"]
+    assert len(calls) == 2 * ut_steps
+
+
+def test_what_stands_in_close_pass_place_at_trace_time_runs(toy):
+    """benchmark/controls/ouro.py ``no_pass_norm`` puts a broken
+    ``_close_pass`` in the module's place while the loss is traced: the
+    pass is jitted inside ``passes``, a trace at a time, so the program
+    runs what stood there."""
+    from benchmark.controls import ouro as controls
+
+    cfg, params, tok, tgt = toy
+    honest = jax.jit(functools.partial(ouro.loss_fn_fused, cfg=cfg))
+    want = float(honest(params, tok, tgt))
+    close_pass = ouro._close_pass
+    got = float(jax.jit(controls.broken("no_pass_norm", cfg))(
+        params, tok, tgt
+    ))
+    assert ouro._close_pass is close_pass
+    assert abs(got - want) > 1e-3 * abs(want), (got, want)
+    # And the honest function again afterwards, traced anew.
+    again = jax.jit(functools.partial(ouro.loss_fn_fused, cfg=cfg))
+    assert float(again(params, tok, tgt)) == want
 
 
 def test_same_loss_on_a_host_device_mesh(toy):
