@@ -16,7 +16,12 @@ save_to_memory:169 with the shm-lock + all-rank-ready barrier
   docs/design/async-checkpoint.md); on a v5e, device idle, GPT-2
   124M's 1.244 GB reach the segment in 0.17 s (7.3 GB/s) and 7 GB of
   Mistral-width state in 1.04 s, where one ``np.asarray`` a shard and
-  then the copy took 0.77 s and 4.57 s (PERF.md, PR 25);
+  then the copy took 0.77 s and 4.57 s (PERF.md, PR 25). The buffers
+  the transfers fill are kept on the C allocator's heap
+  (``_keep_transfer_buffers_on_the_heap``): in a training loop GPT-2's
+  save then stalls the step 0.16 s where it stalled it 0.18 or 0.30 s,
+  by what the process had freed before, and 8.4 GB of Mistral-width
+  state take 1.05 s for 1.18 (PERF.md, PR 47);
 * persistence is delegated to the host agent via a SharedQueue event —
   the trainer never blocks on storage.
 
@@ -28,7 +33,10 @@ reshard-on-restart (atorch/utils/fsdp_save_util.py) by construction.
 from __future__ import annotations
 
 import collections
+import ctypes
 import functools
+import os
+import resource
 import time
 from typing import Any, Dict, Iterator, List, Optional, Tuple
 
@@ -67,16 +75,38 @@ _CKPT_RESTORE_SECONDS = obs.histogram(
 
 # How a save reads the state off the device (``_ReadAhead``). A shard
 # above _PIECE_BYTES goes to the host as linear pieces of about that
-# size: well under the 32 MiB from which the C allocator maps fresh
-# pages for every buffer, so a piece written into the segment leaves
-# its memory, already mapped, to a later one. Shards are started until
-# _AHEAD_BYTES of them are on their way, so the read holds that much of
-# device memory beyond the state, and while it cuts a shard up to twice
-# that shard more. Measured on a v5e with GPT-2 124M's and Mistral's
-# states, pieces of 2 to 32 MiB and 128 MiB to 2 GiB ahead (PERF.md,
-# PR 25); not options.
+# size, so a piece written into the segment leaves its memory to a
+# later one. Shards are started until _AHEAD_BYTES of them are on their
+# way, so the read holds that much of device memory beyond the state,
+# and while it cuts a shard up to twice that shard more. Measured on a
+# v5e with GPT-2 124M's and Mistral's states, pieces of 2 to 32 MiB and
+# 128 MiB to 2 GiB ahead (PERF.md, PR 25); not options.
 _PIECE_BYTES = 8 << 20
 _AHEAD_BYTES = 512 << 20
+
+# Where the memory of those pieces comes from. The runtime ``malloc``s
+# a host buffer for every transfer, and glibc serves a ``malloc`` from
+# the heap below its mmap threshold and from a fresh mapping, whose
+# pages fault in as they are filled, from it up. That threshold moves:
+# it starts at 128 KiB and rises to the size of any mapped chunk the
+# process frees, up to 32 MiB, and the trim threshold, above which
+# glibc hands the heap's freed top back to the kernel, follows it at
+# twice its value. So what a save cost hung on what the process had
+# freed before its first one (the step's executable read back from
+# the compile cache, for one): GPT-2 124M's stalled the step 175 ms
+# or 300. ``_keep_transfer_buffers_on_the_heap`` pins the first at its
+# ceiling, which also stops it moving, and raises the second (to the
+# largest ``mallopt``'s C int holds), so that a piece is heap memory
+# and its pages are still mapped at the next save: 155-160 ms either
+# way. Each alone leaves one of the two modes slow (PERF.md, PR 47,
+# both measured apart on a v5e); not options. The cost: up to
+# _AHEAD_BYTES of freed buffers stay in the process between saves and
+# are not handed back to the kernel. A user's own
+# MALLOC_MMAP_THRESHOLD_ or MALLOC_TRIM_THRESHOLD_ in the environment
+# wins.
+_MMAP_THRESHOLD = 32 << 20
+_TRIM_THRESHOLD = (2 << 30) - 1
+_M_TRIM_THRESHOLD, _M_MMAP_THRESHOLD = -1, -3  # <malloc.h>
 
 CKPT_EVENT_QUEUE = "ckpt_events"
 CKPT_STATUS_DICT = "ckpt_status"
@@ -151,6 +181,59 @@ def _linear_pieces():
     return jax.jit(cut, static_argnums=1)
 
 
+def _glibc_mallopt():
+    """glibc's ``mallopt`` as a function of two ints, or None where the
+    process's C library is another or has none."""
+    try:
+        libc = ctypes.CDLL(None)
+        libc.gnu_get_libc_version  # glibc alone has it
+        mallopt = libc.mallopt
+    except (OSError, AttributeError):
+        return None
+    mallopt.argtypes = (ctypes.c_int, ctypes.c_int)
+    mallopt.restype = ctypes.c_int
+    return mallopt
+
+
+@functools.cache
+def _keep_transfer_buffers_on_the_heap() -> None:
+    """Pin the C allocator's mmap and trim thresholds (the note at
+    ``_MMAP_THRESHOLD``), once a process (the cache is the guard): the
+    first engine built does it, importing this module does not. A
+    threshold the user set in the environment is left alone; where
+    ``mallopt`` is missing or refuses, a save reads the device as it
+    did before."""
+    mallopt = _glibc_mallopt()
+    glibc = mallopt is not None
+    pinned = {"mmap_threshold": None, "trim_threshold": None}
+    ok = glibc
+    if glibc:
+        for name, param, value in (
+                ("mmap_threshold", _M_MMAP_THRESHOLD, _MMAP_THRESHOLD),
+                ("trim_threshold", _M_TRIM_THRESHOLD, _TRIM_THRESHOLD)):
+            env = f"MALLOC_{name.upper()}_"
+            if env in os.environ:
+                logger.info("%s is set in the environment: %s is left "
+                            "to it", env, name)
+            elif mallopt(param, value) == 1:
+                pinned[name] = value
+            else:
+                ok = False
+    if not ok:
+        logger.warning(
+            "the C allocator's thresholds are not pinned (%s): a save's "
+            "transfer buffers may be fresh mappings",
+            "mallopt refused" if glibc else "no glibc")
+    obs.event("ckpt.heap_pinned", ok=ok,
+              libc="glibc" if glibc else "other", **pinned)
+
+
+def _minor_faults() -> int:
+    """Pages this process has had mapped in without I/O so far: a save
+    that fills fresh mappings adds one for every 4 KiB it moves."""
+    return resource.getrusage(resource.RUSAGE_SELF).ru_minflt
+
+
 class _ReadAhead:
     """The host arrays of ``shards`` (single-device ``jax.Array``s), in
     order: each item is an iterator over the ndarrays whose bytes, in
@@ -161,11 +244,15 @@ class _ReadAhead:
     a v5e, and 2.4 GB/s with all of them in flight: the buffers are
     fresh mappings whose pages fault in as they are filled. Many
     transfers of a few MiB in flight, each dropped once its bytes are
-    in the segment, read 3.6-7.3 GB/s (PERF.md, PR 25). So a shard
-    above ``_PIECE_BYTES`` is first laid out linearly on the device and
-    cut into pieces, and shards are started until ``_AHEAD_BYTES`` are
-    on their way; taking a shard's arrays starts the ones behind it.
-    ``in_flight`` counts the transfers started before the first wait.
+    in the segment, read 3.6-7.3 GB/s (PERF.md, PR 25), provided the
+    allocator serves them from the heap: its threshold for that moves
+    between 128 KiB and 32 MiB with what the process frees, so the
+    engine pins it (``_keep_transfer_buffers_on_the_heap``; PERF.md,
+    PR 47). So a shard above ``_PIECE_BYTES`` is first laid out
+    linearly on the device and cut into pieces, and shards are started
+    until ``_AHEAD_BYTES`` are on their way; taking a shard's arrays
+    starts the ones behind it. ``in_flight`` counts the transfers
+    started before the first wait.
     """
 
     def __init__(self, shards):
@@ -235,6 +322,7 @@ class CheckpointEngine:
             self._events = None
             self._status = None
         self._cached_step = -1
+        _keep_transfer_buffers_on_the_heap()
 
     # -- save ------------------------------------------------------------
 
@@ -280,13 +368,16 @@ class CheckpointEngine:
         nbytes = sum(e.nbytes for e in entries)
         with obs.span("ckpt.d2h", bytes=nbytes, leaves=leaves) as d2h:
             t0 = time.monotonic()
+            faults = _minor_faults()
             arrivals = _ReadAhead(shards)
             d2h.set(in_flight=arrivals.in_flight)
             with obs.span("ckpt.shm_copy", bytes=nbytes) as copy:
                 copy.set(write_s=round(
                     self._shm.save(step, entries, arrivals, extra), 6))
-            d2h.set(gbps=round(
-                nbytes / 1e9 / max(time.monotonic() - t0, 1e-9), 3))
+            d2h.set(
+                gbps=round(
+                    nbytes / 1e9 / max(time.monotonic() - t0, 1e-9), 3),
+                minor_faults=_minor_faults() - faults)
 
     def save_to_memory(self, step: int, state,
                        extra: Optional[dict] = None) -> bool:

@@ -6,6 +6,8 @@ thread in one process, sharded arrays on the virtual 8-device CPU mesh,
 reshard-on-load across different mesh shapes.
 """
 
+import ctypes
+import importlib.util
 import os
 import uuid
 
@@ -15,6 +17,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from dlrover_tpu import obs
 from dlrover_tpu.agent.ckpt_saver import AsyncCheckpointSaver
 from dlrover_tpu.common import ckpt_shm
 from dlrover_tpu.trainer.flash_checkpoint import engine as engine_mod
@@ -206,6 +209,112 @@ class TestReadAhead:
         assert (b"".join(p.tobytes() for p in got_big)
                 == np.asarray(big).tobytes())
         assert got_small[0].tobytes() == np.asarray(small).tobytes()
+
+
+_PIN_MMAP = (engine_mod._M_MMAP_THRESHOLD, engine_mod._MMAP_THRESHOLD)
+_PIN_TRIM = (engine_mod._M_TRIM_THRESHOLD, engine_mod._TRIM_THRESHOLD)
+
+
+class TestHeapPin:
+    """The engine pins the C allocator's mmap and trim thresholds, so
+    that a save's transfer buffers are heap memory whatever the process
+    freed before: once a process, when an engine is built."""
+
+    @pytest.mark.parametrize("env, mallopt, calls, pinned, ok, libc", [
+        pytest.param({}, "real", [_PIN_MMAP, _PIN_TRIM],
+                     (_PIN_MMAP[1], _PIN_TRIM[1]), True, "glibc",
+                     id="two-engines-pin-once"),
+        pytest.param({"MALLOC_MMAP_THRESHOLD_": "131072"}, "real",
+                     [_PIN_TRIM], (None, _PIN_TRIM[1]), True, "glibc",
+                     id="the-users-mmap-threshold-is-left-alone"),
+        pytest.param({"MALLOC_TRIM_THRESHOLD_": "131072"}, "real",
+                     [_PIN_MMAP], (_PIN_MMAP[1], None), True, "glibc",
+                     id="the-users-trim-threshold-is-left-alone"),
+        pytest.param({}, "missing", [], (None, None), False, "other",
+                     id="no-mallopt-and-the-save-goes-on"),
+        pytest.param({}, "refuses", [_PIN_MMAP, _PIN_TRIM], (None, None),
+                     False, "glibc",
+                     id="mallopt-returns-0-and-the-save-goes-on"),
+        pytest.param(None, "real", [], None, None, None,
+                     id="importing-the-module-pins-nothing"),
+    ])
+    def test_the_thresholds_are_pinned_once_a_process(
+            self, saver, monkeypatch, tmp_path, env, mallopt, calls,
+            pinned, ok, libc):
+        real = engine_mod._glibc_mallopt()
+        assert real is not None, "these machines run glibc"
+        made = []
+
+        def counted(param, value):
+            made.append((param, value))
+            return real(param, value) if mallopt == "real" else 0
+
+        pin = engine_mod._keep_transfer_buffers_on_the_heap
+        pin.cache_clear()
+        monkeypatch.setattr(
+            engine_mod, "_glibc_mallopt",
+            lambda: None if mallopt == "missing" else counted)
+        for name in ("MALLOC_MMAP_THRESHOLD_", "MALLOC_TRIM_THRESHOLD_"):
+            monkeypatch.delenv(name, raising=False)
+        for name, value in (env or {}).items():
+            monkeypatch.setenv(name, value)
+        tracer = obs.configure_tracer()
+        engines = []
+        try:
+            if env is None:
+                # A fresh copy of the module, run as an import runs it:
+                # the C library is not even looked up.
+                loaded = []
+                monkeypatch.setattr(ctypes, "CDLL", loaded.append)
+                spec = importlib.util.spec_from_file_location(
+                    "engine_imported_alone", engine_mod.__file__)
+                alone = importlib.util.module_from_spec(spec)
+                spec.loader.exec_module(alone)
+                alone_pin = alone._keep_transfer_buffers_on_the_heap
+                assert alone_pin.cache_info().currsize == 0 and not loaded
+            else:
+                engines = [
+                    CheckpointEngine(str(tmp_path / "ckpt"), use_agent=True)
+                    for _ in range(2)]
+                state = _state(_mesh((4, 2), ("data", "tensor")))
+                assert engines[0].save_to_storage(4, state)
+                assert engines[0].wait_persisted(4, timeout=20)
+                step, restored, _ = engines[1].load(state)
+                assert step == 4
+                for got, want in zip(jax.tree.leaves(restored),
+                                     jax.tree.leaves(state)):
+                    np.testing.assert_array_equal(
+                        np.asarray(got), np.asarray(want))
+            events = [e for e in tracer.events()
+                      if e["name"] == "ckpt.heap_pinned"]
+        finally:
+            obs.disable_tracer()
+            for engine in engines:
+                engine.close()
+            # The next engine of this process pins for itself again.
+            pin.cache_clear()
+        assert made == calls
+        if env is None:
+            assert events == []
+            return
+        (event,) = events
+        assert (event["mmap_threshold"], event["trim_threshold"]) == pinned
+        assert event["ok"] is ok and event["libc"] == libc
+
+    def test_the_d2h_span_counts_the_saves_minor_faults(self, tmp_path):
+        state = _state(_mesh((8,), ("data",)))
+        engine = CheckpointEngine(str(tmp_path / "ckpt"), use_agent=False)
+        tracer = obs.configure_tracer()
+        try:
+            assert engine.save_to_memory(1, state)
+            (d2h,) = [e for e in tracer.events()
+                      if e["name"] == "ckpt.d2h"]
+        finally:
+            obs.disable_tracer()
+            engine._shm.unlink()
+            engine.close()
+        assert type(d2h["minor_faults"]) is int
+        assert d2h["minor_faults"] >= 0
 
 
 class TestEngineSaverEndToEnd:
