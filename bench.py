@@ -209,16 +209,12 @@ def measure() -> int:
     )
     # Autotune pins (tools/autotune_bwd_blocks.py winner -> the watch
     # loop re-runs with these): BENCH_BLOCKS="bq,bk,bqb,bkb",
-    # BENCH_FUSED_NORM=0/1, BENCH_UNROLL=K.
+    # BENCH_UNROLL=K.
     if os.getenv("BENCH_BLOCKS"):
         blocks = tuple(
             int(x) for x in os.environ["BENCH_BLOCKS"].split(",")
         )
         cfg = dataclasses.replace(cfg, attn_blocks=blocks)
-    if os.getenv("BENCH_FUSED_NORM"):
-        cfg = dataclasses.replace(
-            cfg, use_fused_norm=os.environ["BENCH_FUSED_NORM"] == "1"
-        )
     if os.getenv("BENCH_SMOKE", "0") == "1":
         # Tiny model: validates the capture path end-to-end (probe,
         # child, JSON relay) in seconds on any backend. Not a perf run.
@@ -244,44 +240,15 @@ def measure() -> int:
         optimizer,
     )
     params, opt_state = init(jax.random.PRNGKey(0))
-    # BENCH_OVERLAP_REDUCE=1: bucketed gradient reduction issued as
-    # buckets finalize (parallel/compression.py) instead of XLA's
-    # monolithic post-backward reduce; BENCH_REDUCE_BUCKET_MB sizes
-    # the buckets, BENCH_REDUCE_BITS (4/8) quantizes their all-gather
-    # phase. The pure data-parallel bench mesh is exactly the regime
-    # the overlapped schedule supports.
-    _bits_env = os.getenv("BENCH_REDUCE_BITS", "")
-    overlap_on = os.getenv("BENCH_OVERLAP_REDUCE", "0") == "1"
-    overlap = (
-        {
-            "bucket_mb": float(
-                os.getenv("BENCH_REDUCE_BUCKET_MB", "4")
-            ),
-            "bits": int(_bits_env) if _bits_env else None,
-        }
-        if overlap_on
-        else {}
-    )
-    if overlap_on:
-        from dlrover_tpu.parallel.compression import (
-            make_overlapped_train_step,
-        )
-
-        step = make_overlapped_train_step(
-            mesh, loss, optimizer, **overlap
-        )
-    else:
-        step = make_train_step(mesh, loss, optimizer)
+    step = make_train_step(mesh, loss, optimizer)
 
     # The autotune pins in effect for THIS run (names+values — what
     # the emitted record and the bench ledger carry, so a
     # `bench_ledger compare` config mismatch is debuggable without
     # re-running), plus where the non-env ones came from.
     _PIN_KNOBS = (
-        "BENCH_REMAT", "BENCH_BLOCKS", "BENCH_FUSED_NORM",
-        "BENCH_UNROLL", "BENCH_XENT_CHUNKS", "BENCH_BATCH_PER_CHIP",
-        "BENCH_OVERLAP_REDUCE",
-        "BENCH_REDUCE_BUCKET_MB", "BENCH_REDUCE_BITS",
+        "BENCH_REMAT", "BENCH_BLOCKS", "BENCH_UNROLL",
+        "BENCH_XENT_CHUNKS", "BENCH_BATCH_PER_CHIP",
     )
     effective_pins = {
         k: os.environ[k] for k in _PIN_KNOBS if k in os.environ
@@ -384,15 +351,14 @@ def measure() -> int:
                 # never imports jax); the parent's provenance stamp
                 # and the ledger record key on it.
                 "backend": jax.default_backend(),
-                # Applied autotune pins (names+values) + provenance,
-                # the overlap config, and the tune-cache key — the
-                # ledger carries all of it, and capture_perf reuses
-                # the key to consult the cache before re-sweeping.
+                # Applied autotune pins (names+values) + provenance
+                # and the tune-cache key — the ledger carries all of
+                # it, and capture_perf reuses the key to consult the
+                # cache before re-sweeping.
                 "pins": effective_pins,
                 **(
                     {"pins_source": pins_source} if pins_source else {}
                 ),
-                **({"overlap": overlap} if overlap else {}),
                 "tune_key": tune_key,
                 **(
                     {"data_wait_s": round(data_wait_s, 4)}
@@ -412,10 +378,7 @@ def measure() -> int:
         if _cache is not None:
             _cache.record(
                 tune_key,
-                {
-                    "pins": effective_pins,
-                    "overlap": overlap or None,
-                },
+                {"pins": effective_pins},
                 per_chip,
                 extra={
                     "mfu": round(mfu, 4),

@@ -265,28 +265,7 @@ def _build_for_strategy(
     loss = _maybe_bind_seq_attention(
         model_loss, mesh, strategy, seq_attention_kwargs
     )
-    if strategy.overlap_reduce and not strategy.pure_data_parallel:
-        raise ValueError(
-            f"strategy {strategy.name()} sets overlap_reduce on a "
-            "non-pure-data mesh; overlapped reduction needs "
-            "replicated params"
-        )
-    if strategy.overlap_reduce:
-        # Bucketed reduces issued as gradients finalize (the schedule
-        # ElasticTrainer's overlap_reduce uses inside its accumulation
-        # scan; here accum collapses to 1 but bucketing still replaces
-        # XLA's monolithic post-backward reduce). Only sound when
-        # params are replicated over everything but ``data``.
-        from dlrover_tpu.parallel.compression import (
-            make_overlapped_train_step,
-        )
-
-        step = make_overlapped_train_step(
-            mesh, loss, optimizer,
-            bucket_mb=strategy.reduce_bucket_mb,
-        )
-    else:
-        step = make_train_step(mesh, loss, optimizer)
+    step = make_train_step(mesh, loss, optimizer)
     return mesh, optimizer, init, step
 
 
@@ -403,7 +382,7 @@ def _tune_cache_key(
     dims, per-sample batch shape/dtype, device extent, backend and
     toolchain versions (common/runmeta.trial_fingerprint). The
     per-trial *strategy* (mesh axis sizes, remat, dtype, optimizer,
-    microbatch, overlap knobs) is the trial's config, not part of the
+    microbatch) is the trial's config, not part of the
     key — one key indexes the whole candidate space's observations."""
     from dlrover_tpu.common.runmeta import (
         package_version,

@@ -51,16 +51,6 @@ def encode_strategy(s: Strategy) -> np.ndarray:
         1.0 if s.optimizer == o else 0.0 for o in _OPTIMIZERS
     )
     feats.extend(1.0 if s.dtype == t else 0.0 for t in _DTYPES)
-    # Overlapped-reduction knobs: the flag plus log2 bucket size, so
-    # the GP can tune bucket granularity smoothly once overlap is on
-    # (bucket size is meaningless when it is off — zeroed so off
-    # candidates collapse to one coordinate there).
-    feats.append(1.0 if s.overlap_reduce else 0.0)
-    feats.append(
-        math.log2(max(s.reduce_bucket_mb, 0.25))
-        if s.overlap_reduce
-        else 0.0
-    )
     return np.asarray(feats, np.float64)
 
 

@@ -30,23 +30,6 @@ class Strategy:
     # ring otherwise — parallel/seq_attention.py), or forced
     # "ring"/"a2a".
     seq_impl: str = "auto"
-    # Overlapped gradient reduction (parallel/compression.py
-    # make_overlapped_train_step / ElasticTrainer overlap_reduce):
-    # bucketed per-microbatch psum_mean issued inside the
-    # accumulation scan so reduce latency hides behind backward
-    # compute. Only meaningful on a pure data-parallel mesh
-    # (replicated params); the bucket size is a tunable knob the
-    # bayes search can sweep.
-    overlap_reduce: bool = False
-    reduce_bucket_mb: float = 4.0
-
-    @property
-    def pure_data_parallel(self) -> bool:
-        """True when the mesh replicates params: every non-``data``
-        axis has extent 1 (the regime overlapped reduction needs)."""
-        return all(
-            s == 1 for a, s in self.mesh_shape if a != "data"
-        )
 
     @property
     def mesh_dict(self) -> Dict[str, int]:
@@ -60,15 +43,10 @@ class Strategy:
     def name(self) -> str:
         mesh = "x".join(f"{a}{s}" for a, s in self.mesh_shape if s > 1)
         sp = "" if self.seq_impl == "auto" else f"-sp:{self.seq_impl}"
-        ov = (
-            f"-ov:{self.reduce_bucket_mb:g}mb"
-            if self.overlap_reduce
-            else ""
-        )
         return (
             f"{mesh or 'single'}-{self.dtype}"
             f"-remat:{self._remat_name()}-{self.optimizer}"
-            f"-mb{self.micro_batch_size}{sp}{ov}"
+            f"-mb{self.micro_batch_size}{sp}"
         )
 
     def to_json(self) -> str:
@@ -105,8 +83,6 @@ def candidate_strategies(
     max_tensor: int = 8,
     max_pipe: int = 8,
     seq_impls: Tuple[str, ...] = ("auto",),
-    overlap_reduces: Tuple[bool, ...] = (False,),
-    reduce_bucket_mbs: Tuple[float, ...] = (4.0,),
 ) -> List[Strategy]:
     """Enumerate the raw candidate grid (the reference's
     CombinationAlgorithm, auto/engine/sg_algo/combination_sg.py:16).
@@ -131,27 +107,17 @@ def candidate_strategies(
         # The seq_impl knob only distinguishes candidates when a seq
         # axis exists (otherwise every family degenerates identically).
         sps = seq_impls if d.get("seq", 1) > 1 else ("auto",)
-        # Overlapped reduction only exists for pure data-parallel
-        # factorizations (replicated params); elsewhere the knob
-        # degenerates to off so the grid stays duplicate-free.
-        pure_dp = all(s == 1 for a, s in shape if a != "data")
-        ovs = overlap_reduces if pure_dp else (False,)
-        for mb, dt, opt, rm, sp, ov in itertools.product(
-            micro_batch_sizes, dtypes, optimizers, remats, sps, ovs
+        for mb, dt, opt, rm, sp in itertools.product(
+            micro_batch_sizes, dtypes, optimizers, remats, sps
         ):
-            # Bucket size only distinguishes overlapped candidates.
-            bks = reduce_bucket_mbs if ov else (4.0,)
-            for bk in bks:
-                out.append(
-                    Strategy(
-                        mesh_shape=shape,
-                        remat=rm,
-                        dtype=dt,
-                        optimizer=opt,
-                        micro_batch_size=mb,
-                        seq_impl=sp,
-                        overlap_reduce=ov,
-                        reduce_bucket_mb=bk,
-                    )
+            out.append(
+                Strategy(
+                    mesh_shape=shape,
+                    remat=rm,
+                    dtype=dt,
+                    optimizer=opt,
+                    micro_batch_size=mb,
+                    seq_impl=sp,
                 )
+            )
     return out

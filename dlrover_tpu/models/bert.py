@@ -6,7 +6,7 @@ module_replace_optimization.py; FlashMHA mappings
 atorch/modules/transformer/layers.py) and training it through
 auto_accelerate. Here the encoder IS models/gpt.py's backbone with
 ``causal=False`` — identical learned positions, pre-LN blocks, GELU
-MLP, fused-norm and flash kernels, sharding rules and remat policies
+MLP, flash kernels, sharding rules and remat policies
 all apply unchanged — plus the two training surfaces BERT adds:
 
 * the masked-language-model objective (:func:`mask_tokens` +

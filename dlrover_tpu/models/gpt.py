@@ -48,9 +48,6 @@ class GPTConfig:
     remat: Any = True
     # None = auto (flash on TPU at long context); True/False forces.
     use_flash_attention: Optional[bool] = None
-    # None = auto (fused Pallas norm kernels on TPU,
-    # ops/layer_norm.py); True/False forces.
-    use_fused_norm: Optional[bool] = None
     # Declared attention masking. Decoder-only LMs are causal; the
     # auto_accelerate seq-parallel binding reads this so a non-causal
     # model config is never silently given a causal mask.
@@ -166,24 +163,6 @@ def _layer_norm(x, g, b, eps=1e-5):
     return out.astype(x.dtype)
 
 
-def use_fused_norm(cfg) -> bool:
-    """Fused Pallas norms (ops/layer_norm.py) are OPT-IN, default off.
-
-    Measured on v5e (fwd+bwd grad, N=16384 rows, 2026-07-31): XLA's
-    own norm fusion wins at every width — 4.5-5.9 ms vs the Pallas
-    kernel's 18.8-30.5 ms across E in {768, 1024, 2048, 4096, 8192};
-    at the bench config the A/B costs ~1 ms/step (0.891 vs 0.909
-    vs_baseline). The dgamma/dbeta accumulator serializes the row
-    grid ("arbitrary" semantics, one shared partial block), while
-    XLA parallelizes the reduction freely. The kernel stays for
-    capability parity (the reference ships a fused LayerNorm,
-    atorch/normalization) and for hardware where XLA's fusion is
-    weaker — select it per-config with use_fused_norm=True."""
-    if cfg.use_fused_norm is not None:
-        return cfg.use_fused_norm
-    return False
-
-
 def _default_attention(q, k, v, causal=True, window=None, scale=None):
     """Plain fused attention (single-shard fallback; the sharded path
     comes from parallel.ring_attention.make_sharded_attention).
@@ -234,17 +213,8 @@ def _block(x, lp, cfg: GPTConfig, attn_fn):
 
     B, T, E = x.shape
     H, D = cfg.n_head, cfg.head_dim
-    fused = use_fused_norm(cfg)
-    if fused:
-        from dlrover_tpu.ops.layer_norm import (
-            fused_add_layer_norm,
-            fused_layer_norm,
-        )
     with jax.named_scope("attn"):
-        if fused:
-            h = fused_layer_norm(x, lp["ln1_g"], lp["ln1_b"])
-        else:
-            h = _layer_norm(x, lp["ln1_g"], lp["ln1_b"])
+        h = _layer_norm(x, lp["ln1_g"], lp["ln1_b"])
         qkv = keep(h @ lp["wqkv"], ATTN_IN)  # [B,T,3E]
         q, k, v = jnp.split(qkv, 3, axis=-1)
         q = q.reshape(B, T, H, D)
@@ -253,15 +223,8 @@ def _block(x, lp, cfg: GPTConfig, attn_fn):
         att = attn_fn(q, k, v).reshape(B, T, E)
         att_out = att @ lp["wo"]
     with jax.named_scope("mlp"):
-        if fused:
-            # The attention residual add rides inside the norm kernel
-            # (one HBM pass for the branch point).
-            h, x = fused_add_layer_norm(
-                att_out, x, lp["ln2_g"], lp["ln2_b"]
-            )
-        else:
-            x = x + att_out
-            h = _layer_norm(x, lp["ln2_g"], lp["ln2_b"])
+        x = x + att_out
+        h = _layer_norm(x, lp["ln2_g"], lp["ln2_b"])
         h = jax.nn.gelu(keep(h @ lp["wi"], MLP_HIDDEN) + lp["bi"])
         x = x + h @ lp["wo2"] + lp["bo2"]
     return x

@@ -52,8 +52,6 @@ class LlamaConfig:
     dtype: Any = jnp.bfloat16
     remat: Any = True  # same named policies as GPTConfig.remat
     use_flash_attention: Optional[bool] = None
-    # None = auto (fused Pallas RMSNorm on TPU, ops/layer_norm.py).
-    use_fused_norm: Optional[bool] = None
     # Declared attention masking (read by the auto_accelerate
     # seq-parallel binding, like GPTConfig.causal).
     causal: bool = True
@@ -434,32 +432,14 @@ def attention_half(h, lp, cfg: LlamaConfig, attn_fn, cos, sin):
 def _block(x, lp, cfg: LlamaConfig, attn_fn, cos, sin):
     """One block. Returns (x, aux_loss) — aux is 0 for dense MLPs,
     this layer's share of the router losses for MoE blocks."""
-    from dlrover_tpu.models.gpt import use_fused_norm
-
-    fused = use_fused_norm(cfg)
-    if fused:
-        from dlrover_tpu.ops.layer_norm import (
-            fused_add_rms_norm,
-            fused_rms_norm,
-        )
     # The scopes models/gpt.py has: the module profiler and the
     # device trace's operation metadata attribute cost by them.
     with jax.named_scope("attn"):
-        if fused:
-            h = fused_rms_norm(x, lp["rms1"], eps=cfg.rms_eps)
-        else:
-            h = _rms_norm(x, lp["rms1"], cfg.rms_eps)
+        h = _rms_norm(x, lp["rms1"], cfg.rms_eps)
         att_out = attention_half(h, lp, cfg, attn_fn, cos, sin)
     with jax.named_scope("mlp"):
-        if fused:
-            # Attention residual add fused into the second norm's
-            # kernel.
-            h, x = fused_add_rms_norm(
-                att_out, x, lp["rms2"], eps=cfg.rms_eps
-            )
-        else:
-            x = x + att_out
-            h = _rms_norm(x, lp["rms2"], cfg.rms_eps)
+        x = x + att_out
+        h = _rms_norm(x, lp["rms2"], cfg.rms_eps)
         return mlp_tail(x, h, lp, cfg)
 
 

@@ -53,17 +53,6 @@ logger = get_logger("elastic_trainer")
 _STEPS_TOTAL = obs.counter(
     "dlrover_train_steps_total", "Optimizer steps taken this process"
 )
-_REDUCE_BUCKETS = obs.gauge(
-    "dlrover_train_reduce_buckets",
-    "Gradient-reduce buckets per microbatch in the overlapped "
-    "schedule (0 = serial monolithic reduce)",
-)
-_SYNC_BYTES_PER_EL = obs.gauge(
-    "dlrover_train_sync_bytes_per_element",
-    "Bytes moved per gradient element per optimizer step by the "
-    "configured gradient sync (4.0 = exact serial allreduce; the "
-    "overlapped schedule pays this once per microbatch)",
-)
 _STEP_SECONDS = obs.histogram(
     "dlrover_train_step_seconds",
     "Wall time between consecutive train_step DISPATCHES (first "
@@ -129,9 +118,6 @@ class ElasticTrainer:
         step_fn: Optional[Callable] = None,
         donate_state: bool = True,
         report_max_pending: int = 8,
-        overlap_reduce: Optional[bool] = None,
-        reduce_bucket_mb: Optional[float] = None,
-        reduce_bits: Optional[int] = None,
     ):
         """``step_fn``: a prebuilt full-batch training step —
         ``step_fn(params, opt_state, tokens[B, ...], targets) ->
@@ -155,23 +141,7 @@ class ElasticTrainer:
 
         ``report_max_pending``: bound of the async reporter's deque of
         un-materialized (step, device-loss) entries; above it the
-        oldest entry is force-fetched so memory stays bounded.
-
-        ``overlap_reduce``: build the accumulate-then-update step with
-        bucketed per-microbatch gradient reduction issued INSIDE the
-        scan (parallel/compression.py bucketed_psum_mean), so
-        microbatch k's all-reduce overlaps microbatch k+1's backward
-        instead of one monolithic reduce after the loop. Requires a
-        pure data-parallel mesh (replicated params — every non-data
-        axis extent 1) and the built-in step (no external step_fn).
-        ``None`` reads ``DLROVER_TPU_OVERLAP_REDUCE`` (default off).
-        ``reduce_bucket_mb`` bounds each reduce bucket (default 4, or
-        ``DLROVER_TPU_REDUCE_BUCKET_MB``); ``reduce_bits`` of 4/8
-        additionally quantizes each bucket's all-gather phase
-        (``DLROVER_TPU_REDUCE_BITS``; unset = exact sync). The
-        donation / zero-host-sync contracts are identical to the
-        serial step, and numerics parity is tested
-        (tests/test_elastic_trainer.py)."""
+        oldest entry is force-fetched so memory stays bounded."""
         self.mesh = mesh
         self.loss_fn = loss_fn
         self.optimizer = optimizer
@@ -186,28 +156,6 @@ class ElasticTrainer:
         # the tradeoff is bf16's ~8-bit mantissa on the running sum.
         self.accum_dtype = accum_dtype
         self.donate_state = donate_state
-        # Env-resolved overlap knobs (ctor args win; the env lets a
-        # deployed job flip the schedule without a code change). An
-        # env-defaulted opt-in downgrades to the serial step where the
-        # schedule can't apply (external step_fn, non-pure-data mesh)
-        # — a fleet-wide DLROVER_TPU_OVERLAP_REDUCE=1 must speed up
-        # the data-parallel jobs, not kill every other job at build
-        # time. Only an EXPLICIT overlap_reduce=True raises there.
-        _overlap_explicit = overlap_reduce is not None
-        if overlap_reduce is None:
-            overlap_reduce = (
-                os.getenv("DLROVER_TPU_OVERLAP_REDUCE", "0") == "1"
-            )
-        if reduce_bucket_mb is None:
-            reduce_bucket_mb = float(
-                os.getenv("DLROVER_TPU_REDUCE_BUCKET_MB", "4")
-            )
-        if reduce_bits is None:
-            _bits_env = os.getenv("DLROVER_TPU_REDUCE_BITS", "")
-            reduce_bits = int(_bits_env) if _bits_env else None
-        self.overlap_reduce = bool(overlap_reduce)
-        self.reduce_bucket_mb = float(reduce_bucket_mb)
-        self.reduce_bits = reduce_bits
         self.num_shards = data_shards(mesh)
         self.step_num = 0
         # Loss scalars reach report_fn via the async drain: the hot
@@ -241,21 +189,6 @@ class ElasticTrainer:
                     "pass either loss_fn or step_fn, not both — "
                     "step_fn would silently win"
                 )
-            if self.overlap_reduce:
-                if not _overlap_explicit:
-                    logger.warning(
-                        "ignoring DLROVER_TPU_OVERLAP_REDUCE=1: an "
-                        "external step_fn owns its own collective "
-                        "schedule"
-                    )
-                    self.overlap_reduce = False
-                else:
-                    raise ValueError(
-                        "overlap_reduce applies to the built-in "
-                        "accumulate-then-update step; an external "
-                        "step_fn (e.g. a 1F1B pipeline) owns its own "
-                        "collective schedule"
-                    )
             # The external step (e.g. a 1F1B pipeline) consumes the
             # WHOLE global batch in one call and owns its own
             # microbatching: accumulation collapses to 1, and the
@@ -281,32 +214,7 @@ class ElasticTrainer:
             self.accum_steps = gradient_accumulation_steps(
                 global_batch_size, micro_batch_size, self.num_shards
             )
-            if self.overlap_reduce:
-                impure = {
-                    a: s
-                    for a, s in mesh.shape.items()
-                    if a != "data" and s > 1
-                }
-                if impure and not _overlap_explicit:
-                    logger.warning(
-                        "ignoring DLROVER_TPU_OVERLAP_REDUCE=1: this "
-                        "mesh shards params over %s; using the serial "
-                        "GSPMD step",
-                        impure,
-                    )
-                    self.overlap_reduce = False
-                elif impure:
-                    raise ValueError(
-                        "overlap_reduce needs a pure data-parallel "
-                        "mesh (replicated params); this mesh shards "
-                        f"over {impure} — use the serial GSPMD step "
-                        "(overlap_reduce=False), which lets XLA "
-                        "schedule those axes' collectives"
-                    )
-            if self.overlap_reduce:
-                self._compiled = self._build_overlapped_step()
-            else:
-                self._compiled = self._build_step()
+            self._compiled = self._build_step()
         self._compile_tracker = CompileTracker(
             "train_step", jfn=self._compiled
         )
@@ -375,121 +283,6 @@ class ElasticTrainer:
 
         self._mb_spec = mb_spec
         return StateStep(train_step, self._donate_argnums())
-
-    def _build_overlapped_step(self):
-        """The overlap_reduce variant of :meth:`_build_step`: same
-        accumulate-then-update semantics, but built as an explicit
-        shard_map over the data axis so each microbatch's gradients
-        are mean-reduced in size-bounded buckets INSIDE the scan body
-        — every bucket's psum is an independent collective whose
-        result feeds only the accumulator add, so the scheduler can
-        run microbatch k's reduce behind microbatch k+1's backward.
-        The serial step reduces once, implicitly, after the loop;
-        this schedule pays accum x the collective volume (cut back by
-        ``reduce_bits`` quantization) to buy the overlap. Numerics:
-        sum of per-microbatch means == mean of sums, so parity with
-        the serial step holds to float tolerance."""
-        from dlrover_tpu.parallel.compression import (
-            bucket_plan,
-            bucketed_psum_mean,
-            overlap_sync_bytes_per_element,
-        )
-        from jax import shard_map
-
-        accum = self.accum_steps
-        loss_fn = self.loss_fn
-        optimizer = self.optimizer
-        mesh = self.mesh
-        axis = "data"
-        bspec = batch_spec(mesh)
-        mb_spec = P(None, *bspec)
-        acc_dtype = (
-            self.accum_dtype
-            if self.accum_dtype is not None
-            else jnp.float32
-        )
-        bucket_bytes = int(self.reduce_bucket_mb * (1 << 20))
-        bits = self.reduce_bits
-        trainer = self
-
-        def sharded_step(params, opt_state, tokens, targets):
-            # Trace-time note (once per compile, host-side only): the
-            # bucket plan is static in the param shapes, so this is
-            # where the overlap config becomes observable.
-            trainer._note_overlap_plan(
-                bucket_plan(jax.tree.leaves(params), bucket_bytes)
-            )
-
-            def micro(carry, batch):
-                grad_acc, loss_acc = carry
-                mb_tokens, mb_targets = batch
-                loss, grads = jax.value_and_grad(loss_fn)(
-                    params, mb_tokens, mb_targets
-                )
-                # Pre-scale by 1/accum (same low-precision-accumulator
-                # rationale as the serial step), reduce THIS
-                # microbatch's buckets now, accumulate the reduced
-                # result.
-                reduced = bucketed_psum_mean(
-                    jax.tree.map(lambda g: g / accum, grads),
-                    axis,
-                    bucket_bytes=bucket_bytes,
-                    bits=bits,
-                )
-                grad_acc = jax.tree.map(
-                    lambda a, g: a + g.astype(a.dtype),
-                    grad_acc,
-                    reduced,
-                )
-                return (grad_acc, loss_acc + loss), None
-
-            with jax.named_scope("accumulate"):
-                zeros = jax.tree.map(
-                    lambda p: jnp.zeros(p.shape, acc_dtype), params
-                )
-                (grads, loss_sum), _ = jax.lax.scan(
-                    micro, (zeros, 0.0), (tokens, targets)
-                )
-            # Per-shard losses are local means; pmean makes the
-            # returned scalar the global-batch mean, matching the
-            # serial step's replicated loss.
-            loss = jax.lax.pmean(loss_sum / accum, axis)
-            with jax.named_scope("optimizer"):
-                updates, opt_state = optimizer.update(
-                    grads, opt_state, params
-                )
-                params = optax.apply_updates(params, updates)
-            return params, opt_state, loss
-
-        rep = P()
-        fn = shard_map(
-            sharded_step,
-            mesh=mesh,
-            in_specs=(rep, rep, mb_spec, mb_spec),
-            out_specs=(rep, rep, rep),
-            check_vma=False,
-        )
-        self._mb_spec = mb_spec
-        self._overlap_bytes_per_el = overlap_sync_bytes_per_element(
-            bits, accum
-        )
-        return jax.jit(fn, donate_argnums=self._donate_argnums())
-
-    def _note_overlap_plan(self, plan) -> None:
-        """Trace-time observability hook for the overlapped schedule:
-        bucket count + per-element sync bytes as gauges and a trace
-        event (once per (re)compile — recompiles re-note, which is
-        exactly when the plan could have changed)."""
-        _REDUCE_BUCKETS.set(len(plan))
-        _SYNC_BYTES_PER_EL.set(self._overlap_bytes_per_el)
-        obs.event(
-            "trainer.overlap_reduce",
-            buckets=len(plan),
-            bucket_mb=self.reduce_bucket_mb,
-            bits=self.reduce_bits or 0,
-            accum_steps=self.accum_steps,
-            bytes_per_element=self._overlap_bytes_per_el,
-        )
 
     def _wrap_flat_step(self, step_fn):
         """Adapt an external full-batch step to the trainer's

@@ -152,21 +152,12 @@ class Trainer:
             strategy=args.strategy,
             optimizer_kwargs=self._optimizer_kwargs(),
         )
-        # A strategy that selected overlapped gradient reduction (the
-        # search can tune it) forces the trainer onto that schedule;
-        # otherwise the env default (DLROVER_TPU_OVERLAP_REDUCE)
-        # decides.
-        _overlap = getattr(res.strategy, "overlap_reduce", False)
         trainer = ElasticTrainer(
             res.mesh,
             self.model_loss,
             res.optimizer,
             global_batch_size=args.global_batch_size,
             micro_batch_size=args.micro_batch_size,
-            overlap_reduce=True if _overlap else None,
-            reduce_bucket_mb=(
-                res.strategy.reduce_bucket_mb if _overlap else None
-            ),
         )
         params, opt_state = res.init_fn(
             jax.random.PRNGKey(args.seed)

@@ -512,80 +512,23 @@ class TestTuneCacheWarmStart:
         assert len(self._dry_runs(self._run(cache))) == 0
 
 
-class TestOverlapStrategy:
-    def test_grid_overlap_only_on_pure_data_factorizations(self):
-        cands = candidate_strategies(
-            8,
-            micro_batch_sizes=(4,),
-            remats=(False,),
-            overlap_reduces=(False, True),
-            reduce_bucket_mbs=(2.0, 8.0),
-        )
-        with_ov = [c for c in cands if c.overlap_reduce]
-        assert with_ov, "no overlap candidates generated"
-        assert all(c.pure_data_parallel for c in with_ov)
-        # bucket size only multiplies overlapped candidates
-        assert {c.reduce_bucket_mb for c in with_ov} == {2.0, 8.0}
-        assert all(
-            c.reduce_bucket_mb == 4.0
-            for c in cands
-            if not c.overlap_reduce
-        )
-        assert len({c.name() for c in cands}) == len(cands)
-        s = with_ov[0]
-        assert Strategy.from_json(s.to_json()) == s
-
-    def test_explicit_overlap_strategy_trains(self):
-        init, loss, axes = _model()
-        s = Strategy(
-            mesh_shape=(("data", 4),),
-            dtype="float32",
-            micro_batch_size=4,
-            overlap_reduce=True,
-            reduce_bucket_mb=0.5,
-        )
-        res = auto_accelerate(
-            init, loss, axes, _sample_batch(), strategy=s,
-            devices=jax.devices()[:4],
-        )
-        params, opt_state = res.init_fn(jax.random.PRNGKey(0))
-        tokens, targets = res.shard_batch_fn(*_sample_batch(4))
-        losses = []
-        for _ in range(5):
-            params, opt_state, metrics = res.step_fn(
-                params, opt_state, tokens, targets
-            )
-            losses.append(float(metrics["loss"]))
-        assert losses[-1] < losses[0]
-
-    def test_overlap_on_sharded_mesh_rejected(self):
-        init, loss, axes = _model()
-        s = Strategy(
-            mesh_shape=(("data", 2), ("fsdp", 2)),
-            dtype="float32",
-            micro_batch_size=4,
-            overlap_reduce=True,
-        )
-        with pytest.raises(ValueError, match="overlap_reduce"):
-            auto_accelerate(
-                init, loss, axes, _sample_batch(), strategy=s,
-                devices=jax.devices()[:4],
-            )
-
-
 # What the grid holds, by the three things a candidate is chosen for:
 # (n_devices, candidates, sha256 of the sorted (mesh_shape, remat,
 # micro_batch_size) reprs, first 16 hex digits). Three of the four
 # remat values PR 28's grid had (180 and 420 candidates): the fourth
 # was a name for what remat=True means since PR 33.
 _PARENT_GRID = {4: (135, "2c01bda2b9c55eac"), 8: (315, "935cd865bebf57b5")}
-_RETIRED = ("pipeline_depth", "device_prefetch", "-pd:", "-devpf:")
+_RETIRED = (
+    "pipeline_depth", "device_prefetch", "-pd:", "-devpf:",
+    "overlap_reduce", "reduce_bucket_mb", "-ov:",
+)
 
 
 @pytest.mark.parametrize("n_devices", [4, 8])
 def test_candidate_grid_unchanged_by_retired_axes(n_devices):
-    """Retiring the two input-pipelining axes removed no candidate
-    and added none, and no Strategy still spells a retired field."""
+    """Retiring the two input-pipelining axes, and after them the two
+    of the overlapped gradient reduction, removed no candidate and
+    added none, and no Strategy still spells a retired field."""
     import hashlib
 
     cands = candidate_strategies(n_devices)
@@ -601,7 +544,7 @@ def test_candidate_grid_unchanged_by_retired_axes(n_devices):
     for c in cands:
         assert not any(r in c.name() for r in _RETIRED), c.name()
         assert not any(r in c.to_json() for r in _RETIRED), c.to_json()
-    assert len(dataclasses.fields(Strategy)) == 8
+    assert len(dataclasses.fields(Strategy)) == 6
 
 
 def test_search_raises_when_nothing_fits():

@@ -120,21 +120,6 @@ class TestBayesSearch:
         np.testing.assert_allclose(mu, y, atol=0.3)
         assert (sigma < 0.3).all()
 
-    def test_encoding_covers_overlap_knobs(self):
-        base = Strategy(mesh_shape=(("data", 8),))
-        ov = Strategy(mesh_shape=(("data", 8),), overlap_reduce=True)
-        ov_big = Strategy(
-            mesh_shape=(("data", 8),),
-            overlap_reduce=True,
-            reduce_bucket_mb=16.0,
-        )
-        assert not np.array_equal(
-            encode_strategy(base), encode_strategy(ov)
-        )
-        assert not np.array_equal(
-            encode_strategy(ov), encode_strategy(ov_big)
-        )
-
 
 class TestObserveDedupe:
     """Re-observed cached trials and duplicated candidate grids must

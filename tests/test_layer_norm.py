@@ -1,190 +1,121 @@
-"""Fused Pallas LayerNorm/RMSNorm (+residual) vs plain XLA norms.
+"""The models' one norm: ``models/gpt._layer_norm`` and
+``models/llama._rms_norm``, the plain functions XLA fuses and every
+cell runs, against float64 ``numpy`` (no ``jax.numpy`` in the
+references).
 
-Interpreter mode on CPU exercises the exact kernels that compile on
-TPU (same policy as tests/test_flash_attention.py). Parity target:
-the reference's fused dropout_add_layer_norm integration
-(atorch/modules/transformer/layers.py:74) at dropout 0.
+Both compute in float32 and cast back once: for bfloat16 inputs the
+output is held to the exact result rounded one time (half a unit in
+the last place of bfloat16), which a product or a mean taken in
+bfloat16 on the way would miss by several.
 """
-
-import functools
 
 import jax
 import jax.numpy as jnp
+import ml_dtypes
 import numpy as np
 import pytest
 
-from dlrover_tpu.ops.layer_norm import (
-    fused_add_layer_norm,
-    fused_add_rms_norm,
-    fused_layer_norm,
-    fused_rms_norm,
+from dlrover_tpu.models.gpt import _layer_norm
+from dlrover_tpu.models.llama import _rms_norm
+
+EPS = 1e-5
+ROWS = (3, 70)
+# Relative bounds from the dtype's significand (24 and 8 bits): a few
+# units of float32 for a result computed in float32, half a unit of
+# bfloat16 for that result rounded once, a whole unit where a
+# gradient's cotangent was itself a rounded output.
+F32 = 2.0**-20
+BF16_HALF_ULP = 2.0**-8
+BF16_ULP = 2.0**-7
+
+
+def f64(a):
+    return np.asarray(a).astype(np.float64)
+
+
+def ref_forward(kind, x, g, b):
+    if kind == "layer":
+        mu = x.mean(-1, keepdims=True)
+        xhat = (x - mu) / np.sqrt(x.var(-1, keepdims=True) + EPS)
+        return xhat * g + b
+    return x / np.sqrt((x * x).mean(-1, keepdims=True) + EPS) * g
+
+
+def ref_grads(kind, x, g, b, y):
+    """Gradients of ``sum(y ** 2)`` to the input, the gain and (layer)
+    the bias, from the norm's own derivative; ``y`` is the output the
+    squares were taken of (the rounded one for bfloat16)."""
+    dy = 2.0 * y
+    rows = tuple(range(x.ndim - 1))
+    if kind == "layer":
+        mu = x.mean(-1, keepdims=True)
+        rstd = 1.0 / np.sqrt(x.var(-1, keepdims=True) + EPS)
+        xhat = (x - mu) * rstd
+        dxhat = dy * g
+        dx = rstd * (
+            dxhat
+            - dxhat.mean(-1, keepdims=True)
+            - xhat * (dxhat * xhat).mean(-1, keepdims=True)
+        )
+        return dx, (dy * xhat).sum(rows), dy.sum(rows)
+    s = 1.0 / np.sqrt((x * x).mean(-1, keepdims=True) + EPS)
+    dxs = dy * g
+    dx = s * dxs - x * s**3 * (dxs * x).mean(-1, keepdims=True)
+    return dx, (dy * x * s).sum(rows)
+
+
+def norm(kind):
+    if kind == "layer":
+        return lambda x, g, b: _layer_norm(x, g, b, EPS)
+    return lambda x, g, b: _rms_norm(x, g, EPS)
+
+
+def close(got, want, rel):
+    """Within ``rel`` of each value, and of the array's typical
+    magnitude where a value is near zero by cancellation."""
+    got, want = f64(got), f64(want)
+    bound = rel * (np.abs(want) + np.abs(want).mean())
+    worst = np.max(np.abs(got - want) - bound)
+    assert worst <= 0, (worst, np.max(np.abs(got - want)))
+
+
+@pytest.mark.parametrize("what", ["forward", "grads"])
+@pytest.mark.parametrize("width", [768, 4096])
+@pytest.mark.parametrize(
+    "dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"]
 )
+@pytest.mark.parametrize("kind", ["layer", "rms"])
+def test_norm_matches_float64_reference(kind, dtype, width, what):
+    rng = np.random.default_rng(width)
+    # off-centre rows, so the mean LayerNorm subtracts is not ~0
+    x = jnp.asarray(rng.normal(0.3, 1.5, ROWS + (width,)), dtype)
+    g = jnp.asarray(1.0 + rng.normal(0, 1, width), dtype)
+    b = jnp.asarray(0.1 * rng.normal(0, 1, width), dtype)
+    fn = norm(kind)
+    # the references see the inputs as the function does: a bfloat16
+    # value is a float64 value
+    x64, g64, b64 = f64(x), f64(g), f64(b)
+    y64 = ref_forward(kind, x64, g64, b64)
+    bf16 = dtype == jnp.bfloat16
 
+    if what == "forward":
+        got = jax.jit(fn)(x, g, b)
+        assert got.dtype == dtype and got.shape == x.shape
+        # bfloat16: the float32 result rounded once is within half a
+        # unit of the exact one (and float32's own error)
+        close(got, y64, BF16_HALF_ULP + F32 if bf16 else F32)
+        return
 
-def ref_ln(x, g, b, eps=1e-5):
-    x32 = x.astype(jnp.float32)
-    mu = jnp.mean(x32, -1, keepdims=True)
-    var = jnp.var(x32, -1, keepdims=True)
-    out = (x32 - mu) * jax.lax.rsqrt(var + eps) * g
-    if b is not None:
-        out = out + b
-    return out.astype(x.dtype)
+    # The squares are summed in float32 so that the cotangent into the
+    # norm is twice its (rounded) output, exactly.
+    def loss(x, g, b):
+        return jnp.sum(fn(x, g, b).astype(jnp.float32) ** 2)
 
-
-def ref_rms(x, g, eps=1e-6):
-    x32 = x.astype(jnp.float32)
-    s = jax.lax.rsqrt(jnp.mean(x32**2, -1, keepdims=True) + eps)
-    return (x32 * s * g).astype(x.dtype)
-
-
-@pytest.fixture()
-def data():
-    k = jax.random.PRNGKey(0)
-    # 210 rows: not a multiple of the row block, exercises padding.
-    x = jax.random.normal(k, (3, 70, 64), jnp.float32)
-    r = jax.random.normal(jax.random.PRNGKey(1), x.shape)
-    g = jax.random.normal(jax.random.PRNGKey(2), (64,)) + 1.0
-    b = jax.random.normal(jax.random.PRNGKey(3), (64,)) * 0.1
-    return x, r, g, b
-
-
-class TestForward:
-    def test_layer_norm_matches(self, data):
-        x, _, g, b = data
-        np.testing.assert_allclose(
-            fused_layer_norm(x, g, b), ref_ln(x, g, b),
-            atol=1e-5, rtol=1e-5,
-        )
-
-    def test_rms_norm_matches(self, data):
-        x, _, g, _ = data
-        np.testing.assert_allclose(
-            fused_rms_norm(x, g), ref_rms(x, g),
-            atol=1e-5, rtol=1e-5,
-        )
-
-    def test_add_layer_norm_fuses_residual(self, data):
-        x, r, g, b = data
-        out, resid = fused_add_layer_norm(x, r, g, b)
-        np.testing.assert_allclose(
-            out, ref_ln(x + r, g, b), atol=1e-5, rtol=1e-5
-        )
-        np.testing.assert_allclose(resid, x + r, atol=1e-6)
-
-    def test_bf16_no_bias_under_jit(self, data):
-        x, _, g, _ = data
-        xb = x.astype(jnp.bfloat16)
-        got = jax.jit(fused_layer_norm)(xb, g, None)
-        want = ref_ln(xb, g, None)
-        np.testing.assert_allclose(
-            got.astype(jnp.float32), want.astype(jnp.float32),
-            atol=3e-2, rtol=3e-2,
-        )
-
-
-class TestBackward:
-    def test_layer_norm_grads_match(self, data):
-        x, _, g, b = data
-
-        def f(x, g, b):
-            return jnp.sum(jnp.sin(fused_layer_norm(x, g, b)))
-
-        def ref(x, g, b):
-            return jnp.sum(jnp.sin(ref_ln(x, g, b)))
-
-        got = jax.grad(f, (0, 1, 2))(x, g, b)
-        want = jax.grad(ref, (0, 1, 2))(x, g, b)
-        for a, w in zip(got, want):
-            np.testing.assert_allclose(a, w, atol=2e-4, rtol=2e-4)
-
-    def test_add_norm_grads_include_residual_cotangent(self, data):
-        """The (out, resid) second output feeds downstream compute:
-        both cotangent paths into y = x + r must combine."""
-        x, r, g, b = data
-
-        def f(x, r, g, b):
-            o, res = fused_add_layer_norm(x, r, g, b)
-            return jnp.sum(jnp.sin(o)) + jnp.sum(res * 0.3)
-
-        def ref(x, r, g, b):
-            y = x + r
-            return jnp.sum(jnp.sin(ref_ln(y, g, b))) + jnp.sum(
-                y * 0.3
-            )
-
-        got = jax.grad(f, (0, 1, 2, 3))(x, r, g, b)
-        want = jax.grad(ref, (0, 1, 2, 3))(x, r, g, b)
-        for a, w in zip(got, want):
-            np.testing.assert_allclose(a, w, atol=2e-4, rtol=2e-4)
-
-    def test_add_rms_grads_match(self, data):
-        x, r, g, _ = data
-
-        def f(x, r, g):
-            o, res = fused_add_rms_norm(x, r, g)
-            return jnp.sum(jnp.cos(o)) + jnp.sum(res * 0.1)
-
-        def ref(x, r, g):
-            y = x + r
-            return jnp.sum(jnp.cos(ref_rms(y, g))) + jnp.sum(
-                y * 0.1
-            )
-
-        got = jax.grad(f, (0, 1, 2))(x, r, g)
-        want = jax.grad(ref, (0, 1, 2))(x, r, g)
-        for a, w in zip(got, want):
-            np.testing.assert_allclose(a, w, atol=2e-4, rtol=2e-4)
-
-
-class TestModelIntegration:
-    def test_gpt_loss_and_grads_parity_fused_vs_plain(self):
-        import dataclasses
-
-        from dlrover_tpu.models import gpt
-
-        base = gpt.GPTConfig(
-            vocab_size=128, block_size=32, n_layer=2, n_head=2,
-            n_embd=32, dtype=jnp.float32, remat=False,
-        )
-        tok = jax.random.randint(
-            jax.random.PRNGKey(0), (2, 32), 0, 128
-        )
-        params = gpt.init_params(jax.random.PRNGKey(1), cfg=base)
-        out = {}
-        for fused in (False, True):
-            cfg = dataclasses.replace(base, use_fused_norm=fused)
-            loss_fn = functools.partial(gpt.loss_fn, cfg=cfg)
-            loss, grads = jax.value_and_grad(loss_fn)(
-                params, tok, tok
-            )
-            out[fused] = (float(loss), grads)
-        assert out[False][0] == pytest.approx(out[True][0], rel=1e-5)
-        for a, w in zip(
-            jax.tree.leaves(out[True][1]),
-            jax.tree.leaves(out[False][1]),
-        ):
-            np.testing.assert_allclose(a, w, atol=1e-4, rtol=1e-3)
-
-    def test_llama_loss_parity_fused_vs_plain(self):
-        import dataclasses
-
-        from dlrover_tpu.models import llama
-
-        base = llama.LlamaConfig(
-            vocab_size=128, block_size=32, n_layer=2, n_head=4,
-            n_kv_head=2, n_embd=32, intermediate=64,
-            dtype=jnp.float32, remat=False,
-        )
-        tok = jax.random.randint(
-            jax.random.PRNGKey(0), (2, 32), 0, 128
-        )
-        params = llama.init_params(jax.random.PRNGKey(1), cfg=base)
-        losses = {}
-        for fused in (False, True):
-            cfg = dataclasses.replace(base, use_fused_norm=fused)
-            losses[fused] = float(
-                llama.loss_fn(params, tok, tok, cfg=cfg)
-            )
-        assert losses[True] == pytest.approx(
-            losses[False], rel=1e-5
-        )
+    argnums = (0, 1, 2) if kind == "layer" else (0, 1)
+    got = jax.jit(jax.grad(loss, argnums))(x, g, b)
+    y_out = f64(y64.astype(ml_dtypes.bfloat16)) if bf16 else y64
+    want = ref_grads(kind, x64, g64, b64, y_out)
+    assert len(got) == len(want)
+    for a, w, like in zip(got, want, (x, g, b)):
+        assert a.dtype == dtype and a.shape == like.shape
+        close(a, w, BF16_ULP if bf16 else F32 * 16)

@@ -27,17 +27,16 @@ capture_perf = importlib.import_module("capture_perf")
 class TestBuildSpec:
     def test_positional_and_flag_tokens(self):
         cfg, attn_fn, batch, xc = perf_sweep.build_spec(
-            "dots,flash,20,1024,512,nofn,u4,xc2"
+            "dots,flash,20,1024,512,u4,xc2"
         )
         assert cfg.remat == "dots"
         assert cfg.scan_unroll == 4
-        assert cfg.use_fused_norm is False
         assert batch == 20
         assert xc == 2
 
     def test_flag_tokens_position_independent(self):
-        a = perf_sweep.build_spec("full,flash,18,1024,1024,nofn,u2")
-        b = perf_sweep.build_spec("full,flash,18,u2,1024,1024,nofn")
+        a = perf_sweep.build_spec("full,flash,18,1024,1024,xc4,u2")
+        b = perf_sweep.build_spec("full,flash,18,u2,1024,1024,xc4")
         assert a[0].scan_unroll == b[0].scan_unroll == 2
         assert a[2] == b[2] == 18
 
@@ -61,11 +60,11 @@ class TestBuildSpec:
 class TestParseAutotune:
     OUT = (
         "n_devices: 1\n"
-        "full,flash,18,1024,1024,nofn      step=  166.0ms "
+        "full,flash,18,1024,1024           step=  166.0ms "
         "tok/s=   111037 mfu=0.458 vs=0.924\n"
-        "dots,flash,16,1024,1024,nofn,u4,xc4 step=  140.1ms "
+        "dots,flash,16,1024,1024,u4,xc4 step=  140.1ms "
         "tok/s=   109900 mfu=0.470 vs=0.950\n"
-        "dots,flash,20,1024,1024,nofn,u4,xc4 step=  172.0ms "
+        "dots,flash,20,1024,1024,u4,xc4 step=  172.0ms "
         "tok/s=   119069 mfu=0.480 vs=0.960\n"
         "bogus,flash,18 FAILED: ValueError: nope\n"
     )
@@ -85,12 +84,11 @@ class TestParseAutotune:
 class TestWinnerEnv:
     def test_full_pin_set(self):
         env = capture_perf.winner_env(
-            "dots,flash,20,1024,1024,nofn,u4,xc4", n_chips=1
+            "dots,flash,20,1024,1024,u4,xc4", n_chips=1
         )
         assert env == {
             "BENCH_BLOCKS": "1024,1024,1024,1024",
             "BENCH_BATCH_PER_CHIP": "20",
-            "BENCH_FUSED_NORM": "0",
             "BENCH_UNROLL": "4",
             "BENCH_XENT_CHUNKS": "4",
             "BENCH_REMAT": "dots",
@@ -100,16 +98,16 @@ class TestWinnerEnv:
         """Sweep batch is global across its mesh; bench.py's knob is
         per-chip. A 2-chip sweep at global 40 must pin 20/chip."""
         env = capture_perf.winner_env(
-            "dots,flash,40,1024,1024,nofn", n_chips=2
+            "dots,flash,40,1024,1024", n_chips=2
         )
         assert env["BENCH_BATCH_PER_CHIP"] == "20"
 
     def test_default_batch_not_pinned(self):
-        env = capture_perf.winner_env("full,flash,18,512,1024,nofn")
+        env = capture_perf.winner_env("full,flash,18,512,1024")
         assert "BENCH_BATCH_PER_CHIP" not in env
 
     def test_attn_token_maps_to_policy_name(self):
-        env = capture_perf.winner_env("attn,flash,18,512,1024,nofn")
+        env = capture_perf.winner_env("attn,flash,18,512,1024")
         assert env["BENCH_REMAT"] == "attention"
 
 
@@ -244,7 +242,7 @@ class TestLedgerPinDiff:
             "metric": "m", "value": 100.0, "unit": "u",
             "config_hash": "bbb",
             "pins": {"BENCH_UNROLL": "4", "BENCH_REMAT": "full",
-                     "BENCH_OVERLAP_REDUCE": "1"},
+                     "BENCH_XENT_CHUNKS": "4"},
         }
         bench_ledger.append_record(base, path=path)
         bench_ledger.append_record(head, path=path)
@@ -252,7 +250,7 @@ class TestLedgerPinDiff:
         assert rc == 0
         assert "pin BENCH_UNROLL: head=4 baseline=1" in report
         assert (
-            "pin BENCH_OVERLAP_REDUCE: head=1 baseline=<unset>"
+            "pin BENCH_XENT_CHUNKS: head=4 baseline=<unset>"
             in report
         )
         assert "BENCH_REMAT" not in report  # unchanged pins silent
@@ -297,14 +295,14 @@ class TestLedgerPinDiff:
 
 
 class TestBenchPinsEmission:
-    def test_smoke_child_emits_pins_overlap_and_records_trial(
+    def test_smoke_child_emits_pins_and_records_trial(
         self, tmp_path
     ):
         """bench.py's measurement child (BENCH_SMOKE tiny model, CPU)
-        must emit the applied pins, the overlap config, and the
-        tune-cache key in its JSON record — the fields the ledger
-        carries so compare mismatches are debuggable — and record the
-        run as a cached trial."""
+        must emit the applied pins and the tune-cache key in its
+        JSON record — the fields the ledger carries so compare
+        mismatches are debuggable — and record the run as a cached
+        trial."""
         import subprocess
 
         repo = os.path.dirname(TOOLS)
@@ -315,8 +313,7 @@ class TestBenchPinsEmission:
             "BENCH_SMOKE": "1",
             "BENCH_STEPS": "2",
             "BENCH_NO_LEDGER": "1",
-            "BENCH_OVERLAP_REDUCE": "1",
-            "BENCH_REDUCE_BUCKET_MB": "1",
+            "BENCH_XENT_CHUNKS": "4",
             "DLROVER_TPU_TUNE_CACHE": str(cache),
         }
         p = subprocess.run(
@@ -331,8 +328,8 @@ class TestBenchPinsEmission:
             for line in p.stdout.splitlines()
             if line.startswith("{")
         )
-        assert rec["pins"]["BENCH_OVERLAP_REDUCE"] == "1"
-        assert rec["overlap"] == {"bucket_mb": 1.0, "bits": None}
+        assert rec["pins"] == {"BENCH_XENT_CHUNKS": "4"}
+        assert "overlap" not in rec
         assert rec["tune_key"]
         assert rec["value"] > 0
         trials = [
@@ -340,7 +337,7 @@ class TestBenchPinsEmission:
         ]
         assert len(trials) == 1
         assert trials[0]["key"] == rec["tune_key"]
-        assert trials[0]["config"]["pins"] == rec["pins"]
+        assert trials[0]["config"] == {"pins": rec["pins"]}
         assert not trials[0]["failed"]
 
 

@@ -226,26 +226,6 @@ def test_prefix_lm_blocks():
     assert viol.audited > 0, "pallas_call interception never fired"
 
 
-@pytest.mark.parametrize("e", [768, 1024])
-def test_fused_norm_blocks(e):
-    """The kernel family that carried the actual r4 bug: its dg/db
-    accumulator blocks must stay at the (8, E) fix, never (1, E)."""
-    from dlrover_tpu.ops.layer_norm import fused_layer_norm
-
-    x = jax.random.normal(jax.random.PRNGKey(1), (64, e))
-    g = jnp.ones((e,))
-    b = jnp.zeros((e,))
-    with record_violations() as viol:
-        def loss(x, g, b):
-            return jnp.sum(
-                fused_layer_norm(x, g, b, interpret=True) ** 2
-            )
-
-        jax.grad(loss, argnums=(0, 1, 2))(x, g, b)
-    assert not viol, "\n".join(viol)
-    assert viol.audited > 0, "pallas_call interception never fired"
-
-
 def test_quantization_blocks():
     from dlrover_tpu.ops.quantization import (
         dequantize_blockwise,

@@ -31,17 +31,13 @@ from dlrover_tpu.ops.flash_attention import (
     flash_attention,
     flash_attention_rect,
 )
-from dlrover_tpu.ops.layer_norm import (
-    fused_add_layer_norm,
-    fused_layer_norm,
-    fused_rms_norm,
-)
 from dlrover_tpu.ops.quantization import (
     quantize_blockwise,
     quantize_blockwise_4bit,
 )
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.parallel.sharding import prune_specs_to_mesh, tree_specs
+from dlrover_tpu.trainer.elastic_trainer import ElasticTrainer
 from dlrover_tpu.trainer.step import (
     _match_opt_sharding,
     batch_spec,
@@ -86,8 +82,8 @@ def compiled_kernels(monkeypatch):
     ``use_interpret()``, which sees this process's CPU backend; here
     the answer is the chip's. ``dlrover_tpu.ops.flash_attention`` the
     attribute is the re-exported function, so go through sys.modules."""
-    for name in ("flash_attention", "layer_norm", "quantization",
-                 "grouped_matmul", "ssd"):
+    for name in ("flash_attention", "quantization", "grouped_matmul",
+                 "ssd"):
         monkeypatch.setattr(
             sys.modules[f"dlrover_tpu.ops.{name}"],
             "use_interpret",
@@ -148,28 +144,6 @@ def test_flash_rect_compiles(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("width", [768, 4096])
-@pytest.mark.parametrize("kind", ["layer", "rms", "add_layer"])
-def test_fused_norm_fwd_bwd_compiles(one_chip, kind, width):
-    x = _bf16(one_chip, 18 * 1024, width)
-    g = jax.ShapeDtypeStruct((width,), jnp.float32, sharding=one_chip)
-
-    def loss(x, g, b):
-        if kind == "layer":
-            out = fused_layer_norm(x, g, b, interpret=False)
-        elif kind == "rms":
-            out = fused_rms_norm(x, g, interpret=False)
-        else:
-            out, resid = fused_add_layer_norm(
-                x, x, g, b, interpret=False
-            )
-            out = out + resid
-        return out.astype(jnp.float32).sum()
-
-    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)), x, g, g)
-    assert "tpu_custom_call" in compiled.as_text()
-
-
 @pytest.mark.parametrize(
     "quantize", [quantize_blockwise, quantize_blockwise_4bit]
 )
@@ -179,12 +153,12 @@ def test_blockwise_quantize_compiles(one_chip, compiled_kernels, quantize):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("kernel", ["flash", "prefix_lm", "add_layer_norm"])
+@pytest.mark.parametrize("kernel", ["flash", "prefix_lm"])
 def test_kernels_split_themselves_over_a_mesh(topo, compiled_kernels, kernel):
     """Any caller on any mesh: traced under the mesh a Pallas kernel
     puts itself in a shard_map over batch rows (and heads), so XLA is
-    never asked to partition a Mosaic call — the model's flash choice,
-    GLM's prefix-LM attention and the fused norms alike. data=2 x
+    never asked to partition a Mosaic call — the model's flash choice
+    and GLM's prefix-LM attention alike. data=2 x
     tensor=2: the batch splits over one axis, the heads over the
     other."""
     from dlrover_tpu.ops.prefix_lm import prefix_lm_attention
@@ -197,10 +171,6 @@ def test_kernels_split_themselves_over_a_mesh(topo, compiled_kernels, kernel):
         NamedSharding(mesh, P("data", None, "tensor", None)),
         4, 2048, 8, 128,
     )
-    x = _bf16(NamedSharding(mesh, P("data")), 4, 2048, 768)
-    g = jax.ShapeDtypeStruct(
-        (768,), jnp.float32, sharding=NamedSharding(mesh, P())
-    )
 
     def flash(q, k, v):
         return flash_attention(q, k, v, causal=True)
@@ -208,29 +178,17 @@ def test_kernels_split_themselves_over_a_mesh(topo, compiled_kernels, kernel):
     def prefix_lm(q, k, v):
         return prefix_lm_attention(q, k, v, prefix_len=512)
 
-    def add_layer_norm(x, g, b):
-        out, resid = fused_add_layer_norm(x, x, g, b)
-        return out + resid
-
-    fn, args = {
-        "flash": (flash, (qkv, qkv, qkv)),
-        "prefix_lm": (prefix_lm, (qkv, qkv, qkv)),
-        "add_layer_norm": (add_layer_norm, (x, g, g)),
-    }[kernel]
+    fn = {"flash": flash, "prefix_lm": prefix_lm}[kernel]
 
     def loss(*args):
         return fn(*args).astype(jnp.float32).sum()
 
     grad = jax.grad(under_mesh(loss, mesh), argnums=(0, 1, 2))
-    text = _compile(grad, *args).as_text()
+    text = _compile(grad, qkv, qkv, qkv).as_text()
     assert "tpu_custom_call" in text
-    if kernel == "add_layer_norm":
-        # The weights' gradients are summed over the batch shards.
-        assert "all-reduce" in text
-    else:
-        # Each device runs its own rows and heads: the kernel sees a
-        # (2, 2048, 4, 128) block, and nothing crosses the mesh.
-        assert "all-gather" not in text and "all-reduce" not in text
+    # Each device runs its own rows and heads: the kernel sees a
+    # (2, 2048, 4, 128) block, and nothing crosses the mesh.
+    assert "all-gather" not in text and "all-reduce" not in text
 
 
 def test_head_keeps_the_logits_on_their_chip(topo):
@@ -292,22 +250,35 @@ def test_grouped_matmul_compiles_at_olmoe_widths(one_chip, form):
     assert ("moe_tgmm" if form == "weight_grad" else "moe_gmm") in text
 
 
-def _gpt2_step(devices, axis, global_batch):
-    """make_train_step for GPT-2 124M as chip_smoke.py trains it
-    (full remat, fused cross-entropy, adamw, flash attention) lowered
-    and compiled for ``devices`` laid out along ``axis``."""
+def _gpt2_step(devices, axis, global_batch, accum=None):
+    """The step for GPT-2 124M as chip_smoke.py trains it (full
+    remat, fused cross-entropy, adamw, flash attention) lowered and
+    compiled for ``devices`` laid out along ``axis``:
+    ``make_train_step``'s, or with ``accum`` the trainer's own."""
     cfg = dataclasses.replace(
         gpt.GPTConfig.gpt2(), use_flash_attention=True
     )
-    return _train_step(gpt, cfg, devices, axis, global_batch)
+    return _train_step(gpt, cfg, devices, axis, global_batch, accum)
 
 
-def _train_step(model, cfg, devices, axis, global_batch):
+def _train_step(model, cfg, devices, axis, global_batch, accum=None):
+    """``make_train_step``'s program, or with ``accum`` the trainer's
+    own (``ElasticTrainer._build_step``: ``accum`` microbatches of
+    ``global_batch`` rows through its ``lax.scan``)."""
     mesh = build_mesh(MeshConfig(**{axis: len(devices)}), devices=devices)
     optimizer = optax.adamw(6e-4)
-    step = make_train_step(
-        mesh, functools.partial(model.loss_fn_fused, cfg=cfg), optimizer
-    )
+    loss = functools.partial(model.loss_fn_fused, cfg=cfg)
+    if accum is None:
+        step = make_train_step(mesh, loss, optimizer)
+        batch_shape, spec = (global_batch,), batch_spec(mesh)
+    else:
+        step = ElasticTrainer(
+            mesh, loss, optimizer,
+            global_batch_size=accum * global_batch,
+            micro_batch_size=global_batch // len(devices),
+        )._compiled
+        batch_shape = (accum, global_batch)
+        spec = P(None, *batch_spec(mesh))
     param_shapes = jax.eval_shape(
         functools.partial(model.init_params, cfg=cfg), jax.random.PRNGKey(0)
     )
@@ -332,8 +303,8 @@ def _train_step(model, cfg, devices, axis, global_batch):
         )
 
     tokens = jax.ShapeDtypeStruct(
-        (global_batch, cfg.block_size), jnp.int32,
-        sharding=NamedSharding(mesh, batch_spec(mesh)),
+        batch_shape + (cfg.block_size,), jnp.int32,
+        sharding=NamedSharding(mesh, spec),
     )
     return step.lower(
         with_shardings(param_shapes, param_shardings),
@@ -421,6 +392,54 @@ def test_gpt2_train_step_compiles_on_four_chips(
     _assert_flash_forward_runs_once(compiled)
     if axis == "fsdp":  # PR 29's tree: 2.8190 GB a chip
         assert _step_gb(compiled) < 2.8190 + 0.05
+
+
+def test_trainer_step_accumulates_on_one_chip(topo, compiled_kernels):
+    """The trainer's own step (``ElasticTrainer._build_step``) for
+    GPT-2 124M at two microbatches of 18 x 1024, the program ROADMAP
+    R2's cell will run: one program, the microbatch scan a loop in it
+    with the flash kernels once a layer scan inside, within the
+    chip's memory (7.4554 GB compiled here: the float32 accumulator
+    and a second staged microbatch over the plain step's 6.4555)."""
+    compiled = _gpt2_step(topo.devices[:1], "data", 18, accum=2)
+    _assert_fits_with_flash(compiled)
+    _assert_flash_forward_runs_once(compiled)
+    text = compiled.as_text()
+    assert "/accumulate/" in text and "/optimizer/" in text
+    assert "all-reduce" not in text  # one chip: nothing to reduce
+    assert _step_gb(compiled) < 7.4554 + 0.05
+
+
+def test_trainer_step_on_data4_leaves_the_reduction_to_xla(
+    topo, compiled_kernels
+):
+    """Pure data parallel on four chips, the mesh the deleted
+    overlapped reduction was for: the trainer's step is one program in
+    which XLA's own collectives form the gradients' mean over the
+    shards, and the parameters, replicated, are gathered by nobody."""
+    compiled = _gpt2_step(list(topo.devices), "data", 32, accum=2)
+    _assert_fits_with_flash(compiled)
+    text = compiled.as_text()
+    assert " all-reduce(" in text or " reduce-scatter(" in text
+    # A weight's shape: a leaf's own, or one layer's slice of a
+    # stacked leaf as a layer scan's body sees it (what fsdp=4
+    # gathers: bf16[1,768,3072], bf16[768,3072], bf16[50304,768]).
+    weights = set()
+    for leaf in jax.tree.leaves(jax.eval_shape(
+        functools.partial(gpt.init_params, cfg=gpt.GPTConfig.gpt2()),
+        jax.random.PRNGKey(0),
+    )):
+        weights |= {leaf.shape, leaf.shape[1:], (1,) + leaf.shape[1:]}
+    names = {
+        "[" + ",".join(map(str, shape)) + "]"
+        for shape in weights if shape not in ((), (1,))
+    }
+    gathered = [
+        line for line in text.splitlines()
+        if " all-gather(" in line
+        and any(n in line.split(" all-gather(")[0] for n in names)
+    ]
+    assert not gathered, gathered
 
 
 def test_mistral_block_keeps_flash_outputs_on_four_chips(

@@ -3,7 +3,7 @@
 Sweeps (block_q_bwd, block_k_bwd) over the divisibility-chain-valid
 grid at the shipped forward blocks (1024/1024 — the r4 sweep
 optimum), full remat, batch 18,
-fused CE without saved logits — the bench.py configuration — plus a fused-norm A/B,
+fused CE without saved logits — the bench.py configuration —
 and prints the ranked results with the winning bench spec.
 
 Run (TPU):  python tools/autotune_bwd_blocks.py [--quick]
@@ -60,35 +60,33 @@ def main() -> int:
     # tool needs this line to convert units when pinning a winner.
     print(f"n_devices: {len(jax.devices())}")
     print(f"sweeping {len(candidates)} bwd-block configs at "
-          f"fwd {bq}/{bk} (+ fused-norm A/B at defaults)")
-    # Baseline A/B first: fused norms off (the r4-measured default)
-    # vs forced ON — keep re-checking the A/B as kernels evolve.
-    run_config(mesh, f"full,flash,18,{bq},{bk},nofn")
-    run_config(mesh, f"full,flash,18,{bq},{bk},fn")
+          f"fwd {bq}/{bk}")
+    # The baseline first: the shipped blocks, every lever at rest.
+    run_config(mesh, f"full,flash,18,{bq},{bk}")
     # Layer-scan unroll sweep: the r5 step profile attributes ~16% of
     # step time to scan-carry dynamic-update-slice fusions; unrolling
     # lets XLA fuse across layers at the cost of program size. Also
     # re-check the remat choice at the unrolled optimum — the
     # full-remat win was measured rolled.
     for u in (2, 3, 4, 6, 12):
-        run_config(mesh, f"full,flash,18,{bq},{bk},nofn,u{u}")
-    run_config(mesh, f"none,flash,18,{bq},{bk},nofn,u4")
-    run_config(mesh, f"dots,flash,18,{bq},{bk},nofn,u4")
+        run_config(mesh, f"full,flash,18,{bq},{bk},u{u}")
+    run_config(mesh, f"none,flash,18,{bq},{bk},u4")
+    run_config(mesh, f"dots,flash,18,{bq},{bk},u4")
     # Fused-CE chunk count: the r5 trace prices the CE loops at
     # 35.5 ms/step with the f32 dwte accumulator re-read per chunk;
     # fewer chunks trade accumulator round-trips for logits HBM.
     for xc in (2, 4, 16):
-        run_config(mesh, f"full,flash,18,{bq},{bk},nofn,xc{xc}")
+        run_config(mesh, f"full,flash,18,{bq},{bk},xc{xc}")
     # Lever combinations: each pair/triple, so the winner isn't
     # hostage to one lever losing on hardware.
-    run_config(mesh, f"full,flash,18,{bq},{bk},nofn,u4,xc4")
+    run_config(mesh, f"full,flash,18,{bq},{bk},u4,xc4")
     # Batch interacts with the memory knobs (a small xc holds bigger
     # logits): re-check the b18 optimum one notch up and down on the
     # combined candidate.
-    run_config(mesh, f"full,flash,20,{bq},{bk},nofn,u4,xc4")
-    run_config(mesh, f"full,flash,16,{bq},{bk},nofn,u4,xc4")
+    run_config(mesh, f"full,flash,20,{bq},{bk},u4,xc4")
+    run_config(mesh, f"full,flash,16,{bq},{bk},u4,xc4")
     for bqb, bkb in candidates:
-        run_config(mesh, f"full,flash,18,{bq},{bk},{bqb},{bkb},nofn")
+        run_config(mesh, f"full,flash,18,{bq},{bk},{bqb},{bkb}")
     print("pick the fastest line; bench.py BENCH_* env then pins it")
     return 0
 

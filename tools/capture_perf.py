@@ -4,9 +4,9 @@ Unattended capture chain (VERDICT r4 item 1):
 
 1. loop the health-gated bench until it succeeds -> PERF_r05.json
    gets a ``stage=baseline`` record;
-2. run the backward-block autotune + fused-norm A/B
+2. run the backward-block autotune
    (tools/autotune_bwd_blocks.py --quick) and pick the fastest line;
-3. pin the winner via BENCH_BLOCKS / BENCH_FUSED_NORM and re-bench
+3. pin the winner via BENCH_BLOCKS and its companions and re-bench
    -> ``stage=tuned`` record.
 
 Every successful measurement is appended to PERF_r05.json atomically,
@@ -209,16 +209,8 @@ def run_bench(extra_env: dict, timeout_s: float) -> dict | None:
 def winner_env(spec: str, n_chips: int = 1) -> dict:
     """Map a perf_sweep spec (the autotune's fastest line) onto the
     BENCH_* pins bench.py reads. Field layout: perf_sweep.build_spec —
-    remat,flash,batch,bq,bk[,bqb,bkb], 'nofn' strippable flag."""
+    remat,flash,batch,bq,bk[,bqb,bkb], 'uK'/'xcN' strippable flags."""
     parts = spec.split(",")
-    # Pin fused norms only when the winner spec forced them; an
-    # unflagged spec ran the config default (off since r4), which is
-    # also bench.py's default - no pin needed.
-    fused = None
-    if "nofn" in parts:
-        fused = "0"
-    elif "fn" in parts:
-        fused = "1"
     from perf_sweep import is_unroll_token, is_xent_token
 
     unroll = xent = None
@@ -229,8 +221,7 @@ def winner_env(spec: str, n_chips: int = 1) -> dict:
             xent = p[2:]
     parts = [
         p for p in parts
-        if p not in ("nofn", "fn")
-        and not is_unroll_token(p) and not is_xent_token(p)
+        if not is_unroll_token(p) and not is_xent_token(p)
     ]
 
     def blk(i, default):
@@ -249,8 +240,6 @@ def winner_env(spec: str, n_chips: int = 1) -> dict:
     per_chip = max(1, batch // max(1, n_chips))
     if per_chip != 18:  # bench.py's default batch-per-chip
         env["BENCH_BATCH_PER_CHIP"] = str(per_chip)
-    if fused is not None:
-        env["BENCH_FUSED_NORM"] = fused
     if unroll is not None:
         env["BENCH_UNROLL"] = unroll
     if xent is not None:

@@ -6,8 +6,6 @@ Usage:
   flash: flash | xla | noop (noop stubs attention to measure the
          step's non-attention cost by subtraction)
   bqb,bkb: backward-kernel block sizes (default = forward blocks)
-  nofn / fn (anywhere): force the fused Pallas norms off / on
-         (absent = config default, off since the r4 measurement)
 
 Prints one line per config: config, step ms, MFU, vs_baseline.
 """
@@ -56,16 +54,8 @@ def build_spec(spec: str):
     (xcN token, else SWEEP_XENT_CHUNKS, else 8) so every caller sees
     one value."""
     parts = spec.split(",")
-    # "nofn"/"fn" are flag tokens, not positional: strip them before
-    # the positional fields so they really work anywhere in the spec.
-    # nofn forces the fused Pallas norms OFF, fn forces them ON;
-    # absent = the config default (off since r4 — see
-    # gpt.use_fused_norm).
-    fused_norm = None
-    if "nofn" in parts:
-        fused_norm = False
-    elif "fn" in parts:
-        fused_norm = True
+    # "uK" and "xcN" are flag tokens, not positional: stripped before
+    # the positional fields so they work anywhere in the spec.
     # "uK" (e.g. u2, u4): lax.scan unroll factor for the layer stack.
     # "xcN" (e.g. xc4): fused-CE chunk count (r5 trace: the f32 dwte
     # accumulator is re-read/written once per chunk — 144 MB x chunks
@@ -80,8 +70,7 @@ def build_spec(spec: str):
         xent_chunks = int(os.getenv("SWEEP_XENT_CHUNKS", "8"))
     parts = [
         p for p in parts
-        if p not in ("nofn", "fn")
-        and not is_unroll_token(p) and not is_xent_token(p)
+        if not is_unroll_token(p) and not is_xent_token(p)
     ]
     remat_s = parts[0]
     flash_s = parts[1] if len(parts) > 1 else "flash"
@@ -104,8 +93,7 @@ def build_spec(spec: str):
 
     cfg = dataclasses.replace(
         gpt.GPTConfig.gpt2(), remat=remat,
-        use_flash_attention=use_flash, use_fused_norm=fused_norm,
-        scan_unroll=unroll,
+        use_flash_attention=use_flash, scan_unroll=unroll,
     )
     attn_fn = None
     if flash_s == "noop":
