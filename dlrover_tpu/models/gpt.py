@@ -278,23 +278,20 @@ def backbone(
         x = params["wte"][tokens] + params["wpe"][:T][None]
         x = x.astype(cfg.dtype)
     from dlrover_tpu.accelerate.remat import wire_block
+    from dlrover_tpu.models import layers
 
     block = wire_block(
         lambda x, lp, af: _block(x, lp, cfg=cfg, attn_fn=af),
         cfg.remat,
         attn_fn,
     )
-
-    def scan_body(x, lp):
-        return block(x, lp), None
-
-    # "layers" owns what the scan itself costs (slicing the stacked
-    # parameters, stacking residuals, the while): obs.profiling
-    # compiled_scopes puts device time down to it.
+    # "layers" owns what the stack itself costs (models/layers.py: of
+    # the scanned layers the slices of the stacked parameters, the
+    # stacking of what they keep, the while; of the in-line ones next
+    # to nothing): obs.profiling compiled_scopes puts device time down
+    # to it.
     with jax.named_scope("layers"):
-        x, _ = jax.lax.scan(
-            scan_body, x, params["blocks"], unroll=cfg.scan_unroll
-        )
+        x = layers.run(block, x, params["blocks"], unroll=cfg.scan_unroll)
     return _layer_norm(x, params["lnf_g"], params["lnf_b"])
 
 

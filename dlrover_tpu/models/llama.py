@@ -503,6 +503,7 @@ def backbone_with_aux(
         x = params["wte"][tokens].astype(cfg.dtype)
 
     from dlrover_tpu.accelerate.remat import wire_block
+    from dlrover_tpu.models import layers
 
     block = wire_block(
         lambda x, lp, af: _block(
@@ -512,14 +513,14 @@ def backbone_with_aux(
         attn_fn,
     )
 
-    def scan_body(carry, lp):
+    def layer(carry, lp):
         x, aux_sum = carry
         x, aux = block(x, lp)
-        return (x, aux_sum + aux), None
+        return x, aux_sum + aux
 
     with jax.named_scope("layers"):
-        (x, aux), _ = jax.lax.scan(
-            scan_body, (x, jnp.zeros((), jnp.float32)), params["blocks"]
+        x, aux = layers.run(
+            layer, (x, jnp.zeros((), jnp.float32)), params["blocks"]
         )
     return _rms_norm(x, params["rmsf"], cfg.rms_eps), aux
 

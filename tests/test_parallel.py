@@ -747,10 +747,12 @@ class TestShardedTraining:
         """remat=True on the 8-device mesh (fsdp) with the flash
         kernel forced: the (o, lse) "full" keeps are tagged inside
         the kernel's shard_map (ops.flash_attention.per_device), so
-        the gradient holds the forward kernel once, each device keeps
-        its own rows' outputs, and loss and gradients are remat
-        "none"'s (tests/test_remat_policies.py proves the
-        single-device structure; this proves the mesh path)."""
+        the gradient holds the forward kernel once a layer (both
+        layers run in line, models/layers.py: two calls of one block),
+        each device keeps its own rows' outputs, and loss and
+        gradients are remat "none"'s (tests/test_remat_policies.py
+        proves the single-device structure; this proves the mesh
+        path)."""
         from tests.test_remat_policies import _flash_calls
 
         jaxpr = self._full_against_none_on_fsdp8(
@@ -764,45 +766,54 @@ class TestShardedTraining:
             jax.random.randint(jax.random.PRNGKey(1), (8, 128), 0, 256),
         )
         calls = _flash_calls(jaxpr.jaxpr, [])
-        assert sorted(calls) == [
-            "flash_attention_bwd", "flash_attention_fwd"
+        assert sorted(calls) == 2 * ["flash_attention_bwd"] + 2 * [
+            "flash_attention_fwd"
         ], calls
 
     def test_full_remat_keeps_the_expert_layers_values_when_sharded(self):
         """The same with an expert layer: the sorted path runs inside
         ``per_device``'s shard_map, each device on its own tokens, and
-        what it names there is kept as on one device: the stacked
-        residuals are the one-device set, and no grouped product and
-        no sort runs twice."""
+        what it names there is kept as on one device, by the two
+        layers the stack scans (the stacked residuals) and by the
+        three it runs in line (each call's results; models/layers.py):
+        the one-device set, and no grouped product and no sort runs
+        twice in any of the four copies of the block."""
         from dlrover_tpu.models import llama
         from tests.test_remat_policies import (
             _expert_layer_calls,
+            _in_line_residuals,
             _stacked_residuals,
         )
 
         jaxpr = self._full_against_none_on_fsdp8(
             llama,
             lambda remat: llama.LlamaConfig(
-                vocab_size=128, block_size=64, n_layer=2, n_head=4,
+                vocab_size=128, block_size=64, n_layer=5, n_head=4,
                 n_kv_head=2, n_embd=32, intermediate=96,
                 dtype=jnp.float32, remat=remat, n_experts=4,
             ),
             jax.random.randint(jax.random.PRNGKey(1), (8, 64), 0, 128),
         )
         assert "shard_map" in str(jaxpr)
-        assert _expert_layer_calls(jaxpr.jaxpr) == (6, 3, 2)
+        assert _expert_layer_calls(jaxpr.jaxpr) == (6 * 4, 3 * 4, 2 * 4)
         # Global shapes: 8 x 64 tokens, 2 choices each, 4 experts a
         # device (a device's group sizes are its own, so [8 x 4]).
-        kept = _stacked_residuals(jaxpr.jaxpr)
         rows, f32 = 8 * 64 * 2, "float32"
-        assert kept == sorted([
-            ((8, 64, 32), f32), ((8, 64, 32), f32),    # x, q
+        x = ((8, 64, 32), f32)
+        named = sorted([
+            ((8, 64, 32), f32),                        # q
             ((8, 64, 16), f32), ((8, 64, 16), f32),    # k, v
             ((8 * 64, 4), f32),                        # router logits
             ((rows,), "int32"), ((rows,), "int32"), ((8 * 4,), "int32"),
             ((rows, 32), f32), ((rows, 32), f32),      # rows in, rows out
             ((rows, 96), f32), ((rows, 96), f32),      # up, gate
-        ]), kept
+        ])
+        kept = _stacked_residuals(jaxpr.jaxpr)
+        assert kept == sorted(named + [x]), kept
+        # In line the block's input is the call before's result, and
+        # no result of the layer's own call.
+        in_line = _in_line_residuals(jaxpr.jaxpr, "moe_gmm", "moe_tgmm")
+        assert in_line == 3 * [named], in_line
 
     @pytest.mark.slow
     def test_seq_parallel_with_ring_attention(self):

@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from dlrover_tpu.accelerate.remat import POLICY_NAMES, canonical
-from dlrover_tpu.models import gpt, llama
+from dlrover_tpu.models import gpt, layers, llama
 
 
 def _cfg(remat):
@@ -75,9 +75,16 @@ class TestRematPolicies:
             )
 
     def test_full_remat_uses_less_temp_memory_than_none(self):
-        """XLA's own accounting: recompute trades memory for FLOPs."""
+        """XLA's own accounting: recompute trades memory for FLOPs.
+        Six layers, three in line and three scanned (models/layers.py).
+        A stack that is all in line shows nothing here: the CPU's
+        compiler merges an in-line layer's recompute with its forward
+        (as many products compiled with "full" as with "none", and as
+        many bytes), which a loop's body rules out. The chip's keeps
+        the recompute (tests/test_tpu_compile.py: Mistral's two
+        layers, both in line, 10.8 GB for the scan's 14.4)."""
         def build(remat):
-            cfg = _cfg(remat)
+            cfg = dataclasses.replace(_cfg(remat), n_layer=6)
             params = gpt.init_params(jax.random.PRNGKey(0), cfg)
             tokens = jnp.zeros((4, cfg.block_size), jnp.int32)
             loss_fn = functools.partial(gpt.loss_fn, cfg=cfg)
@@ -130,30 +137,37 @@ def _flash_calls(jaxpr, acc):
     return acc
 
 
-# (B, T) of the flash cases. Every family: 2 layers, E = 32, float32,
-# so that CPU gradients compare at the tolerances of the policies
-# above; shapes chosen so that no two kept residuals but ``x`` and
-# ``flash_o`` share one.
+# (B, T) of the flash cases. Every family: E = 32, float32, so that
+# CPU gradients compare at the tolerances of the policies above;
+# shapes chosen so that no two kept residuals but ``x`` and
+# ``flash_o`` share one. Five layers: three in line and two scanned
+# (models/layers.py), so the gradient's jaxpr holds the block
+# ``BODIES`` times, three in-line calls and the scan's body, and the
+# tests below read both forms.
 B, T = 3, 128
+N_LAYER = 5
+BODIES = layers.IN_LINE + 1
 
 
 def _flash_family(family, remat, **overrides):
-    """(loss_fn(params, tokens, targets), params) of a 2-layer model
+    """(loss_fn(params, tokens, targets), params) of a 5-layer model
     on the flash kernel: GPT; Llama with grouped queries and a
     window; a Llama block with an expert layer."""
     if family == "gpt":
         cfg = dataclasses.replace(
-            _cfg(remat), block_size=T, use_flash_attention=True,
-            attn_blocks=(128, 128, 128, 128), **overrides,
+            _cfg(remat), block_size=T, n_layer=N_LAYER,
+            use_flash_attention=True, attn_blocks=(128, 128, 128, 128),
+            **overrides,
         )
         return (
             functools.partial(gpt.loss_fn, cfg=cfg),
             gpt.init_params(jax.random.PRNGKey(0), cfg),
         )
     cfg = llama.LlamaConfig(
-        vocab_size=128, block_size=T, n_layer=2, n_head=4, n_kv_head=2,
-        n_embd=32, intermediate=96, dtype=jnp.float32, remat=remat,
-        use_flash_attention=True, attn_blocks=(64, 64, 64, 64),
+        vocab_size=128, block_size=T, n_layer=N_LAYER, n_head=4,
+        n_kv_head=2, n_embd=32, intermediate=96, dtype=jnp.float32,
+        remat=remat, use_flash_attention=True,
+        attn_blocks=(64, 64, 64, 64),
         sliding_window=None if family == "moe" else 48,
         n_experts=4 if family == "moe" else 0,
     )
@@ -184,6 +198,42 @@ def _stacked_residuals(jaxpr):
         (v.aval.shape[1:], str(v.aval.dtype)) for v in stacked
         if v.aval.ndim > 1
     )
+
+
+def _in_line_residuals(
+    jaxpr, forward="flash_attention_fwd", backward="flash_attention_bwd"
+):
+    """The same of each layer that runs in line: of every call of the
+    block among the gradient's own equations that holds the kernel
+    ``forward`` and not ``backward`` (a layer's forward), the results
+    of rank > 0 beyond the carry (``x``; with Llama's block
+    ``(x, aux)``). A list a call."""
+    found = []
+    for eqn in jaxpr.eqns:
+        inner = eqn.params.get("jaxpr")
+        if eqn.primitive.name != "jit" or inner is None:
+            continue
+        kernels = [
+            str(e.params.get("name")) for e in _eqns(inner.jaxpr)
+            if e.primitive.name == "pallas_call"
+        ]
+        if forward not in kernels or backward in kernels:
+            continue
+        carried = 1 + (eqn.outvars[1].aval.ndim == 0)
+        found.append(sorted(
+            (v.aval.shape, str(v.aval.dtype))
+            for v in eqn.outvars[carried:] if v.aval.ndim > 0
+        ))
+    return found
+
+
+def _without_x(kept, width=32):
+    """What an in-line layer keeps of ``kept``, the scan's set: all
+    but the block's input ``[B, T, width]``, which in line is the
+    value the call before returned and no result of this one."""
+    kept = list(kept)
+    kept.remove(((B, T, width), "float32"))
+    return kept
 
 
 FAMILIES = ["gpt", "llama_gqa_window", "moe"]
@@ -227,7 +277,8 @@ MOE_NAMES = ["moe_in", "moe_order", "moe_out"]
 
 def _expert_layer_calls(jaxpr):
     """(``moe_gmm`` calls, ``moe_tgmm`` calls, ``sort`` equations) of
-    the one layer body each scan of the gradient's jaxpr holds."""
+    every copy of the layer the gradient's jaxpr holds: one a scan's
+    body, one an in-line call (models/layers.py)."""
     eqns = list(_eqns(jaxpr))
     kernels = [
         str(e.params.get("name")) for e in eqns
@@ -256,42 +307,52 @@ class TestFullKeepsTheFlashOutputs:
     kernel is traced ONCE a layer (its kept (o, lse) feed the
     backward) and the out-projection's output is not among the
     residuals. Assert that on the jaxpr: a numerics test alone would
-    pass even if the policy silently stopped working."""
+    pass even if the policy silently stopped working. Read on both
+    forms of the stack: the scan's body and the three in-line calls
+    (``BODIES`` copies of the block in the gradient's jaxpr)."""
 
     @pytest.mark.parametrize("family", FAMILIES)
     def test_fwd_kernel_not_recomputed(self, family):
         calls = _flash_calls(_grad_jaxpr(family, "full"), [])
-        assert sorted(calls) == [
-            "flash_attention_bwd", "flash_attention_fwd"
-        ], calls
+        assert sorted(calls) == BODIES * ["flash_attention_bwd"] + (
+            BODIES * ["flash_attention_fwd"]
+        ), calls
 
     def test_a_policy_by_type_runs_the_fwd_kernel_twice(self):
         """The contrast, so the test above cannot pass by accident:
         "dots" keeps by primitive type and cannot see inside the
         flash custom_vjp."""
         calls = _flash_calls(_grad_jaxpr("gpt", "dots"), [])
-        assert sorted(calls) == [
-            "flash_attention_bwd", "flash_attention_fwd",
-            "flash_attention_fwd",
-        ], calls
+        assert sorted(calls) == BODIES * ["flash_attention_bwd"] + (
+            2 * BODIES * ["flash_attention_fwd"]
+        ), calls
 
     @pytest.mark.parametrize("family", FAMILIES)
     def test_residuals_are_exactly_the_named_set(self, family):
-        got = _stacked_residuals(_grad_jaxpr(family, "full"))
+        jaxpr = _grad_jaxpr(family, "full")
+        got = _stacked_residuals(jaxpr)
         assert got == KEPT_SHAPES[family], got
+        in_line = _in_line_residuals(jaxpr)
+        assert in_line == layers.IN_LINE * [
+            _without_x(KEPT_SHAPES[family])
+        ], in_line
 
     def test_expert_layer_runs_no_product_twice(self):
         """A gated expert layer needs three grouped products forward,
         three input gradients (``moe_gmm``) and three weight gradients
         (``moe_tgmm``), and its two sorts once: with the named values
         kept, the backward holds no second forward of them."""
-        assert _expert_layer_calls(_grad_jaxpr("moe", "full")) == (6, 3, 2)
+        assert _expert_layer_calls(_grad_jaxpr("moe", "full")) == (
+            6 * BODIES, 3 * BODIES, 2 * BODIES
+        )
 
     def test_a_policy_by_type_runs_the_expert_forward_twice(self):
         """The contrast: "dots" cannot see inside ``gmm``'s custom_vjp
         either, and runs the three forward products and both sorts a
         second time inside the backward."""
-        assert _expert_layer_calls(_grad_jaxpr("moe", "dots")) == (9, 3, 4)
+        assert _expert_layer_calls(_grad_jaxpr("moe", "dots")) == (
+            9 * BODIES, 3 * BODIES, 4 * BODIES
+        )
 
     def test_kept_expert_values_are_not_rounded_a_second_time(self):
         """``jax.checkpoint`` puts a ``reduce_precision`` behind a kept
@@ -336,7 +397,7 @@ class TestFullKeepsTheFlashOutputs:
             if v.aval.shape[-3:] == (heads, T, 1)
         ]
         # The kernel's result and the slice that drops its unit lane.
-        assert columns == ["pallas_call", "slice"], columns
+        assert columns == BODIES * ["pallas_call", "slice"], columns
 
     def test_o_kept_as_the_kernel_wrote_it_at_head_size_128(self):
         """Where the head size fills the chip's 128 lanes the kernel's
@@ -344,14 +405,18 @@ class TestFullKeepsTheFlashOutputs:
         transposition is paid; below that (the cases above) it is
         kept in the model's layout."""
         jaxpr = _grad_jaxpr("gpt", "full", n_embd=128, n_head=1)
-        assert _stacked_residuals(jaxpr) == sorted([
+        kept = sorted([
             ((B, T, 128), F32),                  # x alone
             ((B, 1, T, 128), F32),               # flash_o, one head
             ((B, 1, 1, T), F32),                 # flash_lse
             ((B, T, 384), F32), ((B, T, 512), F32),
         ])
+        assert _stacked_residuals(jaxpr) == kept
+        assert _in_line_residuals(jaxpr) == layers.IN_LINE * [
+            _without_x(kept, 128)
+        ]
         calls = _flash_calls(jaxpr, [])
-        assert calls.count("flash_attention_fwd") == 1, calls
+        assert calls.count("flash_attention_fwd") == BODIES, calls
 
     @pytest.mark.parametrize("family", FAMILIES)
     def test_grads_match_no_remat_with_flash(self, family):
