@@ -13,7 +13,8 @@ layers, every layer followed by the same SwiGLU MLP.
   no bias) with the softmax scale ``attention_multiplier`` and no
   positional embedding ("nope");
 * Mamba-2 mixer: ``[z | xBC | dt] = u @ w_in``; a causal depthwise
-  convolution of width ``ssm_conv`` with bias over ``xBC``, then SiLU;
+  convolution of width ``ssm_conv`` with bias over ``xBC``, then SiLU
+  (ops/causal_conv.py);
   ``[x | B | C] = xBC``; ``dt = softplus(dt + dt_bias)``;
   ``A = -exp(A_log)``; the SSD recurrence (ops/ssd.py, chunked);
   the gate ``y * silu(z)`` BEFORE an RMS norm over the whole inner
@@ -284,21 +285,11 @@ def _scaled(branch, multiplier):
     return (branch.astype(jnp.float32) * multiplier).astype(branch.dtype)
 
 
-def _causal_conv(x, w, bias):
-    """Depthwise causal convolution along T: x [B, T, C], w [K, C]
-    (``w[k]`` multiplies the input ``K - 1 - k`` tokens back), float32."""
-    width, t = w.shape[0], x.shape[1]
-    x = jnp.pad(x.astype(jnp.float32), ((0, 0), (width - 1, 0), (0, 0)))
-    out = bias.astype(jnp.float32)
-    for k in range(width):
-        out = out + x[:, k:k + t] * w[k].astype(jnp.float32)
-    return out
-
-
 def mamba_mixer(u, lp, cfg: GraniteHybridConfig):
     """The Mamba-2 mixer on the normed input ``u`` [B, T, E], without
     the residual."""
     from dlrover_tpu.accelerate.remat import SSM_IN, keep
+    from dlrover_tpu.ops.causal_conv import conv_silu
     from dlrover_tpu.ops.ssd import ssd
 
     bsz, t, _ = u.shape
@@ -309,15 +300,19 @@ def mamba_mixer(u, lp, cfg: GraniteHybridConfig):
     # again.
     proj = keep(u @ lp["w_in"], SSM_IN)
     z = proj[..., :inner]
-    xbc = proj[..., inner:inner + cfg.conv_dim]
     dt = proj[..., inner + cfg.conv_dim:]
     with jax.named_scope("ssm_conv"):
-        xbc = jax.nn.silu(
-            _causal_conv(xbc, lp["conv_w"], lp["conv_b"])
-        ).astype(u.dtype)
-    x = xbc[..., :inner]
-    b = xbc[..., inner:inner + gn].reshape(bsz, t, cfg.ssm_groups, -1)
-    c = xbc[..., inner + gn:].reshape(bsz, t, cfg.ssm_groups, -1)
+        # xBC's convolution is depthwise, so x's columns and B|C's take
+        # a call each, read where they lie in the projection: each
+        # result goes to the scan whole and each cotangent comes back
+        # whole, where one call's result is sliced for every consumer
+        # and their cotangents are laid side by side again (PERF.md
+        # section 6, PR 52).
+        w, bias = lp["conv_w"], lp["conv_b"]
+        x = conv_silu(proj, w[:, :inner], bias[:inner], start=inner)
+        bc = conv_silu(proj, w[:, inner:], bias[inner:], start=2 * inner)
+    b = bc[..., :gn].reshape(bsz, t, cfg.ssm_groups, -1)
+    c = bc[..., gn:].reshape(bsz, t, cfg.ssm_groups, -1)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"])
     with jax.named_scope("ssd"):
         y = ssd(

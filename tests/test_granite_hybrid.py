@@ -1,7 +1,8 @@
 """models/granite_hybrid.py: the pattern's period and runs, one step
 program whatever the depth, what ``remat="full"`` keeps of a Mamba-2
-mixer (``ssd_fwd`` once a layer), the events a trace leaves, and the
-stack on the trainer's normal path."""
+mixer (``ssd_fwd`` once a layer), the events a trace leaves, the
+convolution's hand-written backward against autodiff of the plain
+shifted form, and the stack on the trainer's normal path."""
 
 import dataclasses
 import functools
@@ -12,6 +13,7 @@ import numpy as np
 import pytest
 
 from dlrover_tpu.models import granite_hybrid as model
+from dlrover_tpu.ops import causal_conv
 from dlrover_tpu.models.granite_hybrid import ATTENTION as A
 from dlrover_tpu.models.granite_hybrid import MAMBA as M
 
@@ -157,6 +159,116 @@ def test_a_trace_says_what_it_runs():
         ("attn_in", "mlp_hidden"),  # XLA attention on the CPU: no flash_o
         ("mlp_hidden", "ssd_states", "ssd_y", "ssm_in"),
     ]
+
+
+def _plain_conv_silu(x, w, bias):
+    """The mixer's convolution as it stood before PR 52, which autodiff
+    differentiated: pad, shifted slices, multiply-adds, SiLU."""
+    width, t = w.shape[0], x.shape[1]
+    xf = jnp.pad(x.astype(jnp.float32), ((0, 0), (width - 1, 0), (0, 0)))
+    out = bias.astype(jnp.float32)
+    for k in range(width):
+        out = out + xf[:, k:k + t] * w[k].astype(jnp.float32)
+    return jax.nn.silu(out).astype(x.dtype)
+
+
+def _conv_case(width, t, batch, dtype, only, channels=24):
+    keys = jax.random.split(jax.random.PRNGKey(width * 1000 + t), 4)
+    x = jax.random.normal(keys[0], (batch, t, channels))
+    dy = jax.random.normal(keys[1], (batch, t, channels))
+    # Only the terms at one end of the sequence: an input in the
+    # first K-1 tokens, or a cotangent in the last K-1 (the
+    # anti-causal edge of ``dx``).
+    rows = jnp.arange(t)[None, :, None]
+    if only == "first_inputs":
+        x = jnp.where(rows < width - 1, x, 0.0)
+    if only == "last_cotangents":
+        dy = jnp.where(rows >= t - (width - 1), dy, 0.0)
+    w = jax.random.uniform(keys[2], (width, channels), minval=-0.5, maxval=0.5)
+    bias = jax.random.uniform(keys[3], (channels,), minval=-0.5, maxval=0.5)
+    return tuple(v.astype(dtype) for v in (x, w, bias, dy))
+
+
+@pytest.mark.parametrize("only", [None, "first_inputs", "last_cotangents"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("batch", [1, 2])
+@pytest.mark.parametrize("t", [8, 13, 256])
+@pytest.mark.parametrize("width", [2, 4])
+def test_conv_backward_is_autodiff_of_the_plain_form(
+    width, t, batch, dtype, only
+):
+    """``ops/causal_conv.py``'s backward kernel gives the ``dx``,
+    ``dw`` and ``dbias`` that ``jax.vjp`` gives of the plain shifted
+    form: in float32 to a relative 1e-5, in bf16 to the rounding of
+    the one cast that ends each. This is what guards the backward:
+    the benchmark's ``correct`` reads the forward loss and a falling
+    trend, and would pass a rule that lost the anti-causal edge."""
+    x, w, bias, dy = _conv_case(width, t, batch, dtype, only)
+    y, pull = jax.vjp(causal_conv.conv_silu, x, w, bias)
+    want_y, want_pull = jax.vjp(_plain_conv_silu, x, w, bias)
+    # The forward kernel against the plain form: in bf16 the same
+    # values; float32 differs in the last digit (the CPU contracts the
+    # plain form's multiply-adds).
+    exact = dtype == jnp.bfloat16
+    np.testing.assert_allclose(
+        np.asarray(y, np.float32), np.asarray(want_y, np.float32),
+        rtol=0 if exact else 2e-6, atol=0 if exact else 1e-6,
+    )
+    # One bf16 cast is off by at most 2**-8 of the value either way.
+    rtol = 1e-5 if dtype == jnp.float32 else 2.0 ** -7
+    for name, got, want in zip(
+        ("dx", "dw", "dbias"), pull(dy), want_pull(dy)
+    ):
+        assert got.dtype == want.dtype == dtype, name
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        assert np.any(want != 0), name
+        np.testing.assert_allclose(
+            got, want, rtol=rtol, atol=1e-5 * np.max(np.abs(want)),
+            err_msg=name,
+        )
+
+
+def test_conv_keeps_its_inputs_and_the_block_its_names():
+    """The rule's residuals are its inputs as they came in, the
+    mixer's ``xbc`` (a slice of the kept ``ssm_in`` projection),
+    ``conv_w`` and ``conv_b``: no pre-activation, no float32
+    [B, T, C]. The block under ``remat="full"`` names what it named
+    before the rule."""
+    from dlrover_tpu import obs
+
+    x, w, bias, _ = _conv_case(4, 16, 2, jnp.bfloat16, None)
+    _, res = causal_conv._conv_silu_fwd(x, w, bias, 0, True)
+    assert len(res) == 3
+    assert all(r is v for r, v in zip(res, (x, w, bias)))
+    _, pull = jax.vjp(causal_conv.conv_silu, x, w, bias)
+    held = sorted((v.shape, v.dtype.name) for v in jax.tree.leaves(pull))
+    assert held == sorted((v.shape, v.dtype.name) for v in (x, w, bias))
+
+    cfg = dataclasses.replace(TINY, remat="full")
+    tracer = obs.configure_tracer()
+    try:
+        params = jax.eval_shape(
+            functools.partial(model.init_params, cfg=cfg),
+            jax.random.PRNGKey(0),
+        )
+        jax.jit(jax.value_and_grad(
+            functools.partial(model.loss_fn_fused, cfg=cfg)
+        )).lower(params, *_batch(cfg))
+        events = tracer.events()
+    finally:
+        obs.disable_tracer()
+    convs = [e for e in events if e["name"] == "ssm.conv"]
+    # x's columns of the projection, then B|C's: a call each, once a
+    # trace of the checkpointed layer.
+    gn = cfg.ssm_groups * cfg.ssm_state
+    assert [(e["width"], e["channels"], e["start"]) for e in convs] == [
+        (cfg.ssm_conv, cfg.d_inner, cfg.d_inner),
+        (cfg.ssm_conv, 2 * gn, 2 * cfg.d_inner),
+    ]
+    assert all(e["residuals"] == ["x", "w", "bias"] for e in convs)
+    assert not any(e["per_device"] for e in convs)
+    kept = {n for e in events if e["name"] == "remat.kept" for n in e["names"]}
+    assert kept == {"attn_in", "mlp_hidden", "ssd_states", "ssd_y", "ssm_in"}
 
 
 def test_full_remat_gives_the_same_gradients():

@@ -43,6 +43,38 @@ def test_float32_check_refuses_a_broken_scan(monkeypatch, name, attribute, broke
     assert "relative error" in got["ssd_fwd_bwd_f32"]["error"]
 
 
+def _conv_check(monkeypatch):
+    monkeypatch.setattr(tpu_kernel_smoke, "SMALL", True)
+    tpu_kernel_smoke.RESULTS.clear()
+    tpu_kernel_smoke.ssm_conv_checks()
+    (got,) = tpu_kernel_smoke.RESULTS
+    assert got["kernel"] == "ssm_conv_fwd_bwd_bf16"
+    return got
+
+
+def test_conv_check_passes_the_kernels_as_they_are(monkeypatch):
+    got = _conv_check(monkeypatch)
+    assert got["ok"], got
+    assert got["max_abs_err"] <= 2.0 ** -8 < got["limit"]
+
+
+def test_conv_check_refuses_a_backward_that_does_not_look_ahead(monkeypatch):
+    """``dx[t]`` reads ``g`` at ``t`` and up to K-1 rows AFTER it; a
+    kernel that reads ``g[t]`` alone keeps every shape and the
+    forward's loss, and only a check of the gradients sees it."""
+    from dlrover_tpu.ops import causal_conv
+
+    real = causal_conv._ahead
+    monkeypatch.setattr(
+        causal_conv, "_ahead",
+        # x's taps start _HALO - (K-1) rows in, g's shifts under K.
+        lambda v, offset, rows: real(v, offset if offset >= 8 else 0, rows),
+    )
+    got = _conv_check(monkeypatch)
+    assert not got["ok"], got
+    assert "relative error" in got["error"]
+
+
 def _flash_checks(monkeypatch):
     monkeypatch.setattr(tpu_kernel_smoke, "SEQ", 128)
     tpu_kernel_smoke.RESULTS.clear()

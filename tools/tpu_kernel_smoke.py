@@ -41,19 +41,23 @@ SMALL = False
 SEQ = 1024  # seq for flash checks
 XENT_V = 50304  # rows for xent
 _ERRS = []  # max-abs errors of the _close calls of the running check
+_LIMITS = []  # the relative limits its _close_rel calls held them to
 
 
 def check(name, fn):
     t0 = time.time()
     _ERRS.clear()
+    _LIMITS.clear()
     try:
         fn()
         RESULTS.append(
             {"kernel": name, "ok": True,
              "max_abs_err": max(_ERRS, default=None),
+             "limit": max(_LIMITS, default=None),
              "seconds": round(time.time() - t0, 1)}
         )
         print(f"ok   {name} max_abs_err={max(_ERRS, default=None)} "
+              f"limit={max(_LIMITS, default=None)} "
               f"({time.time() - t0:.1f}s)")
     except Exception as exc:  # noqa: BLE001
         RESULTS.append(
@@ -412,6 +416,7 @@ def _close_rel(got, want, tol):
     got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
     err = float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
     _ERRS.append(err)
+    _LIMITS.append(tol)
     assert err <= tol, f"relative error {err:.3g} over {tol:.3g}"
 
 
@@ -492,6 +497,73 @@ def ssd_checks():
     check("ssd_fwd_bwd_bf16", both(jnp.bfloat16, 2e-2))
 
 
+def _plain_conv_silu(x, w, bias):
+    """A mixer's convolution as pad, shifted slices and multiply-adds,
+    then SiLU, in the inputs' dtype throughout: the reference, and
+    what autodiff differentiates for its gradients."""
+    width, t = w.shape[0], x.shape[1]
+    x = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
+    out = bias
+    for k in range(width):
+        out = out + x[:, k:k + t] * w[k]
+    return jax.nn.silu(out)
+
+
+def ssm_conv_checks():
+    """``conv_silu_fwd`` and ``conv_silu_bwd`` (ops/causal_conv.py)
+    as Granite's mixer calls them: the columns of ``x`` (4,096 from
+    4,096 on) and of ``B|C`` (256 from 8,192 on) read in place from a
+    [1, 4096, 8512] bf16 projection, eight row tiles, width 4; against
+    the plain shifted form and its autodiff on the same values in
+    float32. ``y``, ``dx``, ``dw`` and ``dbias`` each differ by the
+    one bf16 cast that ends them, at most 2**-8 of the largest value
+    (3.9e-3; the limit is twice that; a backward that reads ``g[t]``
+    alone for ``dx[t]`` reads 1.61 at the small shapes:
+    tests/test_tpu_kernel_smoke.py)."""
+    from dlrover_tpu.ops import causal_conv
+
+    t, inner, bc, width = (128, 256, 128, 4) if SMALL else (4096, 4096, 256, 4)
+    wide = 2 * inner + bc + 64
+    keys = jax.random.split(jax.random.PRNGKey(9), 4)
+    proj = jax.random.normal(keys[0], (1, t, wide)).astype(jnp.bfloat16)
+
+    def grads(fn, *args):
+        def call(x, w, bias, dy):
+            y, pull = jax.vjp(fn, x, w, bias)
+            return (y, *pull(dy))
+        return jax.jit(call)(*args)
+
+    def both(start, channels):
+        k_dy, k_w, k_b = jax.random.split(keys[1 + (start > inner)], 3)
+        dy = jax.random.normal(k_dy, (1, t, channels)).astype(jnp.bfloat16)
+        w, bias = (
+            jax.random.uniform(k, shape, jnp.float32, -0.5, 0.5).astype(
+                jnp.bfloat16
+            )
+            for k, shape in ((k_w, (width, channels)), (k_b, (channels,)))
+        )
+        y, dproj, dw, dbias = grads(
+            functools.partial(causal_conv.conv_silu, start=start),
+            proj, w, bias, dy,
+        )
+        beside = dproj.at[..., start:start + channels].set(0)
+        assert not np.any(np.asarray(beside, np.float32)), "dx off its columns"
+        with _prec("f32"):
+            want = grads(_plain_conv_silu, *(
+                v.astype(jnp.float32)
+                for v in (proj[..., start:start + channels], w, bias, dy)
+            ))
+        got = (y, dproj[..., start:start + channels], dw, dbias)
+        for g, r in zip(got, want):
+            _close_rel(g, r, 2.0 ** -7)
+
+    def run():
+        both(inner, inner)
+        both(2 * inner, bc)
+
+    check("ssm_conv_fwd_bwd_bf16", run)
+
+
 def run(small: bool) -> list:
     """Every check, at the full or the small shapes; returns the
     result records (``ok`` False on a compile error or parity miss)."""
@@ -504,6 +576,7 @@ def run(small: bool) -> list:
     quant_checks()
     xent_checks()
     ssd_checks()
+    ssm_conv_checks()
     return list(RESULTS)
 
 
