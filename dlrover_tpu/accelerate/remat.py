@@ -88,8 +88,17 @@ MOE_OUT = "moe_out"
 SSM_IN = "ssm_in"
 SSD_Y = "ssd_y"
 SSD_STATES = "ssd_states"
+# Of a delta-rule mixer (models/kimi_linear.py, ops/kda.py): the three
+# projections into its convolutions, the rule's output and the state
+# every chunk starts from. Of a latent-attention mixer: the latent the
+# keys and values are projected up from.
+KDA_IN = "kda_in"
+KDA_O = "kda_o"
+KDA_STATES = "kda_states"
+MLA_LATENT = "mla_latent"
 KEPT = (ATTN_IN, FLASH_O, FLASH_LSE, MLP_HIDDEN, ROUTER_LOGITS,
-        MOE_ORDER, MOE_IN, MOE_OUT, SSM_IN, SSD_Y, SSD_STATES)
+        MOE_ORDER, MOE_IN, MOE_OUT, SSM_IN, SSD_Y, SSD_STATES,
+        KDA_IN, KDA_O, KDA_STATES, MLA_LATENT)
 
 POLICY_NAMES = ("none", "full", "attention", "dots", "offload")
 

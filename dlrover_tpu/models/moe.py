@@ -30,6 +30,22 @@ chooses it, not an option:
   path alone. It stays until a four-chip expert-parallel cell can
   judge a sorted replacement with an explicit all-to-all.
 
+* **A chip's share of the experts (``held`` > 0): held.** Under
+  expert parallelism a chip holds ``held`` of the ``n_experts``, the
+  experts ``[first_expert, first_expert + held)``. The router keeps
+  its ``n_experts`` outputs and its ``top_k`` a token; the layer
+  computes the part of the result its own experts give and leaves the
+  rest out: no exchange, and no code that stands in for the absent
+  chips. Sorted and dropless like the first path, but only the held
+  pairs' rows are gathered and brought back, and the products run
+  over a buffer of a few times their mean number, all of it whatever
+  the load (``_held_experts`` says how the shapes stay static). The
+  router of such a layer may score by sigmoid, choose with a bias
+  that does not enter the weights, scale the weights, and the layer
+  may add a shared expert every token passes (``scoring``,
+  ``choice_bias``, ``routed_scale``, ``shared_hidden``): what the
+  layer is, read from the model's configuration.
+
 ``routing_stats`` is a pure function of the router logits for tests
 and offline looks; no step calls it (a step returns its loss only, and
 a host callback inside one would stall the chip).
@@ -38,6 +54,7 @@ a host callback inside one would stall the chip).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -65,15 +82,58 @@ class MoEConfig:
     # token's kept choices (Mixtral's softmax-over-top-k) instead of
     # the raw full-softmax probabilities (GShard, OLMoE).
     renorm_top_k: bool = False
+    # What the router scores with: "softmax" over all experts, or
+    # "sigmoid" of each logit on its own.
+    scoring: str = "softmax"
+    # choice_bias=True: a per-expert bias (``router_bias``, a buffer
+    # no gradient reaches) is added to the scores for the top-k choice
+    # and left out of the weights.
+    choice_bias: bool = False
+    # The weights' multiplier after the renormalisation.
+    routed_scale: float = 1.0
+    # Width of a shared expert every token passes (gated); 0: none.
+    shared_hidden: int = 0
+    # held > 0: this chip's share, experts [first_expert,
+    # first_expert + held) of the router's n_experts.
+    first_expert: int = 0
+    held: int = 0
+
+    def __post_init__(self):
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router scoring {self.scoring!r}")
+        if self.held and not (
+            0 <= self.first_expert
+            and self.first_expert + self.held <= self.n_experts
+        ):
+            raise ValueError(
+                f"experts [{self.first_expert}, "
+                f"{self.first_expert + self.held}) are not among "
+                f"{self.n_experts}"
+            )
+        if not self.held and (
+            self.scoring != "softmax" or self.choice_bias
+            or self.routed_scale != 1.0 or self.shared_hidden
+        ):
+            # The sorted and the one-hot paths know the softmax router
+            # and the routed experts alone.
+            raise ValueError(
+                "scoring, choice_bias, routed_scale and shared_hidden "
+                "are the held path's: set held (n_experts for all)"
+            )
 
     @property
     def hidden(self) -> int:
         return self.expert_hidden or 4 * self.n_embd
 
+    @property
+    def experts_here(self) -> int:
+        """Experts whose matrices this parameter tree holds."""
+        return self.held or self.n_experts
+
 
 def init_moe_params(key: jax.Array, cfg: MoEConfig) -> Dict[str, Any]:
     k_r, k_i, k_o, k_g = jax.random.split(key, 4)
-    E, D, H = cfg.n_experts, cfg.n_embd, cfg.hidden
+    E, D, H = cfg.experts_here, cfg.n_embd, cfg.hidden
     std = 0.02
 
     def norm(k, shape):
@@ -84,18 +144,30 @@ def init_moe_params(key: jax.Array, cfg: MoEConfig) -> Dict[str, Any]:
     params = {
         # Router stays float32: tiny, and routing decisions are
         # precision-sensitive.
-        "router": jax.random.normal(k_r, (D, E), jnp.float32) * std,
+        "router": jax.random.normal(
+            k_r, (D, cfg.n_experts), jnp.float32
+        ) * std,
         "wi": norm(k_i, (E, D, H)),
         "wo": norm(k_o, (E, H, D)),
     }
     if cfg.gated:
         params["wg"] = norm(k_g, (E, D, H))
+    if cfg.choice_bias:
+        params["router_bias"] = jnp.zeros((cfg.n_experts,), jnp.float32)
+    if cfg.shared_hidden:
+        k_sg, k_su, k_sd = jax.random.split(jax.random.fold_in(key, 1), 3)
+        S = cfg.shared_hidden
+        params["shared"] = {
+            "w_gate": norm(k_sg, (D, S)),
+            "w_up": norm(k_su, (D, S)),
+            "w_down": norm(k_sd, (S, D)),
+        }
     return params
 
 
 def moe_logical_axes(
-    gated: bool = False,
-) -> Dict[str, Tuple[Optional[str], ...]]:
+    gated: bool = False, choice_bias: bool = False, shared: bool = False,
+) -> Dict[str, Any]:
     axes = {
         "router": (None, None),
         "wi": ("expert", "embed", "mlp"),
@@ -103,6 +175,14 @@ def moe_logical_axes(
     }
     if gated:
         axes["wg"] = ("expert", "embed", "mlp")
+    if choice_bias:
+        axes["router_bias"] = (None,)
+    if shared:
+        axes["shared"] = {
+            "w_gate": ("embed", "mlp"),
+            "w_up": ("embed", "mlp"),
+            "w_down": ("mlp", "embed"),
+        }
     return axes
 
 
@@ -133,6 +213,36 @@ def top_k_route(
     return weights, experts.astype(jnp.int32)
 
 
+def route(
+    logits: jax.Array, bias: Optional[jax.Array], cfg: MoEConfig
+) -> Tuple[jax.Array, jax.Array]:
+    """The layer's router on float32 logits [n, E]: (weights [n, k]
+    float32, experts [n, k] int32). Softmax over all experts or a
+    sigmoid of each; the ``top_k`` largest scores, of ``score + bias``
+    where the layer has a choice bias, which chooses and does not
+    weigh: the weights are the chosen experts' scores without it,
+    over their sum (``renorm_top_k``; plus 1e-20, as the published
+    code guards it) and times ``routed_scale``."""
+    if cfg.scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+    if not cfg.choice_bias:
+        weights, experts = top_k_route(scores, cfg.top_k, False)
+    else:
+        _, experts = jax.lax.top_k(
+            scores + jax.lax.stop_gradient(bias), cfg.top_k
+        )
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if cfg.renorm_top_k:
+        weights = weights / (
+            jnp.sum(weights, axis=-1, keepdims=True) + 1e-20
+        )
+    if cfg.routed_scale != 1.0:
+        weights = weights * cfg.routed_scale
+    return weights, experts.astype(jnp.int32)
+
+
 def expert_counts(experts: jax.Array, n_experts: int) -> jax.Array:
     """(token, choice) pairs each expert received, [E] int32."""
     return jnp.sum(
@@ -158,19 +268,34 @@ def router_losses(
     }
 
 
-def routing_stats(logits: jax.Array, top_k: int) -> Dict[str, jax.Array]:
+def routing_stats(
+    logits: jax.Array, top_k: int, cfg: Optional[MoEConfig] = None,
+    bias: Optional[jax.Array] = None,
+) -> Dict[str, jax.Array]:
     """What a routing looks like, from the router logits [n, E] alone:
     the fullest expert's load over the mean load, the share of experts
-    that received nothing, and the share of (token, choice) pairs the
-    sorted path drops, which is 0 by construction."""
+    that received nothing, the share of (token, choice) pairs the
+    sorted path drops, which is 0 by construction, and, of a layer
+    that holds a share of the experts (``cfg.held``), the pairs a
+    token sends to the experts held here (``top_k x held / n_experts``
+    when the load is even). ``cfg`` and ``bias`` give the layer's
+    scoring and its choice bias; without them the router is the
+    softmax one."""
     n_experts = logits.shape[-1]
-    _, experts = top_k_route(jax.nn.softmax(logits, axis=-1), top_k, False)
+    if cfg is None:
+        _, experts = top_k_route(jax.nn.softmax(logits, axis=-1), top_k, False)
+        first, held = 0, n_experts
+    else:
+        _, experts = route(logits, bias, cfg)
+        first, held = cfg.first_expert, cfg.experts_here
     counts = expert_counts(experts, n_experts)
+    here = jnp.sum(counts[first: first + held])
     return {
         "tokens_per_expert": counts,
         "max_over_mean": jnp.max(counts) / jnp.mean(counts.astype(jnp.float32)),
         "empty_share": jnp.mean((counts == 0).astype(jnp.float32)),
         "dropped_share": jnp.zeros((), jnp.float32),
+        "held_pairs_per_token": here / logits.shape[0],
     }
 
 
@@ -418,6 +543,317 @@ def _sorted_moe(params, flat, logits, cfg: MoEConfig):
     return y, metrics
 
 
+# ---------------------------------------------------------------------------
+# Held path: a chip's share of the experts, sorted and dropless
+# ---------------------------------------------------------------------------
+
+# Rows of the held path's buffer, over the held pairs' mean number
+# ``tokens x top_k x held / n_experts``. Shapes are static and the
+# held rows are not: a token's choices can all be held, so the only
+# bound that never fails is ``tokens x top_k``. The layer is both: the
+# held pairs' rows, sorted by expert, are taken ``rows_cap`` at a time
+# by a ``lax.scan`` over the ``tokens x top_k / rows_cap`` blocks there
+# can be, and a block past the counted rows (but the first, which
+# always runs) is skipped by a ``lax.cond``: one block's program,
+# forward and backward, in the one step program. What a step pays
+# follows its load in blocks and in nothing finer: the common case is
+# the first block alone, and a layer whose held pairs pass the buffer
+# pays for one more buffer, not for every pair of the layer. Within a
+# block the grouped products walk the whole buffer (``_held_block``
+# gives the rows past the held pairs, zeros, to the last group), so a
+# layer up to its buffer costs the same whatever the router sent it:
+# 2.3 ms a layer at 8,192 rows on a v5e, 1.7 over what the mean load's
+# 2,048 rows would take, for a step whose time no longer follows the
+# router (with tiles past the count skipped a step read 637 ms to 647
+# by how many layers' routers had collapsed onto a held expert, and no
+# two sets of runs spread alike: PERF.md section 6, PR 53).
+# Four times the mean (8,192 rows of 65,536 pairs at the
+# benchmark's cell): the held pairs a layer at twelve seeds' initial
+# weights ran from 3 to 7,897 (a token stream is Zipfian, and a
+# frequent token sends all its copies the same way), and a buffer
+# twice as long cost every step 20 ms of buffer-sized passes (PERF.md
+# section 6, PR 53).
+ROWS_CAP_OVER_MEAN = 4
+
+
+def rows_cap(n: int, cfg: MoEConfig) -> int:
+    """Rows of the held path's buffer for ``n`` tokens on a device: a
+    multiple of 16 (bf16's sublane tile), at most ``n x top_k``."""
+    mean = n * cfg.top_k * cfg.experts_here / cfg.n_experts
+    cap = -(-int(ROWS_CAP_OVER_MEAN * mean) // 16) * 16
+    return max(16, min(cap, n * cfg.top_k))
+
+
+def _segment_heads(rows, segment, steps):
+    """rows [r, D] in runs of equal ``segment`` [r] (each at most
+    ``2 ** steps`` long): every run's first row becomes the run's sum,
+    by ``steps`` shifted adds; the other rows hold partial sums."""
+    for s in (1 << i for i in range(steps)):
+        same = jnp.concatenate(
+            [segment[s:] == segment[:-s], jnp.zeros((s,), bool)]
+        )
+        ahead = jnp.concatenate([rows[s:], jnp.zeros_like(rows[:s])])
+        rows = rows + jnp.where(same[:, None], ahead, 0.0)
+    return rows
+
+
+# The two moves between token order and the held rows are gathers
+# forward AND backward, as the sorted path's are, and neither touches a
+# row of an absent expert: tokens -> rows reads one token a row, and
+# rows -> tokens puts the rows in token order (they are sorted by
+# expert), sums each token's at most top_k rows by shifted adds and
+# reads one row a token. Each is the other's transpose.
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _rows_of_tokens(flat, plan, steps):
+    """flat [n, D] -> [cap, D]: row r is the token of the r-th held
+    pair in expert order; rows past the held pairs are zero."""
+    token, live = plan["token"], plan["live"]
+    return jnp.where(live[:, None], flat[token], jnp.zeros((), flat.dtype))
+
+
+def _rows_of_tokens_fwd(flat, plan, steps):
+    return _rows_of_tokens(flat, plan, steps), plan
+
+
+def _rows_of_tokens_bwd(steps, plan, g):
+    with jax.named_scope("moe_route"):
+        return _tokens_of_rows(g, plan, steps), None
+
+
+_rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _tokens_of_rows(rows, plan, steps):
+    """rows [cap, D] -> [n, D]: each token's rows (at most
+    ``2 ** steps``) summed, zero for a token with none."""
+    live = plan["live"]
+    rows = jnp.where(live[:, None], rows, jnp.zeros((), rows.dtype))
+    by_token = rows[plan["by_token"]]
+    heads = _segment_heads(by_token, plan["sorted_token"], steps)
+    first, some = plan["first_row"], plan["some"]
+    return jnp.where(some[:, None], heads[first], jnp.zeros((), rows.dtype))
+
+
+def _tokens_of_rows_fwd(rows, plan, steps):
+    return _tokens_of_rows(rows, plan, steps), plan
+
+
+def _tokens_of_rows_bwd(steps, plan, g):
+    with jax.named_scope("moe_combine"):
+        return _rows_of_tokens(g, plan, steps), None
+
+
+_tokens_of_rows.defvjp(_tokens_of_rows_fwd, _tokens_of_rows_bwd)
+
+
+@jax.custom_vjp
+def _row_weights(weights, plan):
+    """weights [n, k] -> [cap]: the weight of each row's pair, zero
+    past the held pairs; backward a gather too, by the pairs' rows."""
+    taken = weights.reshape(-1)[plan["order"]]
+    return jnp.where(plan["live"], taken, 0.0)
+
+
+def _row_weights_fwd(weights, plan):
+    return _row_weights(weights, plan), (plan, weights.shape)
+
+
+def _row_weights_bwd(res, g):
+    plan, shape = res
+    g = jnp.where(plan["live"], g, 0.0)
+    back = jnp.where(plan["pair_here"], g[plan["row_of_pair"]], 0.0)
+    return back.reshape(shape), None
+
+
+_row_weights.defvjp(_row_weights_fwd, _row_weights_bwd)
+
+
+def _held_order(local, held: int) -> Dict[str, Any]:
+    """Where the held pairs stand, once a layer (int32 and bool
+    vectors, no gradient). local [n, k] int32: a pair's expert among
+    the held ones, ``held`` for an absent one. The pairs are sorted by
+    that (stable): the held experts' first, expert by expert, every
+    absent pair in one trailing group no product touches."""
+    n, k = local.shape
+    pairs = jnp.arange(n * k, dtype=jnp.int32)
+    _, order = jax.lax.sort(
+        (local.reshape(n * k), pairs), num_keys=1, is_stable=True
+    )
+    _, row_of_pair = jax.lax.sort((order, pairs), num_keys=1)
+    ends = jnp.cumsum(expert_counts(local, held + 1)[:held])
+    return {
+        "order": order, "row_of_pair": row_of_pair, "ends": ends,
+        "pair_held": (local < held).reshape(n * k),
+    }
+
+
+def _block_plan(whole, j, n: int, k: int, cap: int) -> Dict[str, Any]:
+    """Block ``j`` of that order, rows ``[j x cap, (j + 1) x cap)``:
+    the buffer's rows, the part of every expert's group that falls
+    among them, and the rows in token order."""
+    lo = j * cap
+    ends = whole["ends"]
+    starts = jnp.concatenate([jnp.zeros((1,), ends.dtype), ends[:-1]])
+    window = lambda x: jnp.clip(x, lo, lo + cap)
+    rows = jnp.arange(cap, dtype=jnp.int32)
+    live = lo + rows < ends[-1]
+    padded = jnp.pad(whole["order"], (0, -(n * k) % cap))
+    order = jax.lax.dynamic_slice(padded, (lo,), (cap,))
+    token = order // k
+    # The rows in token order (a dead row after every live one), and
+    # where each token's run starts there.
+    sorted_token, by_token = jax.lax.sort(
+        (jnp.where(live, token, n), rows), num_keys=1, is_stable=True
+    )
+    at = whole["row_of_pair"] - lo
+    pair_here = whole["pair_held"] & (at >= 0) & (at < cap)
+    per_token = jnp.sum(pair_here.reshape(n, k).astype(jnp.int32), axis=1)
+    return {
+        "order": order, "token": token, "live": live,
+        "group_sizes": window(ends) - window(starts),
+        "by_token": by_token, "sorted_token": sorted_token,
+        "first_row": jnp.minimum(jnp.cumsum(per_token) - per_token, cap - 1),
+        "some": per_token > 0,
+        "pair_here": pair_here,
+        "row_of_pair": jnp.clip(at, 0, cap - 1),
+    }
+
+
+def _held_block(plan, flat, weights, wi, wo, wg, *, steps):
+    """One device's tokens through the experts held here, for the
+    held pairs of one block. flat [n, D], weights [n, k] -> [n, D]
+    float32."""
+    from dlrover_tpu.ops.grouped_matmul import gmm
+
+    with jax.named_scope("moe_route"):
+        # The rows past the held pairs (zeros) go to the last expert's
+        # group, so the grouped products walk every tile of the buffer
+        # whatever the load: a zero row gives a zero row, forward and
+        # backward, and the block's time is the buffer's, not the
+        # count's (``ROWS_CAP_OVER_MEAN`` says why).
+        sizes = plan["group_sizes"]
+        sizes = sizes.at[-1].add(plan["live"].shape[0] - jnp.sum(sizes))
+        row_weight = _row_weights(weights, plan)
+        xs = _rows_of_tokens(flat, plan, steps)
+    with jax.named_scope("moe_experts"):
+        h = gmm(xs, wi, sizes)
+        if wg is not None:
+            g = gmm(xs, wg, sizes)
+            h = (jax.nn.silu(g.astype(jnp.float32)) * h).astype(xs.dtype)
+        else:
+            h = jax.nn.gelu(h.astype(jnp.float32)).astype(xs.dtype)
+        out = gmm(h, wo, sizes)
+    with jax.named_scope("moe_combine"):
+        weighted = out.astype(jnp.float32) * row_weight[:, None]
+        return _tokens_of_rows(weighted, plan, steps)
+
+
+def _held_experts(flat, local, weights, *matrices, held, cap):
+    """One device's tokens through the experts held here: dropless
+    whatever the load. ``matrices``: wi, wo and, of a gated layer, wg.
+    A scan over the blocks of ``cap`` sorted rows there can be, each
+    after the first behind a ``lax.cond`` on the counted rows; the
+    backward takes from
+    the forward its operands alone and forms each block it needs
+    again (the rows are 1/32 of the pairs at the published share, and
+    a value kept inside a ``lax.cond`` branch would be written, as
+    zeros, by the other too), so nothing is stacked over the blocks."""
+    n, k = local.shape
+    steps = max(1, int(np.ceil(np.log2(k))))
+    blocks = -(-n * k // cap)
+
+    def block(whole, j, flat, weights, wi, wo, wg=None):
+        with jax.named_scope("moe_route"):
+            plan = _block_plan(whole, j, n, k, cap)
+        return _held_block(plan, flat, weights, wi, wo, wg, steps=steps)
+
+    def over_blocks(local, add_block, start):
+        """``start`` plus ``add_block(whole, j)`` of every block that
+        holds a row."""
+        with jax.named_scope("moe_route"):
+            whole = _held_order(local, held)
+
+        def step(total, j):
+            # The first block always runs: under a balanced load it
+            # always holds rows, and a step that skipped it would be
+            # one no deployment sees (a router that no balancing step
+            # holds even turns away from a share's experts within tens
+            # of steps: PERF.md section 6, PR 53).
+            return jax.lax.cond(
+                (j == 0) | (j * cap < whole["ends"][-1]),
+                lambda total: jax.tree.map(
+                    jnp.add, total, add_block(whole, j)
+                ),
+                lambda total: total,
+                total,
+            ), None
+
+        return jax.lax.scan(
+            step, start, jnp.arange(blocks, dtype=jnp.int32)
+        )[0]
+
+    @jax.custom_vjp
+    def run(flat, local, weights, *matrices):
+        return over_blocks(
+            local,
+            lambda whole, j: block(whole, j, flat, weights, *matrices),
+            jnp.zeros(flat.shape, jnp.float32),
+        )
+
+    def run_fwd(*operands):
+        return run(*operands), operands
+
+    def run_bwd(operands, g):
+        flat, local, *rest = operands
+
+        def grads(whole, j):
+            _, pull = jax.vjp(
+                lambda flat, *rest: block(whole, j, flat, *rest), flat, *rest
+            )
+            return pull(g)
+
+        d_flat, *d_rest = over_blocks(
+            local, grads, jax.tree.map(jnp.zeros_like, (flat, *rest))
+        )
+        return (d_flat, None, *d_rest)
+
+    run.defvjp(run_fwd, run_bwd)
+    return run(flat, local, weights, *matrices)
+
+
+def _held_moe(params, flat, logits, cfg: MoEConfig):
+    """flat [n, D] -> y [n, D] float32: the part of the layer's result
+    that the experts held here give."""
+    from dlrover_tpu import obs
+    from dlrover_tpu.ops.flash_attention import batch_axes, per_device
+
+    held = cfg.experts_here
+    _, n_here = batch_axes(flat.shape[0])
+    cap = rows_cap(n_here, cfg)
+    obs.event(
+        "moe.held", router_experts=cfg.n_experts,
+        first_expert=cfg.first_expert, held=held, top_k=cfg.top_k,
+        scoring=cfg.scoring, rows_cap=cap, tokens=n_here,
+        row_blocks=-(-n_here * cfg.top_k // cap),
+    )
+    with jax.named_scope("moe_route"):
+        weights, experts = route(logits, params.get("router_bias"), cfg)
+        local = experts - cfg.first_expert
+        local = jnp.where((local >= 0) & (local < held), local, held)
+    operands = [flat.astype(cfg.dtype), local, weights,
+                params["wi"], params["wo"]]
+    if cfg.gated:
+        operands.append(params["wg"])
+
+    return per_device(
+        functools.partial(_held_experts, held=held, cap=cap), *operands,
+        split=(True, True, True) + (False,) * (len(operands) - 3),
+    )
+
+
 def moe_mlp(
     params: Dict[str, Any],
     x: jax.Array,  # [B, T, D]
@@ -440,6 +876,19 @@ def moe_mlp(
         logits = keep(
             router_logits(flat, params["router"]), ROUTER_LOGITS
         )  # [n, E]
+    if cfg.held:
+        # A chip's share of the experts (all of them, at ``held ==
+        # n_experts``). No auxiliary loss: such a router is balanced by
+        # its bias, outside the step.
+        with jax.named_scope("moe_routed"):
+            y = _held_moe(params, flat, logits, cfg)
+        y = y.reshape(B, T, D).astype(x.dtype)
+        if cfg.shared_hidden:
+            from dlrover_tpu.models.llama import swiglu
+
+            with jax.named_scope("moe_shared"):
+                y = y + swiglu(x, params["shared"])
+        return y, jnp.zeros((), jnp.float32)
     mesh = jax.sharding.get_abstract_mesh()
     if not mesh.empty and mesh.shape.get("expert", 1) > 1:
         y, metrics = _onehot_moe(params, flat, logits, cfg)

@@ -308,7 +308,8 @@ class CompileTracker:
 SCOPES = frozenset((
     "accumulate", "layers", "embed", "attn", "mlp", "ssm", "head",
     "optimizer", "moe_route", "moe_experts", "moe_combine", "ssm_conv",
-    "ssd", "ssm_norm", "ut_loop", "exit_gate",
+    "ssd", "ssm_norm", "ut_loop", "exit_gate", "kda", "kda_conv",
+    "kda_scan", "kda_gate", "mla", "moe_routed", "moe_shared",
 ))
 _HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
 _HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')

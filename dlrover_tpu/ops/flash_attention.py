@@ -388,9 +388,11 @@ def _fwd(q, k, v, causal, window, scale, block_q, block_k, seq_len,
     multiple — Tq == Tk for the square call). Returns (o [B,H,Tq,D],
     lse [B,H,1,Tq]). ``seq_len`` is the true KEY length: keys beyond
     it are masked out. ``q_offset`` is the global position of q row 0
-    (causal/window comparisons happen in key coordinates)."""
+    (causal/window comparisons happen in key coordinates). ``v`` may
+    have a head size of its own (latent attention's 192 and 128): the
+    output has v's, and nothing else in the kernel reads a size."""
     b, h, tq, d = q.shape
-    tk = k.shape[2]
+    tk, dv = k.shape[2], v.shape[3]
     num_q = tq // block_q
     num_kv = tk // block_k
     kernel = functools.partial(
@@ -413,23 +415,23 @@ def _fwd(q, k, v, causal, window, scale, block_q, block_k, seq_len,
                          lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, block_k, d),
                          lambda b, h, i, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
+            pl.BlockSpec((1, 1, block_k, dv),
                          lambda b, h, i, j: (b, h, j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, d),
+            pl.BlockSpec((1, 1, block_q, dv),
                          lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, block_q, 1),
                          lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, tq, d), q.dtype),
+            jax.ShapeDtypeStruct((b, h, tq, dv), q.dtype),
             jax.ShapeDtypeStruct((b, h, tq, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
@@ -712,7 +714,7 @@ def _bwd(
     seq_len, interpret, g_lse=None, q_offset=0,
 ):
     b, h, tq, d = q.shape
-    tk = k.shape[2]
+    tk, dv = k.shape[2], v.shape[3]  # v, o and do have v's head size
     num_q = tq // block_q
     num_kv = tk // block_k
     pad = seq_len < tk
@@ -753,9 +755,9 @@ def _bwd(
                          lambda b, h, j, i: (b, h, i, 0)),
             pl.BlockSpec((1, 1, block_k, d),
                          lambda b, h, j, i: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
+            pl.BlockSpec((1, 1, block_k, dv),
                          lambda b, h, j, i: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, block_q, d),
+            pl.BlockSpec((1, 1, block_q, dv),
                          lambda b, h, j, i: (b, h, i, 0)),
             pl.BlockSpec((1, 1, 1, block_q),
                          lambda b, h, j, i: (b, h, 0, i)),
@@ -766,25 +768,25 @@ def _bwd(
             pl.BlockSpec((1, 1, tq, d), lambda b, h, j, i: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, block_k, d),
                          lambda b, h, j, i: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
+            pl.BlockSpec((1, 1, block_k, dv),
                          lambda b, h, j, i: (b, h, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, tq, d), q.dtype),
             jax.ShapeDtypeStruct((b, h, tk, d), k.dtype),
-            jax.ShapeDtypeStruct((b, h, tk, d), v.dtype),
+            jax.ShapeDtypeStruct((b, h, tk, dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((tq, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
                 "parallel", "parallel", "arbitrary", "arbitrary"
             ),
             vmem_limit_bytes=_bwd_vmem_limit(
-                tq, d, q.dtype.itemsize, block_q, block_k
+                tq, max(d, dv), q.dtype.itemsize, block_q, block_k
             ),
         ),
         interpret=interpret,
@@ -1015,6 +1017,11 @@ def flash_attention(
     i attends to keys (i-window, i], and kv blocks entirely below the
     band are skipped — O(T*window) MXU work instead of O(T^2).
     Requires ``causal=True``.
+
+    ``v`` may have a head size of its own (latent attention: queries
+    and keys of 192 columns, values of 128): the output has v's, the
+    default scale is one over the root of q's, and the kernels size
+    each block by the array it is of and adapt on nothing else.
     """
     if interpret is None:
         interpret = use_interpret()

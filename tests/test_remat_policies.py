@@ -471,6 +471,7 @@ class TestRetiredNameAndEvent:
             "attn_in", "flash_o", "flash_lse", "mlp_hidden",
             "router_logits", "moe_order", "moe_in", "moe_out",
             "ssm_in", "ssd_y", "ssd_states",
+            "kda_in", "kda_o", "kda_states", "mla_latent",
         )
         assert remat.BLOCK_OUT not in remat.KEPT
 

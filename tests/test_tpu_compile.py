@@ -24,7 +24,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from dlrover_tpu.models import gpt, granite_hybrid, llama, ouro
+from dlrover_tpu.models import gpt, granite_hybrid, kimi_linear, llama, ouro
 from dlrover_tpu.ops import causal_conv, grouped_matmul
 from dlrover_tpu.ops import ssd as ssd_ops
 from dlrover_tpu.ops.flash_attention import (
@@ -792,3 +792,35 @@ def test_ouro_train_step_compiles_on_one_chip(topo, compiled_kernels):
     # buffer assignment totals 11.694 GB (14.208 nested; PERF.md 7(j)).
     # A reading that moves says the layer scans keep more or less.
     assert 16.7e9 < total < 17.4e9, total
+
+
+def _kimi_cell_cfg():
+    model = kimi_linear
+    return model.KimiLinearConfig(
+        vocab_size=20480,
+        mixers=(model.KDA, model.KDA, model.KDA, model.MLA, model.KDA),
+        ffns=(model.DENSE,) + (model.MOE,) * 4,
+        held=8, remat="full", use_flash_attention=True,
+    )
+
+
+def test_kimi_train_step_compiles_on_one_chip(topo, compiled_kernels):
+    """The program of the benchmark's ``kimi-linear-48b-a3b.steady``:
+    published layers 1 to 5 (dense-KDA, KDA, KDA, MLA, KDA with
+    experts) at published widths, 8 of 256 experts held, an eighth of
+    both tables, 1 x 8192 tokens, full remat. It fits; the flash
+    kernels take the latent layer's two head sizes (192 and 128) and
+    the forward runs once; each expert layer's grouped products stand
+    in the one block of the held path's scan over its blocks of
+    ``rows_cap`` sorted rows, in the one step program."""
+    compiled = _elastic_trainer_step(kimi_linear, _kimi_cell_cfg(), topo)
+    _assert_fits_with_flash(compiled)
+    assert len(_computations_calling(compiled, "flash_attention_fwd")) == 1
+    assert len(_computations_calling(compiled, "flash_attention_bwd")) == 1
+    text = compiled.as_text()
+    assert "moe_gmm" in text and "moe_tgmm" in text
+    assert "conv_silu_fwd" in text and "conv_silu_bwd" in text
+    mem = compiled.memory_analysis()
+    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    print("kimi step bytes", total, mem)
+    assert total / 1e9 < 16.9, total

@@ -130,3 +130,52 @@ def test_subtile_check_refuses_a_backward_that_skips_a_live_subtile(
     got = _flash_checks(monkeypatch)
     assert not got["flash_bwd_subtiles"]["ok"]
     assert "Mismatched elements" in got["flash_bwd_subtiles"]["error"]
+
+
+def _kda_checks(monkeypatch):
+    monkeypatch.setattr(tpu_kernel_smoke, "SMALL", True)
+    tpu_kernel_smoke.RESULTS.clear()
+    tpu_kernel_smoke.kda_checks()
+    return {r["kernel"]: r for r in tpu_kernel_smoke.RESULTS}
+
+
+def test_kda_checks_pass_the_rule_as_it_is(monkeypatch):
+    got = _kda_checks(monkeypatch)
+    assert set(got) == {"kda_fwd_bwd_f32", "kda_fwd_bwd_bf16"}
+    assert all(r["ok"] for r in got.values()), got
+    assert got["kda_fwd_bwd_f32"]["max_abs_err"] < 2e-5
+
+
+@pytest.mark.parametrize("name", ["no_carry", "no_delta"])
+def test_float32_check_refuses_a_broken_rule(monkeypatch, name):
+    """The rule broken as benchmark/controls/kimi_linear.py breaks it:
+    a state that does not cross a chunk boundary, the delta term
+    dropped."""
+    from benchmark.controls import kimi_linear as kimi_controls
+    from dlrover_tpu.ops import kda as kda_module
+
+    broken = {
+        "no_carry": kimi_controls._rule_without_carry(kda_module.kda),
+        "no_delta": kimi_controls._rule_without_delta(kda_module),
+    }[name]
+    monkeypatch.setattr(kda_module, "kda", broken)
+    got = _kda_checks(monkeypatch)
+    assert not got["kda_fwd_bwd_f32"]["ok"], (name, got)
+    assert "relative error" in got["kda_fwd_bwd_f32"]["error"]
+
+
+def test_two_head_sizes_check_passes_and_refuses_the_wrong_scale(monkeypatch):
+    got = _flash_checks(monkeypatch)
+    assert got["flash_qk192_v128"]["ok"], got["flash_qk192_v128"]
+    fa = sys.modules["dlrover_tpu.ops.flash_attention"]
+    real = fa._fwd
+
+    def scaled_by_the_values_head(q, k, v, causal, window, scale, *rest, **kw):
+        if v.shape[-1] != q.shape[-1]:
+            scale = scale * (q.shape[-1] / v.shape[-1]) ** 0.5
+        return real(q, k, v, causal, window, scale, *rest, **kw)
+
+    monkeypatch.setattr(fa, "_fwd", scaled_by_the_values_head)
+    got = _flash_checks(monkeypatch)
+    assert not got.pop("flash_qk192_v128")["ok"]
+    assert all(r["ok"] for r in got.values()), got

@@ -355,6 +355,45 @@ def flash_checks():
 
     check("flash_bwd_subtiles", subtile_check)
 
+    def two_head_sizes():
+        """Latent attention's call (models/kimi_linear.py): queries
+        and keys of 192 columns, values of 128, 32 heads, causal, at
+        scale 192^-0.5, in bf16 against plain attention on the same
+        values in float32. The output and the three gradients differ
+        by the operands' own rounding (3.3e-3 on the chip, PERF.md
+        section 6, PR 53; the limit is six times that); a kernel that
+        sized ``o`` or ``dv`` by the query's head would not compile,
+        and one that scaled by the value's reads 0.2."""
+        d_qk, d_v, heads = (24, 16, 2) if SMALL else (192, 128, 32)
+        keys = jax.random.split(jax.random.PRNGKey(11), 4)
+        t = 2 * SEQ
+        q2, k2 = (
+            jax.random.normal(kk, (1, t, heads, d_qk)).astype(jnp.bfloat16)
+            for kk in keys[:2]
+        )
+        v2 = jax.random.normal(keys[2], (1, t, heads, d_v)).astype(jnp.bfloat16)
+        w2 = jax.random.normal(keys[3], (1, t, heads, d_v))
+        scale = d_qk ** -0.5
+
+        def grads(fn, *args):
+            return jax.jit(jax.value_and_grad(
+                lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w2),
+                argnums=(0, 1, 2),
+            ))(*args)
+
+        o = jax.jit(lambda *a: flash_attention(*a, scale=scale))(q2, k2, v2)
+        assert o.shape == v2.shape, o.shape
+        _, got = grads(lambda *a: flash_attention(*a, scale=scale), q2, k2, v2)
+        with _prec("f32"):
+            f32 = [x.astype(jnp.float32) for x in (q2, k2, v2)]
+            want_o = jax.jit(lambda *a: dense(*a, scale=scale))(*f32)
+            _, want = grads(lambda *a: dense(*a, scale=scale), *f32)
+        _close_rel(o, want_o, 2e-2)
+        for g, r in zip(got, want):
+            _close_rel(g, r, 2e-2)
+
+    check("flash_qk192_v128", two_head_sizes)
+
 
 def quant_checks():
     from dlrover_tpu.ops.quantization import (
@@ -497,6 +536,58 @@ def ssd_checks():
     check("ssd_fwd_bwd_bf16", both(jnp.bfloat16, 2e-2))
 
 
+def kda_checks():
+    """The chunked delta rule (ops/kda.py) against the recurrence a
+    token at a time, at the published head size over eight chunks,
+    with log decays down to -1.6 a token (the family's initial range:
+    a chunk's running sum passes -50, and at the small shapes, where
+    they go down to -3, -100: a decay factored as ``exp(G) exp(-G)``
+    overflows there). The float32 limit lies between what the chip
+    reads for the rule as it is (4.5e-6 forward, 8.4e-6 the largest
+    gradient) and with the state reset at chunk boundaries or the
+    delta term dropped (over 0.1 both; tests/test_tpu_kernel_smoke.py
+    tries them on the CPU); the bf16 one is the operands' own rounding
+    (3.8e-3 on the chip; PERF.md section 6, PR 53)."""
+    from dlrover_tpu.ops import kda
+
+    t, heads, d, strongest = (
+        (128, 2, 16, 3.0) if SMALL else (512, 4, 128, 1.6)
+    )
+    keys = jax.random.split(jax.random.PRNGKey(12), 6)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(keys[0], (1, t, heads, d))) * d ** -0.5
+    k = unit(jax.random.normal(keys[1], (1, t, heads, d)))
+    v = jax.random.normal(keys[2], (1, t, heads, d))
+    g = -jnp.exp(jax.random.uniform(
+        keys[3], (1, t, heads, d), minval=np.log(1e-3),
+        maxval=np.log(strongest),
+    ))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (1, t, heads)))
+    w = jax.random.normal(keys[5], (1, t, heads, d))
+
+    def both(dtype, tol):
+        qs, ks, vs = (x.astype(dtype) for x in (q, k, v))
+
+        def run():
+            grads = lambda rule: jax.jit(jax.value_and_grad(
+                lambda *a: jnp.sum(rule(*a).astype(jnp.float32) * w),
+                argnums=(0, 1, 2, 3, 4),
+            ))(qs, ks, vs, g, beta)
+            with _prec("f32" if dtype == jnp.float32 else "bf16"):
+                o = jax.jit(lambda *a: kda.kda(*a))(qs, ks, vs, g, beta)
+                _close_rel(o, kda.recurrence(qs, ks, vs, g, beta), tol)
+                (_, got), (_, want) = (
+                    grads(lambda *a: kda.kda(*a)), grads(kda.recurrence)
+                )
+            for a, b in zip(got, want):
+                _close_rel(a, b, tol)
+
+        return run
+
+    check("kda_fwd_bwd_f32", both(jnp.float32, 2e-4))
+    check("kda_fwd_bwd_bf16", both(jnp.bfloat16, 2e-2))
+
+
 def _plain_conv_silu(x, w, bias):
     """A mixer's convolution as pad, shifted slices and multiply-adds,
     then SiLU, in the inputs' dtype throughout: the reference, and
@@ -577,6 +668,7 @@ def run(small: bool) -> list:
     xent_checks()
     ssd_checks()
     ssm_conv_checks()
+    kda_checks()
     return list(RESULTS)
 
 
