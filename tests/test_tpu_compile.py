@@ -15,6 +15,7 @@ the worker that is handed this file does.
 
 import dataclasses
 import functools
+import re
 import sys
 
 import jax
@@ -26,6 +27,7 @@ from jax.sharding import SingleDeviceSharding
 
 from dlrover_tpu.models import gpt, granite_hybrid, kimi_linear, llama, ouro
 from dlrover_tpu.ops import causal_conv, grouped_matmul
+from dlrover_tpu.ops import kda as kda_ops
 from dlrover_tpu.ops import ssd as ssd_ops
 from dlrover_tpu.ops.flash_attention import (
     flash_attention,
@@ -83,7 +85,7 @@ def compiled_kernels(monkeypatch):
     the answer is the chip's. ``dlrover_tpu.ops.flash_attention`` the
     attribute is the re-exported function, so go through sys.modules."""
     for name in ("flash_attention", "quantization", "grouped_matmul",
-                 "ssd", "causal_conv"):
+                 "ssd", "causal_conv", "kda"):
         monkeypatch.setattr(
             sys.modules[f"dlrover_tpu.ops.{name}"],
             "use_interpret",
@@ -794,6 +796,66 @@ def test_ouro_train_step_compiles_on_one_chip(topo, compiled_kernels):
     assert 16.7e9 < total < 17.4e9, total
 
 
+def _kda_operands(sharding, bsz, t, heads=32, rows_sharding=None,
+                  dtype=jnp.bfloat16):
+    """ops/kda.py's operands at Kimi Linear's widths: heads of 128,
+    bf16 with float32 log decays and beta."""
+    rows = rows_sharding or sharding
+    shaped = lambda dt, *shape: jax.ShapeDtypeStruct(shape, dt, sharding=rows)
+    wide = shaped(dtype, bsz, t, heads, 128)
+    return (
+        wide, wide, wide, shaped(jnp.float32, bsz, t, heads, 128),
+        shaped(jnp.float32, bsz, t, heads),
+    )
+
+
+def _kda_grad(*args):
+    return jax.grad(
+        lambda *a: kda_ops.kda(*a).astype(jnp.float32).sum(),
+        argnums=range(5),
+    )(*args)
+
+
+@pytest.mark.parametrize("bsz,t,heads,dtype", [
+    (1, 8192, 32, jnp.bfloat16), (128, 64, 32, jnp.bfloat16),
+    (1, 512, 4, jnp.float32),
+])
+def test_kda_fwd_bwd_compiles_at_kimi_widths(
+    one_chip, compiled_kernels, bsz, t, heads, dtype
+):
+    """Blocks of 8 heads of 128 read where the operands lie, a head
+    a dynamic slice of whole lanes in a rolled loop, beta a head a
+    row, every head's state in VMEM scratch along the sequential
+    chunk axis: Mosaic takes both kernels, at the cell's one sequence
+    of 128 chunks, at the ``no_carry`` control's 128 sequences of one
+    chunk and, in float32 under ``default_matmul_precision("highest")``,
+    at tools/tpu_kernel_smoke.py's shape (the exact sums' bf16 pieces
+    are pinned to one pass: Mosaic refuses "highest" of bf16 operands,
+    which the chip's smoke met first)."""
+    operands = _kda_operands(one_chip, bsz, t, heads, dtype=dtype)
+    with jax.default_matmul_precision(
+        "highest" if dtype == jnp.float32 else "default"
+    ):
+        text = _compile(_kda_grad, *operands).as_text()
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) == 2
+    assert "kda_fwd" in text and "kda_bwd" in text
+
+
+def test_kda_splits_itself_over_a_mesh(topo, compiled_kernels):
+    """Under fsdp=4 each chip runs the rule on its own batch row."""
+    from dlrover_tpu.parallel.mesh import under_mesh
+
+    mesh = build_mesh(MeshConfig(fsdp=4), devices=list(topo.devices))
+    args = _kda_operands(
+        NamedSharding(mesh, P()), 4, 512, heads=8,
+        rows_sharding=NamedSharding(mesh, P("fsdp")),
+    )
+    text = _compile(under_mesh(_kda_grad, mesh), *args).as_text()
+    assert "kda_fwd" in text and "kda_bwd" in text
+    assert "bf16[1,512,1024]" in text  # a chip's own row
+    assert "all-gather" not in text
+
+
 def _kimi_cell_cfg():
     model = kimi_linear
     return model.KimiLinearConfig(
@@ -820,6 +882,15 @@ def test_kimi_train_step_compiles_on_one_chip(topo, compiled_kernels):
     text = compiled.as_text()
     assert "moe_gmm" in text and "moe_tgmm" in text
     assert "conv_silu_fwd" in text and "conv_silu_bwd" in text
+    # The rule's kernels, one call each a KDA layer: the rematerialised
+    # layer takes the kept output and chunk states and does not run
+    # ``kda_fwd`` again.
+    calls = lambda name: len(re.findall(
+        rf'custom_call_target="tpu_custom_call"[^\n]*{name}', text
+    ))
+    assert calls("kda_fwd") == 4 and calls("kda_bwd") == 4, (
+        calls("kda_fwd"), calls("kda_bwd")
+    )
     mem = compiled.memory_analysis()
     total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     print("kimi step bytes", total, mem)

@@ -548,6 +548,7 @@ def kda_checks():
     delta term dropped (over 0.1 both; tests/test_tpu_kernel_smoke.py
     tries them on the CPU); the bf16 one is the operands' own rounding
     (3.8e-3 on the chip; PERF.md section 6, PR 53)."""
+    from dlrover_tpu import obs
     from dlrover_tpu.ops import kda
 
     t, heads, d, strongest = (
@@ -573,14 +574,28 @@ def kda_checks():
                 lambda *a: jnp.sum(rule(*a).astype(jnp.float32) * w),
                 argnums=(0, 1, 2, 3, 4),
             ))(qs, ks, vs, g, beta)
-            with _prec("f32" if dtype == jnp.float32 else "bf16"):
-                o = jax.jit(lambda *a: kda.kda(*a))(qs, ks, vs, g, beta)
-                _close_rel(o, kda.recurrence(qs, ks, vs, g, beta), tol)
-                (_, got), (_, want) = (
-                    grads(lambda *a: kda.kda(*a)), grads(kda.recurrence)
-                )
+            tracer = obs.configure_tracer()
+            try:
+                with _prec("f32" if dtype == jnp.float32 else "bf16"):
+                    o = jax.jit(lambda *a: kda.kda(*a))(qs, ks, vs, g, beta)
+                    _close_rel(o, kda.recurrence(qs, ks, vs, g, beta), tol)
+                    (_, got), (_, want) = (
+                        grads(lambda *a: kda.kda(*a)), grads(kda.recurrence)
+                    )
+                scans = [
+                    e for e in tracer.events() if e["name"] == "kda.scan"
+                ]
+            finally:
+                obs.disable_tracer()
             for a, b in zip(got, want):
                 _close_rel(a, b, tol)
+            # The kernels take whole lanes of a head: at the full shape
+            # this check holds kda_fwd and kda_bwd, and must not pass
+            # on the plain form.
+            said = [e.get("kernel") for e in scans]
+            assert said and all(x is (d % 128 == 0) for x in said), (
+                f"kda.scan said kernel: {said} at head size {d}"
+            )
 
         return run
 
