@@ -28,6 +28,12 @@ from dlrover_tpu import obs
 from dlrover_tpu.common.config import tmp_path
 from dlrover_tpu.common.log import get_logger
 from dlrover_tpu.obs import beacon as beacon_mod
+from dlrover_tpu.obs.profiling import (
+    AGENT_MARK_PREFIX,
+    keep_mark,
+    place_mark,
+    startup_timeline,
+)
 
 logger = get_logger("agent_monitor")
 
@@ -37,8 +43,6 @@ RECENT_STEP_TIMES = 32
 
 METRICS_FILE_ENV = "DLROVER_TPU_METRICS_FILE"
 PHASES_FILE_ENV = "DLROVER_TPU_PHASES_FILE"
-# Phase marks of this prefix are the agent's (TrainingMonitor.mark_phase).
-AGENT_MARK_PREFIX = "agent."
 
 # Local staleness threshold before the agent treats the co-hosted
 # trainer's beacon as wedged and fires its forensics hook. Sits above
@@ -425,21 +429,34 @@ class TrainingMonitor:
         os.replace(tmp, path)
 
     @staticmethod
+    def phase_marks() -> Dict[str, float]:
+        """A copy of the marks placed in THIS process
+        (``obs.profiling.startup_timeline()["marks"]``)."""
+        return startup_timeline()["marks"]
+
+    @staticmethod
     def mark_phase(name: str, path: Optional[str] = None) -> None:
         """Timestamp a startup/recovery phase boundary (proc_start,
         dist_ready, devices_ready, accelerate_done, built,
-        restore_read_done, restore_done, first_step_done, ...).
-        Written only when DLROVER_TPU_PHASES_FILE is set (or ``path``
-        given) — chaos drills and the benchmark use the marks to break
-        a recovery time into explainable, budget-checkable segments.
+        restore_read_done, restore_done, first_dispatch,
+        first_step_done, ...). Always kept in the process
+        (:meth:`phase_marks`); written to a file as well only when
+        DLROVER_TPU_PHASES_FILE is set (or ``path`` given) — chaos
+        drills and the benchmark use the marks to break a start or a
+        recovery into explainable, budget-checkable segments.
 
         Two processes write the file. The trainer's marks describe its
-        LATEST attempt: its ``proc_start`` empties them. Marks whose
-        name starts with ``agent.`` are the agent's (exit seen,
-        spawned, persist begun and done): they outlive ``proc_start``,
-        so the file holds a whole relaunch from the exit to the first
-        step, and ``agent.exit_seen`` empties them when the next
-        failure comes. The read-modify-rename runs under a lock on
+        LATEST attempt: its ``proc_start`` starts a new set. Marks
+        whose name starts with ``agent.`` are the agent's (launch
+        started, chips counted, master ready, spawned, exit seen,
+        persist begun and done): they outlive ``proc_start``, so the
+        file holds a whole relaunch from the exit to the first step,
+        and ``agent.exit_seen`` starts their new set when the next
+        failure comes. One generation back is kept: what a writer
+        empties it moves under ``prev.<name>`` (replacing its older
+        ``prev.`` keys), so after one restart the file still holds
+        the FIRST launch, which is what a job's time to its first
+        step is made of. The read-modify-rename runs under a lock on
         ``<path>.lock`` so neither writer loses the other's marks."""
         agents = name.startswith(AGENT_MARK_PREFIX)
         if not agents:
@@ -451,10 +468,11 @@ class TrainingMonitor:
             # agent's marks each stand beside an event or span of
             # their own.
             obs.event(f"trainer.{name}")
+        now = time.time()
+        keep_mark(name, now)
         path = path or os.getenv(PHASES_FILE_ENV)
         if not path:
             return
-        now = time.time()
         with open(f"{path}.lock", "w") as lock:
             fcntl.flock(lock, fcntl.LOCK_EX)
             try:
@@ -462,12 +480,7 @@ class TrainingMonitor:
                     marks = json.load(f)
             except (OSError, ValueError):
                 marks = {}
-            if name in ("proc_start", AGENT_MARK_PREFIX + "exit_seen"):
-                marks = {
-                    k: v for k, v in marks.items()
-                    if k.startswith(AGENT_MARK_PREFIX) != agents
-                }
-            marks[name] = now
+            place_mark(marks, name, now)
             tmp = f"{path}.tmp{os.getpid()}"
             with open(tmp, "w") as f:
                 json.dump(marks, f)

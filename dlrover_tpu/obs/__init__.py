@@ -105,6 +105,7 @@ from dlrover_tpu.obs.tracer import (  # noqa: F401
     IdSource,
     TraceContext,
     activate,
+    completed_span,
     configure_tracer,
     current_context,
     disable_tracer,

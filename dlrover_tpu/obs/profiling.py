@@ -21,6 +21,13 @@ its time goes; this module closes that gap for the training hot path:
   came from and the pass (forward, backward, recompute) it belongs to:
   what turns a device profile's ``fusion.364`` into ``layers/mlp``.
   Nothing is lowered or compiled for it until it is called.
+* :func:`install_compile_listeners` / :func:`startup_timeline`: JAX's
+  own account of every trace, lowering, backend compile and
+  persistent-cache load (``jax.monitoring``), kept by function as
+  ``{stage, fn, t0, t1}`` records beside the phase marks this process
+  placed: what a start is made of, asked for in one call
+  (``dlrover_compile_stage_seconds_total{stage}``; spans ``jax.*``
+  when the tracer is on).
 * :class:`MfuMeter` turns XLA's own cost model
   (``jit(f).lower(*args).cost_analysis()`` — trace+lower only, never
   a second XLA compile) plus measured step time into a live
@@ -44,6 +51,7 @@ import functools
 import json
 import os
 import re
+import sys
 import threading
 import time
 from typing import Callable, Dict, List, Optional
@@ -52,7 +60,9 @@ from dlrover_tpu.common.config import tmp_path
 from dlrover_tpu.common.log import get_logger
 from dlrover_tpu.obs.beacon import ProgressBeacon, default_beacon
 from dlrover_tpu.obs.metrics import counter, gauge
+from dlrover_tpu.obs.tracer import completed_span
 from dlrover_tpu.obs.tracer import event as obs_event
+from dlrover_tpu.obs.tracer import span as obs_span
 
 logger = get_logger("profiling")
 
@@ -92,6 +102,13 @@ _COMPILE_SECONDS = counter(
     "Wall seconds spent in dispatches that traced + compiled, per "
     "jitted function",
     ("fn",),
+)
+_STAGE_SECONDS = counter(
+    "dlrover_compile_stage_seconds_total",
+    "Wall seconds JAX reported per stage of its compile pipeline "
+    "(trace / lower / backend_compile / cache_load, by "
+    "jax.monitoring) plus the trainer's pricing of its step (price)",
+    ("stage",),
 )
 _MFU = gauge(
     "dlrover_train_mfu",
@@ -208,6 +225,189 @@ def step_flops(jfn, *args) -> Optional[float]:
         return None
 
 
+# -- the start-up timeline: marks, and JAX's compile pipeline by function
+
+# Phase marks of this prefix are the agent's (TrainingMonitor.mark_phase).
+AGENT_MARK_PREFIX = "agent."
+# What a writer's new set of marks displaces is kept one generation
+# under this prefix.
+PREV_MARK_PREFIX = "prev."
+# The marks that start a writer's new set: the trainer's, the agent's.
+NEW_SET_MARKS = ("proc_start", AGENT_MARK_PREFIX + "exit_seen")
+
+# jax.monitoring's time-span events (start and end on time.time(),
+# ``fun_name`` the traced function's or the module's name) -> stage.
+_JAX_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+}
+_JAX_CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
+# JAX's four (``price``, the trainer's pricing of its step, is a
+# record's stage too and no stage of a compile).
+STAGES = ("trace", "lower", "backend_compile", "cache_load")
+# A start places a hundred or two records (each jitted function's
+# outermost trace, its lowering, its compile or load); the newest are
+# kept.
+MAX_STAGE_RECORDS = 4096
+
+
+def _is_agents_mark(key: str) -> bool:
+    """Whose mark a key is: a ``prev.`` key belongs to the writer of
+    the name behind the prefix."""
+    return key.removeprefix(PREV_MARK_PREFIX).startswith(AGENT_MARK_PREFIX)
+
+
+def place_mark(marks: dict, name: str, now: float) -> None:
+    """``marks[name] = now``; a mark that starts a writer's new set
+    first moves that writer's marks one generation back, under
+    ``prev.``, and drops the generation before."""
+    if name in NEW_SET_MARKS:
+        agents = _is_agents_mark(name)
+        mine = [k for k in marks if _is_agents_mark(k) == agents]
+        last = {
+            PREV_MARK_PREFIX + k: marks[k]
+            for k in mine if not k.startswith(PREV_MARK_PREFIX)
+        }
+        for k in mine:
+            del marks[k]
+        marks.update(last)
+    marks[name] = now
+
+
+class _StartupTimeline:
+    """What this process keeps of its start, under one lock: every
+    phase mark placed here (``name -> time.time()``) and the stage
+    records of JAX's listeners in arrival order."""
+
+    def __init__(self):
+        self.lock = threading.Lock()
+        self.installed = False
+        self.marks: Dict[str, float] = {}
+        self.records: collections.deque = collections.deque(
+            maxlen=MAX_STAGE_RECORDS
+        )
+        # Per thread. ``loaded_at``: when JAX reported a cache load
+        # whose enclosing backend-compile span is still open;
+        # ``open_stages``: the stages JAX has begun and not ended.
+        self.pending = threading.local()
+
+    def record(self, stage: str, fn: str, t0: float, t1: float) -> None:
+        _STAGE_SECONDS.inc(max(t1 - t0, 0.0), stage=stage)
+        with self.lock:
+            self.records.append(
+                {"stage": stage, "fn": fn, "t0": t0, "t1": t1}
+            )
+
+    # -- jax.monitoring's listeners ---------------------------------------
+
+    def on_time_span(self, event, start_time, end_time, **kwargs) -> None:
+        stage = _JAX_STAGES.get(event)
+        if stage is None:
+            return
+        # A function traced while another stage is open on the thread
+        # (inside another's trace: the one-operation functions of
+        # jax.numpy, thousands in a step whose layers stand in line;
+        # inside a lowering: the rules' own helpers) is part of that
+        # stage: only the outermost trace is a record.
+        depth = max(getattr(self.pending, "open_stages", 1) - 1, 0)
+        self.pending.open_stages = depth
+        if stage == "trace":
+            if depth:
+                return
+        elif stage == "backend_compile":
+            # JAX's span is around compile_or_get_cached: a request
+            # the persistent cache served is a load, not a compile,
+            # and this is where its function's name is known.
+            loaded_at = getattr(self.pending, "loaded_at", None)
+            self.pending.loaded_at = None
+            if loaded_at is not None and loaded_at >= start_time:
+                stage = "cache_load"
+        fn = str(kwargs.get("fun_name", ""))
+        self.record(stage, fn, start_time, end_time)
+        completed_span(f"jax.{stage}", start_time, end_time, fn=fn)
+
+    def on_scalar(self, event, value, **_) -> None:
+        # JAX reports a stage's start as a scalar, its end as a span.
+        if event in _JAX_STAGES:
+            self.pending.open_stages = (
+                getattr(self.pending, "open_stages", 0) + 1
+            )
+
+    def on_duration(self, event, duration, **_) -> None:
+        if event == _JAX_CACHE_LOAD:
+            self.pending.loaded_at = time.time()
+
+    def since(self, t_from: float) -> Dict[str, List[dict]]:
+        """The records that ended at or after ``t_from``, by stage."""
+        out: Dict[str, List[dict]] = {}
+        with self.lock:
+            for rec in reversed(self.records):
+                if rec["t1"] < t_from:
+                    break
+                out.setdefault(rec["stage"], []).append(rec)
+        return out
+
+
+_TIMELINE = _StartupTimeline()
+
+
+def install_compile_listeners() -> bool:
+    """Listen to JAX's own account of its compile pipeline: every
+    trace, lowering and backend compile with the function's name and
+    its start and end (the outermost trace only: a function traced
+    inside another's trace or lowering is part of it), every load
+    from the persistent cache.
+    Idempotent; registers nothing (and returns False) in a process
+    that has not imported ``jax``, which then has nothing to compile
+    either. JAX calls a listener at a compile and never at a cached
+    dispatch."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return False
+    with _TIMELINE.lock:
+        if _TIMELINE.installed:
+            return True
+        _TIMELINE.installed = True
+    jax.monitoring.register_event_time_span_listener(_TIMELINE.on_time_span)
+    jax.monitoring.register_event_duration_secs_listener(
+        _TIMELINE.on_duration
+    )
+    jax.monitoring.register_scalar_listener(_TIMELINE.on_scalar)
+    return True
+
+
+def union_seconds(records) -> float:
+    """Seconds the records cover together, an overlap counted once
+    (two threads compiling at a time)."""
+    total, end = 0.0, float("-inf")
+    for t0, t1 in sorted((r["t0"], r["t1"]) for r in records):
+        if t1 > end:
+            total += t1 - max(t0, end)
+            end = t1
+    return total
+
+
+def keep_mark(name: str, now: float) -> None:
+    """A phase mark of this process (TrainingMonitor.mark_phase)."""
+    with _TIMELINE.lock:
+        place_mark(_TIMELINE.marks, name, now)
+
+
+def startup_timeline() -> dict:
+    """This process's start as the program saw it: ``{"marks": the
+    phase marks placed here, ``prev.`` keys and all (the file, where
+    there is one, also holds the other writer's), "compile": [{stage,
+    fn, t0, t1}] in arrival order (``trace``, ``lower``,
+    ``backend_compile``, ``cache_load`` with JAX's own times on
+    ``time.time()``; ``price``)}``."""
+    with _TIMELINE.lock:
+        return {
+            "marks": dict(_TIMELINE.marks),
+            "compile": [dict(r) for r in _TIMELINE.records],
+        }
+
+
 # The newest tracker of each function name: what compiled_scopes asks
 # once the loop that owned the trainer has returned. A tracker holds
 # the jitted function and an abstract signature, never a device
@@ -237,6 +437,10 @@ class CompileTracker:
     or dtype drift mid-run. Fallback (no cache API): only the first
     observed call counts as the compile.
 
+    Its ``trainer.compile`` event also says what JAX did in the call
+    (:func:`install_compile_listeners`): ``trace_s``, ``lower_s``,
+    ``backend_compile_s``, ``cache_load_s``, ``cache_hit``.
+
     Given the arguments of the calls it observes, it remembers the
     first call's abstract signature (shape, dtype, sharding of every
     leaf; donated arrays keep those once deleted), which is all
@@ -250,6 +454,7 @@ class CompileTracker:
         self._calls = 0
         self.compiles = 0
         self.signature = None
+        install_compile_listeners()
         if jfn is not None:
             _TRACKERS[fn_name] = self
 
@@ -261,6 +466,18 @@ class CompileTracker:
             return int(probe())
         except Exception:  # noqa: BLE001 — private API, best-effort
             return None
+
+    def price(self, *args) -> Optional[float]:
+        """FLOPs one call of the tracked function costs
+        (:func:`step_flops`), under span ``trainer.price_step``
+        (``flops``). What the pricing costs a start is also on the
+        start-up timeline as stage ``price``, tracer or no tracer."""
+        t0 = time.time()
+        with obs_span("trainer.price_step") as span:
+            flops = step_flops(self._jfn, *args)
+            span.set(flops=flops)
+        _TIMELINE.record("price", self.fn_name, t0, time.time())
+        return flops
 
     def observe_call(self, dur_s: float, args=None) -> bool:
         """Record one dispatch of ``args`` lasting ``dur_s``; True
@@ -283,11 +500,21 @@ class CompileTracker:
             self.compiles += 1
             _COMPILE_TOTAL.inc(fn=self.fn_name)
             _COMPILE_SECONDS.inc(max(dur_s, 0.0), fn=self.fn_name)
+            # What JAX did in this call: a retrace, a cold compile
+            # or a load from the persistent cache.
+            stages = _TIMELINE.since(time.time() - max(dur_s, 0.0))
             obs_event(
                 "trainer.compile",
                 fn=self.fn_name,
                 dur_s=round(dur_s, 4),
                 total=self.compiles,
+                cache_hit=bool(stages.get("cache_load")),
+                **{
+                    f"{stage}_s": round(
+                        union_seconds(stages.get(stage, ())), 4
+                    )
+                    for stage in STAGES
+                },
             )
             if self.compiles > 1:
                 logger.warning(

@@ -539,6 +539,23 @@ def event(name: str, **tags) -> Optional[dict]:
     return tr.event(name, **tags)
 
 
+def completed_span(name: str, t0: float, t1: float, **tags) -> Optional[dict]:
+    """Record a span that is already over, from its start and end on
+    ``time.time()`` (a listener is told after the fact), stamped with
+    its own start as :meth:`Span.__exit__` does; a no-op None-check
+    when tracing is disabled."""
+    tr = _tracer if _init_done else _lazy_init()
+    if tr is None:
+        return None
+    return tr._emit(
+        name,
+        ts=t0,
+        mono=time.monotonic() - (time.time() - t0),
+        dur_s=round(t1 - t0, 6),
+        **tags,
+    )
+
+
 def span(name: str, **tags):
     """Span context manager; a shared no-op when tracing is disabled
     and no ``jax.profiler`` capture is running (module docstring)."""

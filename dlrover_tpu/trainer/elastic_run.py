@@ -23,6 +23,7 @@ from typing import List, Optional, Tuple
 
 from dlrover_tpu.agent.agent import AgentConfig, ElasticAgent
 from dlrover_tpu.agent.master_client import MasterClient
+from dlrover_tpu.agent.monitor import TrainingMonitor
 from dlrover_tpu.common.constants import NodeEnv
 from dlrover_tpu.common.log import get_logger
 
@@ -190,10 +191,16 @@ def _local_chip_count() -> int:
 
 
 def run(args) -> int:
+    # The launcher's three steps, as the agent's marks: they stand
+    # before the first trainer's proc_start on the start-up timeline.
+    TrainingMonitor.mark_phase("agent.launch_start")
     min_nodes, max_nodes = parse_nnodes(args.nnodes)
     if args.standalone:
         min_nodes = max_nodes = 1
-    nproc = args.nproc_per_node or _local_chip_count()
+    nproc = args.nproc_per_node
+    if not nproc:
+        nproc = _local_chip_count()
+        TrainingMonitor.mark_phase("agent.chips_counted")
     node_rank = (
         args.node_rank
         if args.node_rank >= 0
@@ -207,6 +214,7 @@ def run(args) -> int:
             master_proc, master_addr = _launch_local_master(
                 max_nodes, min_nodes, args.node_unit
             )
+            TrainingMonitor.mark_phase("agent.master_ready")
         else:
             raise SystemExit(
                 "--master is required on non-rank-0 nodes"

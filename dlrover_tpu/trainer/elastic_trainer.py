@@ -35,13 +35,13 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dlrover_tpu import obs
+from dlrover_tpu.agent.monitor import TrainingMonitor
 from dlrover_tpu.common.log import get_logger
 from dlrover_tpu.obs.profiling import (
     MFU_ENV,
     CompileTracker,
     MfuMeter,
     StepPhaseProfiler,
-    step_flops,
 )
 from dlrover_tpu.parallel.mesh import under_mesh
 from dlrover_tpu.parallel.sharding import prune_specs_to_mesh
@@ -432,19 +432,21 @@ class ElasticTrainer:
                     "shard_microbatches() (ideally via "
                     "data.prefetch.make_input_pipeline)"
                 )
-        if (
-            self._last_step_t is None
-            and self.mfu_meter.flops_per_step is None
-            and os.getenv(MFU_ENV, "1") != "0"
-        ):
-            # Compile boundary: price the step with XLA's cost model
-            # BEFORE dispatch (donation deletes the input buffers
-            # after it). Trace+lower only — never a second compile.
-            self.mfu_meter.set_flops(
-                step_flops(
-                    self._compiled, params, opt_state, tokens, targets
+        if self._last_step_t is None:
+            TrainingMonitor.mark_phase("first_dispatch")
+            if (
+                self.mfu_meter.flops_per_step is None
+                and os.getenv(MFU_ENV, "1") != "0"
+            ):
+                # Compile boundary: price the step with XLA's cost
+                # model BEFORE dispatch (donation deletes the input
+                # buffers after it). Trace+lower only — never a second
+                # compile.
+                self.mfu_meter.set_flops(
+                    self._compile_tracker.price(
+                        params, opt_state, tokens, targets
+                    )
                 )
-            )
         args = (params, opt_state, tokens, targets)
         t0 = time.perf_counter()
         with obs.span(

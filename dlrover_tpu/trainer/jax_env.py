@@ -16,6 +16,7 @@ from typing import Optional
 from dlrover_tpu import obs
 from dlrover_tpu.common.constants import NodeEnv
 from dlrover_tpu.common.log import get_logger
+from dlrover_tpu.obs.profiling import install_compile_listeners
 
 logger = get_logger("jax_env")
 
@@ -88,6 +89,10 @@ def setup_distributed() -> None:
                 "trainer", rank=int(os.getenv(NodeEnv.NODE_RANK, "-1"))
             )
         enable_compile_cache()
+        # JAX is imported and has traced nothing yet: from here every
+        # trace, lowering, compile and cache load is on the start-up
+        # timeline (obs.profiling.startup_timeline).
+        install_compile_listeners()
         if n > 1:
             import jax
 

@@ -84,8 +84,11 @@ def main(argv=None) -> int:
         StorageType,
     )
 
-    # Phase marks (no-ops unless DLROVER_TPU_PHASES_FILE is set):
-    # chaos drills split recovery time into these segments.
+    # Phase marks (a dict store each, kept in the process; written to
+    # DLROVER_TPU_PHASES_FILE too where that is set): chaos drills
+    # split recovery time into these segments, and
+    # obs.profiling.startup_timeline() returns them with JAX's own
+    # trace / lower / compile records: what this start was made of.
     TrainingMonitor.mark_phase("proc_start")
     jax_env.setup_distributed()
     TrainingMonitor.mark_phase("dist_ready")

@@ -260,14 +260,18 @@ def test_proc_start_keeps_the_agents_marks_and_drops_the_rest(tmp_path):
     assert set(_marks(path)) == {
         "proc_start", "built", "agent.exit_seen", "agent.spawned"}
     TrainingMonitor.mark_phase("proc_start", path)
+    # (What a writer empties stands one generation under ``prev.``.)
     assert set(_marks(path)) == {
-        "proc_start", "agent.exit_seen", "agent.spawned"}
+        "proc_start", "agent.exit_seen", "agent.spawned",
+        "prev.proc_start", "prev.built"}
     TrainingMonitor.mark_phase("dist_ready", path)
     # The next failure: the agent's marks of the last relaunch go, the
     # dead trainer's stay until its successor starts.
     TrainingMonitor.mark_phase("agent.exit_seen", path)
     assert set(_marks(path)) == {
-        "proc_start", "dist_ready", "agent.exit_seen"}
+        "proc_start", "dist_ready", "agent.exit_seen",
+        "prev.proc_start", "prev.built",
+        "prev.agent.exit_seen", "prev.agent.spawned"}
 
 
 def test_the_agents_marks_are_not_mirrored_as_trainer_events(tracer):

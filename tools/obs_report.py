@@ -250,6 +250,20 @@ def perf_summary(events) -> str:
             for fn, (c, t) in sorted(by_fn.items())
         )
         lines.append(f"  compiles: {parts}")
+        # A recompile says what JAX did in it (obs.profiling's
+        # listeners): a retrace, a cold compile or a cache load.
+        for e in compiles:
+            if int(e.get("total", 1)) > 1 and "trace_s" in e:
+                stages = " + ".join(
+                    f"{stage} {float(e.get(stage + '_s', 0.0)):.2f}"
+                    for stage in ("trace", "lower", "backend_compile",
+                                  "cache_load")
+                )
+                lines.append(
+                    f"  {e.get('fn', '?')} #{e['total']}: "
+                    f"{float(e.get('dur_s', 0.0)):.2f}s = {stages}"
+                    + (" (cache hit)" if e.get("cache_hit") else "")
+                )
     for e in captures:
         lines.append(
             f"  profile capture: {e.get('steps')} steps"
