@@ -315,16 +315,40 @@ def param_logical_axes(cfg: KimiLinearConfig) -> Params:
 # ---------------------------------------------------------------------------
 
 
+def _per_head(heads: int, d: int):
+    """A head's sum over its ``d`` channels of a float32
+    ``[..., heads * d]`` array whose heads lie side by side, as
+    ``[..., heads]``, and a head's value back on its channels: products
+    with the heads' constant 0/1 membership ``[heads * d, heads]``,
+    float32 in fact. Not a reduction over a ``[..., heads, d]`` view:
+    on the chip no 4-D layout is a bitcast of the 3-D tiling the
+    convolutions write and the rule's kernels read, so the view is a
+    copy of the whole array, and so is its gradient's (PERF.md
+    section 6, PR 56)."""
+    member = (
+        jnp.arange(heads * d)[:, None] // d == jnp.arange(heads)
+    ).astype(jnp.float32)
+    product = functools.partial(
+        jnp.einsum, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
+    return (
+        lambda x: product("...i,ih->...h", x, member),
+        lambda s: product("...h,ih->...i", s, member),
+    )
+
+
 def kda_mixer(u, lp, cfg: KimiLinearConfig):
     """The KDA mixer on the normed input ``u`` [B, T, E], without the
-    residual."""
+    residual. From the convolutions to ``w_o`` everything stays
+    ``[B, T, inner]``, a head's channels side by side."""
     from dlrover_tpu.accelerate.remat import KDA_IN, keep
+    from dlrover_tpu.ops import kda as rule
     from dlrover_tpu.ops.causal_conv import conv_silu
-    from dlrover_tpu.ops.kda import kda
 
-    bsz, t, _ = u.shape
     heads, d, inner = cfg.n_head, cfg.kda_head_dim, cfg.kda_inner
     f32 = jnp.float32
+    head_sum, on_channels = _per_head(heads, d)
     # Named for remat="full" (accelerate/remat.py KEPT) with the
     # rule's output and chunk states (ops/kda.py): the convolutions,
     # the gates and the norm are recomputed.
@@ -336,34 +360,30 @@ def kda_mixer(u, lp, cfg: KimiLinearConfig):
             conv_silu(
                 proj, w[:, i * inner: (i + 1) * inner], no_bias,
                 start=i * inner,
-            ).reshape(bsz, t, heads, d)
+            )
             for i in range(3)
         )
 
         def unit(x, scale=1.0):
             x = x.astype(f32)
-            norm = jax.lax.rsqrt(
-                jnp.sum(jnp.square(x), axis=-1, keepdims=True) + L2_EPS
-            )
-            return (x * (norm * scale)).astype(u.dtype)
+            norm = jax.lax.rsqrt(head_sum(jnp.square(x)) + L2_EPS)
+            return (x * on_channels(norm * scale)).astype(u.dtype)
 
         q, k = unit(q, d ** -0.5), unit(k)
     with jax.named_scope("kda_gate"):
         step = (u @ lp["w_fa"]) @ lp["w_fb"]
         step = jax.nn.softplus(step.astype(f32) + lp["dt_bias"])
-        rate = -jnp.exp(lp["A_log"])[:, None]
-        g = rate * step.reshape(bsz, t, heads, d)
+        g = jnp.repeat(-jnp.exp(lp["A_log"]), d) * step
         beta = jax.nn.sigmoid((u @ lp["w_b"]).astype(f32))
     with jax.named_scope("kda_scan"):
-        o = kda(q, k, v, g, beta)
+        o = rule.kda_wide(q, k, v, g, beta)
     with jax.named_scope("kda_gate"):
         o = o.astype(f32)
-        o = o * jax.lax.rsqrt(
-            jnp.mean(jnp.square(o), axis=-1, keepdims=True) + cfg.rms_eps
+        o = o * on_channels(
+            jax.lax.rsqrt(head_sum(jnp.square(o)) / d + cfg.rms_eps)
         )
         gate = jax.nn.sigmoid(((u @ lp["w_ga"]) @ lp["w_gb"]).astype(f32))
-        y = o * lp["o_norm"] * gate.reshape(bsz, t, heads, d)
-        y = y.astype(u.dtype).reshape(bsz, t, inner)
+        y = (o * jnp.tile(lp["o_norm"], heads) * gate).astype(u.dtype)
     return y @ lp["w_o"]
 
 

@@ -77,7 +77,11 @@ interpreted.
 
 The plain form, ``jax.numpy`` under XLA, is the path for every other
 shape (:func:`kda` chooses by the shapes alone; event ``kda.scan``
-says ``kernel``) and what the benchmark's controls take apart. There
+says ``kernel``) and what the benchmark's controls take apart.
+:func:`kda_wide` is the same rule for a caller whose operands stay
+``[B, T, H*d]`` (models/kimi_linear.py): they reach the kernels as
+they lie and ``kda.scan`` says ``wide``; :func:`kda` views its
+``[B, T, H, d]`` operands so and views the output back. There
 what is sequential is the state alone (``_chunk_states``): a
 ``lax.scan`` over the chunks of two products a step, with a backward
 rule of its own, the same two products a step on the states'
@@ -713,16 +717,14 @@ def _fwd_kernel(
 
 
 def _layout(q, k, v, g, beta):
-    """The kernels' operands: q, k, v, g as ``[B, T, H*d]`` views,
-    beta a head a row of its chunk, ``[B, N, H, C]``."""
-    b, t, h, dk = q.shape
+    """The kernels' operands: q, k, v, g ``[B, T, H*d]`` as they
+    come, beta a head a row of its chunk, ``[B, N, H, C]``."""
+    b, t, h = beta.shape
     n = t // CHUNK
-    wide = lambda x: x.reshape(b, t, -1)
     rows = beta.astype(jnp.float32).reshape(b, n, CHUNK, h)
-    return dict(b=b, t=t, h=h, dk=dk, dv=v.shape[-1], n=n,
-                hb=heads_per_step(h)), (
-        wide(q), wide(k), wide(v), wide(g.astype(jnp.float32)),
-        jnp.transpose(rows, (0, 1, 3, 2)),
+    return dict(b=b, t=t, h=h, dk=q.shape[-1] // h, dv=v.shape[-1] // h,
+                n=n, hb=heads_per_step(h)), (
+        q, k, v, g.astype(jnp.float32), jnp.transpose(rows, (0, 1, 3, 2)),
     )
 
 
@@ -752,7 +754,7 @@ def _params():
 
 
 def _forward(q, k, v, g, beta, interpret):
-    """(o [B, T, H, dv], states [B, N, H, dv, dk] f32: the state every
+    """(o [B, T, H*dv], states [B, N, H, dv, dk] f32: the state every
     chunk starts from, transposed)."""
     dims, operands = _layout(q, k, v, g, beta)
     in_specs, _, values, _, state = _specs(dims, lambda n: n)
@@ -773,7 +775,7 @@ def _forward(q, k, v, g, beta, interpret):
         interpret=interpret,
         name="kda_fwd",
     )(*operands)
-    return o.reshape(b, t, h, dv), states
+    return o, states
 
 
 def _within_bwd(c, d_kk, d_qk, dkc_scr):
@@ -939,12 +941,9 @@ def _backward(q, k, v, g, beta, states, d_o, interpret):
         compiler_params=_params(),
         interpret=interpret,
         name="kda_bwd",
-    )(*operands, states, d_o.reshape(b, t, h * dv))
+    )(*operands, states, d_o)
     dbeta = jnp.transpose(dbeta, (0, 1, 3, 2)).reshape(b, t, h)
-    return (
-        dq.reshape(q.shape), d_k.reshape(k.shape), d_v.reshape(v.shape),
-        dg.reshape(g.shape).astype(g.dtype), dbeta.astype(beta.dtype),
-    )
+    return dq, d_k, d_v, dg.astype(g.dtype), dbeta.astype(beta.dtype)
 
 
 def _kept(o, states):
@@ -980,6 +979,43 @@ _kernels.defvjp(_kernels_fwd, _kernels_bwd)
 _rule = jax.jit(_kernels, static_argnums=(5,))
 
 
+def _sequences(q, k, v, g, beta, chunk, sub_block, wide):
+    """The rule on ``[B, T, H*d]`` operands, beta ``[B, T, H]`` ->
+    o ``[B, T, H*d_v]``; ``wide`` says the caller holds them so."""
+    b, t, h = beta.shape
+    dk, dv = q.shape[-1] // h, v.shape[-1] // h
+    chunk = min(chunk, -(-t // sub_block) * sub_block)
+    sub = min(sub_block, chunk)
+    pad = -t % chunk
+    # The kernels are written for one tile: chunks of 64 rows in
+    # sub-blocks of 16, head sizes whole lanes.
+    kernel = (chunk, sub) == (CHUNK, SUB_BLOCK) and not (dk % 128 or dv % 128)
+    from dlrover_tpu.accelerate.remat import KDA_O, KDA_STATES
+
+    engaged = dict(
+        heads_per_step=heads_per_step(h), kept=(KDA_O, KDA_STATES)
+    ) if kernel else {}
+    obs.event(
+        "kda.scan", chunk=chunk, chunks=(t + pad) // chunk,
+        heads=h, sub_block=sub, state_dtype="float32",
+        states_kept=True, per_device=bool(batch_axes(b)[0]),
+        kernel=kernel, wide=wide, **engaged,
+    )
+    interpret = use_interpret()
+
+    def call(q, k, v, g, beta):
+        if pad:
+            rows = lambda x: jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
+            q, k, v, g, beta = map(rows, (q, k, v, g, beta))
+        if kernel:
+            return _rule(q, k, v, g, beta, interpret)[:, :t]
+        heads = lambda x: x.reshape(x.shape[:2] + (h, -1))
+        o = _chunked(heads(q), heads(k), heads(v), heads(g), beta, chunk, sub)
+        return o.reshape(o.shape[:2] + (h * dv,))[:, :t]
+
+    return per_device(call, q, k, v, g, beta, split=(True,) * 5)
+
+
 def kda(q, k, v, g, beta, chunk: int = CHUNK, sub_block: int = SUB_BLOCK):
     """The rule over whole sequences from a zero state.
 
@@ -988,36 +1024,33 @@ def kda(q, k, v, g, beta, chunk: int = CHUNK, sub_block: int = SUB_BLOCK):
     most 0), beta [B, T, H] in (0, 1). Returns o [B, T, H, d_v] in
     q's dtype. A sequence that is not whole chunks is padded with tokens
     that neither decay nor write. Differentiable in every argument."""
-    t = q.shape[1]
-    chunk = min(chunk, -(-t // sub_block) * sub_block)
-    sub = min(sub_block, chunk)
-    pad = -t % chunk
-    # The kernels are written for one tile: chunks of 64 rows in
-    # sub-blocks of 16, head sizes whole lanes.
-    kernel = (chunk, sub) == (CHUNK, SUB_BLOCK) and not (
-        q.shape[-1] % 128 or v.shape[-1] % 128
+    b, t, h, _ = q.shape
+    wide = lambda x: x.reshape(b, t, -1)
+    o = _sequences(
+        wide(q), wide(k), wide(v), wide(g), beta, chunk, sub_block,
+        wide=False,
     )
-    from dlrover_tpu.accelerate.remat import KDA_O, KDA_STATES
+    return o.reshape(b, t, h, -1)
 
-    engaged = dict(
-        heads_per_step=heads_per_step(q.shape[2]), kept=(KDA_O, KDA_STATES)
-    ) if kernel else {}
-    obs.event(
-        "kda.scan", chunk=chunk, chunks=(t + pad) // chunk,
-        heads=q.shape[2], sub_block=sub, state_dtype="float32",
-        states_kept=True, per_device=bool(batch_axes(q.shape[0])[0]),
-        kernel=kernel, **engaged,
-    )
-    interpret = use_interpret()
 
-    def call(q, k, v, g, beta):
-        if pad:
-            rows = lambda x: jnp.pad(
-                x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2)
-            )
-            q, k, v, g, beta = map(rows, (q, k, v, g, beta))
-        if kernel:
-            return _rule(q, k, v, g, beta, interpret)[:, :t]
-        return _chunked(q, k, v, g, beta, chunk, sub)[:, :t]
+_KDA = kda
 
-    return per_device(call, q, k, v, g, beta, split=(True,) * 5)
+
+def kda_wide(q, k, v, g, beta, chunk: int = CHUNK,
+             sub_block: int = SUB_BLOCK):
+    """:func:`kda` on operands that stay as a convolution writes them
+    and the kernels read them: q, k, g [B, T, H*d_k], v [B, T, H*d_v],
+    a head's channels side by side, the head count beta's [B, T, H];
+    o [B, T, H*d_v]. Nothing is reshaped at the kernels' edge: on the
+    chip no 4-D layout is a bitcast of the 3-D tiling, and a view is a
+    copy of the whole array (PERF.md section 6, PR 56).
+
+    ``kda`` is where the benchmark's controls put a broken rule while
+    a loss is traced (benchmark/controls/kimi_linear.py): whatever
+    stands in its place is called, on 4-D views."""
+    if kda is not _KDA:
+        b, t, h = beta.shape
+        heads = lambda x: x.reshape(b, t, h, -1)
+        o = kda(heads(q), heads(k), heads(v), heads(g), beta, chunk, sub_block)
+        return o.reshape(b, t, -1)
+    return _sequences(q, k, v, g, beta, chunk, sub_block, wide=True)

@@ -4,8 +4,10 @@ the CPU at the tile they are written for (head size 128, chunks of
 say, against the recurrence a token at a time and its autodiff, for
 the output and all five gradients, at tests/test_kimi_linear.py's
 tolerances; the two hazards at the kernels' shape; padding, a batch
-of sequences of one chunk; the plain form on the same operands; and
-the head size that stays on the plain form."""
+of sequences of one chunk; the plain form on the same operands; the
+head size that stays on the plain form; and ``kda_wide``, the entry
+for operands that stay ``[B, T, H*d]``, against ``kda`` on both
+paths."""
 
 import functools
 
@@ -36,12 +38,13 @@ def _operands(seed, dtype, b=1, t=128, heads=1, d=D, weakest=1e-3,
     return tuple(x.astype(dtype) for x in (q, k, v)) + (g, beta), w
 
 
-def _scans(operands):
-    """The ``kda.scan`` events of one trace of ``kda`` on ``operands``."""
+def _scans(operands, rule=None):
+    """The ``kda.scan`` events of one trace of ``kda`` (or ``rule``)
+    on ``operands``."""
     tracer = obs.configure_tracer()
     try:
         # A function of its own a call: ``eval_shape`` keeps traces.
-        jax.eval_shape(lambda *a: kda.kda(*a), *operands)
+        jax.eval_shape(lambda *a: (rule or kda.kda)(*a), *operands)
         return [e for e in tracer.events() if e["name"] == "kda.scan"]
     finally:
         obs.disable_tracer()
@@ -70,6 +73,14 @@ def _both(rule, operands, w):
 
 def _plain(*operands):
     return kda._chunked(*operands, kda.CHUNK, kda.SUB_BLOCK)
+
+
+def _wide(*operands):
+    """``kda_wide`` on the 4-D operands' ``[B, T, H*d]`` views."""
+    *wide, beta = operands
+    b, t, h = beta.shape
+    o = kda.kda_wide(*(x.reshape(b, t, -1) for x in wide), beta)
+    return o.reshape(b, t, h, -1)
 
 
 def _assert_close(got, want, tol):
@@ -146,16 +157,57 @@ def test_a_run_of_one_token_stays_finite_in_the_kernels(beta):
         np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-4)
 
 
-def test_kernels_and_plain_form_agree():
-    operands, w = _operands(3, jnp.float32, t=128, heads=1)
-    _assert_close(
-        _both(kda.kda, operands, w), _both(_plain, operands, w), 1e-5
-    )
+@pytest.mark.parametrize("other,heads,tol", [
+    (_plain, 1, 1e-5),
+    # The same two kernels on the same operands: the same numbers.
+    (_wide, 2, 0.0),
+])
+def test_kernels_and_another_form_agree(other, heads, tol):
+    dtype = jnp.float32 if other is _plain else jnp.bfloat16
+    operands, w = _operands(3, dtype, t=128 * heads, heads=heads)
+    got, want = _both(kda.kda, operands, w), _both(other, operands, w)
+    if tol:
+        return _assert_close(got, want, tol)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(
+            np.asarray(a, np.float32), np.asarray(b, np.float32)
+        )
 
 
-def test_head_size_16_takes_the_plain_form():
-    """tests/test_kimi_linear.py holds that path's numbers."""
-    operands, _ = _operands(4, jnp.float32, t=128, heads=2, d=16)
-    (scan,) = _scans(operands)
-    assert scan["kernel"] is False
+@pytest.mark.parametrize("rule,wide", [(kda.kda, False), (_wide, True)])
+def test_the_event_says_whether_the_operands_came_wide(rule, wide):
+    operands, _ = _operands(3, jnp.bfloat16, t=256, heads=2)
+    (scan,) = _scans(operands, rule)
+    assert scan["kernel"] is True and scan["wide"] is wide
+    assert scan["heads"] == 2 and scan["chunks"] == 4
+
+
+@pytest.mark.parametrize("rule", [kda.kda, _wide])
+def test_head_size_16_takes_the_plain_form(rule):
+    """tests/test_kimi_linear.py holds that path's numbers; the wide
+    entry reshapes there and gives ``kda``'s, padding and all."""
+    operands, w = _operands(4, jnp.float32, t=72, heads=2, d=16)
+    (scan,) = _scans(operands, rule)
+    assert scan["kernel"] is False and scan["wide"] is (rule is _wide)
     assert "heads_per_step" not in scan and "kept" not in scan
+    if rule is _wide:
+        _assert_close(
+            _both(_wide, operands, w), _both(kda.kda, operands, w), 1e-6
+        )
+
+
+def test_a_rule_in_kda_s_place_is_the_wide_entry_s_too(monkeypatch):
+    """benchmark/controls/kimi_linear.py puts a broken 4-D rule in
+    ``kda``'s place while a loss is traced: the mixer's entry calls
+    it, on 4-D views, whatever the head size."""
+    seen = []
+
+    def broken(q, k, v, g, beta, chunk, sub_block):
+        seen.append((q.shape, k.shape, v.shape, g.shape, beta.shape))
+        return jnp.zeros_like(v)
+
+    monkeypatch.setattr(kda, "kda", broken)
+    operands, _ = _operands(5, jnp.bfloat16, t=64, heads=2)
+    shape = jax.eval_shape(_wide, *operands)
+    assert seen == [((1, 64, 2, D),) * 4 + ((1, 64, 2),)]
+    assert shape.shape == (1, 64, 2, D)

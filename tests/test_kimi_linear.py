@@ -340,6 +340,76 @@ def test_the_model_is_the_reference(toy):
     assert float(jnp.max(jnp.abs(logits - ref))) < 0.1 * float(jnp.std(ref))
 
 
+@pytest.mark.parametrize("heads,d", [(3, 16), (4, 128)])
+def test_per_head_products_are_the_reductions_over_a_4d_view(heads, d):
+    """``unit()`` and the output's RMS norm as the mixer forms them,
+    a head's sum and its broadcast back as products with the heads'
+    0/1 membership on ``[B, T, H*d]``, against the reductions over
+    ``[B, T, H, d]``'s last axis: values and gradients to float32
+    rounding."""
+    head_sum, on_channels = model._per_head(heads, d)
+    x, w = jax.random.normal(jax.random.PRNGKey(7), (2, 2, 24, heads * d))
+    x = x * jnp.exp(jax.random.normal(jax.random.PRNGKey(8), x.shape))
+
+    def wide(x, eps, mean):
+        total = head_sum(jnp.square(x)) / (d if mean else 1)
+        return x * on_channels(jax.lax.rsqrt(total + eps))
+
+    def viewed(x, eps, mean):
+        x = x.reshape(x.shape[:-1] + (heads, d))
+        total = jnp.sum(jnp.square(x), axis=-1, keepdims=True)
+        x = x * jax.lax.rsqrt(total / (d if mean else 1) + eps)
+        return x.reshape(x.shape[:-2] + (heads * d,))
+
+    for eps, mean in ((model.L2_EPS, False), (1e-5, True)):
+        for fn in (
+            lambda f, x: f(x, eps, mean),
+            jax.grad(lambda f, x: jnp.sum(f(x, eps, mean) * w), argnums=1),
+        ):
+            np.testing.assert_allclose(
+                fn(wide, x), fn(viewed, x), rtol=2e-6, atol=2e-6
+            )
+    np.testing.assert_array_equal(
+        on_channels(jnp.arange(heads, dtype=jnp.float32)),
+        jnp.repeat(jnp.arange(heads, dtype=jnp.float32), d),
+    )
+
+
+def test_the_mixer_is_the_reference_s(toy):
+    """``kda_mixer`` alone, float32, on the toy's first layer: its
+    ``[B, T, H*d]`` columns against the reference's 4-D heads and
+    token-by-token rule, output and the gradient of every leaf."""
+    built, params, _ = toy
+    cfg = dataclasses.replace(built["cfg"], dtype=jnp.float32)
+    f32 = lambda tree: jax.tree.map(lambda a: a.astype(jnp.float32), tree)
+    lp = f32(params["layers"][cfg.layer_names[0]])
+    u = jax.random.normal(jax.random.PRNGKey(5), (2, 96, cfg.n_embd))
+    w = jax.random.normal(jax.random.PRNGKey(6), u.shape)
+
+    def both(mixer, last):
+        def loss(u, lp):
+            y = mixer(u, lp, last)
+            return jnp.sum(y * w), y
+
+        (_, y), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True
+        ))(u, lp)
+        return y, grads
+
+    y, grads = both(model.kda_mixer, cfg)
+    y_ref, grads_ref = both(reference.kda_mixer, _toy_config())
+    scale = float(jnp.max(jnp.abs(y_ref)))
+    assert float(jnp.max(jnp.abs(y - y_ref))) < 2e-5 * scale
+    used = set(jax.tree.leaves(jax.tree.map(
+        lambda g: bool(jnp.any(g != 0)), grads_ref[1]
+    )))
+    assert used == {True, False}  # the MLP's leaves are not the mixer's
+    for got, want in zip(jax.tree.leaves(grads), jax.tree.leaves(grads_ref)):
+        assert float(jnp.max(jnp.abs(got - want))) <= 1e-4 * max(
+            float(jnp.max(jnp.abs(want))), 1e-30
+        )
+
+
 def test_every_parameter_but_the_bias_gets_a_gradient(toy):
     built, params, batch = toy
     grads = jax.jit(jax.grad(built["loss"]))(params, *batch)
@@ -472,6 +542,9 @@ def test_events_say_what_was_traced(toy):
         assert scan["chunk"] == 64 and scan["sub_block"] == 16
         assert scan["heads"] == cfg.n_head
         assert scan["state_dtype"] == "float32" and scan["states_kept"]
+        # The mixer's columns go over as they lie; head size 16 is the
+        # plain form's.
+        assert scan["wide"] is True and scan["kernel"] is False
         attn = _events(tracer, "mla.attn")[0]
         assert (attn["d_qk"], attn["d_v"]) == (24, 16)
         held = _events(tracer, "moe.held")[0]

@@ -391,3 +391,25 @@ class TestAGDTraceSelection:
         agd = importlib.import_module("agd_convergence")
         runs = {1e-3: [(5, float("nan"))]}
         assert agd.best_finite_trace(runs)[0] == 1e-3
+
+
+def test_the_changelog_keeps_every_entry():
+    """A PR adds a line to CHANGES.md and removes none (ROADMAP D20:
+    PR 55's commit cut twelve). Every line is one PR's entry, and the
+    PRs that had one when PR 56 restored them still do."""
+    import re
+
+    root = os.path.dirname(TOOLS)
+    with open(os.path.join(root, "CHANGES.md")) as f:
+        lines = f.read().splitlines()
+    numbers = [re.match(r"- PR (\d+) \(\w+\)", line) for line in lines]
+    assert all(numbers), [
+        line[:60] for line, n in zip(lines, numbers) if not n
+    ]
+    had = {int(n.group(1)) for n in numbers}
+    assert had >= {
+        1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 12, 13, 14, 15, 16, 19, 20, 21,
+        23, 24, 25, 26, 27, 28, 29, 33, 34, 35, 37, 39, 42, 44, 45, 47,
+        48, 51, 52, 53, 54, 55, 56,
+    }
+    assert len(lines) >= 42

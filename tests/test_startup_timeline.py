@@ -11,6 +11,7 @@ import json
 import os
 import subprocess
 import sys
+import time
 import types
 
 import jax
@@ -236,10 +237,16 @@ def test_only_the_outermost_trace_is_a_record_and_a_span(tracer):
         return jnp.sum(y)
 
     x = jnp.ones(4)
-    n = len(profiling.startup_timeline()["compile"])
+    # The records are a ring of the newest 4,096: once this process
+    # has made that many, a position taken before is past its end.
+    # JAX stamps a stage's start on ``time.time()``.
+    started = time.time()
     n_events = len(tracer.events())
     jax.jit(jax.value_and_grad(toy_scanned_fn)).lower(x)
-    new = profiling.startup_timeline()["compile"][n:]
+    new = [
+        r for r in profiling.startup_timeline()["compile"]
+        if r["t0"] >= started
+    ]
     assert [(r["stage"], r["fn"]) for r in new if r["stage"] == "trace"] == [
         ("trace", "toy_scanned_fn")
     ]

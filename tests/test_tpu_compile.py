@@ -15,6 +15,7 @@ the worker that is handed this file does.
 
 import dataclasses
 import functools
+import math
 import re
 import sys
 
@@ -332,17 +333,23 @@ def _step_gb(compiled):
     ) / 1e9
 
 
+def _by_computation(text):
+    """(computation's name, line) for every line of an HLO text."""
+    current = ""
+    for line in text.splitlines():
+        if line.endswith("{") and line[:1] in "%E":
+            current = line.split()[1 if line.startswith("ENTRY") else 0]
+        yield current, line
+
+
 def _computations_calling(compiled, kernel):
     """Names of the HLO computations that hold a custom call of the
     Pallas kernel ``kernel`` (the forward layer scan's body, the
     backward scan's body, ...)."""
-    found, current = [], None
-    for line in compiled.as_text().splitlines():
-        if line.endswith("{") and line[:1] in "%E":
-            current = line.split()[1 if line.startswith("ENTRY") else 0]
-        elif "tpu_custom_call" in line and f"/{kernel}/" in line:
-            found.append(current)
-    return found
+    return [
+        current for current, line in _by_computation(compiled.as_text())
+        if "tpu_custom_call" in line and f"/{kernel}/" in line
+    ]
 
 
 def _assert_flash_forward_runs_once(compiled, times=1, in_line=0):
@@ -856,6 +863,26 @@ def test_kda_splits_itself_over_a_mesh(topo, compiled_kernels):
     assert "all-gather" not in text
 
 
+def _whole_array_passes(text, elements):
+    """(``copy`` instructions, fusions with no ``op_name``) whose
+    result has ``elements`` or more, among the instructions the chip
+    runs one by one: those of every computation that is not a
+    fusion's body."""
+    fused = set(re.findall(r"fusion\([^\n]*calls=%([\w.-]+)", text))
+    copies, unnamed = [], []
+    for current, line in _by_computation(text):
+        found = re.search(r"= \w+\[([0-9,]+)\]\S* (copy|fusion)\(", line)
+        if current.lstrip("%") in fused or found is None:
+            continue
+        if math.prod(map(int, found.group(1).split(","))) < elements:
+            continue
+        if found.group(2) == "copy":
+            copies.append(line)
+        elif "op_name=" not in line:
+            unnamed.append(line)
+    return copies, unnamed
+
+
 def _kimi_cell_cfg():
     model = kimi_linear
     return model.KimiLinearConfig(
@@ -891,6 +918,24 @@ def test_kimi_train_step_compiles_on_one_chip(topo, compiled_kernels):
     assert calls("kda_fwd") == 4 and calls("kda_bwd") == 4, (
         calls("kda_fwd"), calls("kda_bwd")
     )
+    # No relayout at the rule's edge: from its convolutions to ``w_o``
+    # a KDA mixer stays [B, T, H*d], what ``conv_silu`` writes and the
+    # rule's kernels read, and a head's sums are products with the
+    # heads' membership. No 4-D layout is a bitcast of that tiling, so
+    # every [B, T, H, d] view was a copy of the whole array: 56 ``copy``
+    # of a [T, inner] array's elements or more before PR 56 and 31
+    # fusions with no ``op_name`` that fed them. The six copies left
+    # are the latent layer's, by name; the four fusions a
+    # rematerialised KDA layer's ``y`` in bf16, named for nothing
+    # because their root is the out-projection's own bitcast.
+    copies, unnamed = _whole_array_passes(text, 8192 * 4096)
+    assert len(copies) == 6 and all("/attn/mla/" in c for c in copies), (
+        copies
+    )
+    assert len(unnamed) <= 4 and all(
+        "= bf16[8192,4096]{1,0" in f for f in unnamed
+    ), unnamed
+    assert not re.search(r"\[1024,8,32,128\]|f32\[1,8192,32,128\]", text)
     mem = compiled.memory_analysis()
     total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     print("kimi step bytes", total, mem)

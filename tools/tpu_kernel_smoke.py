@@ -582,6 +582,11 @@ def kda_checks():
                     (_, got), (_, want) = (
                         grads(lambda *a: kda.kda(*a)), grads(kda.recurrence)
                     )
+                    # The mixer's entry, on the [B, T, H*d] views.
+                    flat = lambda x: x.reshape(1, t, -1)
+                    _, got_wide = grads(lambda *a: kda.kda_wide(
+                        *map(flat, a[:4]), a[4]
+                    ).reshape(v.shape))
                 scans = [
                     e for e in tracer.events() if e["name"] == "kda.scan"
                 ]
@@ -589,6 +594,11 @@ def kda_checks():
                 obs.disable_tracer()
             for a, b in zip(got, want):
                 _close_rel(a, b, tol)
+            # The same kernels on the same operands: the same numbers.
+            for a, b in zip(got_wide, got):
+                assert np.array_equal(
+                    np.asarray(a, np.float32), np.asarray(b, np.float32)
+                ), "kda_wide is not kda"
             # The kernels take whole lanes of a head: at the full shape
             # this check holds kda_fwd and kda_bwd, and must not pass
             # on the plain form.
