@@ -25,12 +25,12 @@ the last three in line, and reads 6.22 with the first three.
 ``gpt.backbone`` of ``models/bert.py``) and of ``models/llama.py``
 (and through ``llama.backbone_with_aux`` of ``models/glm.py``); all
 four adapt through the one thing the code sees, the stack's length.
-``models/ouro.py`` and ``models/granite_hybrid.py`` keep their own
-scans and are separate paths by file: Ouro's stack runs ``ut_steps``
-times a step, so every layer in line would be that many copies in an
-executable that already compiles for 18 s (all of them in line was
-worth +2.25%); Granite's unit is a period of two kinds of layer, in a
-step at 15.59 of the 16.91 GB a program gets.
+``models/ouro.py``, ``models/granite_hybrid.py`` and ``models/mellum.py``
+keep their own scans, separate paths by file: Ouro's stack runs
+``ut_steps`` times a step, so every layer in line would be that many
+copies in an executable that already compiles for 18 s (all in line:
++2.25%); Granite's and Mellum's unit is a period of two kinds of layer
+(Granite's in a step at 15.59 of the 16.91 GB a program gets).
 """
 
 from __future__ import annotations

@@ -425,7 +425,7 @@ def attention_half(h, lp, cfg: LlamaConfig, attn_fn, cos, sin):
         # per block on-device.
         k = jnp.repeat(k, cfg.q_per_kv, axis=2)
         v = jnp.repeat(v, cfg.q_per_kv, axis=2)
-    att = attn_fn(q, k, v).reshape(B, T, E)
+    att = attn_fn(q, k, v).reshape(B, T, H * D)
     return att @ lp["wo"]
 
 

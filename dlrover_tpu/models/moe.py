@@ -834,10 +834,10 @@ def _held_moe(params, flat, logits, cfg: MoEConfig):
     _, n_here = batch_axes(flat.shape[0])
     cap = rows_cap(n_here, cfg)
     obs.event(
-        "moe.held", router_experts=cfg.n_experts,
+        "moe.held", router_experts=cfg.n_experts, scoring=cfg.scoring,
         first_expert=cfg.first_expert, held=held, top_k=cfg.top_k,
-        scoring=cfg.scoring, rows_cap=cap, tokens=n_here,
-        row_blocks=-(-n_here * cfg.top_k // cap),
+        rows_cap=cap, tokens=n_here, row_blocks=-(-n_here * cfg.top_k // cap),
+        cap_over_mean=cap * cfg.n_experts / (n_here * cfg.top_k * held),
     )
     with jax.named_scope("moe_route"):
         weights, experts = route(logits, params.get("router_bias"), cfg)

@@ -531,12 +531,16 @@ class CompileTracker:
 # "ut_loop" is a looped model's passes (models/ouro.py: the calls of
 # its one jitted pass in a row; its own is the norm that closes a pass
 # and the stacking of the passes' outputs), "exit_gate" its gate, exit
-# distribution and entropy.
+# distribution and entropy; "attn_window" and "attn_full" are the two
+# kinds of attention layer of a patterned stack (models/mellum.py),
+# beneath "attn", and "moe_balance" the load-balancing loss it adds
+# beside a held expert layer, beneath "mlp".
 SCOPES = frozenset((
     "accumulate", "layers", "embed", "attn", "mlp", "ssm", "head",
     "optimizer", "moe_route", "moe_experts", "moe_combine", "ssm_conv",
     "ssd", "ssm_norm", "ut_loop", "exit_gate", "kda", "kda_conv",
     "kda_scan", "kda_gate", "mla", "moe_routed", "moe_shared",
+    "attn_window", "attn_full", "moe_balance",
 ))
 _HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
 _HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')

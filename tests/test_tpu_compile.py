@@ -26,7 +26,9 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from dlrover_tpu.models import gpt, granite_hybrid, kimi_linear, llama, ouro
+from dlrover_tpu.models import (
+    gpt, granite_hybrid, kimi_linear, llama, mellum, ouro,
+)
 from dlrover_tpu.ops import causal_conv, grouped_matmul
 from dlrover_tpu.ops import kda as kda_ops
 from dlrover_tpu.ops import ssd as ssd_ops
@@ -881,6 +883,33 @@ def _whole_array_passes(text, elements):
         elif "op_name=" not in line:
             unnamed.append(line)
     return copies, unnamed
+
+
+def test_mellum_train_step_compiles_on_one_chip(topo, compiled_kernels):
+    """The program of the benchmark's ``mellum2-12b-a2.5b.steady``: one
+    period (three window-1024 layers, one full with YaRN) at published
+    widths, 32/4 heads of 128 on a hidden size of 2304, 16 of 64
+    experts held, a quarter of both tables, 1 x 8192 tokens, full
+    remat, as ONE program. It fits; the flash kernels are compiled for
+    both masks (a banded and a plain causal call of each), and the
+    forward runs once a layer: four calls, not eight."""
+    cfg = mellum.MellumConfig(
+        vocab_size=24576, layer_types=mellum.MellumConfig().period,
+        held=16, remat="full", use_flash_attention=True,
+    )
+    compiled = _elastic_trainer_step(mellum, cfg, topo)
+    _assert_fits_with_flash(compiled)
+    text = compiled.as_text()
+    calls = lambda name: len(re.findall(
+        rf'custom_call_target="tpu_custom_call"[^\n]*{name}', text
+    ))
+    assert calls("flash_attention_fwd") == 4, calls("flash_attention_fwd")
+    assert calls("flash_attention_bwd") == 4, calls("flash_attention_bwd")
+    assert "moe_gmm" in text and "moe_tgmm" in text
+    mem = compiled.memory_analysis()
+    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    print("mellum step bytes", total, mem)
+    assert total / 1e9 < 16.9, total
 
 
 def _kimi_cell_cfg():

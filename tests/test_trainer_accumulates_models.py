@@ -23,7 +23,9 @@ import optax
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from dlrover_tpu.models import gpt, granite_hybrid, kimi_linear, llama, ouro
+from dlrover_tpu.models import (
+    gpt, granite_hybrid, kimi_linear, llama, mellum, ouro,
+)
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.trainer.elastic_trainer import ElasticTrainer
 
@@ -37,7 +39,7 @@ _FLASH = dict(use_flash_attention=True, attn_blocks=(64, 64, 64, 64))
 # kernels, interpreted here: the flash kernel for the three dense
 # stacks; the expert layer's grouped products and Granite's chunked
 # scan with plain attention beside them, which keeps the file's
-# thirty-six compiles inside its time.
+# forty-two compiles inside its time.
 _LLAMA = llama.LlamaConfig(
     vocab_size=128, block_size=T, n_layer=2, n_head=4, n_kv_head=2,
     n_embd=32, intermediate=96, dtype=jnp.float32, remat=True,
@@ -71,6 +73,12 @@ FAMILIES = {
         kimi_linear.KimiLinearConfig.tiny(),
         mixers=(kimi_linear.KDA, kimi_linear.MLA),
         ffns=(kimi_linear.DENSE, kimi_linear.MOE), remat="full",
+    )),
+    # two scanned periods of a sliding and a full layer, each kind with
+    # its own rotation, 4 of 16 experts held and the router's loss
+    "mellum": (mellum, dataclasses.replace(
+        mellum.MellumConfig.tiny(),
+        layer_types=(mellum.SLIDING, mellum.FULL) * 2, remat="full",
     )),
 }
 MESHES = {"one": 1, "data4": 4}
