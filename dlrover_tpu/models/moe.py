@@ -38,7 +38,7 @@ chooses it, not an option:
   rest out: no exchange, and no code that stands in for the absent
   chips. Sorted and dropless like the first path, but only the held
   pairs' rows are gathered and brought back, and the products run
-  over a buffer of a few times their mean number, all of it whatever
+  over a buffer sized by the share (``rows_cap``), all of it whatever
   the load (``_held_experts`` says how the shapes stay static). The
   router of such a layer may score by sigmoid, choose with a bias
   that does not enter the weights, scale the weights, and the layer
@@ -274,20 +274,19 @@ def routing_stats(
 ) -> Dict[str, jax.Array]:
     """What a routing looks like, from the router logits [n, E] alone:
     the fullest expert's load over the mean load, the share of experts
-    that received nothing, the share of (token, choice) pairs the
-    sorted path drops, which is 0 by construction, and, of a layer
-    that holds a share of the experts (``cfg.held``), the pairs a
-    token sends to the experts held here (``top_k x held / n_experts``
-    when the load is even). ``cfg`` and ``bias`` give the layer's
-    scoring and its choice bias; without them the router is the
-    softmax one."""
-    n_experts = logits.shape[-1]
+    that received nothing, the share of (token, choice) pairs dropped
+    (0: the sorted path drops none) and, of a layer that holds a share
+    of the experts (``cfg.held``), the pairs a token sends to those
+    (``top_k x held / n_experts`` at even load) and the ``rows_cap``
+    blocks the held path then runs (at least 1). ``cfg`` and ``bias``
+    give the layer's scoring and choice bias; else a softmax router."""
+    n, n_experts = logits.shape
     if cfg is None:
         _, experts = top_k_route(jax.nn.softmax(logits, axis=-1), top_k, False)
-        first, held = 0, n_experts
+        first, held, cap = 0, n_experts, n * top_k
     else:
         _, experts = route(logits, bias, cfg)
-        first, held = cfg.first_expert, cfg.experts_here
+        first, held, cap = cfg.first_expert, cfg.experts_here, rows_cap(n, cfg)
     counts = expert_counts(experts, n_experts)
     here = jnp.sum(counts[first: first + held])
     return {
@@ -295,7 +294,8 @@ def routing_stats(
         "max_over_mean": jnp.max(counts) / jnp.mean(counts.astype(jnp.float32)),
         "empty_share": jnp.mean((counts == 0).astype(jnp.float32)),
         "dropped_share": jnp.zeros((), jnp.float32),
-        "held_pairs_per_token": here / logits.shape[0],
+        "held_pairs_per_token": here / n,
+        "held_row_blocks": jnp.maximum(1, -(-here // cap)),
     }
 
 
@@ -547,40 +547,40 @@ def _sorted_moe(params, flat, logits, cfg: MoEConfig):
 # Held path: a chip's share of the experts, sorted and dropless
 # ---------------------------------------------------------------------------
 
-# Rows of the held path's buffer, over the held pairs' mean number
-# ``tokens x top_k x held / n_experts``. Shapes are static and the
-# held rows are not: a token's choices can all be held, so the only
-# bound that never fails is ``tokens x top_k``. The layer is both: the
-# held pairs' rows, sorted by expert, are taken ``rows_cap`` at a time
-# by a ``lax.scan`` over the ``tokens x top_k / rows_cap`` blocks there
-# can be, and a block past the counted rows (but the first, which
-# always runs) is skipped by a ``lax.cond``: one block's program,
-# forward and backward, in the one step program. What a step pays
-# follows its load in blocks and in nothing finer: the common case is
-# the first block alone, and a layer whose held pairs pass the buffer
-# pays for one more buffer, not for every pair of the layer. Within a
-# block the grouped products walk the whole buffer (``_held_block``
-# gives the rows past the held pairs, zeros, to the last group), so a
-# layer up to its buffer costs the same whatever the router sent it:
-# 2.3 ms a layer at 8,192 rows on a v5e, 1.7 over what the mean load's
-# 2,048 rows would take, for a step whose time no longer follows the
-# router (with tiles past the count skipped a step read 637 ms to 647
-# by how many layers' routers had collapsed onto a held expert, and no
-# two sets of runs spread alike: PERF.md section 6, PR 53).
-# Four times the mean (8,192 rows of 65,536 pairs at the
-# benchmark's cell): the held pairs a layer at twelve seeds' initial
-# weights ran from 3 to 7,897 (a token stream is Zipfian, and a
-# frequent token sends all its copies the same way), and a buffer
-# twice as long cost every step 20 ms of buffer-sized passes (PERF.md
-# section 6, PR 53).
-ROWS_CAP_OVER_MEAN = 4
+# Rows of the held path's buffer. Shapes are static and the held rows
+# are not: a token's choices can all be held, so the only bound that
+# never fails is ``tokens x top_k``. The layer is both: the held
+# pairs' rows, sorted by expert, are taken ``rows_cap`` at a time by a
+# ``lax.scan`` over the ``tokens x top_k / rows_cap`` blocks there can
+# be, and a block past the counted rows (but the first, which always
+# runs) is skipped by a ``lax.cond``: one block's program, forward and
+# backward, in the one step program. What a step pays follows its load
+# in blocks and in nothing finer: the common case is the first block
+# alone, and a layer whose held pairs pass the buffer pays for one
+# more buffer, not for every pair of the layer. Within a block the
+# grouped products walk the whole buffer (``_held_block`` gives the
+# rows past the held pairs, zeros, to the last group), so a layer up
+# to its buffer costs the same whatever the router sent it, and a
+# step's time does not follow the router (with tiles past the count
+# skipped a step read 637 ms to 647 by how many layers' routers had
+# collapsed onto a held expert: PERF.md section 6, PR 53).
+# The rows are the tokens times h*, the number of a token's ``top_k``
+# choices that are held here in all but ``ROWS_CAP_TAIL`` of the draws
+# (``covered_choices``, at the end of this file, has the law and why a
+# load comes in whole tokens' worth): the same tail at every share
+# from one number, and no constant multiple of the mean load. 8 of 256
+# held, 8 a token: h* = 1, 8,192 rows for 8,192 tokens, 4 x the mean;
+# 16 of 64: h* = 4, 32,768 rows, 2 x the mean, two blocks of which the
+# second is behind the ``lax.cond`` (4 x the mean was every pair of
+# that layer: PERF.md section 6, PR 58); every expert: every pair.
+ROWS_CAP_TAIL = 1 / 40
 
 
 def rows_cap(n: int, cfg: MoEConfig) -> int:
-    """Rows of the held path's buffer for ``n`` tokens on a device: a
-    multiple of 16 (bf16's sublane tile), at most ``n x top_k``."""
-    mean = n * cfg.top_k * cfg.experts_here / cfg.n_experts
-    cap = -(-int(ROWS_CAP_OVER_MEAN * mean) // 16) * 16
+    """Rows of the held path's buffer for ``n`` tokens on a device:
+    ``n x h*`` (``covered_choices``) up to a multiple of 16 (bf16's
+    sublane tile), at most ``n x top_k``."""
+    cap = -(-n * covered_choices(cfg)[0] // 16) * 16
     return max(16, min(cap, n * cfg.top_k))
 
 
@@ -733,7 +733,7 @@ def _held_block(plan, flat, weights, wi, wo, wg, *, steps):
         # group, so the grouped products walk every tile of the buffer
         # whatever the load: a zero row gives a zero row, forward and
         # backward, and the block's time is the buffer's, not the
-        # count's (``ROWS_CAP_OVER_MEAN`` says why).
+        # count's (the comment above ``ROWS_CAP_TAIL`` says why).
         sizes = plan["group_sizes"]
         sizes = sizes.at[-1].add(plan["live"].shape[0] - jnp.sum(sizes))
         row_weight = _row_weights(weights, plan)
@@ -825,19 +825,19 @@ def _held_experts(flat, local, weights, *matrices, held, cap):
 
 
 def _held_moe(params, flat, logits, cfg: MoEConfig):
-    """flat [n, D] -> y [n, D] float32: the part of the layer's result
-    that the experts held here give."""
+    """flat [n, D] -> y [n, D] float32, the part the experts held here give."""
     from dlrover_tpu import obs
     from dlrover_tpu.ops.flash_attention import batch_axes, per_device
 
     held = cfg.experts_here
     _, n_here = batch_axes(flat.shape[0])
-    cap = rows_cap(n_here, cfg)
+    cap, (choices, tail) = rows_cap(n_here, cfg), covered_choices(cfg)
     obs.event(
         "moe.held", router_experts=cfg.n_experts, scoring=cfg.scoring,
         first_expert=cfg.first_expert, held=held, top_k=cfg.top_k,
         rows_cap=cap, tokens=n_here, row_blocks=-(-n_here * cfg.top_k // cap),
         cap_over_mean=cap * cfg.n_experts / (n_here * cfg.top_k * held),
+        covered_choices=choices, tail=tail,
     )
     with jax.named_scope("moe_route"):
         weights, experts = route(logits, params.get("router_bias"), cfg)
@@ -899,3 +899,40 @@ def moe_mlp(
         + cfg.z_loss_weight * metrics["z_loss"]
     )
     return y.reshape(B, T, D).astype(x.dtype), aux
+
+
+# ---------------------------------------------------------------------------
+# The held path's buffer: how many of a token's choices it has rows for
+# ---------------------------------------------------------------------------
+
+# What the records say about the held load is that it comes in whole
+# tokens' worth. A frequent token sends all its copies the same way
+# (the benchmark stream's most frequent token is 17 to 19% of a
+# sequence), and a router that no balancing step holds even sends
+# EVERY token the same way within five steps (PERF.md section 6, PR
+# 53), so a layer's held pairs are ``tokens x h``, h the number of the
+# ``top_k`` chosen experts that this chip holds. For a choice that
+# knows nothing of the share, h follows the hypergeometric law of
+# ``top_k`` drawn of ``n_experts`` with ``held`` marked, and the buffer
+# has rows for the smallest h >= 1 that all but ``ROWS_CAP_TAIL`` of
+# the draws stay within; a layer past it runs one more block. At 8 of
+# 256, 8 a token: P(h > 0) = 0.227, P(h > 1) = 0.0218, so h* = 1 (the
+# 8,192 rows PR 53's third session chose by measurement: at 4,096 a
+# collapsed layer paid a second block in every step). At 16 of 64:
+# P(h > 3) = 0.099, P(h > 4) = 0.0192, so h* = 4. At ``held ==
+# n_experts`` h is always ``top_k``. It stands here, below the layer,
+# because the lines above are on the grouped kernels' call stacks and
+# a compiled step's identity holds their numbers (ROADMAP D21).
+
+
+def covered_choices(cfg: MoEConfig) -> Tuple[int, float]:
+    """(h*, the tail it leaves): of a token's ``top_k`` choices, how
+    many held here the buffer has rows for, and the probability that
+    ``top_k`` experts drawn of ``n_experts`` hold more than that many
+    of this chip's ``experts_here``."""
+    from math import comb
+
+    E, H, k = cfg.n_experts, cfg.experts_here, cfg.top_k
+    p = [comb(H, h) * comb(E - H, k - h) / comb(E, k) for h in range(k + 1)]
+    tails = ((h, sum(p[h + 1:])) for h in range(1, k + 1))
+    return next((h, tail) for h, tail in tails if tail <= ROWS_CAP_TAIL)

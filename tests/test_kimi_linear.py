@@ -206,6 +206,19 @@ def test_the_bias_chooses_and_does_not_weigh():
     stats = moe.routing_stats(logits, 4, cfg, bias)
     here = np.isin(np.asarray(experts), np.arange(4, 8)).sum() / 64
     assert float(stats["held_pairs_per_token"]) == pytest.approx(here)
+    # Counted by hand: 64 tokens x 3 covered choices are one buffer,
+    # which these pairs stay within; with none held it still runs one.
+    assert moe.rows_cap(64, cfg) == 192 and 0 < here * 64 <= 192
+    assert int(stats["held_row_blocks"]) == 1
+    flat = jnp.zeros_like(bias)
+    none = moe.routing_stats(logits.at[:, 4:8].set(-1e9), 4, cfg, flat)
+    assert float(none["held_pairs_per_token"]) == 0.0
+    assert int(none["held_row_blocks"]) == 1
+    every = moe.routing_stats(logits.at[:, 4:8].set(1e9), 4, cfg, flat)
+    assert float(every["held_pairs_per_token"]) == 4.0
+    assert int(every["held_row_blocks"]) == 2 == -(-64 * 4 // 192)
+    # Without a configuration: every expert here, one buffer.
+    assert int(moe.routing_stats(logits, 4)["held_row_blocks"]) == 1
     # No gradient reaches the bias.
     grad = jax.grad(lambda b: jnp.sum(moe.route(logits, b, cfg)[0] ** 2))(bias)
     assert float(jnp.max(jnp.abs(grad))) == 0.0
@@ -223,28 +236,33 @@ _LAYER_CONFIG = {
 
 
 @pytest.mark.parametrize(
-    "first,held,over_mean",
-    [(4, 4, 4), (0, 16, 4), (8, 4, 0.25), (4, 4, 0.5)],
+    "first,held,rows",
+    [(4, 4, None), (0, 16, None), (8, 4, 16), (4, 4, 32)],
     ids=["a_share", "every_expert", "past_the_buffer", "two_buffers"],
 )
-def test_held_experts_are_the_reference_s(monkeypatch, first, held, over_mean):
+def test_held_experts_are_the_reference_s(monkeypatch, first, held, rows):
     """Forward and every gradient; ``past_the_buffer`` and
-    ``two_buffers`` shrink the buffer under the held pairs, so the
-    layer runs several blocks of sorted rows (and skips the rest of
-    the blocks there can be) and still drops nothing."""
-    monkeypatch.setattr(moe, "ROWS_CAP_OVER_MEAN", over_mean)
+    ``two_buffers`` shrink the buffer under the held pairs (a
+    ``rows_cap`` of their own in the rule's place: 16 and 32 rows for
+    a mean load of 48), so the layer runs several blocks of sorted
+    rows (and skips the rest of the blocks there can be) and still
+    drops nothing."""
+    if rows:
+        monkeypatch.setattr(moe, "rows_cap", lambda n, cfg: rows)
     cfg = _moe_cfg(first_expert=first, held=held)
     params = _moe_params(cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 24, 32))
     w = jax.random.normal(jax.random.PRNGKey(2), (2, 24, 32))
     logits = x.reshape(-1, 32) @ params["router"]
-    pairs = float(moe.routing_stats(logits, 4, cfg, params["router_bias"])[
-        "held_pairs_per_token"]) * 48
+    stats = moe.routing_stats(logits, 4, cfg, params["router_bias"])
+    pairs = round(float(stats["held_pairs_per_token"]) * 48)
     cap = moe.rows_cap(48, cfg)
-    assert (pairs > cap) == (over_mean < 1)
-    if over_mean < 1:
-        # Blocks with a row in them, of the blocks there can be.
-        assert 1 < -(-round(pairs) // cap) < -(-48 * 4 // cap)
+    assert cap == (rows or (144 if held == 4 else 192))
+    assert (pairs > cap) == bool(rows)
+    # Blocks with a row in them, of the blocks there can be.
+    assert int(stats["held_row_blocks"]) == -(-pairs // cap)
+    if rows:
+        assert 1 < -(-pairs // cap) < -(-48 * 4 // cap)
 
     def want(params, x):
         shared = reference.swiglu(x, params["shared"])
@@ -554,7 +572,11 @@ def test_events_say_what_was_traced(toy):
         assert held["rows_cap"] == moe.rows_cap(held["tokens"], cfg.moe_cfg)
         assert held["row_blocks"] == -(
             -held["tokens"] * held["top_k"] // held["rows_cap"]
-        )
+        ) == 2
+        # 4 of 16 held, 4 a token: rows for three held choices a
+        # token, three times the even load's; four in 1 draw of 1,820.
+        assert held["covered_choices"] == 3 and held["cap_over_mean"] == 3.0
+        assert held["tail"] == pytest.approx(1 / 1820)
         names = set().union(*(e["names"] for e in _events(tracer, "remat.kept")))
         from dlrover_tpu.accelerate import remat
 

@@ -8,6 +8,7 @@ against the uncut layer, and the normal path through the trainer."""
 
 import dataclasses
 import json
+import math
 import os
 
 import jax
@@ -409,7 +410,12 @@ def test_events_and_scopes_say_what_was_traced(toy):
         assert held["first_expert"] == 4 and held["scoring"] == "softmax"
         assert held["rows_cap"] == moe.rows_cap(held["tokens"], cfg.moe_cfg)
         mean = held["tokens"] * held["top_k"] * 4 / 16
-        assert held["cap_over_mean"] == held["rows_cap"] / mean
+        assert held["cap_over_mean"] == held["rows_cap"] / mean == 3.0
+        # 4 of 16 held, 4 a token: rows for three held choices a
+        # token; all four are held in 1 draw of 1,820.
+        assert held["covered_choices"] == 3
+        assert held["tail"] == pytest.approx(1 / 1820)
+        assert held["row_blocks"] == 2
     finally:
         obs.disable_tracer()
     assert {
@@ -428,11 +434,131 @@ def test_events_and_scopes_say_what_was_traced(toy):
     ] == "layers/attn/attn_window"
 
 
-def test_a_quarter_share_s_buffer_is_every_pair_of_the_layer():
-    """``rows_cap`` is ``min(4 x mean, n x top_k)``: at the cell's
-    share (16 of 64, 8 a token) four times the mean IS every pair, so
-    the grouped products walk 65,536 rows where the even load sends
-    16,384 (PERF.md section 6, PR 57)."""
-    cfg = family.build(_config(CELL))["cfg"].moe_cfg
-    assert moe.rows_cap(8192, cfg) == 8192 * 8 == 65536
-    assert 8192 * 8 * cfg.experts_here // cfg.n_experts == 16384
+def _share(n_experts, held, top_k, first=0):
+    return moe.MoEConfig(
+        n_embd=32, n_experts=n_experts, expert_hidden=16, top_k=top_k,
+        gated=True, renorm_top_k=True, held=held, first_expert=first,
+        dtype=jnp.float32,
+    )
+
+
+@pytest.mark.parametrize(
+    "n_experts,held,top_k,n,choices,rows",
+    [
+        (256, 8, 8, 8192, 1, 8192),
+        (64, 16, 8, 8192, 4, 32768),
+        (64, 64, 8, 8192, 8, 65536),
+        (64, 8, 8, 8192, 3, 24576),
+        (16, 4, 4, 48, 3, 144),
+        (16, 16, 4, 48, 4, 192),
+        (16, 4, 4, 40, 3, 128),
+        (8, 2, 2, 64, 2, 128),
+    ],
+    ids=["kimi_s_cell", "mellum_s_cell", "every_expert", "an_eighth",
+         "toy", "toy_every_expert", "toy_rounded_up", "two_of_eight"],
+)
+def test_the_buffer_follows_the_share(
+    n_experts, held, top_k, n, choices, rows
+):
+    """``rows_cap`` reads the tokens and the router's three numbers
+    and nothing else: ``n x h*``, h* the fewest held choices a token
+    that all but one draw in forty stay within (hypergeometric), a
+    multiple of 16 between the even load and every pair."""
+    cfg = _share(n_experts, held, top_k)
+    covered, tail = moe.covered_choices(cfg)
+    assert covered == choices and 0.0 <= tail <= moe.ROWS_CAP_TAIL
+    by_hand = lambda h: sum(
+        math.comb(held, j) * math.comb(n_experts - held, top_k - j)
+        for j in range(h + 1, top_k + 1)
+    ) / math.comb(n_experts, top_k)
+    assert tail == pytest.approx(by_hand(covered), abs=1e-12)
+    # One choice fewer would leave more than the tail (or none at all).
+    assert covered == 1 or by_hand(covered - 1) > moe.ROWS_CAP_TAIL
+    got = moe.rows_cap(n, cfg)
+    assert got == rows and got % 16 == 0
+    assert n * top_k * held / n_experts <= got <= n * top_k
+    # Where the share starts changes nothing.
+    assert moe.rows_cap(n, _share(
+        n_experts, held, top_k, first=n_experts - held
+    )) == rows
+
+
+def test_the_two_cells_buffers():
+    """Mellum's cell (16 of 64 held, 8 a token): 32,768 rows, twice
+    the even load's 16,384 and half the layer's pairs, so two blocks
+    of which the second is behind the ``lax.cond``; Kimi's (8 of 256):
+    8,192, what it had under four times the mean. The ``moe.held``
+    event of each, traced at the cell's shapes, says so."""
+    from benchmark.families import kimi_linear as kimi_family
+    from dlrover_tpu import obs
+
+    kimi = os.path.join(cell_files.HERE, "configs", "kimi-linear-48b-a3b.json")
+    # cell: (layer, rows, h*, its tail, blocks there can be, rows over
+    # the even load's)
+    cells = {
+        "mellum": (family.build(_config(CELL))["cfg"].moe_cfg,
+                   32768, 4, 0.019237, 2, 2.0),
+        "kimi": (kimi_family.build(_config(kimi))["cfg"].moe_cfg,
+                 8192, 1, 0.021833, 8, 4.0),
+    }
+    for name, (cfg, rows, choices, tail, blocks, over_mean) in cells.items():
+        assert moe.rows_cap(8192, cfg) == rows, name
+        params = jax.eval_shape(
+            lambda k: moe.init_moe_params(k, cfg), jax.random.PRNGKey(0)
+        )
+        x = jax.ShapeDtypeStruct((1, 8192, cfg.n_embd), cfg.dtype)
+        tracer = obs.configure_tracer()
+        try:
+            jax.eval_shape(lambda p, x: moe.moe_mlp(p, x, cfg), params, x)
+            (held,) = [e for e in tracer.events() if e["name"] == "moe.held"]
+        finally:
+            obs.disable_tracer()
+        assert held["tokens"] == 8192 and held["rows_cap"] == rows, name
+        assert held["covered_choices"] == choices, name
+        assert held["row_blocks"] == blocks, name
+        assert held["cap_over_mean"] == over_mean, name
+        assert held["tail"] == pytest.approx(tail, abs=1e-6), name
+
+
+def test_five_held_choices_a_token_run_two_blocks():
+    """At the cell's share (16 of 64, 8 a token) the buffer has rows
+    for four held choices a token. A router that sends EVERY token to
+    the same five held experts (and three absent ones) is past it: the
+    layer runs both blocks, drops nothing and is the reference's,
+    forward and every gradient. Nothing is patched."""
+    cfg = _share(64, 16, 8, first=16)
+    params = moe.init_moe_params(jax.random.PRNGKey(0), cfg)
+    params = jax.tree.map(lambda a: a * 20 if a.ndim == 3 else a, params)
+    chosen = jnp.asarray([17, 20, 23, 26, 31, 2, 40, 63])
+    router = 0.2 * jax.random.normal(jax.random.PRNGKey(1), (32, 64))
+    params["router"] = router.at[0, chosen].add(12.0)
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 24, 32)).at[..., 0].set(1.0)
+    w = jax.random.normal(jax.random.PRNGKey(3), (2, 24, 32))
+    cap = moe.rows_cap(48, cfg)
+    assert cap == 48 * 4 and -(-48 * 8 // cap) == 2
+    stats = moe.routing_stats(x.reshape(-1, 32) @ params["router"], 8, cfg)
+    assert float(stats["held_pairs_per_token"]) == 5.0
+    assert int(stats["held_row_blocks"]) == 2 == -(-48 * 5 // cap)
+    config = {"num_experts_per_tok": 8, "norm_topk_prob": True}
+
+    def want(params, x):
+        return reference.expert_layer(
+            x.reshape(-1, 32), params, config, cfg.first_expert
+        )[0].reshape(x.shape)
+
+    got = moe.moe_mlp(params, x, cfg)[0]
+    assert float(jnp.max(jnp.abs(got))) > 0.0
+    np.testing.assert_allclose(got, want(params, x), rtol=1e-4, atol=1e-5)
+    grads = jax.grad(
+        lambda p, x: jnp.sum(moe.moe_mlp(p, x, cfg)[0] * w), (0, 1)
+    )(params, x)
+    ref = jax.grad(lambda p, x: jnp.sum(want(p, x) * w), (0, 1))(params, x)
+    for (path, a), b in zip(
+        jax.tree_util.tree_leaves_with_path(grads), jax.tree.leaves(ref)
+    ):
+        name = jax.tree_util.keystr(path)
+        assert float(jnp.max(jnp.abs(b))) > 0.0, name
+        np.testing.assert_allclose(
+            a, b, rtol=1e-3, atol=1e-3 * float(jnp.max(jnp.abs(b))),
+            err_msg=name,
+        )

@@ -892,7 +892,16 @@ def test_mellum_train_step_compiles_on_one_chip(topo, compiled_kernels):
     experts held, a quarter of both tables, 1 x 8192 tokens, full
     remat, as ONE program. It fits; the flash kernels are compiled for
     both masks (a banded and a plain causal call of each), and the
-    forward runs once a layer: four calls, not eight."""
+    forward runs once a layer: four calls, not eight. Each expert
+    layer's buffer is two blocks of 32,768 rows (``rows_cap`` at 16 of
+    64 held, 8 a token: four held choices a token), the second behind
+    the held path's ``lax.cond``, so the scan over the blocks is a
+    loop in the program and no longer folds away as the one block of
+    65,536 rows did: the grouped kernels stand in its body, and
+    ``memory_analysis()`` reads 9.63 GB for that program's 9.14 (the
+    buffer's arrays halve; the loop's carries, the three matrices'
+    gradient sums among them, and the block's own copies beside them
+    are counted at once: PERF.md section 6, PR 58)."""
     cfg = mellum.MellumConfig(
         vocab_size=24576, layer_types=mellum.MellumConfig().period,
         held=16, remat="full", use_flash_attention=True,
@@ -906,10 +915,13 @@ def test_mellum_train_step_compiles_on_one_chip(topo, compiled_kernels):
     assert calls("flash_attention_fwd") == 4, calls("flash_attention_fwd")
     assert calls("flash_attention_bwd") == 4, calls("flash_attention_bwd")
     assert "moe_gmm" in text and "moe_tgmm" in text
+    # The buffer: [32768, 2304] rows through the products, never the
+    # layer's 65,536 pairs.
+    assert "bf16[32768,2304]" in text and "bf16[65536,2304]" not in text
     mem = compiled.memory_analysis()
     total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     print("mellum step bytes", total, mem)
-    assert total / 1e9 < 16.9, total
+    assert total / 1e9 < 9.7, total
 
 
 def _kimi_cell_cfg():
