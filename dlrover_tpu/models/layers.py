@@ -26,11 +26,11 @@ the last three in line, and reads 6.22 with the first three.
 (and through ``llama.backbone_with_aux`` of ``models/glm.py``); all
 four adapt through the one thing the code sees, the stack's length.
 ``models/ouro.py``, ``models/granite_hybrid.py`` and ``models/mellum.py``
-keep their own scans, separate paths by file: Ouro's stack runs
-``ut_steps`` times a step, so every layer in line would be that many
-copies in an executable that already compiles for 18 s (all in line:
-+2.25%); Granite's and Mellum's unit is a period of two kinds of layer
-(Granite's in a step at 15.59 of the 16.91 GB a program gets).
+keep their own scans: Ouro's stack runs ``ut_steps`` times a step (all
+in line: that many copies in an executable that compiles for 18 s,
++2.25%); Granite's and Mellum's unit is a period of two kinds of layer.
+``models/kimi_linear.py`` and ``models/deepseek_v2.py`` keep rows of
+calls: five or six layers of two or three kinds, a subtree a layer.
 """
 
 from __future__ import annotations

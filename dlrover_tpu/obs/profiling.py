@@ -533,14 +533,14 @@ class CompileTracker:
 # and the stacking of the passes' outputs), "exit_gate" its gate, exit
 # distribution and entropy; "attn_window" and "attn_full" are the two
 # kinds of attention layer of a patterned stack (models/mellum.py),
-# beneath "attn", and "moe_balance" the load-balancing loss it adds
-# beside a held expert layer, beneath "mlp".
+# "moe_balance" the balance loss a stack adds beside a held expert
+# layer, "mla_rope" a latent mixer's rotation (models/deepseek_v2.py).
 SCOPES = frozenset((
     "accumulate", "layers", "embed", "attn", "mlp", "ssm", "head",
     "optimizer", "moe_route", "moe_experts", "moe_combine", "ssm_conv",
     "ssd", "ssm_norm", "ut_loop", "exit_gate", "kda", "kda_conv",
     "kda_scan", "kda_gate", "mla", "moe_routed", "moe_shared",
-    "attn_window", "attn_full", "moe_balance",
+    "attn_window", "attn_full", "moe_balance", "mla_rope",
 ))
 _HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
 _HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')

@@ -27,7 +27,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from dlrover_tpu.models import (
-    gpt, granite_hybrid, kimi_linear, llama, mellum, ouro,
+    deepseek_v2, gpt, granite_hybrid, kimi_linear, llama, mellum, ouro,
 )
 from dlrover_tpu.ops import causal_conv, grouped_matmul
 from dlrover_tpu.ops import kda as kda_ops
@@ -922,6 +922,41 @@ def test_mellum_train_step_compiles_on_one_chip(topo, compiled_kernels):
     total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     print("mellum step bytes", total, mem)
     assert total / 1e9 < 9.7, total
+
+
+def test_deepseek_train_step_compiles_on_one_chip(topo, compiled_kernels):
+    """The program of the benchmark's ``deepseek-v2-lite.steady``:
+    published layers 0 to 5 (the dense layer, then five expert layers)
+    at published widths, latent attention on every one with the shared
+    key part rotated, 8 of 64 experts held beside two shared ones, an
+    eighth of both tables, 1 x 8192 tokens, full remat, as ONE program.
+    It fits; the flash kernels take the two head sizes (192 and 128)
+    six times each way, the forward once a layer and not twice; each
+    expert layer's buffer is 16,384 rows (``rows_cap`` at 8 of 64
+    held, 6 a token: two held choices a token), three blocks of which
+    the last two are behind the held path's ``lax.cond``.
+    ``memory_analysis()`` reads 9.86 GB: arguments 6.36 (635,466,752
+    parameters at 10 bytes), temporaries 3.50."""
+    cfg = deepseek_v2.DeepseekV2Config(
+        vocab_size=12800, n_layer=6, held=8, remat="full",
+        use_flash_attention=True,
+    )
+    compiled = _elastic_trainer_step(deepseek_v2, cfg, topo)
+    _assert_fits_with_flash(compiled)
+    text = compiled.as_text()
+    calls = lambda name: len(re.findall(
+        rf'custom_call_target="tpu_custom_call"[^\n]*{name}', text
+    ))
+    assert calls("flash_attention_fwd") == 6, calls("flash_attention_fwd")
+    assert calls("flash_attention_bwd") == 6, calls("flash_attention_bwd")
+    assert "moe_gmm" in text and "moe_tgmm" in text
+    # The buffer: [16384, 2048] rows through the products, never the
+    # layer's 49,152 pairs.
+    assert "bf16[16384,2048]" in text and "bf16[49152,2048]" not in text
+    mem = compiled.memory_analysis()
+    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    print("deepseek step bytes", total, mem)
+    assert total / 1e9 < 10.0, total
 
 
 def _kimi_cell_cfg():

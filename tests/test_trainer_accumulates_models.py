@@ -24,7 +24,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from dlrover_tpu.models import (
-    gpt, granite_hybrid, kimi_linear, llama, mellum, ouro,
+    deepseek_v2, gpt, granite_hybrid, kimi_linear, llama, mellum, ouro,
 )
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.trainer.elastic_trainer import ElasticTrainer
@@ -79,6 +79,12 @@ FAMILIES = {
     "mellum": (mellum, dataclasses.replace(
         mellum.MellumConfig.tiny(),
         layer_types=(mellum.SLIDING, mellum.FULL) * 2, remat="full",
+    )),
+    # latent attention with a rotated key part on a dense layer and an
+    # expert layer: 2 of 16 experts held, two shared, and the balance
+    # loss a sequence
+    "deepseek": (deepseek_v2, dataclasses.replace(
+        deepseek_v2.DeepseekV2Config.tiny(), n_layer=2, remat="full",
     )),
 }
 MESHES = {"one": 1, "data4": 4}
