@@ -584,66 +584,63 @@ def rows_cap(n: int, cfg: MoEConfig) -> int:
     return max(16, min(cap, n * cfg.top_k))
 
 
-def _segment_heads(rows, segment, steps):
-    """rows [r, D] in runs of equal ``segment`` [r] (each at most
-    ``2 ** steps`` long): every run's first row becomes the run's sum,
-    by ``steps`` shifted adds; the other rows hold partial sums."""
-    for s in (1 << i for i in range(steps)):
-        same = jnp.concatenate(
-            [segment[s:] == segment[:-s], jnp.zeros((s,), bool)]
-        )
-        ahead = jnp.concatenate([rows[s:], jnp.zeros_like(rows[:s])])
-        rows = rows + jnp.where(same[:, None], ahead, 0.0)
-    return rows
+# The two moves between token order and the held rows, each the other's
+# transpose, and neither touches a row of an absent expert. tokens ->
+# rows is a gather, one token a row. rows -> tokens is one kernel
+# (ops/rows_sum.py, ``moe_rows_sum``): the rows are sorted by expert and
+# ascend by token inside a group, where a token stands at most once, so
+# a tile of tokens has one range of rows a group (``plan["visits"]``)
+# and picks its rows out of that range's chunks by a 0/1 product.
 
 
-# The two moves between token order and the held rows are gathers
-# forward AND backward, as the sorted path's are, and neither touches a
-# row of an absent expert: tokens -> rows reads one token a row, and
-# rows -> tokens puts the rows in token order (they are sorted by
-# expert), sums each token's at most top_k rows by shifted adds and
-# reads one row a token. Each is the other's transpose.
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _rows_of_tokens(flat, plan, steps):
+@jax.custom_vjp
+def _rows_of_tokens(flat, plan):
     """flat [n, D] -> [cap, D]: row r is the token of the r-th held
     pair in expert order; rows past the held pairs are zero."""
     token, live = plan["token"], plan["live"]
     return jnp.where(live[:, None], flat[token], jnp.zeros((), flat.dtype))
 
 
-def _rows_of_tokens_fwd(flat, plan, steps):
-    return _rows_of_tokens(flat, plan, steps), plan
+def _rows_of_tokens_fwd(flat, plan):
+    return _rows_of_tokens(flat, plan), (plan, flat.shape[0])
 
 
-def _rows_of_tokens_bwd(steps, plan, g):
+def _rows_of_tokens_bwd(res, g):
+    plan, n = res
     with jax.named_scope("moe_route"):
-        return _tokens_of_rows(g, plan, steps), None
+        return _tokens_of_rows(g, None, plan, n).astype(g.dtype), None
 
 
 _rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _tokens_of_rows(rows, plan, steps):
-    """rows [cap, D] -> [n, D]: each token's rows (at most
-    ``2 ** steps``) summed, zero for a token with none."""
-    live = plan["live"]
-    rows = jnp.where(live[:, None], rows, jnp.zeros((), rows.dtype))
-    by_token = rows[plan["by_token"]]
-    heads = _segment_heads(by_token, plan["sorted_token"], steps)
-    first, some = plan["first_row"], plan["some"]
-    return jnp.where(some[:, None], heads[first], jnp.zeros((), rows.dtype))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _tokens_of_rows(rows, weight, plan, n, grad_dtype=None):
+    """rows [cap, D], weight [cap] float32 or None -> [n, D] float32:
+    each token's rows, times their weights, summed in float32; zero
+    for a token with none. The backward gathers the cotangent in
+    ``grad_dtype``, the dtype the caller holds these sums in."""
+    from dlrover_tpu.ops.rows_sum import rows_sum
+
+    return rows_sum(rows, plan["token"], weight, plan["visits"], n)
 
 
-def _tokens_of_rows_fwd(rows, plan, steps):
-    return _tokens_of_rows(rows, plan, steps), plan
+def _tokens_of_rows_fwd(rows, weight, plan, n, grad_dtype=None):
+    res = (rows, weight, plan)
+    return _tokens_of_rows(*res, n, grad_dtype), res
 
 
-def _tokens_of_rows_bwd(steps, plan, g):
+def _tokens_of_rows_bwd(n, grad_dtype, res, g):
+    rows, weight, plan = res
     with jax.named_scope("moe_combine"):
-        return _rows_of_tokens(g, plan, steps), None
+        back = _rows_of_tokens(g.astype(grad_dtype or g.dtype), plan)
+        if weight is None:
+            return back.astype(rows.dtype), None, None
+        return (  # float32 products, whatever ``back`` is held in
+            (back * weight[:, None]).astype(rows.dtype),
+            jnp.sum(back * rows.astype(jnp.float32), axis=1),
+            None,
+        )
 
 
 _tokens_of_rows.defvjp(_tokens_of_rows_fwd, _tokens_of_rows_bwd)
@@ -677,6 +674,8 @@ def _held_order(local, held: int) -> Dict[str, Any]:
     the held ones, ``held`` for an absent one. The pairs are sorted by
     that (stable): the held experts' first, expert by expert, every
     absent pair in one trailing group no product touches."""
+    from dlrover_tpu.ops.rows_sum import pairs_before
+
     n, k = local.shape
     pairs = jnp.arange(n * k, dtype=jnp.int32)
     _, order = jax.lax.sort(
@@ -687,45 +686,43 @@ def _held_order(local, held: int) -> Dict[str, Any]:
     return {
         "order": order, "row_of_pair": row_of_pair, "ends": ends,
         "pair_held": (local < held).reshape(n * k),
+        "before": pairs_before(local, held),
     }
 
 
 def _block_plan(whole, j, n: int, k: int, cap: int) -> Dict[str, Any]:
     """Block ``j`` of that order, rows ``[j x cap, (j + 1) x cap)``:
     the buffer's rows, the part of every expert's group that falls
-    among them, and the rows in token order."""
+    among them, and of that part the rows of each tile of tokens."""
+    from dlrover_tpu.ops.rows_sum import visits
+
     lo = j * cap
     ends = whole["ends"]
     starts = jnp.concatenate([jnp.zeros((1,), ends.dtype), ends[:-1]])
     window = lambda x: jnp.clip(x, lo, lo + cap)
-    rows = jnp.arange(cap, dtype=jnp.int32)
-    live = lo + rows < ends[-1]
+    live = lo + jnp.arange(cap, dtype=jnp.int32) < ends[-1]
     padded = jnp.pad(whole["order"], (0, -(n * k) % cap))
     order = jax.lax.dynamic_slice(padded, (lo,), (cap,))
     token = order // k
-    # The rows in token order (a dead row after every live one), and
-    # where each token's run starts there.
-    sorted_token, by_token = jax.lax.sort(
-        (jnp.where(live, token, n), rows), num_keys=1, is_stable=True
-    )
     at = whole["row_of_pair"] - lo
     pair_here = whole["pair_held"] & (at >= 0) & (at < cap)
-    per_token = jnp.sum(pair_here.reshape(n, k).astype(jnp.int32), axis=1)
+    # Where each tile of tokens' rows start in this block, by expert:
+    # the expert's start in the whole order plus its pairs of the tokens
+    # before the tile, cut to the block; consecutive edges are a window.
+    edges = window(starts[None, :] + whole["before"]) - lo
     return {
         "order": order, "token": token, "live": live,
         "group_sizes": window(ends) - window(starts),
-        "by_token": by_token, "sorted_token": sorted_token,
-        "first_row": jnp.minimum(jnp.cumsum(per_token) - per_token, cap - 1),
-        "some": per_token > 0,
+        "visits": visits(edges[:-1], edges[1:], cap),
         "pair_here": pair_here,
         "row_of_pair": jnp.clip(at, 0, cap - 1),
     }
 
 
-def _held_block(plan, flat, weights, wi, wo, wg, *, steps):
+def _held_block(plan, flat, weights, wi, wo, wg, dtype=None):
     """One device's tokens through the experts held here, for the
     held pairs of one block. flat [n, D], weights [n, k] -> [n, D]
-    float32."""
+    float32, which the caller holds in ``dtype``."""
     from dlrover_tpu.ops.grouped_matmul import gmm
 
     with jax.named_scope("moe_route"):
@@ -737,7 +734,7 @@ def _held_block(plan, flat, weights, wi, wo, wg, *, steps):
         sizes = plan["group_sizes"]
         sizes = sizes.at[-1].add(plan["live"].shape[0] - jnp.sum(sizes))
         row_weight = _row_weights(weights, plan)
-        xs = _rows_of_tokens(flat, plan, steps)
+        xs = _rows_of_tokens(flat, plan)
     with jax.named_scope("moe_experts"):
         h = gmm(xs, wi, sizes)
         if wg is not None:
@@ -747,28 +744,27 @@ def _held_block(plan, flat, weights, wi, wo, wg, *, steps):
             h = jax.nn.gelu(h.astype(jnp.float32)).astype(xs.dtype)
         out = gmm(h, wo, sizes)
     with jax.named_scope("moe_combine"):
-        weighted = out.astype(jnp.float32) * row_weight[:, None]
-        return _tokens_of_rows(weighted, plan, steps)
+        return _tokens_of_rows(out, row_weight, plan, flat.shape[0], dtype)
 
 
-def _held_experts(flat, local, weights, *matrices, held, cap):
+def _held_experts(flat, local, weights, *matrices, held, cap, dtype=None):
     """One device's tokens through the experts held here: dropless
-    whatever the load. ``matrices``: wi, wo and, of a gated layer, wg.
+    whatever the load, summed in float32 and handed on in ``dtype``
+    (float32 if none). ``matrices``: wi, wo and, of a gated layer, wg.
     A scan over the blocks of ``cap`` sorted rows there can be, each
     after the first behind a ``lax.cond`` on the counted rows; the
-    backward takes from
-    the forward its operands alone and forms each block it needs
-    again (the rows are 1/32 of the pairs at the published share, and
-    a value kept inside a ``lax.cond`` branch would be written, as
-    zeros, by the other too), so nothing is stacked over the blocks."""
+    backward takes from the forward its operands alone and forms each
+    block it needs again (the rows are 1/32 of the pairs at the
+    published share, and a value kept inside a ``lax.cond`` branch
+    would be written, as zeros, by the other too), so nothing is
+    stacked over the blocks."""
     n, k = local.shape
-    steps = max(1, int(np.ceil(np.log2(k))))
     blocks = -(-n * k // cap)
 
     def block(whole, j, flat, weights, wi, wo, wg=None):
         with jax.named_scope("moe_route"):
             plan = _block_plan(whole, j, n, k, cap)
-        return _held_block(plan, flat, weights, wi, wo, wg, steps=steps)
+        return _held_block(plan, flat, weights, wi, wo, wg, dtype)
 
     def over_blocks(local, add_block, start):
         """``start`` plus ``add_block(whole, j)`` of every block that
@@ -801,7 +797,7 @@ def _held_experts(flat, local, weights, *matrices, held, cap):
             local,
             lambda whole, j: block(whole, j, flat, weights, *matrices),
             jnp.zeros(flat.shape, jnp.float32),
-        )
+        ).astype(dtype or jnp.float32)
 
     def run_fwd(*operands):
         return run(*operands), operands
@@ -813,7 +809,7 @@ def _held_experts(flat, local, weights, *matrices, held, cap):
             _, pull = jax.vjp(
                 lambda flat, *rest: block(whole, j, flat, *rest), flat, *rest
             )
-            return pull(g)
+            return pull(g.astype(jnp.float32))
 
         d_flat, *d_rest = over_blocks(
             local, grads, jax.tree.map(jnp.zeros_like, (flat, *rest))
@@ -825,19 +821,22 @@ def _held_experts(flat, local, weights, *matrices, held, cap):
 
 
 def _held_moe(params, flat, logits, cfg: MoEConfig):
-    """flat [n, D] -> y [n, D] float32, the part the experts held here give."""
+    """flat [n, D] -> y likewise: the part the experts held here give."""
     from dlrover_tpu import obs
     from dlrover_tpu.ops.flash_attention import batch_axes, per_device
+    from dlrover_tpu.ops.rows_sum import layout
 
     held = cfg.experts_here
     _, n_here = batch_axes(flat.shape[0])
     cap, (choices, tail) = rows_cap(n_here, cfg), covered_choices(cfg)
+    sizes = layout(n_here, cap, held)
     obs.event(
         "moe.held", router_experts=cfg.n_experts, scoring=cfg.scoring,
         first_expert=cfg.first_expert, held=held, top_k=cfg.top_k,
         rows_cap=cap, tokens=n_here, row_blocks=-(-n_here * cfg.top_k // cap),
         cap_over_mean=cap * cfg.n_experts / (n_here * cfg.top_k * held),
         covered_choices=choices, tail=tail,
+        sum_tile=sizes["tile"], sum_chunk_visits=sizes["visits"],
     )
     with jax.named_scope("moe_route"):
         weights, experts = route(logits, params.get("router_bias"), cfg)
@@ -849,7 +848,8 @@ def _held_moe(params, flat, logits, cfg: MoEConfig):
         operands.append(params["wg"])
 
     return per_device(
-        functools.partial(_held_experts, held=held, cap=cap), *operands,
+        functools.partial(_held_experts, held=held, cap=cap, dtype=flat.dtype),
+        *operands,
         split=(True, True, True) + (False,) * (len(operands) - 3),
     )
 

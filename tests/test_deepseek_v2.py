@@ -22,6 +22,7 @@ from benchmark.families import deepseek_v2 as family
 from benchmark.reference import deepseek_v2 as reference
 from dlrover_tpu.models import deepseek_v2 as model
 from dlrover_tpu.models import llama, mellum, moe
+from dlrover_tpu.ops import rows_sum
 
 TOY = os.path.join(cell_files.HERE, "testdata", "cells", "configs")
 CELL = os.path.join(cell_files.HERE, "configs", "deepseek-v2-lite.json")
@@ -320,6 +321,11 @@ def test_the_cell_s_tree_and_its_count():
     assert moe.covered_choices(mcfg)[0] == 2
     assert moe.covered_choices(mcfg)[1] == pytest.approx(0.0222, abs=1e-4)
     assert moe.rows_cap(8192, mcfg) == 16384
+    # The rows' sum at the cell's widths: tiles of 256 tokens, chunks
+    # of 128 rows, a list of 128 + 32 x 8 visits.
+    assert rows_sum.layout(8192, 16384, 8) == {
+        "tile": 256, "visits": 384
+    }
     with pytest.raises(ValueError, match="dense layers"):
         model.DeepseekV2Config(n_layer=2, first_dense=3)
 
@@ -434,6 +440,9 @@ def test_events_and_scopes_say_what_was_traced(toy):
         assert held["first_expert"] == 4 and held["scoring"] == "softmax"
         assert held["rows_cap"] == moe.rows_cap(held["tokens"], cfg.moe_cfg)
         assert held["covered_choices"] == 2
+        sizes = rows_sum.layout(held["tokens"], held["rows_cap"], 2)
+        assert held["sum_tile"] == sizes["tile"] == held["tokens"]
+        assert held["sum_chunk_visits"] == sizes["visits"]
     finally:
         obs.disable_tracer()
     assert {

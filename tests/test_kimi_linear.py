@@ -22,7 +22,7 @@ from benchmark.families import kimi_linear as family
 from benchmark.reference import kimi_linear as reference
 from dlrover_tpu.models import kimi_linear as model
 from dlrover_tpu.models import moe
-from dlrover_tpu.ops import kda
+from dlrover_tpu.ops import kda, rows_sum
 from dlrover_tpu.ops.flash_attention import flash_attention
 
 TOY = os.path.join(cell_files.HERE, "testdata", "cells", "configs")
@@ -577,6 +577,11 @@ def test_events_say_what_was_traced(toy):
         # token, three times the even load's; four in 1 draw of 1,820.
         assert held["covered_choices"] == 3 and held["cap_over_mean"] == 3.0
         assert held["tail"] == pytest.approx(1 / 1820)
+        # The rows' sum is the kernel's at every shape: one tile of
+        # the toy's tokens, a visit a chunk and one more a held expert.
+        sizes = rows_sum.layout(held["tokens"], held["rows_cap"], 4)
+        assert held["sum_tile"] == sizes["tile"] == held["tokens"]
+        assert held["sum_chunk_visits"] == sizes["visits"]
         names = set().union(*(e["names"] for e in _events(tracer, "remat.kept")))
         from dlrover_tpu.accelerate import remat
 

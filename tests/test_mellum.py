@@ -20,6 +20,7 @@ from benchmark import cell as cell_files
 from benchmark.families import mellum as family
 from benchmark.reference import mellum as reference
 from dlrover_tpu.models import llama, mellum as model, moe
+from dlrover_tpu.ops import rows_sum
 
 TOY = os.path.join(cell_files.HERE, "testdata", "cells", "configs")
 CELL = os.path.join(cell_files.HERE, "configs", "mellum2-12b-a2.5b.json")
@@ -416,6 +417,9 @@ def test_events_and_scopes_say_what_was_traced(toy):
         assert held["covered_choices"] == 3
         assert held["tail"] == pytest.approx(1 / 1820)
         assert held["row_blocks"] == 2
+        sizes = rows_sum.layout(held["tokens"], held["rows_cap"], 4)
+        assert held["sum_tile"] == sizes["tile"] == held["tokens"]
+        assert held["sum_chunk_visits"] == sizes["visits"]
     finally:
         obs.disable_tracer()
     assert {
@@ -494,14 +498,15 @@ def test_the_two_cells_buffers():
 
     kimi = os.path.join(cell_files.HERE, "configs", "kimi-linear-48b-a3b.json")
     # cell: (layer, rows, h*, its tail, blocks there can be, rows over
-    # the even load's)
+    # the even load's, visits of the rows' sum: a chunk of 128 rows
+    # each, rows / 128 + 32 tiles x the held experts)
     cells = {
         "mellum": (family.build(_config(CELL))["cfg"].moe_cfg,
-                   32768, 4, 0.019237, 2, 2.0),
+                   32768, 4, 0.019237, 2, 2.0, 256 + 32 * 16),
         "kimi": (kimi_family.build(_config(kimi))["cfg"].moe_cfg,
-                 8192, 1, 0.021833, 8, 4.0),
+                 8192, 1, 0.021833, 8, 4.0, 64 + 32 * 8),
     }
-    for name, (cfg, rows, choices, tail, blocks, over_mean) in cells.items():
+    for name, (cfg, rows, choices, tail, blocks, over_mean, visits) in cells.items():
         assert moe.rows_cap(8192, cfg) == rows, name
         params = jax.eval_shape(
             lambda k: moe.init_moe_params(k, cfg), jax.random.PRNGKey(0)
@@ -518,6 +523,8 @@ def test_the_two_cells_buffers():
         assert held["row_blocks"] == blocks, name
         assert held["cap_over_mean"] == over_mean, name
         assert held["tail"] == pytest.approx(tail, abs=1e-6), name
+        assert held["sum_tile"] == 256, name
+        assert held["sum_chunk_visits"] == visits, name
 
 
 def test_five_held_choices_a_token_run_two_blocks():

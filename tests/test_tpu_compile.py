@@ -27,9 +27,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from dlrover_tpu.models import (
-    deepseek_v2, gpt, granite_hybrid, kimi_linear, llama, mellum, ouro,
+    deepseek_v2, gpt, granite_hybrid, kimi_linear, llama, mellum, moe, ouro,
 )
-from dlrover_tpu.ops import causal_conv, grouped_matmul
+from dlrover_tpu.ops import causal_conv, grouped_matmul, rows_sum
 from dlrover_tpu.ops import kda as kda_ops
 from dlrover_tpu.ops import ssd as ssd_ops
 from dlrover_tpu.ops.flash_attention import (
@@ -88,7 +88,7 @@ def compiled_kernels(monkeypatch):
     the answer is the chip's. ``dlrover_tpu.ops.flash_attention`` the
     attribute is the re-exported function, so go through sys.modules."""
     for name in ("flash_attention", "quantization", "grouped_matmul",
-                 "ssd", "causal_conv", "kda"):
+                 "ssd", "causal_conv", "kda", "rows_sum"):
         monkeypatch.setattr(
             sys.modules[f"dlrover_tpu.ops.{name}"],
             "use_interpret",
@@ -885,6 +885,44 @@ def _whole_array_passes(text, elements):
     return copies, unnamed
 
 
+@pytest.mark.parametrize(
+    "n,top_k,held,cap,d",
+    [(8192, 8, 8, 8192, 2304), (8192, 8, 16, 32768, 2304),
+     (8192, 6, 8, 16384, 2048)],
+    ids=["kimi", "mellum", "deepseek"],
+)
+@pytest.mark.parametrize("weighted", [True, False], ids=["combine", "bwd"])
+def test_moe_rows_sum_compiles_at_the_held_widths(
+    one_chip, compiled_kernels, n, top_k, held, cap, d, weighted
+):
+    """The held path's rows summed back by token at the three cells'
+    shapes, from the plan the layer forms (``_held_order``,
+    ``_block_plan``): the forward's form, bf16 rows with float32
+    weights, and the backward's, the rows alone. One custom call,
+    tiles of 256 tokens by chunks of 128 rows, and nothing
+    buffer-sized beside it: no float32 copy of the rows, no sort of
+    them."""
+    def fn(local, rows, weights):
+        whole = moe._held_order(local, held)
+        plan = moe._block_plan(whole, 0, n, top_k, cap)
+        weight = moe._row_weights(weights, plan) if weighted else None
+        return moe._tokens_of_rows(rows, weight, plan, n)
+
+    one = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip
+    )
+    text = _compile(
+        fn, one((n, top_k), jnp.int32), _bf16(one_chip, cap, d),
+        one((n, top_k), jnp.float32),
+    ).as_text()
+    assert len(re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*moe_rows_sum', text
+    )) == 1
+    assert rows_sum.layout(n, cap, held)["tile"] == 256
+    assert cap == n or f"f32[{cap},{d}]" not in text
+    assert not re.search(rf"= [^\n]*\[{cap}\][^\n]* sort\(", text)
+
+
 def test_mellum_train_step_compiles_on_one_chip(topo, compiled_kernels):
     """The program of the benchmark's ``mellum2-12b-a2.5b.steady``: one
     period (three window-1024 layers, one full with YaRN) at published
@@ -901,7 +939,14 @@ def test_mellum_train_step_compiles_on_one_chip(topo, compiled_kernels):
     ``memory_analysis()`` reads 9.63 GB for that program's 9.14 (the
     buffer's arrays halve; the loop's carries, the three matrices'
     gradient sums among them, and the block's own copies beside them
-    are counted at once: PERF.md section 6, PR 58)."""
+    are counted at once: PERF.md section 6, PR 58). Since PR 60 the
+    rows are summed back by token by ``moe_rows_sum`` and it reads
+    9.68, which is the compiler's heap, 2.66 GiB for the parent's
+    2.78, plus the holes in it, 826 MiB for 657:
+    ``temp_size_in_bytes`` counts a heap's fragmentation a second
+    time, so it rises where a program's live bytes fall faster than
+    its heap (PERF.md section 6, PR 60, has the compiler's own
+    lines)."""
     cfg = mellum.MellumConfig(
         vocab_size=24576, layer_types=mellum.MellumConfig().period,
         held=16, remat="full", use_flash_attention=True,
@@ -918,6 +963,9 @@ def test_mellum_train_step_compiles_on_one_chip(topo, compiled_kernels):
     # The buffer: [32768, 2304] rows through the products, never the
     # layer's 65,536 pairs.
     assert "bf16[32768,2304]" in text and "bf16[65536,2304]" not in text
+    # The rows' sum is the kernel's, forward and (the rows' gather's
+    # backward) in each layer's backward.
+    assert calls("moe_rows_sum") == 8, calls("moe_rows_sum")
     mem = compiled.memory_analysis()
     total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     print("mellum step bytes", total, mem)
@@ -935,8 +983,10 @@ def test_deepseek_train_step_compiles_on_one_chip(topo, compiled_kernels):
     expert layer's buffer is 16,384 rows (``rows_cap`` at 8 of 64
     held, 6 a token: two held choices a token), three blocks of which
     the last two are behind the held path's ``lax.cond``.
-    ``memory_analysis()`` reads 9.86 GB: arguments 6.36 (635,466,752
-    parameters at 10 bytes), temporaries 3.50."""
+    ``memory_analysis()`` reads 9.21 GB since PR 60 (9.86 before, the
+    rows' sum in plain ``jax.numpy`` with its float32 copies of the
+    buffer): arguments 6.36 (635,466,752 parameters at 10 bytes),
+    temporaries 2.85."""
     cfg = deepseek_v2.DeepseekV2Config(
         vocab_size=12800, n_layer=6, held=8, remat="full",
         use_flash_attention=True,
@@ -953,10 +1003,11 @@ def test_deepseek_train_step_compiles_on_one_chip(topo, compiled_kernels):
     # The buffer: [16384, 2048] rows through the products, never the
     # layer's 49,152 pairs.
     assert "bf16[16384,2048]" in text and "bf16[49152,2048]" not in text
+    assert calls("moe_rows_sum") == 10, calls("moe_rows_sum")
     mem = compiled.memory_analysis()
     total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     print("deepseek step bytes", total, mem)
-    assert total / 1e9 < 10.0, total
+    assert total / 1e9 < 9.5, total
 
 
 def _kimi_cell_cfg():
@@ -994,6 +1045,9 @@ def test_kimi_train_step_compiles_on_one_chip(topo, compiled_kernels):
     assert calls("kda_fwd") == 4 and calls("kda_bwd") == 4, (
         calls("kda_fwd"), calls("kda_bwd")
     )
+    # The held path's rows summed by token: forward and, as the
+    # backward of the rows' gather, once more, in each expert layer.
+    assert calls("moe_rows_sum") == 8, calls("moe_rows_sum")
     # No relayout at the rule's edge: from its convolutions to ``w_o``
     # a KDA mixer stays [B, T, H*d], what ``conv_silu`` writes and the
     # rule's kernels read, and a head's sums are products with the
