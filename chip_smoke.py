@@ -565,7 +565,7 @@ def run_kernels(size: str) -> list:
 
 
 def kernels_role(size: str) -> dict:
-    from dlrover_tpu.ops.flash_attention import use_interpret
+    from dlrover_tpu.parallel.mesh import use_interpret
 
     expect(not use_interpret(), "on a TPU the kernels must compile")
     return {"parity": run_kernels(size)}

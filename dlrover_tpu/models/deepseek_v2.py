@@ -9,7 +9,8 @@ expert layer with shared experts in every other.
     every layer:  h = h + mla(rms_1(h));  h = h + ffn(rms_2(h))
     logits = rms_f(h) @ lm_head^T                      (untied head)
 
-* ``mla``: ``q = u w_q`` in heads of ``[q_n | q_r]`` (``qk_nope |
+* ``mla`` (models/mla.py holds the mixer, which models/kimi_linear.py
+  shares): ``q = u w_q`` in heads of ``[q_n | q_r]`` (``qk_nope |
   qk_rope`` columns; no compressed query: ``q_lora_rank`` null); the
   latent ``[c | k_r] = u w_kva``, ``k_r`` one vector a token and no
   head's; ``[k_n | v] = rms(c) w_kvb`` a head (``k_r`` is not normed);
@@ -71,8 +72,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from dlrover_tpu import obs
-from dlrover_tpu.models import llama, mellum, moe
-from dlrover_tpu.models.kimi_linear import _nested  # path -> subtree
+from dlrover_tpu.models import llama, mellum, mla, moe
 
 Params = Dict[str, Any]
 DENSE, MOE = "dense", "moe"
@@ -268,7 +268,7 @@ def init_params(key: jax.Array, cfg: DeepseekV2Config) -> Params:
         cfg.layer_names, cfg.ffns, jax.random.split(k_layers, cfg.n_layer)
     ):
         shapes = _layer_shapes(cfg, ffn)
-        layers[name] = _nested({
+        layers[name] = mla.nested({
             path: _init_leaf(k, path, shape, cfg)
             for (path, (shape, _)), k in zip(
                 sorted(shapes.items()),
@@ -297,7 +297,7 @@ def param_logical_axes(cfg: DeepseekV2Config) -> Params:
     return {
         "wte": ("vocab", "embed"),
         "layers": {
-            name: _nested({
+            name: mla.nested({
                 path: axes
                 for path, (_, axes) in _layer_shapes(cfg, ffn).items()
             })
@@ -327,35 +327,6 @@ def published_layout(params: Params, cfg: DeepseekV2Config) -> Params:
 # ---------------------------------------------------------------------------
 
 
-def mla_mixer(u, lp, attn_fn, cfg: DeepseekV2Config, cos, sin):
-    """The latent-attention mixer on the normed input ``u``; ``cos``
-    and ``sin`` ``[T, qk_rope / 2]`` turn the rotated parts."""
-    from dlrover_tpu.accelerate.remat import ATTN_IN, MLA_LATENT, keep
-
-    bsz, t, _ = u.shape
-    heads, rank, d_n, d_r = cfg.n_head, cfg.kv_rank, cfg.qk_nope, cfg.qk_rope
-    obs.event(
-        "mla.attn", d_qk=cfg.d_qk, d_v=cfg.v_head, padded_to=cfg.d_qk,
-        heads=heads, rotated=True, rope_dim=d_r, scale=cfg.softmax_scale,
-        mscale=cfg.softmax_mscale,
-    )
-    q = keep(u @ lp["wq"], ATTN_IN).reshape(bsz, t, heads, cfg.d_qk)
-    latent = keep(u @ lp["w_kva"], MLA_LATENT)
-    c = llama._rms_norm(latent[..., :rank], lp["kv_norm"], cfg.rms_eps)
-    kv = (c @ lp["w_kvb"]).reshape(bsz, t, heads, d_n + cfg.v_head)
-    with jax.named_scope("mla_rope"):
-        # k_r as one head: turned once, before the heads share it.
-        k_r = llama.apply_rope(latent[..., None, rank:], cos, sin)
-        q = jnp.concatenate(
-            [q[..., :d_n], llama.apply_rope(q[..., d_n:], cos, sin)], axis=-1
-        )
-    k = jnp.concatenate(
-        [kv[..., :d_n], jnp.broadcast_to(k_r, (bsz, t, heads, d_r))], axis=-1
-    )
-    att = attn_fn(q, k, kv[..., d_n:], scale=cfg.softmax_scale)
-    return att.reshape(bsz, t, heads * cfg.v_head) @ lp["w_o"]
-
-
 def balance_loss(h, router, cfg: DeepseekV2Config):
     """One layer's load-balancing loss on the normed ``h`` [B, T, E],
     weighted: each sequence's ``sum_e f_e P_e`` (``moe.router_losses``
@@ -381,8 +352,15 @@ def _layer(x, lp, attn_fn, *, cfg: DeepseekV2Config, ffn: str, cos, sin):
     """One layer; returns (x, the layer's weighted balance loss)."""
     with jax.named_scope("attn"):
         h = llama._rms_norm(x, lp["rms1"], cfg.rms_eps)
+        obs.event(
+            "mla.attn", d_qk=cfg.d_qk, d_v=cfg.v_head, padded_to=cfg.d_qk,
+            heads=cfg.n_head, rotated=True, rope_dim=cfg.qk_rope,
+            scale=cfg.softmax_scale, mscale=cfg.softmax_mscale,
+        )
         with jax.named_scope("mla"):
-            x = x + mla_mixer(h, lp, attn_fn, cfg, cos, sin)
+            x = x + mla.mla_mixer(
+                h, lp, attn_fn, cfg, cfg.softmax_scale, (cos, sin)
+            )
     with jax.named_scope("mlp"):
         h = llama._rms_norm(x, lp["rms2"], cfg.rms_eps)
         if ffn == DENSE:

@@ -18,8 +18,9 @@ layers and an expert layer in every other.
   ``g = -exp(A_log[head]) * softplus((u w_fa) w_fb + dt_bias)``;
   ``beta = sigmoid(u w_b)`` a head; the rule; an RMS norm over each
   head's output times ``sigmoid((u w_ga) w_gb)``; ``w_o``.
-* ``mla``: ``q = u w_q`` in heads of ``qk_nope + qk_rope``; the
-  latent ``[c | k_r] = u w_kva``; ``[k_n | v] = rms(c) w_kvb`` a head;
+* ``mla`` (models/mla.py holds the mixer): ``q = u w_q`` in heads of
+  ``qk_nope + qk_rope``; the latent ``[c | k_r] = u w_kva``;
+  ``[k_n | v] = rms(c) w_kvb`` a head;
   the key of a head is ``[k_n | k_r]``, ``k_r`` shared by the heads
   and, as the published configuration has it (``mla_use_nope``),
   nothing is rotated; causal softmax attention at scale
@@ -50,7 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from dlrover_tpu import obs
-from dlrover_tpu.models import llama
+from dlrover_tpu.models import llama, mla
 from dlrover_tpu.models.moe import MoEConfig, moe_logical_axes, moe_mlp
 
 Params = Dict[str, Any]
@@ -249,17 +250,6 @@ def _init_leaf(key, path: str, shape, cfg: KimiLinearConfig):
     return value if name == "router" else value.astype(cfg.dtype)
 
 
-def _nested(flat: Dict[str, Any]) -> Dict[str, Any]:
-    tree: Dict[str, Any] = {}
-    for path, leaf in flat.items():
-        node = tree
-        *parents, name = path.split("/")
-        for p in parents:
-            node = node.setdefault(p, {})
-        node[name] = leaf
-    return tree
-
-
 def init_params(key: jax.Array, cfg: KimiLinearConfig) -> Params:
     k_table, k_head, k_final, k_layers = jax.random.split(key, 4)
     layers = {}
@@ -268,7 +258,7 @@ def init_params(key: jax.Array, cfg: KimiLinearConfig) -> Params:
         jax.random.split(k_layers, cfg.n_layer),
     ):
         shapes = _layer_shapes(cfg, mixer, ffn)
-        layers[name] = _nested({
+        layers[name] = mla.nested({
             path: _init_leaf(k, path, shape, cfg)
             for (path, (shape, _)), k in zip(
                 sorted(shapes.items()),
@@ -297,7 +287,7 @@ def param_logical_axes(cfg: KimiLinearConfig) -> Params:
     return {
         "wte": ("vocab", "embed"),
         "layers": {
-            name: _nested({
+            name: mla.nested({
                 path: axes
                 for path, (_, axes) in _layer_shapes(cfg, mixer, ffn).items()
             })
@@ -387,29 +377,6 @@ def kda_mixer(u, lp, cfg: KimiLinearConfig):
     return y @ lp["w_o"]
 
 
-def mla_mixer(u, lp, attn_fn, cfg: KimiLinearConfig):
-    """The latent-attention mixer on the normed input ``u``."""
-    from dlrover_tpu.accelerate.remat import ATTN_IN, MLA_LATENT, keep
-
-    bsz, t, _ = u.shape
-    heads, rank = cfg.n_head, cfg.kv_rank
-    d_qk = cfg.qk_nope + cfg.qk_rope
-    obs.event(
-        "mla.attn", d_qk=d_qk, d_v=cfg.v_head, padded_to=d_qk,
-        heads=heads, rotated=False,
-    )
-    q = keep(u @ lp["wq"], ATTN_IN).reshape(bsz, t, heads, d_qk)
-    latent = keep(u @ lp["w_kva"], MLA_LATENT)
-    c = llama._rms_norm(latent[..., :rank], lp["kv_norm"], cfg.rms_eps)
-    kv = (c @ lp["w_kvb"]).reshape(bsz, t, heads, cfg.qk_nope + cfg.v_head)
-    shared = jnp.broadcast_to(
-        latent[..., None, rank:], (bsz, t, heads, cfg.qk_rope)
-    )
-    k = jnp.concatenate([kv[..., :cfg.qk_nope], shared], axis=-1)
-    att = attn_fn(q, k, kv[..., cfg.qk_nope:], scale=d_qk ** -0.5)
-    return att.reshape(bsz, t, heads * cfg.v_head) @ lp["w_o"]
-
-
 def _layer(x, lp, attn_fn, *, cfg: KimiLinearConfig, mixer: str, ffn: str):
     with jax.named_scope("attn"):
         h = llama._rms_norm(x, lp["rms1"], cfg.rms_eps)
@@ -417,8 +384,13 @@ def _layer(x, lp, attn_fn, *, cfg: KimiLinearConfig, mixer: str, ffn: str):
             with jax.named_scope("kda"):
                 x = x + kda_mixer(h, lp, cfg)
         else:
+            d_qk = cfg.qk_nope + cfg.qk_rope
+            obs.event(
+                "mla.attn", d_qk=d_qk, d_v=cfg.v_head, padded_to=d_qk,
+                heads=cfg.n_head, rotated=False,
+            )
             with jax.named_scope("mla"):
-                x = x + mla_mixer(h, lp, attn_fn, cfg)
+                x = x + mla.mla_mixer(h, lp, attn_fn, cfg, d_qk ** -0.5)
     with jax.named_scope("mlp"):
         h = llama._rms_norm(x, lp["rms2"], cfg.rms_eps)
         if ffn == DENSE:

@@ -19,7 +19,7 @@ chooses it, not an option:
   capacity, so no token is ever dropped, and the work is the
   ``top_k`` experts' a token, whatever ``n_experts`` is. Routing is
   per token, so under a mesh each device sorts its own shard's tokens
-  (``ops/flash_attention.per_device``: the expert weights whole on
+  (``parallel/mesh.per_device``: the expert weights whole on
   every device, their gradients summed over the mesh); the auxiliary
   losses are means over all tokens and stay outside that.
 * **An ``expert`` axis larger than 1: one-hot (GShard).** Dispatch
@@ -55,11 +55,15 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+from math import comb
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from dlrover_tpu.ops import rows_sum
+from dlrover_tpu.parallel.mesh import batch_axes, per_device
 
 
 @dataclasses.dataclass(frozen=True)
@@ -507,8 +511,6 @@ def _onehot_moe(params, flat, logits, cfg: MoEConfig):
 
 def _sorted_moe(params, flat, logits, cfg: MoEConfig):
     """flat [n, D] -> (y [n, D] float32, router losses)."""
-    from dlrover_tpu.ops.flash_attention import per_device
-
     with jax.named_scope("moe_route"):
         probs = jax.nn.softmax(logits, axis=-1)
         weights, experts = top_k_route(probs, cfg.top_k, cfg.renorm_top_k)
@@ -566,14 +568,42 @@ def _sorted_moe(params, flat, logits, cfg: MoEConfig):
 # collapsed onto a held expert: PERF.md section 6, PR 53).
 # The rows are the tokens times h*, the number of a token's ``top_k``
 # choices that are held here in all but ``ROWS_CAP_TAIL`` of the draws
-# (``covered_choices``, at the end of this file, has the law and why a
-# load comes in whole tokens' worth): the same tail at every share
-# from one number, and no constant multiple of the mean load. 8 of 256
+# (``covered_choices``, below, has the law and why a load comes in
+# whole tokens' worth): the same tail at every share from one number,
+# and no constant multiple of the mean load. 8 of 256
 # held, 8 a token: h* = 1, 8,192 rows for 8,192 tokens, 4 x the mean;
 # 16 of 64: h* = 4, 32,768 rows, 2 x the mean, two blocks of which the
 # second is behind the ``lax.cond`` (4 x the mean was every pair of
 # that layer: PERF.md section 6, PR 58); every expert: every pair.
 ROWS_CAP_TAIL = 1 / 40
+
+# What the records say about the held load is that it comes in whole
+# tokens' worth. A frequent token sends all its copies the same way
+# (the benchmark stream's most frequent token is 17 to 19% of a
+# sequence), and a router that no balancing step holds even sends
+# EVERY token the same way within five steps (PERF.md section 6, PR
+# 53), so a layer's held pairs are ``tokens x h``, h the number of the
+# ``top_k`` chosen experts that this chip holds. For a choice that
+# knows nothing of the share, h follows the hypergeometric law of
+# ``top_k`` drawn of ``n_experts`` with ``held`` marked, and the buffer
+# has rows for the smallest h >= 1 that all but ``ROWS_CAP_TAIL`` of
+# the draws stay within; a layer past it runs one more block. At 8 of
+# 256, 8 a token: P(h > 0) = 0.227, P(h > 1) = 0.0218, so h* = 1 (the
+# 8,192 rows PR 53's third session chose by measurement: at 4,096 a
+# collapsed layer paid a second block in every step). At 16 of 64:
+# P(h > 3) = 0.099, P(h > 4) = 0.0192, so h* = 4. At ``held ==
+# n_experts`` h is always ``top_k``.
+
+
+def covered_choices(cfg: MoEConfig) -> Tuple[int, float]:
+    """(h*, the tail it leaves): of a token's ``top_k`` choices, how
+    many held here the buffer has rows for, and the probability that
+    ``top_k`` experts drawn of ``n_experts`` hold more than that many
+    of this chip's ``experts_here``."""
+    E, H, k = cfg.n_experts, cfg.experts_here, cfg.top_k
+    p = [comb(H, h) * comb(E - H, k - h) / comb(E, k) for h in range(k + 1)]
+    tails = ((h, sum(p[h + 1:])) for h in range(1, k + 1))
+    return next((h, tail) for h, tail in tails if tail <= ROWS_CAP_TAIL)
 
 
 def rows_cap(n: int, cfg: MoEConfig) -> int:
@@ -620,9 +650,7 @@ def _tokens_of_rows(rows, weight, plan, n, grad_dtype=None):
     each token's rows, times their weights, summed in float32; zero
     for a token with none. The backward gathers the cotangent in
     ``grad_dtype``, the dtype the caller holds these sums in."""
-    from dlrover_tpu.ops.rows_sum import rows_sum
-
-    return rows_sum(rows, plan["token"], weight, plan["visits"], n)
+    return rows_sum.rows_sum(rows, plan["token"], weight, plan["visits"], n)
 
 
 def _tokens_of_rows_fwd(rows, weight, plan, n, grad_dtype=None):
@@ -674,8 +702,6 @@ def _held_order(local, held: int) -> Dict[str, Any]:
     the held ones, ``held`` for an absent one. The pairs are sorted by
     that (stable): the held experts' first, expert by expert, every
     absent pair in one trailing group no product touches."""
-    from dlrover_tpu.ops.rows_sum import pairs_before
-
     n, k = local.shape
     pairs = jnp.arange(n * k, dtype=jnp.int32)
     _, order = jax.lax.sort(
@@ -686,7 +712,7 @@ def _held_order(local, held: int) -> Dict[str, Any]:
     return {
         "order": order, "row_of_pair": row_of_pair, "ends": ends,
         "pair_held": (local < held).reshape(n * k),
-        "before": pairs_before(local, held),
+        "before": rows_sum.pairs_before(local, held),
     }
 
 
@@ -694,8 +720,6 @@ def _block_plan(whole, j, n: int, k: int, cap: int) -> Dict[str, Any]:
     """Block ``j`` of that order, rows ``[j x cap, (j + 1) x cap)``:
     the buffer's rows, the part of every expert's group that falls
     among them, and of that part the rows of each tile of tokens."""
-    from dlrover_tpu.ops.rows_sum import visits
-
     lo = j * cap
     ends = whole["ends"]
     starts = jnp.concatenate([jnp.zeros((1,), ends.dtype), ends[:-1]])
@@ -713,7 +737,7 @@ def _block_plan(whole, j, n: int, k: int, cap: int) -> Dict[str, Any]:
     return {
         "order": order, "token": token, "live": live,
         "group_sizes": window(ends) - window(starts),
-        "visits": visits(edges[:-1], edges[1:], cap),
+        "visits": rows_sum.visits(edges[:-1], edges[1:], cap),
         "pair_here": pair_here,
         "row_of_pair": jnp.clip(at, 0, cap - 1),
     }
@@ -823,13 +847,11 @@ def _held_experts(flat, local, weights, *matrices, held, cap, dtype=None):
 def _held_moe(params, flat, logits, cfg: MoEConfig):
     """flat [n, D] -> y likewise: the part the experts held here give."""
     from dlrover_tpu import obs
-    from dlrover_tpu.ops.flash_attention import batch_axes, per_device
-    from dlrover_tpu.ops.rows_sum import layout
 
     held = cfg.experts_here
     _, n_here = batch_axes(flat.shape[0])
     cap, (choices, tail) = rows_cap(n_here, cfg), covered_choices(cfg)
-    sizes = layout(n_here, cap, held)
+    sizes = rows_sum.layout(n_here, cap, held)
     obs.event(
         "moe.held", router_experts=cfg.n_experts, scoring=cfg.scoring,
         first_expert=cfg.first_expert, held=held, top_k=cfg.top_k,
@@ -899,40 +921,3 @@ def moe_mlp(
         + cfg.z_loss_weight * metrics["z_loss"]
     )
     return y.reshape(B, T, D).astype(x.dtype), aux
-
-
-# ---------------------------------------------------------------------------
-# The held path's buffer: how many of a token's choices it has rows for
-# ---------------------------------------------------------------------------
-
-# What the records say about the held load is that it comes in whole
-# tokens' worth. A frequent token sends all its copies the same way
-# (the benchmark stream's most frequent token is 17 to 19% of a
-# sequence), and a router that no balancing step holds even sends
-# EVERY token the same way within five steps (PERF.md section 6, PR
-# 53), so a layer's held pairs are ``tokens x h``, h the number of the
-# ``top_k`` chosen experts that this chip holds. For a choice that
-# knows nothing of the share, h follows the hypergeometric law of
-# ``top_k`` drawn of ``n_experts`` with ``held`` marked, and the buffer
-# has rows for the smallest h >= 1 that all but ``ROWS_CAP_TAIL`` of
-# the draws stay within; a layer past it runs one more block. At 8 of
-# 256, 8 a token: P(h > 0) = 0.227, P(h > 1) = 0.0218, so h* = 1 (the
-# 8,192 rows PR 53's third session chose by measurement: at 4,096 a
-# collapsed layer paid a second block in every step). At 16 of 64:
-# P(h > 3) = 0.099, P(h > 4) = 0.0192, so h* = 4. At ``held ==
-# n_experts`` h is always ``top_k``. It stands here, below the layer,
-# because the lines above are on the grouped kernels' call stacks and
-# a compiled step's identity holds their numbers (ROADMAP D21).
-
-
-def covered_choices(cfg: MoEConfig) -> Tuple[int, float]:
-    """(h*, the tail it leaves): of a token's ``top_k`` choices, how
-    many held here the buffer has rows for, and the probability that
-    ``top_k`` experts drawn of ``n_experts`` hold more than that many
-    of this chip's ``experts_here``."""
-    from math import comb
-
-    E, H, k = cfg.n_experts, cfg.experts_here, cfg.top_k
-    p = [comb(H, h) * comb(E - H, k - h) / comb(E, k) for h in range(k + 1)]
-    tails = ((h, sum(p[h + 1:])) for h in range(1, k + 1))
-    return next((h, tail) for h, tail in tails if tail <= ROWS_CAP_TAIL)

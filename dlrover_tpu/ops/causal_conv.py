@@ -36,7 +36,7 @@ chunk's taps, pre-activation and ``g`` stay in registers. The
 backward's row tiles run in turn and a column block's ``dw`` and
 ``dbias`` accumulate in its output block. Under an ambient mesh the
 whole ``custom_vjp`` runs once per device on its batch rows
-(``flash_attention.per_device``); interpreted off the TPU.
+(``parallel.mesh.per_device``); interpreted off the TPU.
 """
 
 from __future__ import annotations
@@ -50,7 +50,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from dlrover_tpu import obs
-from dlrover_tpu.ops.flash_attention import (
+from dlrover_tpu.parallel.mesh import (
     batch_axes,
     per_device,
     use_interpret,

@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from dlrover_tpu import obs
-from dlrover_tpu.ops.flash_attention import batch_axes, per_device
+from dlrover_tpu.parallel.mesh import batch_axes, per_device
 
 
 # XLA fuses the cotangent's elementwise chain (exp, one-hot, scale,
@@ -115,7 +115,7 @@ def _on_own_rows(rows_fn, by_row, whole, num_chunks, summed=(),
 
     Under an ambient mesh whose batch axes divide the rows (and leave
     every device a multiple of ``num_chunks``) the chunked loop runs
-    once per device (ops.flash_attention ``per_device``): the
+    once per device (parallel.mesh ``per_device``): the
     ``by_row`` operands stay where the batch put them and ``whole``
     (the table) enters whole, gathered once a step. Left to XLA the
     loop is partitioned along the table's ``embed`` dimension, which

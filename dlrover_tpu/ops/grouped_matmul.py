@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dlrover_tpu.ops.flash_attention import use_interpret
+from dlrover_tpu.parallel.mesh import use_interpret
 
 # Rows a visit: the smaller the tile, the less of a boundary tile is
 # computed for rows of another group, and the expert's matrix is

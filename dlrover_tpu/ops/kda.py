@@ -91,7 +91,7 @@ differentiated by JAX. Its stages stand under scopes of their own
 ``kda_out``) for whoever reads a profile by ``op_name``; the
 benchmark reads the caller's ``kda_scan`` around either form. Under an
 ambient mesh the call runs once per device on its batch rows
-(``ops.flash_attention.per_device``), as the package's kernels do.
+(``parallel.mesh.per_device``), as the package's kernels do.
 """
 
 from __future__ import annotations
@@ -104,7 +104,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from dlrover_tpu import obs
-from dlrover_tpu.ops.flash_attention import (
+from dlrover_tpu.parallel.mesh import (
     batch_axes,
     per_device,
     use_interpret,

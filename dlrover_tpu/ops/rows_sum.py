@@ -42,8 +42,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dlrover_tpu.ops.flash_attention import use_interpret
 from dlrover_tpu.ops.grouped_matmul import _vmem_limit
+from dlrover_tpu.parallel.mesh import use_interpret
 
 # Tokens a tile and rows a chunk. The product's cost follows the tile
 # (every chunk is multiplied by a whole tile's selector) and the list's

@@ -40,7 +40,7 @@ for ``remat="full"`` (accelerate/remat.py ``SSD_Y``, ``SSD_STATES``)
 so that a rematerialised block does not run ``ssd_fwd`` again.
 
 Under an ambient mesh the whole ``custom_vjp`` runs once per device
-on that device's batch rows (``ops.flash_attention.per_device``): a
+on that device's batch rows (``parallel.mesh.per_device``): a
 Mosaic kernel cannot be partitioned by XLA. Off the TPU the kernels
 are interpreted.
 """
@@ -55,7 +55,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from dlrover_tpu import obs
-from dlrover_tpu.ops.flash_attention import (
+from dlrover_tpu.parallel.mesh import (
     batch_axes,
     per_device,
     use_interpret,

@@ -22,7 +22,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, PartitionSpec as P
 
 from dlrover_tpu.common.log import get_logger
 
@@ -193,13 +193,13 @@ def under_mesh(fn: Callable, mesh: Mesh) -> Callable:
 
     jit learns the mesh from its arguments' shardings, after the
     trace; code that must know it DURING the trace reads the ambient
-    one. The Pallas kernels do (ops/flash_attention.py
-    ``per_device``): XLA cannot partition a Mosaic call, so under a
-    mesh of several devices they split themselves over batch rows
-    and heads. The step builders wrap the loss in this, so a bare
-    model loss compiles on any mesh; one device needs nothing, and a
-    trace that already has a mesh (a caller's ``jax.set_mesh``, the
-    inside of a ``shard_map``) keeps its own."""
+    one. The Pallas kernels do (:func:`per_device`, below): XLA
+    cannot partition a Mosaic call, so under a mesh of several
+    devices they split themselves over batch rows and heads. The step
+    builders wrap the loss in this, so a bare model loss compiles on
+    any mesh; one device needs nothing, and a trace that already has
+    a mesh (a caller's ``jax.set_mesh``, the inside of a
+    ``shard_map``) keeps its own."""
     if mesh.size == 1:
         return fn
 
@@ -211,6 +211,108 @@ def under_mesh(fn: Callable, mesh: Mesh) -> Callable:
             return fn(*args, **kwargs)
 
     return traced
+
+
+# -- how a Pallas kernel meets the backend and the ambient mesh -----------
+
+
+def use_interpret() -> bool:
+    """Off the TPU the package's kernels run interpreted."""
+    return jax.default_backend() != "tpu"
+
+
+# The mesh axes that split an activation's batch rows and its heads
+# (parallel/sharding.py DEFAULT_RULES: "batch" and "heads").
+_BATCH_AXES = ("data", "fsdp")
+_HEAD_AXIS = "tensor"
+
+
+def _ambient_mesh():
+    """The mesh the trace is under, or None where :func:`per_device`
+    makes a plain call: no mesh, one device, or the inside of a
+    ``shard_map`` (the operands already are one device's blocks)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1 or mesh.manual_axes:
+        return None
+    return mesh
+
+
+def batch_axes(rows: int):
+    """(the ambient mesh's batch axes that :func:`per_device` splits
+    ``rows`` rows over, the rows one device then holds): every batch
+    axis larger than one that divides what the axes before it left."""
+    mesh = _ambient_mesh()
+    batch = []
+    for axis in _BATCH_AXES if mesh is not None else ():
+        n = mesh.shape.get(axis, 1)
+        if n > 1 and rows % n == 0:
+            batch.append(axis)
+            rows //= n
+    return tuple(batch), rows
+
+
+def per_device(call, *operands, split, heads_dim=None, summed=(),
+               manual_all=True):
+    """``call(*operands)``, run once per device of the mesh the trace
+    is under.
+
+    A Pallas kernel is a Mosaic custom call, and XLA refuses to
+    partition one ("Mosaic kernels cannot be automatically
+    partitioned. Please wrap the call in a shard_map"). The kernels
+    of this package treat every batch row, and the attention ones
+    every head, on its own, so under an ambient mesh (``jax.set_mesh``
+    or the step builders' :func:`under_mesh`) the call goes
+    through ``shard_map``: dim 0 of each operand flagged in ``split``
+    over the batch axes, dim ``heads_dim`` over ``tensor``, the other
+    operands (weights) and dims whole on every device; outputs are
+    split like the operands, and autodiff sums the weights'
+    gradients over the mesh. An axis that does not divide its dim is
+    left out, and XLA gathers that dim instead.
+
+    ``summed`` flags outputs (``call`` then returns a tuple) that are
+    one device's share of a sum over the batch rows, a weight's
+    gradient formed by hand: they are summed over the batch axes in
+    the dtype they have and come back whole. ``manual_all=False``
+    leaves the mesh axes no spec names (``tensor``, ``seq``) to XLA
+    inside the call, which nothing but a Mosaic kernel forbids.
+
+    With no ambient mesh or one device it is a plain call, and so it
+    is inside somebody else's ``shard_map`` (ring attention, the
+    overlapped-reduce steps), where the operands already are one
+    device's blocks."""
+    mesh = _ambient_mesh()
+    if mesh is None:
+        return call(*operands)
+    shape = operands[split.index(True)].shape
+    batch, _ = batch_axes(shape[0])
+    dims = [batch or None]
+    if heads_dim is not None:
+        n = mesh.shape.get(_HEAD_AXIS, 1)
+        heads = n > 1 and shape[heads_dim] % n == 0
+        dims += [None] * (heads_dim - 1)
+        dims.append(_HEAD_AXIS if heads else None)
+    if all(d is None for d in dims):
+        return call(*operands)
+    spec = P(*dims)
+    out_specs, body = spec, call
+    if summed:
+        out_specs = tuple(P() if s else spec for s in summed)
+
+        def body(*blocks):
+            return tuple(
+                jax.lax.psum(o, batch) if s else o
+                for o, s in zip(call(*blocks), summed)
+            )
+
+    # No names: every mesh axis is manual, shard_map's default.
+    named = set() if manual_all else set(batch) | ({_HEAD_AXIS} & set(dims))
+    return jax.shard_map(
+        body,
+        in_specs=tuple(spec if s else P() for s in split),
+        out_specs=out_specs,
+        axis_names=frozenset(named),
+        check_vma=False,
+    )(*operands)
 
 
 def mesh_slice_blocks(mesh: Mesh, num_slices: int) -> List[List]:

@@ -114,7 +114,6 @@ class ElasticTrainer:
         global_batch_size: int,
         micro_batch_size: int,
         report_fn: Optional[Callable[[TrainerReport], None]] = None,
-        accum_dtype=None,
         step_fn: Optional[Callable] = None,
         donate_state: bool = True,
         report_max_pending: int = 8,
@@ -148,13 +147,6 @@ class ElasticTrainer:
         self.global_batch_size = global_batch_size
         self.micro_batch_size = micro_batch_size
         self.report_fn = report_fn
-        # Gradient-accumulator dtype. None = float32 (safe default:
-        # bf16 accumulation silently drops late microbatches once
-        # |acc| >> |g/accum|). Memory-constrained FSDP jobs can pass
-        # the params' dtype to halve the accumulator footprint —
-        # microbatches are pre-scaled by 1/accum so the range is fine;
-        # the tradeoff is bf16's ~8-bit mantissa on the running sum.
-        self.accum_dtype = accum_dtype
         self.donate_state = donate_state
         self.num_shards = data_shards(mesh)
         self.step_num = 0
@@ -239,12 +231,6 @@ class ElasticTrainer:
         # Microbatch dim leads: [accum, per_shard_batch, ...]
         mb_spec = P(None, *bspec)
 
-        acc_dtype = (
-            self.accum_dtype
-            if self.accum_dtype is not None
-            else jnp.float32
-        )
-
         def train_step(params, opt_state, tokens, targets):
 
             def micro(carry, batch):
@@ -253,9 +239,10 @@ class ElasticTrainer:
                 loss, grads = jax.value_and_grad(loss_fn)(
                     params, mb_tokens, mb_targets
                 )
-                # Pre-scale each microbatch by 1/accum so low-precision
-                # accumulators stay in the gradients' own range (no
-                # overflow headroom needed, no final divide).
+                # Each microbatch pre-scaled by 1/accum: no final
+                # divide. The accumulator is float32 whatever the
+                # parameters are: a bf16 sum drops late microbatches
+                # once |acc| >> |g / accum|.
                 grad_acc = jax.tree.map(
                     lambda a, g: a + (g / accum).astype(a.dtype),
                     grad_acc,
@@ -269,7 +256,7 @@ class ElasticTrainer:
             # (obs.profiling.compiled_scopes).
             with jax.named_scope("accumulate"):
                 zeros = jax.tree.map(
-                    lambda p: jnp.zeros(p.shape, acc_dtype), params
+                    lambda p: jnp.zeros(p.shape, jnp.float32), params
                 )
                 (grads, loss_sum), _ = jax.lax.scan(
                     micro, (zeros, 0.0), (tokens, targets)

@@ -47,15 +47,29 @@ def enable_compile_cache() -> str:
     resume.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set (or the caller already
-    configured a directory) JAX has it and nothing is set here, the
-    owner's minimum compile time included; otherwise the cache lives
-    at the fixed ``<checkout>/.cache/jax`` and keeps fast compiles
-    too, since strategy-search candidates are often small.
+    configured a directory) JAX has it and nothing of the cache is
+    set here, the owner's minimum compile time included; otherwise
+    the cache lives at the fixed ``<checkout>/.cache/jax`` and keeps
+    fast compiles too, since strategy-search candidates are often
+    small.
+
+    What keys the cache is the computation, on both paths: a Pallas
+    kernel's body is serialized into its custom call with the Python
+    frames of whoever called it, by absolute path and line, and the
+    body is no metadata, so JAX keeps it in the key. With the frames a
+    comment added above the trainer's ``value_and_grad`` or a moved
+    checkout was a cold compile of every step with a kernel in it.
+    ``jax_traceback_in_locations_limit`` 0 leaves no frame in any
+    location; the scopes and kernel names that traces are read by
+    come from the name stack and the call's ``name``, not from
+    frames. ``tools/step_hash.py`` prints each cell's lowered step's
+    hash and the bodies that still name a file (none).
     """
     import jax
 
     from dlrover_tpu.common.config import cache_dir
 
+    jax.config.update("jax_traceback_in_locations_limit", 0)
     path = jax.config.jax_compilation_cache_dir
     if path:
         os.makedirs(path, exist_ok=True)

@@ -21,7 +21,7 @@ from benchmark import cell as cell_files
 from benchmark.families import deepseek_v2 as family
 from benchmark.reference import deepseek_v2 as reference
 from dlrover_tpu.models import deepseek_v2 as model
-from dlrover_tpu.models import llama, mellum, moe
+from dlrover_tpu.models import llama, mellum, mla, moe
 from dlrover_tpu.ops import rows_sum
 
 TOY = os.path.join(cell_files.HERE, "testdata", "cells", "configs")
@@ -177,7 +177,7 @@ def test_the_mixer_rotates_the_key_part_once_and_gives_the_scale():
     honest = llama.apply_rope
     try:
         llama.apply_rope = lambda x, c, s: (calls.append(x.shape), honest(x, c, s))[1]
-        model.mla_mixer(u, lp, attn_fn, cfg, cos, sin)
+        mla.mla_mixer(u, lp, attn_fn, cfg, cfg.softmax_scale, (cos, sin))
     finally:
         llama.apply_rope = honest
     assert sorted(calls) == [(2, 40, 1, 16), (2, 40, 4, 16)]

@@ -21,7 +21,7 @@ from benchmark import cell as cell_files
 from benchmark.families import kimi_linear as family
 from benchmark.reference import kimi_linear as reference
 from dlrover_tpu.models import kimi_linear as model
-from dlrover_tpu.models import moe
+from dlrover_tpu.models import mla, moe
 from dlrover_tpu.ops import kda, rows_sum
 from dlrover_tpu.ops.flash_attention import flash_attention
 
@@ -149,10 +149,12 @@ def test_latent_attention_is_plain_attention(flash):
         attn = lambda q, k, v, scale: flash_attention(
             q, k, v, scale=scale, block_q=16, block_k=32
         )
-    loss = lambda u, lp: jnp.sum(jnp.sin(model.mla_mixer(u, lp, attn, cfg)))
+    scale = (cfg.qk_nope + cfg.qk_rope) ** -0.5
+    mixer = lambda u, lp: mla.mla_mixer(u, lp, attn, cfg, scale)
+    loss = lambda u, lp: jnp.sum(jnp.sin(mixer(u, lp)))
     want = lambda u, lp: jnp.sum(jnp.sin(reference.mla_mixer(u, lp, config)))
     np.testing.assert_allclose(
-        model.mla_mixer(u, lp, attn, cfg), reference.mla_mixer(u, lp, config),
+        mixer(u, lp), reference.mla_mixer(u, lp, config),
         rtol=2e-4, atol=2e-5,
     )
     got, ref = jax.grad(loss, (0, 1))(u, lp), jax.grad(want, (0, 1))(u, lp)

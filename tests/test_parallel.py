@@ -746,7 +746,7 @@ class TestShardedTraining:
     def test_full_remat_keeps_flash_outputs_when_sharded(self):
         """remat=True on the 8-device mesh (fsdp) with the flash
         kernel forced: the (o, lse) "full" keeps are tagged inside
-        the kernel's shard_map (ops.flash_attention.per_device), so
+        the kernel's shard_map (parallel.mesh.per_device), so
         the gradient holds the forward kernel once a layer (both
         layers run in line, models/layers.py: two calls of one block),
         each device keeps its own rows' outputs, and loss and

@@ -81,7 +81,7 @@ class TestRematPolicies:
         compiler merges an in-line layer's recompute with its forward
         (as many products compiled with "full" as with "none", and as
         many bytes), which a loop's body rules out. The chip's keeps
-        the recompute (tests/test_tpu_compile.py: Mistral's two
+        the recompute (tests/test_tpu_compile_dense.py: Mistral's two
         layers, both in line, 10.8 GB for the scan's 14.4)."""
         def build(remat):
             cfg = dataclasses.replace(_cfg(remat), n_layer=6)

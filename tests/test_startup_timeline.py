@@ -9,6 +9,7 @@ steps; all returned by ``obs.profiling.startup_timeline()``.
 import functools
 import json
 import os
+import random
 import subprocess
 import sys
 import time
@@ -269,9 +270,14 @@ def test_each_record_is_a_span_stamped_with_its_own_start_and_a_counter(
     seconds = obs.get_registry().get("dlrover_compile_stage_seconds_total")
     before = seconds.value(stage="trace")
 
+    # A constant no earlier run drew: where another test of the worker
+    # has turned the persistent cache on, a body that cache has seen
+    # is answered by a ``jax.cache_load`` in the compile's place.
+    drawn = random.SystemRandom().random()
+
     @jax.jit
     def toy_span_fn(x):
-        return x + 1
+        return x + drawn
 
     toy_span_fn(jnp.ones(4)).block_until_ready()
     ev = _by_name(e for e in tracer.events() if "toy_span_fn" in e.get("fn", ""))

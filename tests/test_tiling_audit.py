@@ -6,8 +6,8 @@ tiling constraints — the round-3 fused-norm backward shipped three
 rounds of green tests while uncompilable on real TPU because its
 dg/db partials used (1, E) blocks, below the 8-sublane f32 floor
 (docs/ROOFLINE.md epilogue). Real-chip compilation
-(tools/tpu_kernel_smoke.py, tests/test_tpu_compile.py) is the ground
-truth; this audit catches the same bug CLASS at every kernel call by
+(tools/tpu_kernel_smoke.py, tests/test_tpu_compile_kernels.py) is the
+ground truth; this audit catches the same bug CLASS at every kernel call by
 intercepting ``pl.pallas_call`` and checking every BlockSpec against
 the floors that bit us:
 

@@ -8,8 +8,8 @@ below the 8-sublane tile floor).
 This tool is the guard: one run lowers and executes every kernel
 variant on the live chip and checks numerics against the XLA
 reference. ``chip_smoke.py`` runs it as part of its kernel phase
-(``run(small=False)``); ``tests/test_tpu_compile.py`` asks the same
-of the chip's compiler without a chip.
+(``run(small=False)``); ``tests/test_tpu_compile_kernels.py`` asks the
+same of the chip's compiler without a chip.
 
 Run on a TPU host:  python tools/tpu_kernel_smoke.py
 Exit code is the number of failing kernels (0 = all good); off the
