@@ -395,22 +395,56 @@ def attention_half(h, lp, cfg: LlamaConfig, attn_fn, cos, sin):
     ``att @ wo`` WITHOUT the residual, so that a family that scales
     the branch (models/granite_hybrid.py) can put its multiplier
     between. ``cos`` None leaves queries and keys unrotated (no
-    positional embedding)."""
+    positional embedding).
+
+    Where ``attn_fn`` is the flash kernels' and a head fills whole
+    lanes (``flash_attention.wide_form``, ``wide_head_size``), q, k,
+    v and the result stay ``[B, T, H*D]`` from the projections to
+    ``wo``: the kernels read a head as a column block, a key-value
+    head by the block's index. Every other attention function and
+    head size takes ``[B, T, H, D]`` views."""
     B, T, E = h.shape
     H, Hkv, D = cfg.n_head, cfg.n_kv_head, cfg.head_dim
     from dlrover_tpu.accelerate.remat import ATTN_IN, keep
+    from dlrover_tpu.ops.flash_attention import wide_form, wide_head_size
 
+    wide = wide_form(attn_fn) if wide_head_size(D) else None
     # Named for remat="full" (accelerate/remat.py KEPT), before
     # the head repeat; ``att @ wo`` below is recomputed from the
-    # flash forward's kept output.
+    # flash forward's kept output. On the wide path q and k are named
+    # as the kernels read them, rotated, where nothing between the
+    # projection and the rotation needs the projection's own value
+    # for its backward (a norm does): the same bytes kept, and the
+    # backward does not rotate them a second time.
+    rotated = wide is not None and cos is not None and not cfg.qk_norm
     q, k, v = (
-        keep(h @ lp[w], ATTN_IN) for w in ("wq", "wk", "wv")
+        h @ lp[w] if rotated and w != "wv" else keep(h @ lp[w], ATTN_IN)
+        for w in ("wq", "wk", "wv")
     )
     if cfg.qkv_bias:
         q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
     if cfg.qk_norm:
         q = _rms_norm(q, lp["q_norm"], cfg.rms_eps)
         k = _rms_norm(k, lp["k_norm"], cfg.rms_eps)
+    if wide is not None:
+        from dlrover_tpu.ops.rope import rope_wide
+
+        def attend(q, k, v, wo):
+            if cos is not None:
+                q = rope_wide(q, cos, sin, H)
+                k = rope_wide(k, cos, sin, Hkv)
+            if rotated:
+                q, k = keep(q, ATTN_IN), keep(k, ATTN_IN)
+            return wide(q, k, v, n_head=H, n_kv_head=Hkv) @ wo
+
+        # A call of its own (``jax.jit``), as models/moe._sorted_moe's
+        # and for its reason: where a block under ``jax.checkpoint``
+        # itself consumes a kept value it rounds it once more, and
+        # ``o`` as the kernel wrote it has no neighbour on the chip to
+        # fuse that into (the 4-D entry's transposition was one): a
+        # pass of its own over ``o``. Kept and consumed inside a call
+        # it is left as it is.
+        return jax.jit(attend)(q, k, v, lp["wo"])
     q = q.reshape(B, T, H, D)
     k = k.reshape(B, T, Hkv, D)
     v = v.reshape(B, T, Hkv, D)
@@ -422,7 +456,8 @@ def attention_half(h, lp, cfg: LlamaConfig, attn_fn, cos, sin):
         # group. GQA-aware attention (the seq-parallel
         # constructors) takes the COMPACT k/v instead — the
         # ring/a2a then move 1/q_per_kv the bytes and broadcast
-        # per block on-device.
+        # per block on-device; the flash kernels' wide entry above
+        # has it by construction, the group in a block's index.
         k = jnp.repeat(k, cfg.q_per_kv, axis=2)
         v = jnp.repeat(v, cfg.q_per_kv, axis=2)
     att = attn_fn(q, k, v).reshape(B, T, H * D)

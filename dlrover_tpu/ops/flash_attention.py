@@ -9,10 +9,28 @@ one Pallas kernel family designed for the MXU:
   running (max, sum, acc) per query block in VMEM scratch that persists
   across the sequential kv grid dimension (a sequence of one kv block
   carries nothing and leaves the scratch alone).
-* layout-native: kernels block directly over the model's
-  [batch, seq, heads, head_dim] arrays (grid over batch x heads), so no
-  HBM transpose/reshape passes are spent on either side of the call —
-  measured ~0.7ms/layer of pure relayout traffic saved at GPT-2 size.
+* two entries, one set of kernels. :func:`flash_attention` takes
+  [batch, seq, heads, head_dim] and hands the kernels [B, H, T, D]
+  (grid over batch x heads): a transposition of every operand in
+  front of the call and of ``o`` behind it, and on the chip already
+  the [B, T, H, D] view of a projection's [B, T, H*D] is a copy of
+  the whole array. :func:`flash_attention_wide` takes q [B, T, H*D]
+  and k, v [B, T, Hkv*D] where the projections wrote them and returns
+  ``o`` where the out-projection reads it: a head is a column block
+  of ``D`` lanes (so ``D`` is a multiple of 128: :func:`wide_head_size`),
+  query head ``h`` reads key-value head ``h // (H // Hkv)`` through
+  the block's index, and nothing is transposed, repeated or viewed on
+  either side of the call, forward or backward (``dk`` and ``dv``
+  leave the backward kernel a query head each and one small kernel,
+  ``flash_group_sum``, adds a group's column slabs). Grouped queries
+  are the wide entry's by construction: what ``supports_gqa`` says of
+  the sequence-parallel attention functions (compact k and v in, the
+  broadcast per block on the device) it has through the index map.
+  The kernels' bodies, blocks and names are the same in both
+  (:func:`_layout`); ``models/llama.attention_half`` chooses by what
+  it can see, the attention function and the head size
+  (:func:`wide_form`). Head sizes 64 (two heads a block) and latent
+  attention's 192 still take the 4-D entry.
 * bf16 inputs feed the 128x128 MXU; all softmax statistics and
   accumulators are float32; the forward's running stats are
   [block_q, 1] columns in VMEM (one lane, not lane-replicated tiles).
@@ -183,6 +201,43 @@ def _dispatch_block(iq, jk, accumulate, *, causal, pad, block_q,
 
 
 # ---------------------------------------------------------------------------
+# The operands' two layouts
+# ---------------------------------------------------------------------------
+
+
+def _layout(q, k, head_dim):
+    """Where a head's rows lie in the kernels' operands: ``(batch,
+    query heads, query rows, q's and k's head size, q_at, k_at,
+    shape)``. ``q_at(b, h, i)`` / ``k_at(b, h, j)`` are the block
+    indices of query head ``h``'s row block in a query-side array
+    (q, o, do, dq, and dk and dv, which leave the backward a query
+    head each) and in a key-side one (k, v); ``shape(t, d)`` is a
+    query-side array of ``t`` rows and head size ``d``.
+
+    ``head_dim`` None: ``[B, H, T, D]``, a head a block of dim 1.
+    Else the model's own layout under one more unit dim,
+    ``[B, 1, T, H*D]`` and ``[B, 1, T, Hkv*D]`` (the projections'
+    ``[B, T, H*D]`` with nothing moved): a head is a column block of
+    ``head_dim`` lanes, and a query head reads the key-value head of
+    its group, so k and v are never written ``H`` wide. The blocks
+    have the same rank and sizes either way and the kernels' bodies
+    cannot tell."""
+    b, h, tq, d = q.shape
+    if head_dim is None:
+        def at(b, h, t):
+            return b, h, t, 0
+
+        return b, h, tq, d, at, at, lambda t, d: (b, h, t, d)
+    h, group = d // head_dim, d // k.shape[3]
+    return (
+        b, h, tq, head_dim,
+        lambda b, h, t: (b, 0, t, h),
+        lambda b, h, t: (b, 0, t, h // group),
+        lambda t, d: (b, 1, t, h * d),
+    )
+
+
+# ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
 
@@ -285,16 +340,18 @@ def _fwd_kernel(
 
 
 def _fwd(q, k, v, causal, window, scale, block_q, block_k, seq_len,
-         interpret, q_offset=0):
+         interpret, q_offset=0, head_dim=None):
     """q: [B, H, Tq, D]; k/v: [B, H, Tk, D] (each padded to its block
     multiple — Tq == Tk for the square call). Returns (o [B,H,Tq,D],
     lse [B,H,1,Tq]). ``seq_len`` is the true KEY length: keys beyond
     it are masked out. ``q_offset`` is the global position of q row 0
     (causal/window comparisons happen in key coordinates). ``v`` may
     have a head size of its own (latent attention's 192 and 128): the
-    output has v's, and nothing else in the kernel reads a size."""
-    b, h, tq, d = q.shape
-    tk, dv = k.shape[2], v.shape[3]
+    output has v's, and nothing else in the kernel reads a size.
+    With ``head_dim`` the operands and ``o`` are :func:`_layout`'s
+    wide ones; ``lse`` is the same row either way."""
+    b, h, tq, d, q_at, k_at, shape = _layout(q, k, head_dim)
+    tk, dv = k.shape[2], head_dim or v.shape[3]
     num_q = tq // block_q
     num_kv = tk // block_k
     kernel = functools.partial(
@@ -314,20 +371,20 @@ def _fwd(q, k, v, causal, window, scale, block_q, block_k, seq_len,
         grid=(b, h, num_q, num_kv),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d),
-                         lambda b, h, i, j: (b, h, i, 0)),
+                         lambda b, h, i, j: q_at(b, h, i)),
             pl.BlockSpec((1, 1, block_k, d),
-                         lambda b, h, i, j: (b, h, j, 0)),
+                         lambda b, h, i, j: k_at(b, h, j)),
             pl.BlockSpec((1, 1, block_k, dv),
-                         lambda b, h, i, j: (b, h, j, 0)),
+                         lambda b, h, i, j: k_at(b, h, j)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_q, dv),
-                         lambda b, h, i, j: (b, h, i, 0)),
+                         lambda b, h, i, j: q_at(b, h, i)),
             pl.BlockSpec((1, 1, block_q, 1),
                          lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, tq, dv), q.dtype),
+            jax.ShapeDtypeStruct(shape(tq, dv), q.dtype),
             jax.ShapeDtypeStruct((b, h, tq, 1), jnp.float32),
         ],
         scratch_shapes=[
@@ -613,10 +670,11 @@ def _bwd_kernel(
 
 def _bwd(
     q, k, v, o, lse, do, causal, window, scale, block_q, block_k,
-    seq_len, interpret, g_lse=None, q_offset=0,
+    seq_len, interpret, g_lse=None, q_offset=0, head_dim=None,
 ):
-    b, h, tq, d = q.shape
-    tk, dv = k.shape[2], v.shape[3]  # v, o and do have v's head size
+    b, h, tq, d, q_at, k_at, shape = _layout(q, k, head_dim)
+    # v, o and do have v's head size
+    tk, dv = k.shape[2], head_dim or v.shape[3]
     num_q = tq // block_q
     num_kv = tk // block_k
     pad = seq_len < tk
@@ -627,9 +685,8 @@ def _bwd(
         **bwd_area(tq, tk, block_q, block_k, sub, causal, window,
                    seq_len, q_offset),
     )
-    delta = jnp.sum(
-        do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
-    )[:, :, None]  # [B, H, 1, T] like lse; XLA fuses this rowsum
+    # [B, H, 1, T] like lse; XLA fuses this rowsum
+    delta = _head_sums(do, o, head_dim)[:, :, None]
     if g_lse is not None:
         # lse cotangent: dlse/ds_j = p_j, so dS gains p * g_lse — the
         # same rank-1 shape as the delta term, folded in host-side.
@@ -654,29 +711,30 @@ def _bwd(
         grid=(b, h, num_kv, num_q),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d),
-                         lambda b, h, j, i: (b, h, i, 0)),
+                         lambda b, h, j, i: q_at(b, h, i)),
             pl.BlockSpec((1, 1, block_k, d),
-                         lambda b, h, j, i: (b, h, j, 0)),
+                         lambda b, h, j, i: k_at(b, h, j)),
             pl.BlockSpec((1, 1, block_k, dv),
-                         lambda b, h, j, i: (b, h, j, 0)),
+                         lambda b, h, j, i: k_at(b, h, j)),
             pl.BlockSpec((1, 1, block_q, dv),
-                         lambda b, h, j, i: (b, h, i, 0)),
+                         lambda b, h, j, i: q_at(b, h, i)),
             pl.BlockSpec((1, 1, 1, block_q),
                          lambda b, h, j, i: (b, h, 0, i)),
             pl.BlockSpec((1, 1, 1, block_q),
                          lambda b, h, j, i: (b, h, 0, i)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, tq, d), lambda b, h, j, i: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, tq, d), lambda b, h, j, i: q_at(b, h, 0)),
+            # dk and dv a query head each, where the head's q lies.
             pl.BlockSpec((1, 1, block_k, d),
-                         lambda b, h, j, i: (b, h, j, 0)),
+                         lambda b, h, j, i: q_at(b, h, j)),
             pl.BlockSpec((1, 1, block_k, dv),
-                         lambda b, h, j, i: (b, h, j, 0)),
+                         lambda b, h, j, i: q_at(b, h, j)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, tq, d), q.dtype),
-            jax.ShapeDtypeStruct((b, h, tk, d), k.dtype),
-            jax.ShapeDtypeStruct((b, h, tk, dv), v.dtype),
+            jax.ShapeDtypeStruct(shape(tq, d), q.dtype),
+            jax.ShapeDtypeStruct(shape(tk, d), k.dtype),
+            jax.ShapeDtypeStruct(shape(tk, dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((tq, d), jnp.float32),
@@ -696,11 +754,91 @@ def _bwd(
         # encloses the call (remat, shard_map, a scope).
         name="flash_attention_bwd",
     )(q, k, v, do, lse, delta)
-    return dq, dk, dv
+    if head_dim is None:
+        return dq, dk, dv
+    group = q.shape[3] // k.shape[3]
+    return (dq, *_group_sums((dk, dv), group, d, interpret))
+
+
+def _head_sums(do, o, head_dim):
+    """``delta``'s rows ``[B, H, T]``: the sum of ``do * o`` (float32)
+    over each head's columns. The operands ``[B, H, T, D]``, or with
+    ``head_dim`` the wide ``[B, 1, T, H*D]``: there a head's sum is a
+    product with the heads' constant 0/1 membership ``[H*D, H]``
+    (``models/kimi_linear._per_head``'s way), float32 at precision
+    ``highest``, which XLA fuses with the multiply into one pass over
+    ``do`` and ``o`` where they lie; a ``[B, T, H, D]`` view of such an
+    array is on the chip a copy of all of it, and slices a head are a
+    fusion a head."""
+    prod = do.astype(jnp.float32) * o.astype(jnp.float32)
+    if head_dim is None:
+        return jnp.sum(prod, axis=-1)
+    e = prod.shape[3]
+    member = (
+        jnp.arange(e)[:, None] // head_dim == jnp.arange(e // head_dim)
+    ).astype(jnp.float32)
+    return jnp.einsum(
+        "bte,eh->bht", prod[:, 0], member,
+        precision=jax.lax.Precision.HIGHEST,
+    )
+
+
+def row_block(t: int) -> int:
+    """Rows a grid step of a kernel that streams an array once
+    (``flash_group_sum``, ``ops/rope.rope_wide``): the largest power
+    of two up to 1024 that divides ``t``, or all of them."""
+    return next((r for r in (1024, 512, 256, 128, 64, 32, 16, 8)
+                 if t % r == 0), t)
+
+
+def _group_sum_kernel(*refs, group, d):
+    """Each input block holds a key-value head's ``group`` query
+    heads' shares side by side: their sum, float32, rounded once."""
+    n = len(refs) // 2
+    for x_ref, y_ref in zip(refs[:n], refs[n:]):
+        total = x_ref[0, 0, :, pl.ds(0, d)].astype(jnp.float32)
+        for r in range(1, group):
+            total += x_ref[0, 0, :, pl.ds(r * d, d)].astype(jnp.float32)
+        y_ref[0, 0] = total.astype(y_ref.dtype)
+
+
+def _group_sums(xs, group, d, interpret):
+    """The wide backward's ``dk`` and ``dv``, each ``[B, 1, T, H*d]``
+    with a query head's share in the head's columns, summed over each
+    key-value head's ``group`` query heads: ``[B, 1, T, Hkv*d]``. A
+    group's shares lie side by side, slabs of whole lanes, so one
+    kernel reads each array once and writes it ``Hkv`` wide (the sum
+    in float32, rounded once, as XLA sums a repeat's cotangent).
+    Written as slices and a ``concatenate`` XLA makes a fusion a
+    key-value head of it and a pass that joins them."""
+    if group == 1:
+        return xs
+    b, _, t, e = xs[0].shape
+    rows = row_block(t)
+    return pl.pallas_call(
+        functools.partial(_group_sum_kernel, group=group, d=d),
+        grid=(b, t // rows, e // (group * d)),
+        in_specs=[
+            pl.BlockSpec((1, 1, rows, group * d),
+                         lambda b, i, g: (b, 0, i, g))
+        ] * len(xs),
+        out_specs=[
+            pl.BlockSpec((1, 1, rows, d), lambda b, i, g: (b, 0, i, g))
+        ] * len(xs),
+        out_shape=[
+            jax.ShapeDtypeStruct((b, 1, t, e // group), x.dtype)
+            for x in xs
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel")
+        ),
+        interpret=interpret,
+        name="flash_group_sum",
+    )(*xs)
 
 
 # ---------------------------------------------------------------------------
-# custom_vjp plumbing on the [B, H, T, D] layout
+# custom_vjp plumbing, on either layout (``head_dim``: :func:`_layout`)
 # ---------------------------------------------------------------------------
 
 
@@ -713,7 +851,9 @@ def _kept(o, lse):
     reads it, ``[B, H, 1, T]`` with the rows along the lanes (0.9 MB
     a layer at GPT-2's shape; a ``[B, H, T, 1]`` column is padded to
     113). ``o`` is kept as
-    the kernel wrote it where the head size fills the lanes; at a
+    the kernel wrote it where the head size fills the lanes (on the
+    wide entry that is ``[B, 1, T, H*D]``, the model's own layout:
+    :func:`_kernel_layout` has nothing to do there); at a
     smaller head size ``[B, H, T, D]`` is padded too (twice the bytes
     at 64: 0.34 GB of GPT-2's step, and slower than the transposition
     it saves), so there it is kept in the model's layout
@@ -738,12 +878,13 @@ def _kernel_layout(kept_o, h):
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11, 12)
+    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11, 12, 13)
 )
 def _flash(q, k, v, causal, window, scale, block_q, block_k,
-           block_q_bwd, block_k_bwd, seq_len, interpret, q_offset=0):
+           block_q_bwd, block_k_bwd, seq_len, interpret, q_offset=0,
+           head_dim=None):
     o, lse = _fwd(q, k, v, causal, window, scale, block_q, block_k,
-                  seq_len, interpret, q_offset)
+                  seq_len, interpret, q_offset, head_dim)
     # Named here too: the forward rule is traced only later, under
     # differentiation, and the ``remat.kept`` event reads the names
     # while the block is traced.
@@ -752,10 +893,10 @@ def _flash(q, k, v, causal, window, scale, block_q, block_k,
 
 def _flash_fwd(q, k, v, causal, window, scale, block_q, block_k,
                block_q_bwd, block_k_bwd, seq_len, interpret,
-               q_offset=0):
+               q_offset=0, head_dim=None):
     o, lse = _fwd(
         q, k, v, causal, window, scale, block_q, block_k, seq_len,
-        interpret, q_offset
+        interpret, q_offset, head_dim
     )
     # The primal output and the residuals are the kept values, so a
     # block under remat="full" hands them to the backward as they are
@@ -765,12 +906,13 @@ def _flash_fwd(q, k, v, causal, window, scale, block_q, block_k,
 
 
 def _flash_bwd(causal, window, scale, block_q, block_k, block_q_bwd,
-               block_k_bwd, seq_len, interpret, q_offset, res, g):
+               block_k_bwd, seq_len, interpret, q_offset, head_dim,
+               res, g):
     q, k, v, kept_o, kept_lse = res
     return _bwd(
         q, k, v, _kernel_layout(kept_o, q.shape[1]), kept_lse, g,
         causal, window, scale, block_q_bwd, block_k_bwd, seq_len,
-        interpret, q_offset=q_offset,
+        interpret, q_offset=q_offset, head_dim=head_dim,
     )
 
 
@@ -778,53 +920,65 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11, 12)
+    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11, 12, 13)
 )
 def _flash_lse(q, k, v, causal, window, scale, block_q, block_k,
                block_q_bwd, block_k_bwd, seq_len, interpret,
-               q_offset=0):
+               q_offset=0, head_dim=None):
     """Like _flash but also returns the per-row logsumexp — the
     ingredient ring attention needs to merge normalized block outputs
     across devices (parallel/ring_attention.py)."""
     return _fwd(
         q, k, v, causal, window, scale, block_q, block_k, seq_len,
-        interpret, q_offset
+        interpret, q_offset, head_dim
     )
 
 
 def _flash_lse_fwd(q, k, v, causal, window, scale, block_q, block_k,
                    block_q_bwd, block_k_bwd, seq_len, interpret,
-                   q_offset=0):
+                   q_offset=0, head_dim=None):
     o, lse = _fwd(
         q, k, v, causal, window, scale, block_q, block_k, seq_len,
-        interpret, q_offset
+        interpret, q_offset, head_dim
     )
     return (o, lse), (q, k, v, o, lse)
 
 
 def _flash_lse_bwd(causal, window, scale, block_q, block_k,
                    block_q_bwd, block_k_bwd, seq_len, interpret,
-                   q_offset, res, g):
+                   q_offset, head_dim, res, g):
     g_o, g_lse = g
     q, k, v, o, lse = res
     return _bwd(
         q, k, v, o, lse, g_o, causal, window, scale, block_q_bwd,
         block_k_bwd, seq_len, interpret, g_lse=g_lse,
-        q_offset=q_offset,
+        q_offset=q_offset, head_dim=head_dim,
     )
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
-def _per_device_flash(flash, q, k, v, static):
+def _per_device_flash(flash, q, k, v, static, head_dim=None):
     """``_flash``/``_flash_lse`` on [B, H, T, D] operands, batch rows
     and heads split over the ambient mesh (:func:`per_device`). The
     whole custom_vjp sits inside the shard_map, so the backward
-    kernel is split with it."""
+    kernel is split with it. With ``head_dim`` the operands are the
+    wide ones, ``[B, 1, T, H*D]`` and ``[B, 1, T, Hkv*D]``: the heads
+    are then split along the columns, whole heads of q and of k and v
+    to a device, and a device's query heads find their groups'
+    key-value heads among its own."""
+    if head_dim is None:
+        return per_device(
+            lambda q, k, v: flash(q, k, v, *static),
+            q, k, v, split=(True, True, True), heads_dim=1,
+        )
     return per_device(
-        lambda q, k, v: flash(q, k, v, *static),
-        q, k, v, split=(True, True, True), heads_dim=1,
+        lambda q, k, v: flash(q, k, v, *static, 0, head_dim),
+        q, k, v, split=(True, True, True), heads_dim=3,
+        head_size=head_dim,
+        # ``lse`` is a row a head, [B, H, 1, T], in either layout.
+        out_heads_dims=(3, 1) if flash is _flash_lse else None,
     )
 
 
@@ -880,6 +1034,69 @@ def default_block_sizes(t: int) -> tuple:
     return bq, bk
 
 
+def _square_plan(t, d, causal, window, scale, block_q, block_k,
+                 block_q_bwd, block_k_bwd):
+    """What a square call settles before it touches an operand, for
+    ``t`` tokens and heads of ``d``: ``(window, fold, scale, blocks,
+    pad)``. ``window`` checked, and None where the band covers the
+    sequence; ``fold`` a factor to multiply q by outside the kernel
+    (else None) and ``scale`` what the kernel then applies; ``blocks``
+    the four block sizes; ``pad`` the rows that bring ``t`` to a
+    multiple of every block."""
+    if window is not None:
+        if not causal:
+            raise ValueError(
+                "window (sliding-window attention) requires causal=True"
+            )
+        if window < 1:
+            raise ValueError(f"window must be >= 1, got {window}")
+        if window >= t:
+            window = None  # band covers the whole sequence: plain causal
+    if scale is None:
+        scale = 1.0 / (d**0.5)
+    # Power-of-2 scales (every power-of-4 head_dim, e.g. 64 -> 1/8)
+    # multiply exactly in any float dtype, so fold them into q outside
+    # the kernel: XLA fuses the multiply into the surrounding
+    # transpose/pad, the kernel's `s * scale` pass over each
+    # [block_q, block_k] tile disappears (scale==1.0 folds at trace
+    # time), and autodiff routes the q-gradient scale through this
+    # multiply.
+    fold = None
+    if scale != 1.0 and math.frexp(scale)[0] == 0.5:
+        fold, scale = scale, 1.0
+    # A requested block larger than the sequence means "one tile
+    # spanning the whole (padded) sequence". Clamp those to the padded
+    # length implied by the in-range blocks — that adds no padding and
+    # always satisfies the divisibility-chain guard below, unlike
+    # clamping to t itself (block_k=1024 at t=520 -> 520 used to trip
+    # the guard for a call that tuned fine at longer sequences).
+    cap = max(t, 8)
+    dq_, dk_ = default_block_sizes(t)
+    req_q = dq_ if block_q is None else block_q
+    req_k = dk_ if block_k is None else block_k
+    req_qb = req_q if block_q_bwd is None else block_q_bwd
+    req_kb = req_k if block_k_bwd is None else block_k_bwd
+    reqs = (req_q, req_k, req_qb, req_kb)
+    in_range = [r for r in reqs if r <= cap]
+    # Guard the in-range blocks BEFORE substituting padded_base (a
+    # multiple of their lcm): the substitution makes padded_base the
+    # max of the final block set, so the post-substitution check alone
+    # can never fire for coprime in-range blocks — e.g. bq=512,
+    # bqb=384, bk=1024 at t=520 must be rejected, not silently padded
+    # 520 -> 1536 (~3x kernel work).
+    unit = _check_block_chain(in_range, t) if in_range else 1
+    padded_base = max(8, math.ceil(t / unit) * unit)
+    blocks = tuple(r if r <= cap else padded_base for r in reqs)
+    # Pad so the padded length is divisible by EVERY block size (lcm),
+    # otherwise the floor-divided grids would silently drop tail
+    # blocks. Inflation protection lives entirely in the
+    # pre-substitution check above: after substitution padded_base is
+    # a multiple of lcm(in_range) and the max of the set, so this lcm
+    # equals padded_base (or lcm(in_range) when nothing was
+    # substituted) and cannot explode.
+    return window, fold, scale, blocks, (-t) % math.lcm(*blocks)
+
+
 def flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -928,62 +1145,12 @@ def flash_attention(
     if interpret is None:
         interpret = use_interpret()
     b, t, h, d = q.shape
-    if window is not None:
-        if not causal:
-            raise ValueError(
-                "window (sliding-window attention) requires causal=True"
-            )
-        if window < 1:
-            raise ValueError(f"window must be >= 1, got {window}")
-        if window >= t:
-            window = None  # band covers the whole sequence: plain causal
-    if scale is None:
-        scale = 1.0 / (d**0.5)
-    # Power-of-2 scales (every power-of-4 head_dim, e.g. 64 -> 1/8)
-    # multiply exactly in any float dtype, so fold them into q outside
-    # the kernel: XLA fuses the multiply into the surrounding
-    # transpose/pad, the kernel's `s * scale` pass over each
-    # [block_q, block_k] tile disappears (scale==1.0 folds at trace
-    # time), and autodiff routes the q-gradient scale through this
-    # multiply.
-    if scale != 1.0 and math.frexp(scale)[0] == 0.5:
-        q = q * jnp.asarray(scale, q.dtype)
-        scale = 1.0
-    # A requested block larger than the sequence means "one tile
-    # spanning the whole (padded) sequence". Clamp those to the padded
-    # length implied by the in-range blocks — that adds no padding and
-    # always satisfies the divisibility-chain guard below, unlike
-    # clamping to t itself (block_k=1024 at t=520 -> 520 used to trip
-    # the guard for a call that tuned fine at longer sequences).
-    cap = max(t, 8)
-    dq_, dk_ = default_block_sizes(t)
-    req_q = dq_ if block_q is None else block_q
-    req_k = dk_ if block_k is None else block_k
-    req_qb = req_q if block_q_bwd is None else block_q_bwd
-    req_kb = req_k if block_k_bwd is None else block_k_bwd
-    reqs = (req_q, req_k, req_qb, req_kb)
-    in_range = [r for r in reqs if r <= cap]
-    # Guard the in-range blocks BEFORE substituting padded_base (a
-    # multiple of their lcm): the substitution makes padded_base the
-    # max of the final block set, so the post-substitution check alone
-    # can never fire for coprime in-range blocks — e.g. bq=512,
-    # bqb=384, bk=1024 at t=520 must be rejected, not silently padded
-    # 520 -> 1536 (~3x kernel work).
-    unit = _check_block_chain(in_range, t) if in_range else 1
-    padded_base = max(8, math.ceil(t / unit) * unit)
-    block_q, block_k, block_q_bwd, block_k_bwd = (
-        r if r <= cap else padded_base for r in reqs
+    window, fold, scale, blocks, pad = _square_plan(
+        t, d, causal, window, scale, block_q, block_k, block_q_bwd,
+        block_k_bwd,
     )
-
-    # Pad so the padded length is divisible by EVERY block size (lcm),
-    # otherwise the floor-divided grids would silently drop tail
-    # blocks. Inflation protection lives entirely in the
-    # pre-substitution check above: after substitution padded_base is
-    # a multiple of lcm(in_range) and the max of the set, so this lcm
-    # equals padded_base (or lcm(in_range) when nothing was
-    # substituted) and cannot explode.
-    blocks = (block_q, block_k, block_q_bwd, block_k_bwd)
-    pad = (-t) % math.lcm(*blocks)
+    if fold is not None:
+        q = q * jnp.asarray(fold, q.dtype)
 
     def to_kernel_layout(x):
         x = jnp.transpose(x, (0, 2, 1, 3))  # [B,H,T,D]
@@ -992,10 +1159,7 @@ def flash_attention(
         return x
 
     qk, kk, vk = map(to_kernel_layout, (q, k, v))
-    static = (
-        causal, window, scale, block_q, block_k, block_q_bwd,
-        block_k_bwd, t, interpret,
-    )
+    static = (causal, window, scale, *blocks, t, interpret)
     if return_lse:
         o, lse = _per_device_flash(_flash_lse, qk, kk, vk, static)
         o = o[:, :, :t].transpose(0, 2, 1, 3)
@@ -1003,6 +1167,99 @@ def flash_attention(
     o = _per_device_flash(_flash, qk, kk, vk, static)
     o = o[:, :, :t].transpose(0, 2, 1, 3)
     return o.astype(q.dtype)
+
+
+def flash_attention_wide(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    *,
+    n_head: int,
+    n_kv_head: Optional[int] = None,
+    causal: bool = True,
+    scale: Optional[float] = None,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
+    block_q_bwd: Optional[int] = None,
+    block_k_bwd: Optional[int] = None,
+    interpret: Optional[bool] = None,
+    return_lse: bool = False,
+    window: Optional[int] = None,
+) -> "jax.Array | tuple[jax.Array, jax.Array]":
+    """:func:`flash_attention` on the layout the projections write and
+    the out-projection reads: q ``[B, T, H*D]``, k and v
+    ``[B, T, Hkv*D]``, the result ``[B, T, H*D]`` (with
+    ``return_lse`` also ``[B, H, T]``, as there).
+
+    The same kernels, bodies and blocks; only where a block lies
+    differs (:func:`_layout`): a head is a column block of ``D``
+    lanes of the array as it is, so ``D`` is a multiple of 128
+    (:func:`wide_head_size` asks), and nothing is transposed into or
+    out of a kernel's layout on either side of the call, forward or
+    backward. Grouped queries are this entry's by construction: query
+    head ``h`` reads key-value head ``h // (H // Hkv)`` through the
+    block's index, k and v are never repeated to the query heads, and
+    ``dk`` and ``dv`` come back ``Hkv`` wide (the kernel writes a
+    query head's share each; a group's shares are summed in one
+    fused add of column slabs). ``remat="full"`` keeps ``o`` as the
+    kernel wrote it, which is the model's layout. Event
+    ``flash.wide`` says, once a traced call, that this entry ran."""
+    if interpret is None:
+        interpret = use_interpret()
+    b, t, e = q.shape
+    n_kv_head = n_kv_head or n_head
+    d = e // n_head
+    if not wide_head_size(d) or e != n_head * d or n_head % n_kv_head:
+        raise ValueError(
+            f"flash_attention_wide reads a head as a block of whole "
+            f"lanes: {n_head} heads of {e} columns over {n_kv_head} "
+            f"key-value heads are not heads of a multiple of {_LANES}"
+        )
+    if k.shape != (b, t, n_kv_head * d) or v.shape != k.shape:
+        raise ValueError(
+            f"k {k.shape} and v {v.shape} are not [B, T, Hkv*D] = "
+            f"{(b, t, n_kv_head * d)}"
+        )
+    obs.event("flash.wide", heads=n_head, kv_heads=n_kv_head, head_dim=d)
+    window, fold, scale, blocks, pad = _square_plan(
+        t, d, causal, window, scale, block_q, block_k, block_q_bwd,
+        block_k_bwd,
+    )
+    if fold is not None:
+        q = q * jnp.asarray(fold, q.dtype)
+
+    def to_kernel_layout(x):
+        if pad:
+            x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
+        return x[:, None]  # one more unit dim: nothing moves
+
+    qk, kk, vk = map(to_kernel_layout, (q, k, v))
+    static = (causal, window, scale, *blocks, t, interpret)
+    if return_lse:
+        o, lse = _per_device_flash(_flash_lse, qk, kk, vk, static, d)
+        return o[:, 0, :t].astype(q.dtype), lse[:, :, 0, :t]
+    o = _per_device_flash(_flash, qk, kk, vk, static, d)
+    return o[:, 0, :t].astype(q.dtype)
+
+
+def wide_head_size(head_dim: int) -> bool:
+    """Whether :func:`flash_attention_wide` can read heads of this
+    size where they lie: a column block of ``[B, T, H*D]`` is whole
+    lanes. (64, two heads a block, and latent attention's 192 are not
+    written yet: such callers keep the ``[B, T, H, D]`` entry.)"""
+    return head_dim % _LANES == 0
+
+
+def wide_form(attn_fn):
+    """:func:`flash_attention_wide` with ``attn_fn``'s keywords bound,
+    where ``attn_fn`` is :func:`flash_attention` with keywords bound
+    (what ``gpt.default_attention_for`` returns and a family binds a
+    window to); None for any other attention function, which takes
+    ``[B, T, H, D]``."""
+    if isinstance(attn_fn, functools.partial) and not attn_fn.args:
+        if attn_fn.func is flash_attention:
+            return functools.partial(flash_attention_wide, **attn_fn.keywords)
+    return None
 
 
 def flash_attention_rect(

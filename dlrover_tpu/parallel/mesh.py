@@ -251,8 +251,8 @@ def batch_axes(rows: int):
     return tuple(batch), rows
 
 
-def per_device(call, *operands, split, heads_dim=None, summed=(),
-               manual_all=True):
+def per_device(call, *operands, split, heads_dim=None, head_size=1,
+               out_heads_dims=None, summed=(), manual_all=True):
     """``call(*operands)``, run once per device of the mesh the trace
     is under.
 
@@ -263,11 +263,15 @@ def per_device(call, *operands, split, heads_dim=None, summed=(),
     every head, on its own, so under an ambient mesh (``jax.set_mesh``
     or the step builders' :func:`under_mesh`) the call goes
     through ``shard_map``: dim 0 of each operand flagged in ``split``
-    over the batch axes, dim ``heads_dim`` over ``tensor``, the other
-    operands (weights) and dims whole on every device; outputs are
-    split like the operands, and autodiff sums the weights'
-    gradients over the mesh. An axis that does not divide its dim is
-    left out, and XLA gathers that dim instead.
+    over the batch axes, dim ``heads_dim`` over ``tensor`` (it holds
+    heads of ``head_size`` entries each, every split operand a count
+    of its own, and is split only into whole heads of all of them),
+    the other operands (weights) and dims whole on every device;
+    outputs are split like the operands (``out_heads_dims``: the
+    heads' dim of each output, where it is not the operands'), and
+    autodiff sums the weights' gradients over the mesh. An axis that
+    does not divide its dim is left out, and XLA gathers that dim
+    instead.
 
     ``summed`` flags outputs (``call`` then returns a tuple) that are
     one device's share of a sum over the batch rows, a weight's
@@ -285,16 +289,28 @@ def per_device(call, *operands, split, heads_dim=None, summed=(),
         return call(*operands)
     shape = operands[split.index(True)].shape
     batch, _ = batch_axes(shape[0])
-    dims = [batch or None]
+    heads = False
     if heads_dim is not None:
         n = mesh.shape.get(_HEAD_AXIS, 1)
-        heads = n > 1 and shape[heads_dim] % n == 0
-        dims += [None] * (heads_dim - 1)
-        dims.append(_HEAD_AXIS if heads else None)
-    if all(d is None for d in dims):
+        heads = n > 1 and all(
+            op.shape[heads_dim] // head_size % n == 0
+            for op, s in zip(operands, split) if s
+        )
+    if not batch and not heads:
         return call(*operands)
-    spec = P(*dims)
+
+    def spec_at(dim):
+        """Batch rows over the batch axes, dim ``dim`` over the heads'."""
+        if dim is None:
+            return P(batch or None)
+        return P(
+            batch or None, *[None] * (dim - 1), _HEAD_AXIS if heads else None
+        )
+
+    spec = spec_at(heads_dim)
     out_specs, body = spec, call
+    if out_heads_dims is not None:
+        out_specs = tuple(spec_at(dim) for dim in out_heads_dims)
     if summed:
         out_specs = tuple(P() if s else spec for s in summed)
 
@@ -305,7 +321,9 @@ def per_device(call, *operands, split, heads_dim=None, summed=(),
             )
 
     # No names: every mesh axis is manual, shard_map's default.
-    named = set() if manual_all else set(batch) | ({_HEAD_AXIS} & set(dims))
+    named = set()
+    if not manual_all:
+        named = set(batch) | ({_HEAD_AXIS} if heads else set())
     return jax.shard_map(
         body,
         in_specs=tuple(spec if s else P() for s in split),

@@ -129,7 +129,11 @@ def test_the_tool_prints_a_line_a_step(
     assert step_hash.main() == 0
     (line,) = capsys.readouterr().out.splitlines()
     said = json.loads(line)
-    assert said["step"] == "mistral" and said["custom_calls"] == 2
+    # One Mosaic call in the lowered text for each distinct traced call
+    # of a kernel: the flash forward and backward, and since PR 62 the
+    # rotation (q and k, forward and on the cotangents; the second
+    # layer's are the first's again) and the group sums.
+    assert said["step"] == "mistral" and said["custom_calls"] == 7
     assert said["bodies_naming_a_file"] == 0
     assert len(said["sha256"]) == 64 and said["bytes"] > 100_000
     assert said == {

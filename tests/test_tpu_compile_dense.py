@@ -7,6 +7,7 @@ from dlrover_tpu.models import llama
 from tests.tpu_steps import (  # noqa: F401 — the fixtures
     assert_fits_with_flash,
     assert_flash_forward_runs_once,
+    attn_relayouts,
     compiled_kernels,
     elastic_trainer_step,
     gpt2_step,
@@ -89,8 +90,8 @@ def test_mistral_train_step_has_no_layer_scan_on_one_chip(
     remat, ``ElasticTrainer``'s step. Both layers run in line
     (models/layers.py): no loop and no ``dynamic-slice`` stands under
     the ``layers`` scope, nothing is stacked ``[2, 1, 8192, ...]``,
-    the flash kernels are called once a layer. 10.8164 GB compiled
-    here, for the 14.4058 of the two-layer scan
+    the flash kernels are called once a layer. 10.4862 GB compiled
+    here since PR 62 (10.8164 before), for the 14.4058 of the two-layer scan
     (``peak_memory_in_bytes`` 10.33: ``temp_size_in_bytes``, which the
     reading sums, counts a scan's stacks above the step's peak)."""
     compiled = elastic_trainer_step(llama, mistral_cfg(), topo)
@@ -109,3 +110,13 @@ def test_mistral_train_step_has_no_layer_scan_on_one_chip(
     ]
     assert "[2,1,8192," not in compiled.as_text()
     assert step_gb(compiled) < 14.4058 * 1.02
+    # Attention's operands keep one layout (PR 62): from the
+    # projections to ``wo`` q, k, v, o and their gradients stay
+    # [B, T, H*D], the flash kernels read a head as a column block and
+    # a key-value head by the block's index, the rotation and the
+    # group sums are kernels of their own. Under ``/attn/`` no
+    # ``copy``, transposition, repeat, or half of a rotation of a
+    # k-sized array or larger is left: 32 before, none now.
+    assert not attn_relayouts(compiled.as_text(), 8192 * 1024)
+    assert "rope_wide" in compiled.as_text()
+    assert "flash_group_sum" in compiled.as_text()

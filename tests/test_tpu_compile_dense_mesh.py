@@ -12,6 +12,7 @@ from dlrover_tpu.models import gpt, llama
 from tests.tpu_steps import (  # noqa: F401 — the fixtures
     assert_fits_with_flash,
     assert_flash_forward_runs_once,
+    attn_relayouts,
     compiled_kernels,
     gpt2_step,
     mistral_cfg,
@@ -87,3 +88,14 @@ def test_mistral_block_keeps_flash_outputs_on_four_chips(
     assert_fits_with_flash(compiled)
     assert_flash_forward_runs_once(compiled, times=0, in_line=2)
     assert step_gb(compiled) < 7.2069 + 0.05
+    # Attention's operands keep one layout (PR 62): from the
+    # projections to ``wo`` q, k, v, o and their gradients stay
+    # [B, T, H*D], the flash kernels read a head as a column block and
+    # a key-value head by the block's index, the rotation and the
+    # group sums are kernels of their own. Under ``/attn/`` no
+    # ``copy``, transposition, repeat, or half of a rotation of a
+    # k-sized array or larger is left: 34 before, none now.
+    assert not attn_relayouts(compiled.as_text(), 8192 * 1024)
+    # The wide operands go through the kernels' ``shard_map`` a chip's
+    # batch rows each: the calls stand in the step, none is gathered.
+    assert "rope_wide" in compiled.as_text()

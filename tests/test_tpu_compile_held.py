@@ -7,6 +7,7 @@ import re
 from dlrover_tpu.models import deepseek_v2, mellum
 from tests.tpu_steps import (  # noqa: F401 — the fixtures
     assert_fits_with_flash,
+    attn_relayouts,
     compiled_kernels,
     deepseek_cfg,
     elastic_trainer_step,
@@ -54,10 +55,23 @@ def test_mellum_train_step_compiles_on_one_chip(topo, compiled_kernels):
     # The rows' sum is the kernel's, forward and (the rows' gather's
     # backward) in each layer's backward.
     assert calls("moe_rows_sum") == 8, calls("moe_rows_sum")
+    # Attention's operands keep one layout (PR 62): from the
+    # projections to ``wo`` q, k, v, o and their gradients stay
+    # [B, T, H*D], the flash kernels read a head as a column block and
+    # a key-value head by the block's index, the rotation and the
+    # group sums are kernels of their own. Under ``/attn/`` no
+    # ``copy``, transposition, repeat, or half of a rotation of a
+    # k-sized array or larger is left: 64 before, none now.
+    assert not attn_relayouts(text, 8192 * 512)
+    # A layer: q and k rotated forward, dq and dk back (kept rotated,
+    # not rotated again), dk and dv summed over a group's eight heads.
+    assert calls("rope_wide") == 16, calls("rope_wide")
+    assert calls("flash_group_sum") == 4, calls("flash_group_sum")
     mem = compiled.memory_analysis()
     total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     print("mellum step bytes", total, mem)
-    assert total / 1e9 < 9.7, total
+    # 8.515 since PR 62: k and v are never written 32 heads wide.
+    assert total / 1e9 < 8.6, total
 
 
 def test_deepseek_train_step_compiles_on_one_chip(topo, compiled_kernels):

@@ -8,6 +8,7 @@ from dlrover_tpu.models import granite_hybrid, kimi_linear, ouro
 from tests.tpu_steps import (  # noqa: F401 — the fixtures
     assert_fits_with_flash,
     assert_flash_forward_runs_once,
+    attn_relayouts,
     compiled_kernels,
     computations_calling,
     elastic_trainer_step,
@@ -68,6 +69,14 @@ def test_ouro_train_step_compiles_on_one_chip(topo, compiled_kernels):
     assert "tpu_custom_call" in compiled.as_text()
     assert_flash_forward_runs_once(compiled, times=cfg.ut_steps)
     assert f"[{cfg.ut_steps},{cfg.n_layer}," not in compiled.as_text()
+    # Attention's operands keep one layout (PR 62): from the
+    # projections to ``wo`` q, k, v, o and their gradients stay
+    # [B, T, H*D], the flash kernels read a head as a column block and
+    # a key-value head by the block's index, the rotation and the
+    # group sums are kernels of their own. Under ``/attn/`` no
+    # ``copy``, transposition, repeat, or half of a rotation of a
+    # k-sized array or larger is left: 48 before, none now.
+    assert not attn_relayouts(compiled.as_text(), 4096 * 2048)
     mem = compiled.memory_analysis()
     total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     # 17.194 GB here (17.586 with the layers' scan inside a scan over

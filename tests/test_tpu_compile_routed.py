@@ -4,6 +4,7 @@ held expert path's cells: tests/test_tpu_compile_held.py."""
 
 from tests.tpu_steps import (  # noqa: F401 — the fixtures
     assert_fits_with_flash,
+    attn_relayouts,
     compiled_kernels,
     computations_calling,
     olmoe_step,
@@ -33,6 +34,14 @@ def test_olmoe_train_step_compiles_on_one_chip(topo, compiled_kernels):
         if " reduce-precision(" in line and "= bf16[131072," in line
     ]
     assert step_gb(compiled) < 10.2454 + 0.05
+    # Attention's operands keep one layout (PR 62): from the
+    # projections to ``wo`` q, k, v, o and their gradients stay
+    # [B, T, H*D], the flash kernels read a head as a column block and
+    # a key-value head by the block's index, the rotation and the
+    # group sums are kernels of their own. Under ``/attn/`` no
+    # ``copy``, transposition, repeat, or half of a rotation of a
+    # k-sized array or larger is left: 12 before, none now.
+    assert not attn_relayouts(compiled.as_text(), 4 * 4096 * 2048)
 
 
 def test_olmoe_train_step_compiles_on_four_chips(topo, compiled_kernels):

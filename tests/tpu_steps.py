@@ -57,7 +57,7 @@ HBM_BYTES = 16e9  # one v5e chip
 # are given no ``interpret`` argument.
 _KERNEL_MODULES = (
     "flash_attention", "quantization", "grouped_matmul", "ssd",
-    "causal_conv", "kda", "rows_sum",
+    "causal_conv", "kda", "rows_sum", "rope",
 )
 
 
@@ -375,3 +375,31 @@ def whole_array_passes(text, elements):
             unnamed.append(line)
     return copies, unnamed
 
+
+
+def attn_relayouts(text, elements):
+    """The instructions under the ``attn`` scope, among those the chip
+    runs one by one, that move an array of ``elements`` or more
+    without computing on it: a ``copy``, or one whose ``op_name`` ends
+    in ``transpose``, ``broadcast_in_dim`` (k and v repeated to the
+    query heads), ``concatenate`` or ``slice`` (a rotation's halves).
+    A step whose attention operands keep one layout has none."""
+    fused = set(re.findall(r"fusion\([^\n]*calls=%([\w.-]+)", text))
+    found = []
+    for current, line in by_computation(text):
+        head = re.search(r"= \(?\w+\[([0-9,]+)\]\S* ([\w-]+)\(", line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if current.lstrip("%") in fused or head is None or name is None:
+            continue
+        if head.group(2) in ("get-tuple-element", "bitcast", "tuple"):
+            continue  # no instruction of the chip's
+        if "/attn/" not in name.group(1):
+            continue
+        if math.prod(map(int, head.group(1).split(","))) < elements:
+            continue
+        tail = name.group(1).rsplit("/", 1)[-1]
+        if head.group(2) == "copy" or tail in (
+            "transpose", "broadcast_in_dim", "concatenate", "slice"
+        ):
+            found.append(line)
+    return found

@@ -395,6 +395,69 @@ def flash_checks():
     check("flash_qk192_v128", two_head_sizes)
 
 
+def flash_wide_checks():
+    """The flash kernels on the layout the projections write
+    (``flash_attention_wide``, ``ops/rope.rope_wide``,
+    ``flash_group_sum``) against the 4-D entry with ``apply_rope`` and
+    the repeat in front of it, at Mistral's shape a head: 8 x SEQ
+    tokens, a window of half of them, groups of four heads of 128,
+    bf16. The same kernels on the same blocks; the rotated q and k
+    may differ in their last place (XLA keeps or drops the excess
+    precision inside ``apply_rope`` as it fuses), dq and dk pass
+    through ``delta``, summed in another order (a membership
+    product), and dk through a rotation after the group sum and not
+    before it."""
+    from dlrover_tpu.models import llama
+    from dlrover_tpu.ops.flash_attention import (
+        flash_attention,
+        flash_attention_wide,
+    )
+    from dlrover_tpu.ops.rope import rope_wide
+
+    t, h, hkv, d = 8 * SEQ, 8, 2, 128
+    keys = jax.random.split(jax.random.PRNGKey(62), 4)
+    q = jax.random.normal(keys[0], (1, t, h * d)).astype(jnp.bfloat16)
+    k, v = (
+        jax.random.normal(kk, (1, t, hkv * d)).astype(jnp.bfloat16)
+        for kk in keys[1:3]
+    )
+    w = jax.random.normal(keys[3], (1, t, h * d))
+    angle = jnp.arange(t)[:, None] * (
+        10000.0 ** (-jnp.arange(d // 2) / (d // 2))
+    )[None]
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    kw = dict(causal=True, window=t // 2)
+
+    def wide(q, k, v):
+        return flash_attention_wide(
+            rope_wide(q, cos, sin, h), rope_wide(k, cos, sin, hkv), v,
+            n_head=h, n_kv_head=hkv, **kw,
+        )
+
+    def four_d(q, k, v):
+        q4 = llama.apply_rope(q.reshape(1, t, h, d), cos, sin)
+        k4 = llama.apply_rope(k.reshape(1, t, hkv, d), cos, sin)
+        return flash_attention(
+            q4, jnp.repeat(k4, h // hkv, axis=2),
+            jnp.repeat(v.reshape(1, t, hkv, d), h // hkv, axis=2), **kw,
+        ).reshape(1, t, h * d)
+
+    def both(fn):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w),
+            argnums=(0, 1, 2),
+        ))(q, k, v)
+
+    def parity():
+        o_wide, o_4d = jax.jit(wide)(q, k, v), jax.jit(four_d)(q, k, v)
+        _close_rel(o_wide, o_4d, 2e-2)
+        (_, got), (_, want) = both(wide), both(four_d)
+        for g, r in zip(got, want):
+            _close_rel(g, r, 2e-2)
+
+    check("flash_wide_gqa_bf16", parity)
+
+
 def quant_checks():
     from dlrover_tpu.ops.quantization import (
         dequantize_blockwise,
@@ -689,6 +752,7 @@ def run(small: bool) -> list:
     XENT_V = 1024 if small else 50304
     RESULTS.clear()
     flash_checks()
+    flash_wide_checks()
     quant_checks()
     xent_checks()
     ssd_checks()
