@@ -4,6 +4,12 @@ the resume cell imports this and must stay off the chip.
 ``workloads/<name>.json`` names a configuration and a traffic mix;
 ``configs/<config>.json`` and ``traffic/<traffic>.json`` hold them. A
 later PR adds a cell by adding files; nothing here knows a name.
+
+Which cells report a per-layer metric is said by the cells: a file
+under ``layer_metrics/`` with ``"restricted": true`` is reported by the
+cells whose workload file names it under ``per_layer``, and one
+without by every cell. So a new cell joins an accepted metric by
+naming it, and no metric file is copied or edited for it.
 """
 
 from __future__ import annotations
@@ -44,6 +50,7 @@ def load_cell(workload: str, root: str = HERE) -> dict:
     }
     if cell["chips"] not in (1, 4):
         raise CellError(f"{workload}: chips must be 1 or 4")
+    cell_metric_specs(cell)  # a name no file has: refused before any run
     return cell
 
 
@@ -58,6 +65,22 @@ def layer_metric_specs(root: str = HERE) -> list:
             spec.setdefault("name", fname[: -len(".json")])
             out.append(spec)
     return out
+
+
+def cell_metric_specs(cell: dict) -> list:
+    """The per-layer metrics ``cell`` reports, sorted by name: every
+    file that is not ``restricted``, and the restricted ones the cell's
+    workload file names under ``per_layer``. A name there that no
+    restricted file has is a ``CellError``, not a silent gap."""
+    specs = layer_metric_specs()
+    restricted = {s["name"] for s in specs if s.get("restricted")}
+    named = set(cell["workload"].get("per_layer", []))
+    if named - restricted:
+        raise CellError(
+            f"{cell['name']}: per_layer names {sorted(named - restricted)}, "
+            "which no restricted file under layer_metrics/ has"
+        )
+    return [s for s in specs if s["name"] not in restricted or s["name"] in named]
 
 
 def mesh_shape(traffic: dict, chips: int) -> tuple:

@@ -98,6 +98,34 @@ def shape(config: dict) -> dict:
     }
 
 
+def flops_per_token(shape: dict) -> float:
+    """What the passes of a whole step require for a token, nothing
+    recomputed and no row of padding counted
+    (``flops.train_flops_per_token`` asks here first): 6 x the matrix
+    parameters it passes in each layer held (the latent mixer's four
+    projections; the dense MLP, or the expert layer's router, its
+    shared experts and the routed experts at the load this share
+    expects, ``expert_matmul_params``) and in the loss head's rows; and
+    for each layer the causal half of QK^T at the query/key head size
+    and of PV at the value head size, forward and backward
+    (``flops.mean_keys``: 4,096.5 of 8,192). The rotation is
+    elementwise and counts nothing."""
+    from benchmark import flops
+
+    matrices = (
+        shape["mla_layers"] * shape["mla_matmul_params"]
+        + shape["dense_layers"] * shape["dense_matmul_params"]
+        + shape["moe_layers"] * shape["moe_matmul_params"]
+        + shape["vocab_rows"] * shape["embd"]
+    )
+    attention = (
+        6.0 * shape["mla_layers"] * shape["heads"]
+        * (shape["head_dim"] + shape["v_head_dim"])
+        * flops.mean_keys(shape["seq_len"], shape["window"])
+    )
+    return 6.0 * matrices + attention
+
+
 def build(config: dict) -> dict:
     from benchmark.reference import deepseek_v2 as reference
     from dlrover_tpu.models import deepseek_v2 as model

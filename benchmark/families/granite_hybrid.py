@@ -69,6 +69,33 @@ def shape(config: dict) -> dict:
     }
 
 
+def flops_per_token(shape: dict) -> float:
+    """What the passes of a whole step require for a token, nothing
+    recomputed (``flops.train_flops_per_token`` asks here first): 6 x
+    the matrix parameters of each layer held (by its own kind) and of
+    the loss head's rows; for each attention layer the causal half of
+    QK^T and PV, forward and backward (``flops.py``'s count); for each
+    Mamba-2 layer three times the chunked scan's forward operations
+    (``kernel_work/ssd_fwd.py``: the backward's are twice the
+    forward's)."""
+    from benchmark import flops
+    from benchmark.kernel_work import ssd_fwd
+
+    matrices = (
+        shape["mamba_layers"] * shape["mamba_matmul_params"]
+        + shape["attention_layers"] * shape["attention_matmul_params"]
+        + shape["vocab_rows"] * shape["embd"]
+    )
+    attention = (
+        12.0 * shape["attention_layers"] * shape["heads"] * shape["head_dim"]
+        * flops.mean_keys(shape["seq_len"], shape["window"])
+    )
+    scan = 3.0 * shape["mamba_layers"] * (
+        ssd_fwd.work(shape, 1)["flops"] / shape["seq_len"]
+    )
+    return 6.0 * matrices + attention + scan
+
+
 def build(config: dict) -> dict:
     from benchmark.reference import granite_hybrid as reference
     from dlrover_tpu.models import granite_hybrid as model

@@ -122,6 +122,39 @@ def shape(config: dict) -> dict:
     }
 
 
+def flops_per_token(shape: dict) -> float:
+    """What the passes of a whole step require for a token, nothing
+    recomputed (``flops.train_flops_per_token`` asks here first): 6 x
+    the matrix parameters of each layer held, by its own kind (the KDA
+    or latent mixer; the dense MLP, or the expert layer's router,
+    shared expert and the routed experts at the load this share
+    expects, ``expert_matmul_params``) and of the loss head's rows; for
+    each latent layer the causal half of QK^T at the query/key head
+    size and of PV at the value head size, forward and backward; for
+    each KDA layer three times the chunked rule's forward operations
+    (``kernel_work/kda_fwd.py``: the backward's are twice the
+    forward's)."""
+    from benchmark import flops
+    from benchmark.kernel_work import kda_fwd
+
+    matrices = (
+        shape["kda_layers"] * shape["kda_matmul_params"]
+        + shape["mla_layers"] * shape["mla_matmul_params"]
+        + shape["dense_layers"] * shape["dense_matmul_params"]
+        + shape["moe_layers"] * shape["moe_matmul_params"]
+        + shape["vocab_rows"] * shape["embd"]
+    )
+    attention = (
+        6.0 * shape["mla_layers"] * shape["heads"]
+        * (shape["head_dim"] + shape["v_head_dim"])
+        * flops.mean_keys(shape["seq_len"], shape["window"])
+    )
+    rule = 3.0 * shape["kda_layers"] * (
+        kda_fwd.work(shape, 1)["flops"] / shape["seq_len"]
+    )
+    return 6.0 * matrices + attention + rule
+
+
 def build(config: dict) -> dict:
     from benchmark.reference import kimi_linear as reference
     from dlrover_tpu.models import kimi_linear as model

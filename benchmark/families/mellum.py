@@ -91,6 +91,31 @@ def shape(config: dict) -> dict:
     }
 
 
+def flops_per_token(shape: dict) -> float:
+    """What the passes of a whole step require for a token, nothing
+    recomputed and no row of padding counted
+    (``flops.train_flops_per_token`` asks here first): 6 x the matrix
+    parameters it passes in each layer held (the attention projections,
+    the router's every output, and the held experts at the load this
+    share expects, ``expert_matmul_params``) and in the loss head's
+    rows; and for each layer the causal QK^T and PV, forward and
+    backward, over its own kind's mean number of keys
+    (``flops.mean_keys``: 960 of 8,192 under the window of 1,024,
+    4,096.5 without)."""
+    from benchmark import flops
+
+    matrices = (
+        shape["layers"] * shape["layer_matmul_params"]
+        + shape["vocab_rows"] * shape["embd"]
+    )
+    keys = sum(
+        shape[f"{kind}_layers"]
+        * flops.mean_keys(shape["seq_len"], shape[f"{kind}_window"])
+        for kind in ("sliding", "full")
+    )
+    return 6.0 * matrices + 12.0 * shape["heads"] * shape["head_dim"] * keys
+
+
 def _rope(model, entry: dict):
     if entry["rope_type"] == "default":
         return model.Rope(theta=entry["rope_theta"])

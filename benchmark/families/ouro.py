@@ -18,7 +18,7 @@ def shape(config: dict) -> dict:
     ``kernel_work/``, ``layers`` the layers HELD (``flops.py``'s own
     count then multiplies a token by each once, as it does for every
     family), and ``ut_steps``, the times a step runs them
-    (``readers/looped_flops.py`` counts with it)."""
+    (``flops_per_token`` below counts with it)."""
     e = config["hidden_size"]
     heads = config["num_attention_heads"]
     kv = config["num_key_value_heads"]
@@ -39,6 +39,29 @@ def shape(config: dict) -> dict:
         ),
         "ut_steps": config["total_ut_steps"],
     }
+
+
+def flops_per_token(shape: dict) -> float:
+    """What the passes of a whole step require for a token, nothing
+    recomputed (``flops.train_flops_per_token`` asks here first): a
+    token is multiplied by every matrix of every layer held once a
+    pass, and by the head's rows once a pass (the loss reads every
+    pass's logits): 6 x ``ut_steps`` x (``layers`` x
+    ``layer_matmul_params`` + ``vocab_rows`` x ``embd``); attention's
+    causal half of QK^T and PV, forward and backward, is ``flops.py``'s
+    count a layer, once a pass. The exit gate's ``embd``
+    multiplications a pass are left out."""
+    from benchmark import flops
+
+    matrices = (
+        shape["layers"] * shape["layer_matmul_params"]
+        + shape["vocab_rows"] * shape["embd"]
+    )
+    attention = (
+        12.0 * shape["layers"] * shape["heads"] * shape["head_dim"]
+        * flops.mean_keys(shape["seq_len"], shape["window"])
+    )
+    return shape["ut_steps"] * (6.0 * matrices + attention)
 
 
 def build(config: dict) -> dict:

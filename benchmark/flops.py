@@ -1,9 +1,15 @@
 """What the arithmetic of a training step requires, from shapes alone.
 
 The yardstick's own count, so that no later PR can move a numerator:
-``train_flops_per_token`` is 6 x (parameters that sit in a matrix
-multiplication) + attention, with the causal half and the sliding
-window taken off, nothing recomputed. The program's own
+``train_flops_per_token`` is the count of the configuration's family
+(``families/<family>.py`` ``flops_per_token(shape)``: layers of
+several kinds, a stack run several times, experts held) and, where the
+family gives none, the count of a stack whose layers are all alike:
+6 x (parameters a token is multiplied by: ``layers`` x
+``layer_matmul_params``, which for OLMoE counts the experts a token is
+sent to and not all of them, and the loss head's rows) + attention,
+with the causal half and the sliding window taken off, nothing
+recomputed. The program's own
 ``flops_per_token`` (models/gpt.py, models/llama.py) counts
 ``12*L*T*E`` with no causal discount and ``block_size`` where
 Mistral's window is 4096; it overstates GPT-2 124M at T=1024 by 6.6%.
@@ -19,11 +25,14 @@ from __future__ import annotations
 import importlib
 
 
+def _family(config: dict):
+    return importlib.import_module(f"benchmark.families.{config['family']}")
+
+
 def shape_of(config: dict) -> dict:
     """The configuration's sizes under one set of names, from the
     module of its family."""
-    family = importlib.import_module(f"benchmark.families.{config['family']}")
-    return family.shape(config)
+    return _family(config).shape(config)
 
 
 def kernel_work(kernel: str, config: dict, batch_rows: int) -> dict:
@@ -62,6 +71,12 @@ def attention_flops_per_token(config: dict) -> float:
 
 
 def train_flops_per_token(config: dict) -> float:
+    """What the passes of a whole step require for a token, nothing
+    recomputed and no row of padding counted: the family's own count
+    where it gives one, else the count of layers all alike."""
+    own = getattr(_family(config), "flops_per_token", None)
+    if own is not None:
+        return own(shape_of(config))
     return 6.0 * matmul_params(config) + attention_flops_per_token(config)
 
 

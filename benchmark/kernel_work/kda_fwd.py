@@ -1,7 +1,9 @@
 """Operations and bytes the chunked delta rule's forward requires
-(``ops/kda.py``: no Pallas kernel ships, PR 53; the count is of the
-mathematics, for ``readers/kimi_flops.py`` and for the kernel a later
-PR may bring), one call on one device, nothing recomputed.
+(``ops/kda.py``: kernels ``kda_fwd`` / ``kda_bwd`` since PR 54, the
+plain ``jax.numpy`` form where the head sizes are no multiple of 128;
+the count is of the mathematics, whatever implements it, for the Kimi
+family's ``flops_per_token`` and for the kernels' rooflines, which no
+metric reads yet), one call on one device, nothing recomputed.
 
 A chunk of C tokens of one head of size d (keys and values alike):
 the two pair matrices' lower triangles (k k^T and q k^T with their

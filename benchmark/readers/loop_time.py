@@ -5,23 +5,23 @@ to the program's description of its compiled step, as
 ``readers/scope_time.py`` joins them (and under its rule on the share
 of the window the description has to know).
 
-args: {"scope": <name>, "whole": <false>}
+args: {"scope": <name>}
 
-``whole`` true reads the scope's whole time instead, wherever it
-stands on a path and whatever is entered within it: ``scope_time``'s
-own nested reading, asked for from here because
-``tests/benchmark/test_scope_time.py`` counts the metric files that
-name that reader (eleven) and is not a ``model_config`` PR's to edit.
-Either reading fills the line's ``notes.scope_split``.
+A scope's whole time, wherever it stands on a path and whatever is
+entered within it, is ``scope_time``'s ``nested`` reading. This
+reading fills the line's ``notes.scope_split`` as that one does.
 
-For ``ut_loop`` (models/ouro.py's scan over the passes; inside it
-``layers``, and inside that ``attn`` and ``mlp``) that is the outer
-scan's own work: its ``while`` and carries, the stacking of each pass's
-output, the stacked weights' gradients summed over the passes, the norm
-that closes a pass. In ``scope_time``'s partition these instructions
-fall to ``accumulate`` (the innermost of its two scan names on their
-path: it does not know this one), so this number is a part of
-``accumulate_ms_per_step``, not a term beside it.
+For ``ut_loop`` (models/ouro.py: since PR 45 ``ut_steps`` calls in a
+row of one jitted pass, the layers' scan and the norm that closes it;
+inside the scope ``layers``, and inside that ``attn`` and ``mlp``) the
+scope's own work is the zero fills of the kept stacks before each layer
+scan writes them and of the backward scans' gradient stacks, the norm
+that closes a pass and the stacking of the passes' outputs; the
+weights' gradients summed over the passes are not here (XLA fuses the
+sums into the optimizer's reads). In ``scope_time``'s partition these
+instructions fall to ``accumulate`` (the innermost of its two scan
+names on their path: it does not know this one), so this number is a
+part of ``accumulate_ms_per_step``, not a term beside it.
 
 Nothing to read is ``None``: no device plane, no description, or a
 program that never enters the scope (every configuration but a looped
@@ -44,15 +44,13 @@ def own_ms(reduced: dict, description: dict, scope: str):
     return 1e3 * seconds / reduced["steps"] if found else None
 
 
-def read(ctx: dict, scope: str, whole: bool = False):
+def read(ctx: dict, scope: str):
     red = ctx.get("trace") or {}
     if not red.get("steps") or not red.get("ops"):
         return None
-    # scope_time's table (made once a run) has the scope's whole time,
-    # whatever is entered within it: None with no table, or in a program
-    # that never enters the scope.
-    everything = scope_time.read(ctx, scope, nested=True)
-    if whole or everything is None:
-        return everything
+    # scope_time's table (made once a run) has the scope's whole time:
+    # None with no table, or in a program that never enters the scope.
+    if scope_time.read(ctx, scope, nested=True) is None:
+        return None
     description = scope_time.describe()
     return own_ms(red, description, scope) if description else None

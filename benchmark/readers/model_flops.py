@@ -1,7 +1,8 @@
-"""Reader ``model_flops``: model FLOP/s utilisation, in percent: the
-operations the passes require for a token (``flops.py``: nothing
-recomputed, the causal half and the window taken off) times tokens
-per second, over chips times the peak in ``peaks.json``."""
+"""Reader ``model_flops``: model FLOP/s utilisation of the whole step,
+in percent: the operations the passes require for a token
+(``flops.train_flops_per_token``: the family's own count or the dense
+stack's, nothing recomputed, the causal half and the window taken off)
+times tokens per second, over chips times the peak in ``peaks.json``."""
 
 from benchmark import flops
 

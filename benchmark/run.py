@@ -6,9 +6,11 @@ Finds the cell's files by name (``workloads/``, ``configs/``,
 ``traffic/``), hands the cell to the module of its traffic kind
 (``kinds/``), and prints one JSON object as the last line of standard
 output. With ``--trace 0`` its metrics are the cell's end-to-end
-metrics, with ``--trace 1`` its per-layer metrics, each read by the
-reader its file under ``layer_metrics/`` names. No TPU, or another
-count of chips than the cell's, is a non-zero exit and no result.
+metrics, with ``--trace 1`` its per-layer metrics (those every cell
+reports and those its workload file names under ``per_layer``), each
+read by the reader its file under ``layer_metrics/`` names. No TPU, or
+another count of chips than the cell's, is a non-zero exit and no
+result.
 
 ``--cells-root`` and ``--allow-cpu`` are the CPU rehearsal's
 (tests/benchmark/test_cells_cpu.py): toy cells from ``testdata/``, and
@@ -32,7 +34,7 @@ sys.path.insert(0, REPO)
 os.environ.setdefault("TPU_LOG_DIR", os.path.join(REPO, ".cache", "tpu_logs"))
 
 
-def layer_metrics(cell: dict, result: dict, any_cell: bool) -> dict:
+def layer_metrics(cell: dict, result: dict) -> dict:
     from benchmark import cell as cell_files
     from benchmark import peaks
 
@@ -44,14 +46,18 @@ def layer_metrics(cell: dict, result: dict, any_cell: bool) -> dict:
             raise
         ctx["peaks"] = None  # a rehearsal: readers of a peak read nothing
     out = {}
-    for spec in cell_files.layer_metric_specs():
-        if not any_cell and cell["name"] not in spec.get("workloads", [cell["name"]]):
-            continue
+    specs = cell_files.cell_metric_specs(cell)
+    for spec in specs:
         reader = importlib.import_module(f"benchmark.readers.{spec['reader']}")
         value = reader.read(ctx, **spec.get("args", {}))
         if value is not None:
             out[spec["name"]] = {"value": value, "unit": spec["unit"]}
-    result["notes"] = ctx.get("notes", {})
+    # The cell's metrics whose reader found nothing to read: off the
+    # chip most of them; on it, a name the cell should not have listed.
+    ctx.setdefault("notes", {})["read_nothing"] = sorted(
+        s["name"] for s in specs if s["name"] not in out
+    )
+    result["notes"] = ctx["notes"]
     return out
 
 
@@ -95,7 +101,7 @@ def main(argv=None) -> int:
 
     red = (result["ctx"].get("trace") or {}) if args.trace else {}
     if args.trace:
-        metrics = layer_metrics(cell, result, args.cells_root != HERE)
+        metrics = layer_metrics(cell, result)
         if red:
             result["device"]["busy_s"] = red["busy_s"]
             result["device"]["window_s"] = red["window_s"]

@@ -84,9 +84,83 @@ def test_traced_rehearsal_reports_layer_metrics():
     assert {"data_wait_ms.train", "dispatch_ms.train",
             "step_programs.train", "step_hbm_gb.train"} <= set(line["metrics"])
     assert line["metrics"]["step_programs.train"]["value"] == 1
-    # No device plane in a CPU trace: no device metric, no busy_s.
+    # No device plane in a CPU trace: no device metric, no busy_s. The
+    # line says which of the cell's metrics found nothing to read.
     assert "mfu.train" not in line["metrics"]
     assert "busy_s" not in line["device"]
+    nothing = line["notes"]["read_nothing"]
+    assert {"mfu.train", "attn_ms_per_step.train"} <= set(nothing)
+    assert not set(nothing) & set(line["metrics"])
+
+
+# -- which cells report a metric is said by the cells --------------------
+
+
+def _names(workload):
+    cell = cell_files.load_cell(workload, TOY)
+    return {s["name"] for s in cell_files.cell_metric_specs(cell)}
+
+
+def test_a_cell_reports_the_restricted_metrics_it_names_and_no_other():
+    specs = cell_files.layer_metric_specs()
+    restricted = {s["name"] for s in specs if s.get("restricted")}
+    for_all = {s["name"] for s in specs} - restricted
+    assert {"boot_s.setup", "first_step_s.setup"} <= for_all
+    assert {"attn_ms_per_step.train", "mfu.train"} <= restricted
+    assert not any("workloads" in s for s in specs)
+    # toy-gpt.steady names some; toy-gpt.bare is the same cell and
+    # names none; each reports what every cell reports. Held to the
+    # cell's own list and never to the directory: the next PR's metric
+    # is a file more there, and no cell here names it.
+    named = set(cell_files.load_cell("toy-gpt.steady", TOY)["workload"]["per_layer"])
+    assert {"attn_ms_per_step.train", "mfu.train"} <= named <= restricted
+    assert _names("toy-gpt.steady") == for_all | named
+    assert _names("toy-gpt.bare") == for_all
+
+
+def _stand_ins():
+    """(toy cell, its workload file) for every toy cell that stands for
+    a cell under ``workloads/``."""
+    d = os.path.join(TOY, "workloads")
+    out = []
+    for fname in sorted(os.listdir(d)):
+        with open(os.path.join(d, fname)) as f:
+            workload = json.load(f)
+        if "stands_for" in workload:
+            out.append(pytest.param(workload, id=fname[: -len(".json")]))
+    return out
+
+
+@pytest.mark.parametrize("workload", _stand_ins())
+def test_a_toy_cell_rehearses_the_line_of_the_cell_it_stands_for(workload):
+    """``stands_for`` in a toy workload file is the cell under
+    ``workloads/`` whose traced line it rehearses off the chip: the
+    same names on the same kind of traffic, so that each reader runs
+    on the CPU where it will run on the chip. A new cell brings its
+    toy cell with it, a file each."""
+    real = cell_files.load_cell(workload["stands_for"])
+    assert workload["per_layer"] == real["workload"].get("per_layer", [])
+    assert workload["chips"] == real["chips"]
+    with open(os.path.join(TOY, "traffic", workload["traffic"] + ".json")) as f:
+        assert json.load(f)["kind"] == real["traffic"]["kind"]
+
+
+def test_a_traced_rehearsal_leaves_out_what_its_cell_does_not_name():
+    line = _last_line(_run("toy-gpt.bare", 1, trace=1))
+    assert "boot_s.setup" in line["metrics"]
+    for name in ("data_wait_ms.train", "step_programs.train"):
+        # read off the chip too, where the cell names them (above)
+        assert name not in line["metrics"]
+        assert name not in line["notes"]["read_nothing"]
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_a_cell_that_names_a_metric_no_file_has_is_refused(trace):
+    with pytest.raises(cell_files.CellError, match="no_such_metric.train"):
+        cell_files.load_cell("toy-gpt.typo", TOY)
+    proc = _run("toy-gpt.typo", 1, trace=trace, timeout=60)
+    assert proc.returncode != 0 and proc.stdout.strip() == ""
+    assert "no_such_metric.train" in proc.stderr
 
 
 def test_resume_cell_rehearsal_kills_a_real_trainer():
@@ -197,14 +271,33 @@ def manifest():
         return json.load(f)
 
 
+MFU_CELLS_METRIC = "tokens_per_s"
+# A metric exists once: no cell's or family's name in a metric's own.
+CELL_WORDS = (".kimi.", ".mellum.", ".deepseek.", "_hybrid", "_looped",
+              "_kimi", "_mellum", "_deepseek")
+
+
+def _line(text):
+    return (isinstance(text, str) and 1 <= len(text) <= 200
+            and "\n" not in text and "\t" not in text)
+
+
 def test_manifest_names_units_and_limits(manifest):
+    """The contract's limits, asserted here once and not once a cell."""
     assert set(manifest) == {"command", "paths", "run_seconds", "configs",
                              "workloads", "end_to_end", "per_layer"}
     assert 1 <= manifest["run_seconds"] <= 51
+    assert 1 <= len(manifest["command"]) <= 32
+    assert all(_line(word) for word in manifest["command"])
+    assert 1 <= len(manifest["paths"]) <= 16
+    assert 1 <= len(manifest["configs"]) <= 24
+    assert 1 <= len(manifest["workloads"]) <= 24
+    assert 1 <= len(manifest["end_to_end"]) <= 16
+    assert 1 <= len(manifest["per_layer"]) <= 128
     names = []
     for group in ("configs", "workloads", "end_to_end", "per_layer"):
         for entry in manifest[group]:
-            assert NAME.match(entry["name"]), entry["name"]
+            assert NAME.match(entry["name"]), entry["name"]  # 64 at most
             names.append((group in ("end_to_end", "per_layer"), entry["name"]))
     assert len(names) == len(set(names))
     for m in manifest["end_to_end"] + manifest["per_layer"]:
@@ -217,22 +310,42 @@ def test_manifest_names_units_and_limits(manifest):
         assert m["source"] in ("host_clock", "device_trace")
         assert 0.01 <= m["bound"] <= 0.1
     assert any(m["name"] == "setup_s" for m in manifest["end_to_end"])
+    for m in manifest["per_layer"]:
+        assert {"name", "unit", "better", "source", "layer", "moves"} <= set(m)
+        assert set(m) <= {"name", "unit", "better", "source", "layer",
+                          "moves", "workloads"}
+        assert _line(m["layer"])
+        assert not any(word in m["name"] for word in CELL_WORDS), m["name"]
     for w in manifest["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
         assert NAME.match(w["config"]) and NAME.match(w["traffic"])
-        assert w["chips"] in (1, 4) and 0 < len(w["why"]) <= 200
+        assert w["chips"] in (1, 4) and _line(w["why"])
     four = sum(w["chips"] == 4 for w in manifest["workloads"])
     assert four <= max(1, len(manifest["workloads"]) // 4)
     for c in manifest["configs"]:
         assert set(c) == {"name", "source", "file", "reduced", "why"}
         assert any(c["file"].startswith(p + "/") for p in manifest["paths"])
+        assert len(c["reduced"]) <= 16
         assert all(NAME.match(k) for k in c["reduced"])
-    assert len(json.dumps(manifest)) < 64 * 1024
+        assert _line(c["source"]) and _line(c["why"])
+    with open(os.path.join(REPO, "BENCHMARK.json"), "rb") as f:
+        assert len(f.read()) < 64 * 1024
+
+
+def test_one_whole_step_share_bounds_every_training_cell(manifest):
+    """Exactly one metric with ``mfu`` in its name, and every cell that
+    reports ``tokens_per_s`` reports it."""
+    (mfu,) = [m for m in manifest["per_layer"] if "mfu" in m["name"]]
+    (rate,) = [m for m in manifest["end_to_end"]
+               if m["name"] == MFU_CELLS_METRIC]
+    assert mfu["moves"] == MFU_CELLS_METRIC
+    assert sorted(mfu["workloads"]) == sorted(rate["workloads"])
 
 
 def test_manifest_agrees_with_the_benchmarks_files(manifest):
     e2e = {m["name"]: m for m in manifest["end_to_end"]}
     cells = {w["name"] for w in manifest["workloads"]}
+    named = {}  # restricted metric -> the cells that name it, in order
     for w in manifest["workloads"]:
         cell = cell_files.load_cell(w["name"])
         assert cell["workload"]["config"] == w["config"]
@@ -246,6 +359,10 @@ def test_manifest_agrees_with_the_benchmarks_files(manifest):
         assert reports == listed, (w["name"], reports, listed)
         for n, unit in cell["traffic"]["end_to_end"].items():
             assert e2e[n]["unit"] == unit
+        per_layer = cell["workload"].get("per_layer", [])
+        assert len(per_layer) == len(set(per_layer)), w["name"]
+        for name in per_layer:
+            named.setdefault(name, []).append(w["name"])
     used = {w["config"] for w in manifest["workloads"]}
     for c in manifest["configs"]:
         assert c["name"] in used
@@ -253,12 +370,21 @@ def test_manifest_agrees_with_the_benchmarks_files(manifest):
             conf = json.load(f)
         assert conf["source"] == c["source"] and conf["reduced"] == c["reduced"]
     specs = {s["name"]: s for s in cell_files.layer_metric_specs()}
+    # Every file has its entry and every entry its file.
+    assert set(specs) == {m["name"] for m in manifest["per_layer"]}
     for m in manifest["per_layer"]:
         spec = specs[m["name"]]
         for key in ("unit", "better", "source", "layer", "moves"):
             assert spec[key] == m[key], (m["name"], key)
-        where = [c for c in m.get("workloads", cells) if c in cells]
-        assert where, m["name"]
+        assert "workloads" not in spec, m["name"]
+        # Both ways: a restricted metric's list in the manifest is the
+        # cells whose workload files name it, in the manifest's order;
+        # one that every cell reports has no list.
+        if spec.get("restricted"):
+            assert m["workloads"] == named[m["name"]], m["name"]
+        else:
+            assert "workloads" not in m and m["name"] not in named
+        where = m.get("workloads", sorted(cells))
         # The metric it moves is reported wherever it is.
         for c in where:
             assert c in e2e[m["moves"]].get("workloads", cells)

@@ -21,7 +21,8 @@ from benchmark import cell as cell_files
 from benchmark import flops
 from benchmark.families import granite_hybrid as family
 from benchmark.kernel_work import ssd_bwd, ssd_fwd
-from benchmark.readers import hybrid_flops
+from benchmark.readers import model_flops
+from tests.benchmark import membership
 from benchmark.reference import granite_hybrid as reference
 from dlrover_tpu.models import granite_hybrid as model
 
@@ -61,11 +62,14 @@ def test_toy_granite_cell_rehearsal_prints_a_correct_line():
 
 
 def test_traced_rehearsal_reports_no_device_metric():
-    """One step program; the new readers find no device plane and no
-    peak off the chip, return nothing and do not raise."""
+    """The toy cell names what the cell names; its readers find no
+    device plane and no peak off the chip, return nothing and do not
+    raise, and the line says which they were."""
     line = _rehearse(1)
-    assert line["metrics"]["step_programs.train"]["value"] == 1
+    assert line["metrics"]["step_trace_lower_s.setup"]["value"] > 0
     assert not [m for m in line["metrics"] if m.startswith(("ssd_", "mfu"))]
+    assert {"ssd_fwd_roofline.train", "ssd_bwd_roofline.train",
+            "mfu.train"} <= set(line["notes"]["read_nothing"])
 
 
 # -- the published configuration's counts, by hand ------------------------
@@ -167,38 +171,34 @@ def test_required_operations_a_token_by_hand():
     assert matrices == 797_573_120
     attention = 12 * 2048 * (4096 + 1) / 2  # one attention layer
     scans = 3 * 9 * 12_499_550_208 / 4096
-    assert hybrid_flops.flops_per_token(shape) == (
+    assert family.flops_per_token(shape) == (
         6 * matrices + attention + scans
     ) == 4_918_177_152
-    # The reader reads nothing for a family that does not count its
-    # layers by kind, and nothing without a peak.
+    # The yardstick asks the family first; the reader reads nothing
+    # without a peak.
+    config = _config("granite-4.0-h-micro")
+    assert flops.train_flops_per_token(config) == 4_918_177_152
     window = {"tokens_per_s": 1000.0}
     peaks = {"bf16_flops_per_s": 197e12}
-    dense = {"config": _config("toy-mistral", TOY)}
-    assert hybrid_flops.read(
-        {"window": window, "peaks": peaks, "cell": dense, "device": {"count": 1}}
-    ) is None
-    cell = {"config": _config("granite-4.0-h-micro")}
-    assert hybrid_flops.read({"window": window, "peaks": None, "cell": cell}) is None
-    assert hybrid_flops.read(
+    cell = {"config": config}
+    assert model_flops.read({"window": window, "peaks": None, "cell": cell}) is None
+    assert model_flops.read(
         {"window": {"tokens_per_s": 16_000.0}, "peaks": peaks, "cell": cell,
          "device": {"count": 1}}
     ) == pytest.approx(100 * 4_918_177_152 * 16_000 / 197e12)
 
 
-def test_manifest_lists_the_cell_and_its_metrics():
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        manifest = json.load(f)
-    cell = [w for w in manifest["workloads"] if w["name"] == CELL]
-    assert cell and cell[0]["chips"] == 1 and cell[0]["traffic"] == "steady"
-    mine = {m["name"]: m for m in manifest["per_layer"]
-            if m.get("workloads") == [CELL]}
-    assert set(mine) == {"ssd_ms_per_step.train", "ssd_fwd_roofline.train",
-                         "ssd_bwd_roofline.train", "mfu_hybrid.train"}
-    assert all(m["moves"] == "tokens_per_s" for m in mine.values())
-    for name in ("tokens_per_s", "step_ms_p90"):
-        listed = [m for m in manifest["end_to_end"] if m["name"] == name][0]
-        assert listed["workloads"][-1] == CELL
+def test_manifest_lists_the_cell():
+    membership.assert_cell_is_listed(membership.manifest(), CELL)
+
+
+@pytest.mark.parametrize("name", [
+    "ssd_ms_per_step.train", "ssd_fwd_roofline.train",
+    "ssd_bwd_roofline.train", "mfu.train", "attn_ms_per_step.train",
+])
+def test_manifest_lists_the_cell_in_its_metrics(name):
+    spec = membership.assert_cell_reports(membership.manifest(), CELL, name)
+    assert spec["moves"] == "tokens_per_s"
 
 
 def test_shape_stays_off_jax_and_off_the_model():

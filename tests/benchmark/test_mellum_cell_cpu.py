@@ -19,10 +19,10 @@ from benchmark import flops, peaks
 from benchmark.controls import mellum as controls
 from benchmark.families import mellum as family
 from benchmark.kernel_work import (
-    flash_bwd, flash_bwd_pattern, flash_fwd, flash_fwd_pattern, moe_gmm,
-    moe_gmm_held, moe_tgmm_held,
+    flash_bwd, flash_fwd, moe_gmm, moe_tgmm,
 )
-from benchmark.readers import mellum_flops
+from benchmark.readers import model_flops
+from tests.benchmark import membership
 
 REPO = cell_files.REPO
 TOY = os.path.join(cell_files.HERE, "testdata", "cells")
@@ -30,31 +30,29 @@ CONTROLS = os.path.join(cell_files.HERE, "controls", "mellum_cells")
 CONFIG = "mellum2-12b-a2.5b"
 CELL = "mellum2-12b-a2.5b.steady"
 METRICS = (
-    "mfu_mellum.train", "attn_window_ms_per_step.train",
-    "attn_full_ms_per_step.train", "flash_fwd_roofline.mellum.train",
-    "flash_bwd_roofline.mellum.train", "moe_gmm_roofline.mellum.train",
-    "attn_ms_per_step.mellum.train", "embed_ms_per_step.mellum.train",
-    "head_ms_per_step.mellum.train", "mlp_ms_per_step.mellum.train",
-    "moe_combine_ms_per_step.mellum.train",
-    "moe_experts_ms_per_step.mellum.train", "moe_gmm_ms_per_step.mellum.train",
-    "moe_route_ms_per_step.mellum.train", "moe_routed_ms_per_step.mellum.train",
-    "optimizer_ms_per_step.mellum.train", "step_hbm_gb.mellum.train",
-    "step_programs.mellum.train", "unscoped_ms_per_step.mellum.train",
-    "moe_tgmm_roofline.mellum.train", "moe_tgmm_ms_per_step.mellum.train",
-    "flash_bwd_ms_per_step.mellum.train", "pallas_ms_per_step.mellum.train",
-    "data_wait_ms.mellum.train", "dispatch_ms.mellum.train",
+    "mfu.train", "attn_window_ms_per_step.train",
+    "attn_full_ms_per_step.train", "flash_fwd_roofline.train",
+    "flash_bwd_roofline.train", "moe_gmm_roofline.train",
+    "attn_ms_per_step.train", "embed_ms_per_step.train",
+    "head_ms_per_step.train", "mlp_ms_per_step.train",
+    "moe_combine_ms_per_step.train",
+    "moe_experts_ms_per_step.train", "moe_gmm_ms_per_step.train",
+    "moe_route_ms_per_step.train", "moe_routed_ms_per_step.train",
+    "optimizer_ms_per_step.train", "step_hbm_gb.train",
+    "step_programs.train", "unscoped_ms_per_step.train",
+    "moe_tgmm_roofline.train", "moe_tgmm_ms_per_step.train",
+    "flash_bwd_ms_per_step.train", "pallas_ms_per_step.train",
+    "data_wait_ms.train", "dispatch_ms.train",
 )
-# The start-up stages of the trainer's process, which move ``setup_s``:
-# copies of the accepted ``*.setup`` files, whose lists are not this
-# PR's to extend.
+# The start-up stages of the trainer's process, which move ``setup_s``.
 STAGES = ("step_trace_lower_s", "trace_lower_s", "compile_s", "cache_load_s",
           "compile_requests", "price_step_s")
-SETUP_METRICS = tuple(f"{stage}.mellum.setup" for stage in STAGES)
+SETUP_METRICS = tuple(f"{stage}.setup" for stage in STAGES)
 # What a run off the chip has to read: the host's clocks and the
 # program's own counters and spans.
 OFF_CHIP = {
-    "step_programs.mellum.train", "step_hbm_gb.mellum.train",
-    "data_wait_ms.mellum.train", "dispatch_ms.mellum.train",
+    "step_programs.train", "step_hbm_gb.train",
+    "data_wait_ms.train", "dispatch_ms.train",
 }
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 # What a control's files may leave out of the cell's: words, not numbers.
@@ -106,12 +104,11 @@ def test_traced_rehearsal_reports_no_device_metric():
     device plane or a peak find none off the chip, return nothing and
     do not raise."""
     line = _rehearse(1)
-    assert line["metrics"]["step_programs.mellum.train"]["value"] == 1
+    assert line["metrics"]["step_programs.train"]["value"] == 1
     assert not (set(METRICS) - OFF_CHIP) & set(line["metrics"])
     assert OFF_CHIP | set(SETUP_METRICS) <= set(line["metrics"])
-    # Each copy reads what the accepted metric of its stage reads.
     for stage in STAGES:
-        assert line["metrics"][f"{stage}.mellum.setup"]["value"] >= 0
+        assert line["metrics"][f"{stage}.setup"]["value"] >= 0
 
 
 # -- the published configuration and its counts, by hand ------------------
@@ -230,7 +227,8 @@ def test_required_operations_by_hand():
     matrices = 4 * (21_233_664 + 12_533_760) + 24576 * 2304
     attention = 12 * 32 * 128 * (3 * 960.0625 + 4096.5)
     want = 6 * matrices + attention
-    assert mellum_flops.flops_per_token(shape) == pytest.approx(want)
+    assert family.flops_per_token(shape) == pytest.approx(want)
+    assert flops.train_flops_per_token(_config()) == family.flops_per_token(shape)
     assert f"{want / 1e9:.3g}" == "1.49"
     assert f"{want * 8192:.3g}" == "1.22e+13"
     # The head is 30% of the matrix parameters a token passes here.
@@ -244,14 +242,13 @@ def test_pattern_work_is_the_mean_over_the_period_s_calls():
     are compute-bound the mean's least time is the mean of theirs."""
     shape = family.shape(_config())
     chip = peaks.chip_peaks(V5E)
-    for pattern, one in ((flash_fwd_pattern, flash_fwd),
-                         (flash_bwd_pattern, flash_bwd)):
-        kinds = pattern.by_kind(shape, 1)
+    for kernel in (flash_fwd, flash_bwd):
+        kinds = kernel.by_kind(shape, 1)
         assert [n for n, _ in kinds] == [3, 1]
-        sliding = one.work(dict(shape, window=1024), 1)
-        full = one.work(dict(shape, window=None), 1)
+        sliding = kernel.one_call(dict(shape, window=1024), 1)
+        full = kernel.one_call(dict(shape, window=None), 1)
         assert [w for _, w in kinds] == [sliding, full]
-        mean = pattern.work(shape, 1)
+        mean = kernel.work(shape, 1)
         for key in ("flops", "bytes"):
             assert 4 * mean[key] == pytest.approx(3 * sliding[key] + full[key])
         least = [flops.roofline_seconds(w, chip) for w in (sliding, full)]
@@ -261,27 +258,32 @@ def test_pattern_work_is_the_mean_over_the_period_s_calls():
         assert 4 * of_mean["seconds"] == pytest.approx(
             3 * least[0]["seconds"] + least[1]["seconds"]
         )
-    fwd = flash_fwd_pattern.work(shape, 1)
+    fwd = flash_fwd.work(shape, 1)
     assert fwd["flops"] == 4.0 * 32 * 128 * 8192 * (3 * 960.0625 + 4096.5) / 4
-    assert flash_bwd_pattern.work(shape, 1)["flops"] == 2 * fwd["flops"]
-    # With one kind of layer the count is the accepted module's.
+    assert flash_bwd.work(shape, 1)["flops"] == 2 * fwd["flops"]
+    # With one kind of layer, or no pattern in the shape, the count is
+    # one call's.
     one_kind = dict(shape, sliding_layers=0, full_layers=4)
-    assert flash_fwd_pattern.work(one_kind, 2) == flash_fwd.work(shape, 2)
+    assert flash_fwd.work(one_kind, 2) == flash_fwd.one_call(shape, 2)
+    plain = {k: v for k, v in shape.items() if not k.startswith(("sliding", "full"))}
+    assert flash_fwd.work(plain, 2) == flash_fwd.one_call(shape, 2)
 
 
 def test_held_products_count_the_held_pairs_and_not_the_buffer():
     shape = family.shape(_config())
-    held = moe_gmm_held.work(shape, 1)
+    held = moe_gmm.work(shape, 1)
     rows = 8192 * 8 * 16 / 64
     assert rows == 16384
     assert held["flops"] == 2.0 * rows * 2304 * 896
     assert held["bytes"] == 2.0 * (rows * 2304 + rows * 896 + 16 * 2304 * 896)
     # A quarter of the whole layer's pairs, which is what the buffer
     # holds: a product that walks all of it reads a quarter at most.
-    whole = moe_gmm.work(dict(shape, experts=64), 1)
+    uncut = {k: v for k, v in shape.items()
+             if k not in ("experts_held", "router_experts")}
+    whole = moe_gmm.work(dict(uncut, experts=64), 1)
     assert 4 * held["flops"] == whole["flops"]
     # The weight-gradient product is the same count the other way round.
-    assert moe_tgmm_held.work(shape, 1) == held
+    assert moe_tgmm.work(shape, 1) == held
 
 
 def test_no_count_is_over_its_kernel_s_peak():
@@ -290,8 +292,7 @@ def test_no_count_is_over_its_kernel_s_peak():
     count exceeds what the kernel must do."""
     config = _config()
     chip = peaks.chip_peaks(V5E)
-    for kernel in ("flash_fwd_pattern", "flash_bwd_pattern", "moe_gmm_held",
-                   "moe_tgmm_held"):
+    for kernel in ("flash_fwd", "flash_bwd", "moe_gmm", "moe_tgmm"):
         work = flops.kernel_work(kernel, config, 1)
         least = flops.roofline_seconds(work, chip)["seconds"]
         assert least == pytest.approx(
@@ -299,30 +300,28 @@ def test_no_count_is_over_its_kernel_s_peak():
                 work["bytes"] / chip["hbm_bytes_per_s"])
         )
     # The whole step's required operations take 62 ms at the peak.
-    step = mellum_flops.flops_per_token(family.shape(config)) * 8192
+    step = flops.train_flops_per_token(config) * 8192
     assert round(1e3 * step / chip["bf16_flops_per_s"]) == 62
 
 
-def test_mellum_flops_reads_the_rate_and_nothing_without_one():
+def test_the_whole_step_s_share_reads_the_rate_and_nothing_without_one():
     cell = cell_files.load_cell(CELL)
     ctx = {
         "cell": cell, "window": {"tokens_per_s": 30000.0},
         "device": {"count": 1}, "peaks": {"bf16_flops_per_s": 197e12},
     }
     shape = family.shape(_config())
-    want = 100 * mellum_flops.flops_per_token(shape) * 30000.0 / 197e12
-    assert mellum_flops.read(ctx) == pytest.approx(want, rel=1e-9)
-    assert 0 < mellum_flops.read(ctx) < 100
-    assert mellum_flops.read(dict(ctx, peaks=None)) is None
-    assert mellum_flops.read(dict(ctx, window={})) is None
-    other = cell_files.load_cell("mistral-7b.steady")
-    assert mellum_flops.read(dict(ctx, cell=other)) is None
+    want = 100 * family.flops_per_token(shape) * 30000.0 / 197e12
+    assert model_flops.read(ctx) == pytest.approx(want, rel=1e-9)
+    assert 0 < model_flops.read(ctx) < 100
+    assert model_flops.read(dict(ctx, peaks=None)) is None
+    assert model_flops.read(dict(ctx, window={})) is None
 
 
 def test_scope_readers_on_a_hand_made_table(monkeypatch):
     """The two kinds' scopes stand beneath ``attn``: ``attn`` still
-    reads the whole, ``loop_time`` (whole) each kind."""
-    from benchmark.readers import loop_time, scope_time, top_scope
+    reads the whole, the nested reading each kind."""
+    from benchmark.readers import scope_time
 
     reduced = {"steps": 2, "device_ops": [], "ops": {
         "fusion.1": {"seconds": 0.006}, "fusion.2": {"seconds": 0.020},
@@ -337,11 +336,11 @@ def test_scope_readers_on_a_hand_made_table(monkeypatch):
     }
     monkeypatch.setattr(scope_time, "describe", lambda: description)
     ctx = {"trace": reduced}
-    assert top_scope.read(ctx, scope="attn") == pytest.approx(14.0)
-    assert loop_time.read(ctx, scope="attn_window", whole=True) == pytest.approx(3.0)
-    assert loop_time.read(ctx, scope="attn_full", whole=True) == pytest.approx(10.0)
-    assert loop_time.read(ctx, scope="moe_routed", whole=True) == pytest.approx(4.0)
-    assert loop_time.read({"trace": {}}, scope="attn_full", whole=True) is None
+    assert scope_time.read(ctx, scope="attn") == pytest.approx(14.0)
+    assert scope_time.read(ctx, scope="attn_window", nested=True) == pytest.approx(3.0)
+    assert scope_time.read(ctx, scope="attn_full", nested=True) == pytest.approx(10.0)
+    assert scope_time.read(ctx, scope="moe_routed", nested=True) == pytest.approx(4.0)
+    assert scope_time.read({"trace": {}}, scope="attn_full", nested=True) is None
 
 
 def test_shape_stays_off_jax_and_off_the_model():
@@ -421,81 +420,24 @@ def _printable_line(text):
     )
 
 
-def test_manifest_lists_the_cell_and_its_metrics(manifest):
+def test_manifest_lists_the_cell(manifest):
     (config,) = [c for c in manifest["configs"] if c["name"] == CONFIG]
-    (cell,) = [w for w in manifest["workloads"] if w["name"] == CELL]
-    assert cell["config"] == CONFIG and cell["traffic"] == "steady"
-    assert cell["chips"] == 1
+    cell = membership.assert_cell_is_listed(manifest, CELL)
+    assert cell["config"] == CONFIG
     assert config["file"] == f"benchmark/configs/{CONFIG}.json"
     assert config["reduced"] == _config()["reduced"]
     e2e = {m["name"]: m for m in manifest["end_to_end"]}
-    # A member of the two lists, wherever a later cell stands.
-    for name in ("tokens_per_s", "step_ms_p90"):
-        assert CELL in e2e[name]["workloads"]
-    assert "workloads" not in e2e["setup_s"]
     assert CELL not in e2e["save_stall_ms"]["workloads"]
-    per_layer = {m["name"]: m for m in manifest["per_layer"]}
-    specs = {s["name"]: s for s in cell_files.layer_metric_specs()}
-    names = [m["name"] for m in manifest["per_layer"]]
-    assert set(METRICS + SETUP_METRICS) <= set(names)
-    for name in METRICS + SETUP_METRICS:
-        assert per_layer[name]["workloads"] == [CELL]
-        assert per_layer[name]["moves"] == (
-            "setup_s" if name in SETUP_METRICS else "tokens_per_s"
-        )
-        for key in ("unit", "better", "source", "layer", "moves", "workloads"):
-            assert per_layer[name][key] == specs[name][key], (name, key)
-    assert specs["mfu_mellum.train"]["reader"] == "mellum_flops"
-    for scope in ("attn_window", "attn_full"):
-        spec = specs[f"{scope}_ms_per_step.train"]
-        assert spec["reader"] == "loop_time"
-        assert spec["args"] == {"scope": scope, "whole": True}
-    for scope in ("moe_route", "moe_experts", "moe_combine", "moe_routed"):
-        spec = specs[f"{scope}_ms_per_step.mellum.train"]
-        assert spec["reader"] == "loop_time"
-        assert spec["args"] == {"scope": scope, "whole": True}
-    for scope in ("attn", "mlp", "head", "optimizer", "unscoped", "embed"):
-        spec = specs[f"{scope}_ms_per_step.mellum.train"]
-        assert spec["reader"] == "top_scope" and spec["args"] == {"scope": scope}
-    for kernel in ("flash_fwd", "flash_bwd"):
-        spec = specs[f"{kernel}_roofline.mellum.train"]
-        assert spec["reader"] == "trace_events" and spec["unit"] == "%"
-        assert spec["args"] == {
-            "what": "roofline", "kernel": kernel + "_pattern",
-            "name": "^flash_attention_" + kernel[-3:],
-        }
-    assert specs["moe_gmm_roofline.mellum.train"]["args"] == {
-        "what": "roofline", "kernel": "moe_gmm_held", "name": "^moe_gmm",
-    }
-    assert specs["moe_gmm_ms_per_step.mellum.train"]["args"] == {
-        "what": "per_step_ms", "name": "^moe_t?gmm",
-    }
-    assert specs["moe_tgmm_roofline.mellum.train"]["args"] == {
-        "what": "roofline", "kernel": "moe_tgmm_held", "name": "^moe_tgmm",
-    }
-    # A copy reads what the accepted metric of its name reads.
-    for name in ("moe_tgmm_ms_per_step", "flash_bwd_ms_per_step",
-                 "pallas_ms_per_step", "data_wait_ms", "dispatch_ms"):
-        copy = specs[f"{name}.mellum.train"]
-        accepted = specs.get(
-            f"{name}.train", specs["moe_gmm_ms_per_step.train"]
-        )
-        assert copy["reader"] == accepted["reader"], name
-        if name == "moe_tgmm_ms_per_step":
-            assert copy["args"] == {"what": "per_step_ms", "name": "^moe_tgmm"}
-        else:
-            assert copy["args"] == accepted["args"], name
-    for stage in STAGES:
-        copy, accepted = (
-            specs[f"{stage}.mellum.setup"], specs[f"{stage}.setup"]
-        )
-        for key in ("reader", "args", "unit", "better", "layer", "source"):
-            assert copy[key] == accepted[key], (stage, key)
-    # No accepted metric's list gained the cell: their files are not
-    # this PR's to edit.
-    for name, m in per_layer.items():
-        if name not in METRICS + SETUP_METRICS:
-            assert CELL not in m.get("workloads", []), name
+
+
+@pytest.mark.parametrize("name", METRICS + SETUP_METRICS)
+def test_manifest_lists_the_cell_in_its_metrics(manifest, name):
+    """A member of each list, wherever a later cell stands, and read
+    as the cell's own copy of the metric was before PR 63 folded it."""
+    spec = membership.assert_cell_reports(manifest, CELL, name)
+    assert spec["moves"] == (
+        "setup_s" if name in SETUP_METRICS else "tokens_per_s")
+    membership.assert_reads_as_its_copy_did(spec)
 
 
 def test_every_line_this_pr_added_to_the_manifest_is_of_the_contracts_form(
@@ -503,7 +445,7 @@ def test_every_line_this_pr_added_to_the_manifest_is_of_the_contracts_form(
 ):
     """``why``, ``source`` and ``layer``: 1 to 200 printable ASCII
     characters on one line; each entry has just its keys; the cell is
-    one-chip; the manifest is under 64 KiB."""
+    one-chip. (The manifest's own limits: ``test_cells_cpu.py``.)"""
     (config,) = [c for c in manifest["configs"] if c["name"] == CONFIG]
     (cell,) = [w for w in manifest["workloads"] if w["name"] == CELL]
     assert set(config) == {"name", "source", "file", "reduced", "why"}
@@ -515,26 +457,9 @@ def test_every_line_this_pr_added_to_the_manifest_is_of_the_contracts_form(
     assert _printable_line(workload["why"]) and workload["why"] == cell["why"]
     assert _config()["source"] == config["source"]
     assert cell["chips"] == 1 == workload["chips"]
-    ours = METRICS + SETUP_METRICS
-    added = [m for m in manifest["per_layer"] if m["name"] in ours]
-    assert len(added) == len(ours)
-    layers = {
-        m["layer"] for m in manifest["per_layer"] if m["name"] not in ours
-    }
-    for m in added:
-        assert set(m) == {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
-        assert _printable_line(m["layer"]) and "\t" not in m["layer"]
-        assert m["layer"] in layers  # a layer the manifest already names
-        assert len(m["name"]) <= 64 and " " not in m["unit"]
-        assert m["source"] in ("device_trace", "program_span",
-                               "program_counter", "host_clock")
-    with open(os.path.join(REPO, "BENCHMARK.json"), "rb") as f:
-        assert len(f.read()) < 64 * 1024
-    four = sum(w["chips"] == 4 for w in manifest["workloads"])
-    assert four <= max(1, len(manifest["workloads"]) // 4)
-    assert len(manifest["configs"]) <= 24 and len(manifest["workloads"]) <= 24
-    assert len(manifest["per_layer"]) <= 128
+    for m in manifest["per_layer"]:
+        if m["name"] in METRICS + SETUP_METRICS:
+            assert _printable_line(m["layer"]) and "\t" not in m["layer"]
 
 
 # -- the controls -----------------------------------------------------------

@@ -184,24 +184,28 @@ def test_a_description_of_another_program_reads_nothing(recorded, monkeypatch):
     assert "scope_split" not in ctx["notes"]
 
 
-def test_the_eleven_metric_files_name_this_reader():
-    """``ssm`` and ``ssm_conv`` are read (``notes.scope_split``,
-    ``notes.scope_inner_ms``) and are no metric yet: the Granite
-    cell's own test pins the metrics that cell alone reports."""
-    specs = {s["name"]: s for s in cell_files.layer_metric_specs()
-             if s["reader"] == "scope_time"}
-    assert len(specs) == 11
-    steady = {"gpt2-124m.steady", "mistral-7b.steady",
-              "mistral-7b-host4.fsdp4", "olmoe-1b-7b.steady",
-              "granite-4.0-h-micro.steady"}
-    top = {s["args"]["scope"] for s in specs.values()
-           if not s["args"].get("nested")}
-    assert top == set(TOP_LEVEL) - {"ssm"}
-    for spec in specs.values():
-        assert spec["unit"] == "ms" and spec["source"] == "device_trace"
-        assert spec["moves"] == "tokens_per_s"
-        if spec["args"].get("nested"):
-            assert spec["args"]["scope"].startswith("moe_")
-            assert spec["workloads"] == ["olmoe-1b-7b.steady"]
-        else:
-            assert set(spec["workloads"]) == steady
+@pytest.mark.parametrize("scope", [n for n in TOP_LEVEL if n != "ssm"])
+def test_every_name_of_the_partition_is_a_metric_that_cells_join(scope):
+    """Membership, not a count: each top-level name but ``ssm`` (read
+    into ``notes.scope_split`` and no metric yet) has one file that
+    names this reader, restricted to the cells that name it."""
+    found = [s for s in cell_files.layer_metric_specs()
+             if s["reader"] == "scope_time"
+             and s["args"] == {"scope": scope}]
+    assert len(found) == 1, scope
+    (spec,) = found
+    assert spec["restricted"] is True and "workloads" not in spec
+    assert spec["unit"] == "ms" and spec["source"] == "device_trace"
+    assert spec["moves"] == "tokens_per_s"
+
+
+def test_an_inner_scope_s_metric_asks_for_the_nested_reading():
+    inner = [s for s in cell_files.layer_metric_specs()
+             if s["reader"] == "scope_time" and s["args"].get("nested")]
+    assert {"moe_route", "moe_experts", "moe_combine", "mla", "kda"} <= {
+        s["args"]["scope"] for s in inner
+    }
+    for spec in inner:
+        assert spec["args"] == {"scope": spec["args"]["scope"], "nested": True}
+        assert spec["args"]["scope"] not in TOP_LEVEL
+        assert spec["unit"] == "ms" and spec["moves"] == "tokens_per_s"
