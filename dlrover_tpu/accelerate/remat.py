@@ -18,7 +18,9 @@ Policies (cfg.remat / Strategy.remat accept these names):
                expert layer the router's logits, the sorted order,
                the rows into and out of the experts and their hidden
                products, and of a state-space mixer its projection,
-               the scan's output and the chunk states
+               the scan's output and the chunk states; what a layer
+               makes for later layers to read (a memory, shared keys
+               and values)
   "attention"  recompute only attention internals
   "dots"       recompute everything except matmul outputs
   "offload"    offload block-boundary residuals (checkpoint_name
@@ -96,9 +98,19 @@ KDA_IN = "kda_in"
 KDA_O = "kda_o"
 KDA_STATES = "kda_states"
 MLA_LATENT = "mla_latent"
+# Of a per-channel selective scan (ops/selective_scan.py): its output
+# and the state every chunk starts from. Of a stack whose later layers
+# read one layer's tensors (models/phi4_flash.py): the scan output
+# that is every gated memory unit's memory and the keys and values
+# every cross-attention layer attends, named where they are made.
+SELSCAN_Y = "selscan_y"
+SELSCAN_STATES = "selscan_states"
+LAYER_MEMORY = "layer_memory"
+SHARED_KV = "shared_kv"
 KEPT = (ATTN_IN, FLASH_O, FLASH_LSE, MLP_HIDDEN, ROUTER_LOGITS,
         MOE_ORDER, MOE_IN, MOE_OUT, SSM_IN, SSD_Y, SSD_STATES,
-        KDA_IN, KDA_O, KDA_STATES, MLA_LATENT)
+        KDA_IN, KDA_O, KDA_STATES, MLA_LATENT, SELSCAN_Y,
+        SELSCAN_STATES, LAYER_MEMORY, SHARED_KV)
 
 POLICY_NAMES = ("none", "full", "attention", "dots", "offload")
 

@@ -31,6 +31,9 @@ in line: that many copies in an executable that compiles for 18 s,
 +2.25%); Granite's and Mellum's unit is a period of two kinds of layer.
 ``models/kimi_linear.py`` and ``models/deepseek_v2.py`` keep rows of
 calls: five or six layers of two or three kinds, a subtree a layer.
+``models/phi4_flash.py`` runs each of its runs of equal units through
+:func:`run`, a unit a pair of layers; what the later layers read of
+two earlier ones enters the unit as a closed-over constant.
 """
 
 from __future__ import annotations

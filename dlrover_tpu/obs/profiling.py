@@ -534,13 +534,18 @@ class CompileTracker:
 # distribution and entropy; "attn_window" and "attn_full" are the two
 # kinds of attention layer of a patterned stack (models/mellum.py),
 # "moe_balance" the balance loss a stack adds beside a held expert
-# layer, "mla_rope" a latent mixer's rotation (models/deepseek_v2.py).
+# layer, "mla_rope" a latent mixer's rotation (models/deepseek_v2.py);
+# "selscan" a per-channel selective scan and "gmu" a gated memory unit
+# (both inside "ssm"), "attn_cross" a layer that attends another
+# layer's keys and values and "attn_diff" what differential attention
+# does outside its two flash calls (models/phi4_flash.py).
 SCOPES = frozenset((
     "accumulate", "layers", "embed", "attn", "mlp", "ssm", "head",
     "optimizer", "moe_route", "moe_experts", "moe_combine", "ssm_conv",
     "ssd", "ssm_norm", "ut_loop", "exit_gate", "kda", "kda_conv",
     "kda_scan", "kda_gate", "mla", "moe_routed", "moe_shared",
     "attn_window", "attn_full", "moe_balance", "mla_rope",
+    "selscan", "gmu", "attn_diff", "attn_cross",
 ))
 _HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
 _HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')

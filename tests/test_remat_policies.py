@@ -472,6 +472,7 @@ class TestRetiredNameAndEvent:
             "router_logits", "moe_order", "moe_in", "moe_out",
             "ssm_in", "ssd_y", "ssd_states",
             "kda_in", "kda_o", "kda_states", "mla_latent",
+            "selscan_y", "selscan_states", "layer_memory", "shared_kv",
         )
         assert remat.BLOCK_OUT not in remat.KEPT
 
